@@ -17,9 +17,6 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
-    jax.config.update("jax_platforms", "cpu")
 import jax.numpy as jnp
 import numpy as np
 

@@ -42,27 +42,22 @@ def _transfer_guard(enabled: bool):
     return no_implicit_transfers()
 
 
-#: where `--compile-cache` lands when the flag is omitted — shared by
-#: every process on the box, namespaced inside by jax version +
-#: backend + topology (compilation_cache.cache_key)
-DEFAULT_COMPILE_CACHE = "~/.cache/paddle_tpu/xla"
-
-
 def _enable_compile_cache(args) -> None:
     """Persistent XLA compile cache, ON BY DEFAULT for serve/train/
     infer (docs/SERVING.md "AOT artifacts & compile cache"): a
     warm-cache restart skips XLA compilation for every jitted body
-    the run builds — the fleet cold-start win `bench.py
-    --serving-only` measures. `--compile-cache DIR` moves it,
-    `--no-compile-cache` opts out. Must run before the first jit
-    compiles, so every cmd_* calls it up front; corrupt or
-    stale-version entries degrade to a miss, never an error."""
+    the run builds. Where `JAX_COMPILATION_CACHE_DIR` is set the
+    cache lives there; otherwise in `--compile-cache DIR`, or by
+    default `<checkout>/.jax_cache`
+    (`compilation_cache.DEFAULT_DIR`). `--no-compile-cache` opts
+    out. Must run before the first jit compiles, so every cmd_*
+    calls it up front; it does not initialise the backend. Corrupt
+    or stale-version entries degrade to a miss, never an error."""
     if getattr(args, "no_compile_cache", False):
         return
     from paddle_tpu import compilation_cache
 
-    compilation_cache.enable(
-        getattr(args, "compile_cache", None) or DEFAULT_COMPILE_CACHE)
+    compilation_cache.enable(getattr(args, "compile_cache", None))
 
 
 def _obs_stack(metrics_out=None, flight_dir=None):
@@ -298,8 +293,7 @@ def cmd_train(args) -> int:
             num_processes=args.num_processes,
             process_id=args.process_id)
 
-    # after the multi-host join (cache keying touches the backend),
-    # before anything compiles
+    # before anything compiles (it does not touch the backend)
     _enable_compile_cache(args)
 
     import jax.numpy as jnp
@@ -518,11 +512,22 @@ def cmd_serve(args) -> int:
             f"{args.config} must define get_serve_config() -> dict "
             "with keys: cfg (TransformerConfig), params; optional: "
             "eos_id, slots, max_len")
-    sc = ns["get_serve_config"]()
-    missing = {"cfg", "params"} - set(sc)
-    if missing:
+    if args.fleet_procs is not None and args.replicas is not None:
         raise SystemExit(
-            f"get_serve_config() is missing {sorted(missing)}")
+            "--fleet-procs and --replicas are mutually exclusive: "
+            "one fleet of threads OR one fleet of processes")
+    # One process per chip: a --fleet-procs parent that built the
+    # model here would initialise the backend and hold the chip its
+    # replica children need. The children run the config themselves
+    # (serve.fleet.build_server_from_config); the parent only checked
+    # above that it defines the entry point, and stays off jax.
+    sc = None
+    if not args.fleet_procs:
+        sc = ns["get_serve_config"]()
+        missing = {"cfg", "params"} - set(sc)
+        if missing:
+            raise SystemExit(
+                f"get_serve_config() is missing {sorted(missing)}")
 
     def make_engine():
         return DecodeEngine(
@@ -533,10 +538,6 @@ def cmd_serve(args) -> int:
                      else args.max_len),
             eos_id=sc.get("eos_id"), seed=args.seed)
 
-    if args.fleet_procs is not None and args.replicas is not None:
-        raise SystemExit(
-            "--fleet-procs and --replicas are mutually exclusive: "
-            "one fleet of threads OR one fleet of processes")
     buckets = (tuple(int(b) for b in args.buckets.split(","))
                if args.buckets else None)
     if args.http is not None:
@@ -548,9 +549,6 @@ def cmd_serve(args) -> int:
     if args.prompts is None:
         raise SystemExit("--prompts is required (unless --http PORT "
                          "serves over the network instead)")
-    # --fleet-procs replicas build their engines IN THE CHILD
-    # processes (serve.fleet builder); the parent never compiles a
-    # pool of its own
     eng = None if args.fleet_procs else make_engine()
 
     with open(args.prompts) as f:
@@ -931,9 +929,8 @@ def _serve_fleet_procs(args, prompts, sampling, buckets, sink):
     # the process boundary; children run their own obs stacks
     registry, _tracer, flight = _obs_stack(args.metrics_out,
                                            args.flight_dir)
-    # children must land on the parent's platform: pass the selection
-    # through the spec env (the child re-asserts it at jax config
-    # level — see serve.fleet._replica_main)
+    # children land on the parent's platform: they inherit its
+    # JAX_PLATFORMS; the spec env restates it with XLA_FLAGS
     env = {k: v for k, v in ((n, os.environ.get(n))
                              for n in ("JAX_PLATFORMS", "XLA_FLAGS"))
            if v is not None}
@@ -1193,9 +1190,9 @@ def build_parser() -> argparse.ArgumentParser:
                         "step tracing + the flight recorder "
                         "(docs/OBSERVABILITY.md)")
     t.add_argument("--compile-cache", default=None, metavar="DIR",
-                   help="persistent XLA compile-cache root (default "
-                        f"{DEFAULT_COMPILE_CACHE}; entries are "
-                        "namespaced by jax version+backend+topology)")
+                   help="persistent XLA compile-cache directory "
+                        "(default <checkout>/.jax_cache; "
+                        "JAX_COMPILATION_CACHE_DIR, where set, wins)")
     t.add_argument("--no-compile-cache", action="store_true",
                    help="disable the persistent compile cache")
     t.add_argument("--coordinator", default=None,
@@ -1252,8 +1249,9 @@ def build_parser() -> argparse.ArgumentParser:
     i.add_argument("--artifact", required=True)
     i.add_argument("--output-prefix", default=None)
     i.add_argument("--compile-cache", default=None, metavar="DIR",
-                   help="persistent XLA compile-cache root (default "
-                        f"{DEFAULT_COMPILE_CACHE})")
+                   help="persistent XLA compile-cache directory "
+                        "(default <checkout>/.jax_cache; "
+                        "JAX_COMPILATION_CACHE_DIR, where set, wins)")
     i.add_argument("--no-compile-cache", action="store_true",
                    help="disable the persistent compile cache")
     i.add_argument("inputs", nargs="+", help=".npy input files")
@@ -1332,9 +1330,10 @@ def build_parser() -> argparse.ArgumentParser:
                     help="write the drain report JSON here on "
                          "graceful shutdown")
     sv.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persistent XLA compile-cache root (default "
-                         f"{DEFAULT_COMPILE_CACHE}; a warm-dir "
-                         "restart skips XLA compilation — "
+                    help="persistent XLA compile-cache directory "
+                         "(default <checkout>/.jax_cache; "
+                         "JAX_COMPILATION_CACHE_DIR, where set, wins; "
+                         "a warm-dir restart skips XLA compilation — "
                          "docs/SERVING.md)")
     sv.add_argument("--no-compile-cache", action="store_true",
                     help="disable the persistent compile cache")

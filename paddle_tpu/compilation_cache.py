@@ -7,11 +7,17 @@ token moved (ROADMAP item 3). jax ships a persistent on-disk
 compilation cache; this module is the ONE place the repo configures
 it, with three production requirements the raw knobs don't enforce:
 
-- **Versioned keys.** Entries are only valid for the (jax version,
-  backend, device topology) that produced them, so the cache root is
-  namespaced by a version key subdirectory. A jax upgrade or a
-  CPU-host pointing at a TPU-host's cache lands in a sibling
-  directory and degrades to a cold cache — never a poisoned one.
+- **Placed from outside, or at one fixed path.** Where
+  `JAX_COMPILATION_CACHE_DIR` is set, jax already reads it into
+  `jax_compilation_cache_dir` and `enable()` sets no directory — only
+  thresholds and listeners — so whoever runs the process (a fleet
+  launcher, the chip tool) decides where entries live and finds them
+  again. Where it is not set, the directory is `<checkout>/.jax_cache`
+  (gitignored): the path is part of jax's cache key, so a directory
+  that moves never hits. jax's own key already covers its version, the
+  backend and the device topology, so there is no namespacing
+  subdirectory — and `enable()` never touches the backend, which keeps
+  a parent process off the chip its children need.
 - **Corrupt/stale entries degrade to a MISS, never an error.**
   `jax_raise_persistent_cache_errors` stays False (asserted, not
   assumed: `enable()` pins it), so a truncated write from a killed
@@ -32,11 +38,12 @@ operational guide.
 from __future__ import annotations
 
 import os
-import re
 import threading
+import warnings
 from typing import Dict, Optional
 
 import jax
+from jax._src import compilation_cache as _jax_cc
 
 #: the cache entries written by a *tiny* test model still matter: a
 #: fleet restart wants EVERY jitted body cached, not just the ones XLA
@@ -52,19 +59,12 @@ _listeners_installed = False
 _counts = {"hits": 0, "requests": 0}
 _enabled_dir: Optional[str] = None
 
-
-def cache_key(backend: Optional[str] = None) -> str:
-    """The versioned namespace for cache entries: jax version +
-    backend + device topology. Anything that changes compiled-code
-    compatibility changes the key, so stale entries are unreachable
-    rather than trusted."""
-    backend = backend or jax.default_backend()
-    try:
-        ndev = jax.device_count()
-    except RuntimeError:
-        ndev = 0
-    raw = f"jax{jax.__version__}-{backend}-d{ndev}"
-    return re.sub(r"[^A-Za-z0-9._-]", "_", raw)
+#: where entries land when the environment does not say: one fixed,
+#: gitignored directory at the root of this checkout
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 
 
 def _on_event(event: str, **kwargs) -> None:
@@ -104,23 +104,36 @@ def counters() -> Dict[str, int]:
 
 
 def enabled_dir() -> Optional[str]:
-    """The versioned directory entries are landing in, or None."""
+    """The directory entries are landing in, or None."""
     return _enabled_dir
 
 
-def enable(cache_dir: str) -> str:
-    """Point jax's persistent compilation cache at
-    `cache_dir/<cache_key()>` and pin the fleet-safe knobs: cache
-    everything (no min compile time / entry size), enable XLA-level
-    subcaches, and NEVER raise on a corrupt entry — a bad read logs
-    a warning and recompiles (tests/test_artifact_cache.py proves
-    it). Returns the versioned directory. Idempotent; call near
-    process start, before the first jit executes, or early compiles
-    simply miss."""
+def enable(cache_dir: Optional[str] = None) -> str:
+    """Turn jax's persistent compilation cache on and pin the
+    fleet-safe knobs: cache everything (no min compile time / entry
+    size), enable XLA-level subcaches, and NEVER raise on a corrupt
+    entry — a bad read logs a warning and recompiles
+    (tests/test_artifact_cache.py proves it).
+
+    The directory: where `JAX_COMPILATION_CACHE_DIR` is set it wins and
+    nothing here writes `jax_compilation_cache_dir` (an explicit
+    `cache_dir` that disagrees is ignored with a warning); otherwise
+    `cache_dir`, or `DEFAULT_DIR`. Returns the directory. Idempotent;
+    does not initialise the backend. Call near process start, before
+    the first jit executes, or early compiles simply miss."""
     global _enabled_dir
-    path = os.path.join(os.path.expanduser(cache_dir), cache_key())
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
+    env_dir = os.environ.get(ENV_VAR)
+    if env_dir:
+        if cache_dir and os.path.abspath(
+                os.path.expanduser(cache_dir)) != os.path.abspath(env_dir):
+            warnings.warn(
+                f"{ENV_VAR}={env_dir!r} places the compile cache; "
+                f"ignoring the requested directory {cache_dir!r}")
+        path = env_dir
+    else:
+        path = os.path.abspath(os.path.expanduser(cache_dir or DEFAULT_DIR))
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       _MIN_COMPILE_TIME_SECS)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes",
@@ -129,30 +142,24 @@ def enable(cache_dir: str) -> str:
     # corrupt/stale entries MUST degrade to a miss (the whole point
     # of a cache a fleet can trust) — pin it, don't assume it
     jax.config.update("jax_raise_persistent_cache_errors", False)
-    _reset_jax_cache_state()
+    # jax latches its cache-backend singleton at the FIRST compile: a
+    # process that compiled anything before `enable()` would silently
+    # never write an entry. Resetting it makes the next compile re-read
+    # the config, so enabling mid-process (tests, notebooks) works.
+    _jax_cc.reset_cache()
     install_listeners()
     _enabled_dir = path
     return path
 
 
-def _reset_jax_cache_state() -> None:
-    """jax latches its cache-backend singleton at the FIRST compile:
-    a process that compiled anything before `enable()` silently never
-    writes an entry (requests are counted, nothing lands). Resetting
-    the singleton makes the next compile re-read the config, so
-    enabling mid-process — tests, notebooks, a server that compiles a
-    probe before parsing flags — actually works."""
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:   # private module: a jax upgrade may move it —
-        pass            # worst case is the old early-compiles-miss
-
-
 def disable() -> None:
     """Turn the persistent cache off (in-memory jit caching is
-    untouched). Counters keep their values for post-mortem reads."""
+    untouched). Counters keep their values for post-mortem reads.
+    A directory placed by the environment is the environment's to
+    remove: with `JAX_COMPILATION_CACHE_DIR` set this only forgets
+    that `enable()` ran."""
     global _enabled_dir
-    jax.config.update("jax_compilation_cache_dir", None)
-    _reset_jax_cache_state()
+    if not os.environ.get(ENV_VAR):
+        jax.config.update("jax_compilation_cache_dir", None)
+    _jax_cc.reset_cache()
     _enabled_dir = None

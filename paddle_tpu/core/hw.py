@@ -1,16 +1,42 @@
-"""TPU hardware constants for the benchmark instruments.
+"""Per-chip peaks, keyed by `device_kind`, and analytic model FLOPs.
 
-ONE definition each — bench.py (the driver-visible headline) and
-benchmarks/suite.py (the full suite) must compute MFU from the same
-peak, or the two driver-visible MFU fields could silently disagree
-after a constant is corrected in only one place.
+ONE table: every utilization or roofline share in the repo divides by a
+row of `PEAKS`, looked up by the `device_kind` JAX reports for the
+device the number was measured on. A device that is not in the table is
+an error, not a default.
 """
 
-# TPU v5e (v5 lite) per-chip peak, bf16 on the MXU.
-V5E_PEAK_TFLOPS = 197.0
+import dataclasses
 
-# TPU v5e per-chip HBM bandwidth.
-V5E_HBM_GBPS = 819.0
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    bf16_tflops: float      # dense bf16 matmul peak, TFLOP/s per chip
+    hbm_gbps: float         # HBM bandwidth, GB/s per chip
+    hbm_gb: float           # HBM capacity, GB per chip
+    source: str
+
+
+PEAKS = {
+    # `jax.devices()[0].device_kind` on a v5e (v5 lite) chip
+    "TPU v5 lite": ChipPeaks(
+        bf16_tflops=197.0, hbm_gbps=819.0, hbm_gb=16.0,
+        source="Google Cloud documentation, 'TPU v5e' system "
+               "architecture: 197 TFLOP/s bf16, 16 GB HBM2e at 819 GB/s "
+               "per chip"),
+}
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"device_kind {device_kind!r} has no row in "
+            f"paddle_tpu.core.hw.PEAKS (known: {sorted(PEAKS)}); add its "
+            "published peaks with their source before reporting a "
+            "utilization on it") from None
+
 
 # Analytic forward GFLOPs per image at 224x224 (2*MACs), for MFU
 # reporting. Train MFU = 3x forward (fwd + ~2x bwd) — remat variants

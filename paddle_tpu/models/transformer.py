@@ -33,6 +33,7 @@ from paddle_tpu.nn import initializers
 from paddle_tpu.ops import linalg
 from paddle_tpu.ops import losses as losses_ops
 from paddle_tpu.ops import norm as norm_ops
+from paddle_tpu.ops import pallas_util
 from paddle_tpu.ops import sampling as sampling_ops
 from paddle_tpu.ops.flash_attention import flash_attention
 from paddle_tpu.parallel.sharding import MEGATRON_RULES, MODEL_AXIS
@@ -254,12 +255,15 @@ def _attention(cfg: TransformerConfig, q, k, v, causal: bool,
                          "that isn't right-padding")
     impl = cfg.attn_impl
     if impl == "auto":
-        # flash ONLY where the Pallas kernel compiles natively — the
-        # same condition ops.flash_attention uses for interpret mode;
+        # flash ONLY where the Pallas kernel compiles natively and the
+        # program lowers for one device (pallas_util.auto_kernel);
         # anywhere else interpret-mode emulation would be far slower
-        # than the dense fallback
-        impl = "flash" if jax.default_backend() == "tpu" else "dense"
+        # than dense, and a partitioned jit cannot hold the kernel
+        impl = "flash" if pallas_util.auto_kernel() else "dense"
     window = cfg.attn_window
+    if impl == "flash" and key_mask is not None:
+        impl = "dense"      # arbitrary masks: the ONE dense path below
+    pallas_util.note_traced("transformer.attention", impl)
     if impl == "flash":
         if key_lens is not None:
             # right-padded variable-length rows ride the kernel's
@@ -268,9 +272,7 @@ def _attention(cfg: TransformerConfig, q, k, v, causal: bool,
             # [B,H,Tq,Tk] dense score tensor
             return flash_attention(q, k, v, causal=causal,
                                    key_lens=key_lens, window=window)
-        if key_mask is None:
-            return flash_attention(q, k, v, causal=causal,
-                                   window=window)
+        return flash_attention(q, k, v, causal=causal, window=window)
     # arbitrary key masks take the dense path — ONE dense
     # implementation decides both masked and unmasked prefills;
     # lens-only callers get the equivalent right-padding mask here

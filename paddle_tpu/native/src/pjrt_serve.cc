@@ -101,8 +101,9 @@ struct Served {
   PJRT_Device* device = nullptr;
 
   // Destructor releases PJRT state so EVERY pts_load failure path (the
-  // unique_ptr unwinding) frees the client — on a single-claim device a
-  // leaked client blocks all later PJRT_Client_Create in this process.
+  // unique_ptr unwinding) frees the client — a chip belongs to one
+  // client at a time, so a leaked one blocks all later
+  // PJRT_Client_Create in this process.
   ~Served() {
     if (exec && api) {
       PJRT_LoadedExecutable_Destroy_Args args;

@@ -167,8 +167,8 @@ class MaxPool2D(Layer):
         # functions reject jvp. None defers to ops.conv.max_pool2d's
         # env-read default (PADDLE_TPU_POOL_TIE_SPLIT), read at TRACE
         # time — one jit compile freezes the choice, so flip the env
-        # only across processes (as benchmarks/probe_pool.py does), not
-        # between jitted calls in one process.
+        # only across processes, not between jitted calls in one
+        # process.
         self.tie_split = tie_split
 
     def _out_hw(self, h, w):

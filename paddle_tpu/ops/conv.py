@@ -327,10 +327,10 @@ def max_pool2d(x, window: IntOr2 = 2, *, stride: Optional[IntOr2] = None,
     differentiability, which custom_vjp functions reject. The default
     (None) reads env PADDLE_TPU_POOL_TIE_SPLIT so the two backward
     formulations can be A/B-benchmarked on the chip without a code
-    edit. Default OFF, now MEASURED (r5 probe_pool A/B, resnet bs64
-    same-protocol: select_and_scatter 28.17 ms vs tie-split 40.18 ms
-    — the custom VJP costs +43% on the full step on v5e, so the
-    default is the faster formulation, results_v5e1.md r5).
+    edit. Default OFF: an earlier builder's same-protocol A/B on a v5e
+    (resnet bs64: select_and_scatter 28.17 ms vs tie-split 40.18 ms,
+    ROADMAP C5) had the custom VJP cost +43% on the full step, so the
+    default is the faster formulation.
     """
     if tie_split is None:
         tie_split = os.environ.get("PADDLE_TPU_POOL_TIE_SPLIT", "0") != "0"

@@ -16,6 +16,10 @@ framework makes fused O(T) -memory attention a first-class op:
 
 On non-TPU backends the kernel runs in Pallas interpret mode (tests) —
 production CPU users should prefer ops in dense form.
+
+The per-row key lengths ride scalar prefetch (`lens [BH] i32` lands in
+SMEM whole, before the grid starts): Mosaic refuses a blocked rank-1
+SMEM operand whose block is neither the array nor a multiple of 128.
 """
 
 from __future__ import annotations
@@ -26,14 +30,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU-specific memory spaces; absent on some builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from paddle_tpu.ops import pallas_util
 
 NEG_INF = -1e30
 
@@ -50,11 +49,13 @@ def _attn_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
     VMEM scratch (acc/m/l) carries streaming-softmax state across k
     steps; only one [BK, D] k/v tile is resident at a time.
 
-    Refs: len [1] i32 (this row's valid key count — t_kv when no key
-    mask; tail padding and right-padded variable-length prompts are the
-    SAME mask); q [1,BQ,D]; k/v [1,BK,D]; o [1,BQ,D]; lse [1,BQ,LANE];
-    scratch acc [BQ,D] f32, m/l [BQ,LANE] f32.
+    Refs: len [BH] i32, scalar-prefetched (row b's valid key count —
+    t_kv when no key mask; tail padding and right-padded
+    variable-length prompts are the SAME mask); q [1,BQ,D]; k/v
+    [1,BK,D]; o [1,BQ,D]; lse [1,BQ,LANE]; scratch acc [BQ,D] f32, m/l
+    [BQ,LANE] f32.
     """
+    n_keys = len_ref[pl.program_id(0)]
     qi = pl.program_id(1)
     j = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -71,7 +72,7 @@ def _attn_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
     # this row's key length (a fully-invalid block is a no-op anyway:
     # p=0, alpha=1 — skipping just saves the dead MXU work; a short row
     # in a long padded batch touches ~len/BK blocks, not ~T/BK)
-    needed = j * block_k < len_ref[0]
+    needed = j * block_k < n_keys
     if causal:
         needed = needed & (j * block_k <= (qi + 1) * bq - 1)
     if window is not None:
@@ -89,7 +90,7 @@ def _attn_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
             preferred_element_type=jnp.float32) * scale  # [BQ, BK]
         kpos = j * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (bq, block_k), 1)
-        valid = kpos < len_ref[0]              # tail padding / key mask
+        valid = kpos < n_keys                  # tail padding / key mask
         if causal:
             qpos = qi * bq + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, block_k), 0)
@@ -133,13 +134,9 @@ def _pad_to(x, size, axis):
 
 
 def _flash_forward(q, k, v, lens, *, causal: bool, block_q: int,
-                   block_k: int, window, interpret: bool):
+                   block_k: int, window):
     """q,k,v: [BH, T, D]; lens: [BH] i32 valid key counts ->
     (o [BH, T, D], lse [BH, T])."""
-    if pltpu is None:
-        raise NotImplementedError(
-            "Pallas TPU support is unavailable in this jax build; use "
-            "parallel.dense_attention instead")
     bh, t, d = q.shape
     t_kv = k.shape[1]
     scale = 1.0 / (d ** 0.5)
@@ -151,40 +148,39 @@ def _flash_forward(q, k, v, lens, *, causal: bool, block_q: int,
     kp = _pad_to(k, tk_pad, 1)
     vp = _pad_to(v, tk_pad, 1)
 
-    grid = (bh, tq_pad // block_q, tk_pad // block_k)
-    kwargs = dict(memory_space=_VMEM) if (_VMEM is not None
-                                          and not interpret) else {}
-    smem = dict(memory_space=pltpu.SMEM) if not interpret else {}
-    scratch = [
-        pltpu.VMEM((block_q, d), jnp.float32),
-        pltpu.VMEM((block_q, _LANE), jnp.float32),
-        pltpu.VMEM((block_q, _LANE), jnp.float32),
-    ]
+    q_map = lambda b, i, j, lens: (b, i, 0)
+    kv_map = lambda b, i, j, lens: (b, j, 0)
+    vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+
     o, lse = pl.pallas_call(
         functools.partial(_attn_kernel, scale=scale, causal=causal,
                           window=window),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, i, j: (b,), **smem),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
-                         **kwargs),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0),
-                         **kwargs),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0),
-                         **kwargs),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
-                         **kwargs),
-            pl.BlockSpec((1, block_q, _LANE), lambda b, i, j: (b, i, 0),
-                         **kwargs),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, tq_pad // block_q, tk_pad // block_k),
+            in_specs=[
+                vmem((1, block_q, d), q_map),
+                vmem((1, block_k, d), kv_map),
+                vmem((1, block_k, d), kv_map),
+            ],
+            out_specs=[
+                vmem((1, block_q, d), q_map),
+                vmem((1, block_q, _LANE), q_map),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((block_q, _LANE), jnp.float32),
+                pltpu.VMEM((block_q, _LANE), jnp.float32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((bh, tq_pad, d), q.dtype),
             jax.ShapeDtypeStruct((bh, tq_pad, _LANE), jnp.float32),
         ],
-        scratch_shapes=scratch,
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=pallas_util.interpret(),
+        name="flash_attention_fwd",
     )(lens.astype(jnp.int32), qp, kp, vp)
     return o[:, :t], lse[:, :t, 0]
 
@@ -311,18 +307,14 @@ def _blockwise_backward(q, k, v, lens, o, lse, g, *, causal: bool,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def _flash(q, k, v, lens_f, causal, block_q, block_k, window):
-    interpret = jax.default_backend() != "tpu"
     o, _ = _flash_forward(q, k, v, lens_f, causal=causal, block_q=block_q,
-                          block_k=block_k, window=window,
-                          interpret=interpret)
+                          block_k=block_k, window=window)
     return o
 
 
 def _flash_fwd(q, k, v, lens_f, causal, block_q, block_k, window):
-    interpret = jax.default_backend() != "tpu"
     o, lse = _flash_forward(q, k, v, lens_f, causal=causal, block_q=block_q,
-                            block_k=block_k, window=window,
-                            interpret=interpret)
+                            block_k=block_k, window=window)
     return o, (q, k, v, lens_f, o, lse)
 
 

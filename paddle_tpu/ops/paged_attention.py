@@ -35,10 +35,10 @@ int8 KV pools ride through unchanged: an arena may be an
 `(s8 data, f32 scale)` pair — THE per-(position, kv-head) absmax
 convention (`kv_quantize` below, shared with the dense caches via
 `transformer._kv_quantize`) quantizes at write and dequantizes inside
-the gathered read. On the fused kernel path the same dequant runs
-per page block on VMEM scratch as each DMA lands
-(ops.ragged_paged_attention._walk_kernel_int8) — identical element
-math, so both reads stay bit-equal.
+the gathered read. The fused kernel (interpret mode only today, see
+ops.ragged_paged_attention) runs the same dequant per page block on
+VMEM scratch as each DMA lands — identical element math, so both reads
+stay bit-equal.
 """
 
 from __future__ import annotations
@@ -251,12 +251,11 @@ def paged_verify_attention(q, k, v, k_arena, v_arena, page_table, pos,
 
 def _ragged_read(q, k_arena, v_arena, page_table, pos0, active, *,
                  page_size: int, max_len: int, impl=None):
-    """The shared read+attend tail: dispatch through the fused ragged
-    kernel (ops.ragged_paged_attention), whose auto mode returns the
-    bit-identical jnp gather everywhere the kernel isn't a win — the
-    drop-in upgrade this module's header promised, with nothing above
-    it changing. int8 `(s8, scale)` arenas take the dequant-fused
-    kernel under the same auto gate."""
+    """The shared read+attend tail: dispatch through
+    ops.ragged_paged_attention, whose auto mode (impl=None) is the jnp
+    gather on every backend until a fused kernel the TPU compiler
+    accepts takes that slot — the seam this module's header promised,
+    with nothing above it changing."""
     from paddle_tpu.ops import ragged_paged_attention as _rpa  # cycle
 
     return _rpa.ragged_attention(q, k_arena, v_arena, page_table,

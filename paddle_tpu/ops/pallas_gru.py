@@ -22,16 +22,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from paddle_tpu.ops import pallas_util
 from paddle_tpu.ops.pallas_lstm import (  # shared plumbing
-    _sigmoid, _specs, _step_mask, pl, pltpu)
+    _sigmoid, _specs, _step_mask, _time_loop_params, fused_fits_vmem,
+    pl, pltpu)
 
 
-def fits_vmem(b: int, hidden: int) -> bool:
-    """Backward-pass residency: W_hh + W_hh^T (bf16) + dW (f32) + [B,3H]
-    gate tiles + [B,H] carries under ~12 MB."""
-    whh_bytes = hidden * 3 * hidden * (2 + 2 + 4)
-    tiles = 4 * (b * 3 * hidden) * 4 + 8 * (b * hidden) * 4
-    return whh_bytes + tiles < 12 * 1024 * 1024
+def fits_vmem(b: int, hidden: int, w_itemsize: int) -> bool:
+    return fused_fits_vmem(b, hidden, 3, w_itemsize)
 
 
 def _fwd_kernel(xp_ref, whh_ref, h0_ref, bounds_ref, hs_ref, h_scr,
@@ -104,76 +102,81 @@ def _bwd_kernel(xp_ref, whh_ref, whht_ref, hsp_ref, dhs_ref, h0_ref,
         preferred_element_type=jnp.float32)
 
 
-def _fwd(x_proj, w_hh, h0, bounds, interpret):
+def _fwd(x_proj, w_hh, h0, bounds):
     t, b, g3 = x_proj.shape
     h = g3 // 3
     return pl.pallas_call(
         functools.partial(_fwd_kernel, hidden=h),
         grid=(t,),
         in_specs=[
-            _specs((1, b, g3), lambda i: (i, 0, 0), interpret),
-            _specs((h, g3), lambda i: (0, 0), interpret),
-            _specs((b, h), lambda i: (0, 0), interpret),
-            _specs((b, 2), lambda i: (0, 0), interpret),
+            _specs((1, b, g3), lambda i: (i, 0, 0)),
+            _specs((h, g3), lambda i: (0, 0)),
+            _specs((b, h), lambda i: (0, 0)),
+            _specs((b, 2), lambda i: (0, 0)),
         ],
-        out_specs=_specs((1, b, h), lambda i: (i, 0, 0), interpret),
+        out_specs=_specs((1, b, h), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((t, b, h), jnp.float32),
         scratch_shapes=[pltpu.VMEM((b, h), jnp.float32)],
-        interpret=interpret,
+        compiler_params=_time_loop_params(),
+        interpret=pallas_util.interpret(),
+        name="fused_gru_fwd",
     )(x_proj, w_hh, h0, bounds)
 
 
 @jax.custom_vjp
 def fused_gru(x_proj, w_hh, h0, bounds):
     """Fused scan: returns (hs [T,B,H] f32, h_last [B,H])."""
-    interpret = jax.default_backend() != "tpu"
-    hs = _fwd(x_proj, w_hh, h0, bounds, interpret)
+    hs = _fwd(x_proj, w_hh, h0, bounds)
     return hs, hs[-1].astype(h0.dtype)
 
 
 def _fused_fwd(x_proj, w_hh, h0, bounds):
-    interpret = jax.default_backend() != "tpu"
-    hs = _fwd(x_proj, w_hh, h0, bounds, interpret)
+    hs = _fwd(x_proj, w_hh, h0, bounds)
     return (hs, hs[-1].astype(h0.dtype)), (x_proj, w_hh, h0, bounds, hs)
 
 
 def _fused_bwd(res, cts):
     x_proj, w_hh, h0, bounds, hs = res
     dhs, dh_last = cts
-    interpret = jax.default_backend() != "tpu"
+    dxp, dwhh, dh0 = _bwd(x_proj, w_hh, w_hh.T, hs, dhs, h0, bounds,
+                          jnp.asarray(dh_last))
+    return dxp, dwhh.astype(w_hh.dtype), dh0.astype(h0.dtype), None
+
+
+def _bwd(x_proj, w_hh, w_hh_t, hs, dhs, h0, bounds, dh_last):
     t, b, g3 = x_proj.shape
     h = g3 // 3
-    w_hh_t = w_hh.T
 
     rev = lambda i: (t - 1 - i, 0, 0)
     rev_prev = lambda i: (jnp.maximum(t - 2 - i, 0), 0, 0)
     const2 = lambda i: (0, 0)
-    dxp, dwhh, dh0 = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_bwd_kernel, hidden=h, steps=t),
         grid=(t,),
         in_specs=[
-            _specs((1, b, g3), rev, interpret),        # x_proj
-            _specs((h, g3), const2, interpret),        # w_hh
-            _specs((g3, h), const2, interpret),        # w_hh^T
-            _specs((1, b, h), rev_prev, interpret),    # hs at t-1
-            _specs((1, b, h), rev, interpret),         # dhs
-            _specs((b, h), const2, interpret),         # h0
-            _specs((b, 2), const2, interpret),         # bounds
-            _specs((b, h), const2, interpret),         # dh_last
+            _specs((1, b, g3), rev),        # x_proj
+            _specs((h, g3), const2),        # w_hh
+            _specs((g3, h), const2),        # w_hh^T
+            _specs((1, b, h), rev_prev),    # hs at t-1
+            _specs((1, b, h), rev),         # dhs
+            _specs((b, h), const2),         # h0
+            _specs((b, 2), const2),         # bounds
+            _specs((b, h), const2),         # dh_last
         ],
         out_specs=[
-            _specs((1, b, g3), rev, interpret),
-            _specs((h, g3), const2, interpret),
-            _specs((b, h), const2, interpret),
+            _specs((1, b, g3), rev),
+            _specs((h, g3), const2),
+            _specs((b, h), const2),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((t, b, g3), x_proj.dtype),
             jax.ShapeDtypeStruct((h, g3), jnp.float32),
             jax.ShapeDtypeStruct((b, h), jnp.float32),
         ],
-        interpret=interpret,
-    )(x_proj, w_hh, w_hh_t, hs, dhs, h0, bounds, jnp.asarray(dh_last))
-    return dxp, dwhh.astype(w_hh.dtype), dh0.astype(h0.dtype), None
+        compiler_params=_time_loop_params(),
+        interpret=pallas_util.interpret(),
+        name="fused_gru_bwd",
+    )(x_proj, w_hh, w_hh_t, hs, dhs, h0, bounds, dh_last)
 
 
 fused_gru.defvjp(_fused_fwd, _fused_bwd)

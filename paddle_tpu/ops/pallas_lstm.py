@@ -2,9 +2,9 @@
 
 Why: the XLA `lax.scan` LSTM round-trips the (h, c) carry and the gate
 tensors through HBM every step and pays while-loop overhead per
-iteration — the round-1 chip trace showed ~97 us/step where the
-recurrence FLOPs justify ~0.1 us (benchmarks/results_v5e1.md lstm rows,
-the reference's published RNN benchmark, benchmark/paddle/rnn/run.sh).
+iteration — an early chip trace showed ~97 us/step where the
+recurrence FLOPs justify ~0.1 us (ROADMAP A3; the reference's published
+RNN benchmark is benchmark/paddle/rnn/run.sh).
 This kernel runs the WHOLE time loop in one pallas_call: W_hh stays
 resident in VMEM, (h, c) live in VMEM scratch across grid steps (the
 TPU grid is sequential), and only x_proj / hs / cs stream from/to HBM.
@@ -24,8 +24,10 @@ index maps (no shifted copies).
 Shapes: x_proj [T, B, 4H] (the hoisted input projection — see
 ops.rnn.lstm), w_hh [H, 4H], h0/c0 [B, H], bounds [B, 2] i32. Gate
 order i, f, g, o (matches ops.rnn.lstm_step_from_proj). Sized for VMEM
-(see fits_vmem): h=512 fits at B<=64, h=256 at B<=256; the auto path
-falls back to the scan for bigger shapes.
+(see fused_fits_vmem): every call states its own `vmem_limit_bytes`,
+because the backward's resident set (both W_hh layouts and the f32 dW
+accumulator, each double-buffered by the pipeline) passes the
+compiler's 16 MiB default scope already at h=512.
 """
 
 from __future__ import annotations
@@ -36,12 +38,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:  # same guard as ops.flash_attention
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pl = None
-    pltpu = None
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from paddle_tpu.ops import pallas_util
 
 
 def _sigmoid(x):
@@ -138,28 +138,46 @@ def _bwd_kernel(xp_ref, whh_ref, whht_ref, hsp_ref, csp_ref, cs_ref,
         preferred_element_type=jnp.float32)
 
 
-def _specs(block, index_map, interpret):
-    kwargs = {} if (pltpu is None or interpret) else dict(
-        memory_space=pltpu.VMEM)
-    return pl.BlockSpec(block, index_map, **kwargs)
+def _specs(block, index_map):
+    return pl.BlockSpec(block, index_map, memory_space=pltpu.VMEM)
 
 
-def _fwd(x_proj, w_hh, h0, c0, bounds, interpret):
+def _time_loop_params():
+    """The grid is the time loop: sequential, carries in VMEM."""
+    return pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",),
+        vmem_limit_bytes=pallas_util.VMEM_LIMIT_BYTES)
+
+
+def fused_fits_vmem(b: int, hidden: int, gates: int, w_itemsize: int) -> bool:
+    """Residency of the WORST pass (backward) of a fused time loop with
+    `gates`*H gate columns: W_hh and W_hh^T (w_itemsize) and the f32 dW
+    accumulator stay resident and the pipeline holds two buffers of
+    each; around them ~16 [B, gates*H] f32 tiles of streams and gate
+    temporaries. LSTM h=512 B=64 bf16 comes to 24 MiB, h=1280 to over
+    100 MiB (scan path)."""
+    w = hidden * gates * hidden
+    resident = 2 * (2 * w * w_itemsize + w * 4)
+    tiles = 16 * b * gates * hidden * 4
+    return resident + tiles <= pallas_util.VMEM_BUDGET_BYTES
+
+
+def _fwd(x_proj, w_hh, h0, c0, bounds):
     t, b, g4 = x_proj.shape
     h = g4 // 4
     hs, cs = pl.pallas_call(
         functools.partial(_fwd_kernel, hidden=h),
         grid=(t,),
         in_specs=[
-            _specs((1, b, g4), lambda i: (i, 0, 0), interpret),
-            _specs((h, g4), lambda i: (0, 0), interpret),
-            _specs((b, h), lambda i: (0, 0), interpret),
-            _specs((b, h), lambda i: (0, 0), interpret),
-            _specs((b, 2), lambda i: (0, 0), interpret),
+            _specs((1, b, g4), lambda i: (i, 0, 0)),
+            _specs((h, g4), lambda i: (0, 0)),
+            _specs((b, h), lambda i: (0, 0)),
+            _specs((b, h), lambda i: (0, 0)),
+            _specs((b, 2), lambda i: (0, 0)),
         ],
         out_specs=[
-            _specs((1, b, h), lambda i: (i, 0, 0), interpret),
-            _specs((1, b, h), lambda i: (i, 0, 0), interpret),
+            _specs((1, b, h), lambda i: (i, 0, 0)),
+            _specs((1, b, h), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((t, b, h), x_proj.dtype),
@@ -169,7 +187,9 @@ def _fwd(x_proj, w_hh, h0, c0, bounds, interpret):
             pltpu.VMEM((b, h), jnp.float32),
             pltpu.VMEM((b, h), jnp.float32),
         ],
-        interpret=interpret,
+        compiler_params=_time_loop_params(),
+        interpret=pallas_util.interpret(),
+        name="fused_lstm_fwd",
     )(x_proj, w_hh, h0, c0, bounds)
     return hs, cs
 
@@ -177,14 +197,12 @@ def _fwd(x_proj, w_hh, h0, c0, bounds, interpret):
 @jax.custom_vjp
 def fused_lstm(x_proj, w_hh, h0, c0, bounds):
     """Fused scan: returns (hs [T,B,H], h_last [B,H], c_last [B,H])."""
-    interpret = jax.default_backend() != "tpu"
-    hs, cs = _fwd(x_proj, w_hh, h0, c0, bounds, interpret)
+    hs, cs = _fwd(x_proj, w_hh, h0, c0, bounds)
     return hs, hs[-1], cs[-1].astype(c0.dtype)
 
 
 def _fused_fwd(x_proj, w_hh, h0, c0, bounds):
-    interpret = jax.default_backend() != "tpu"
-    hs, cs = _fwd(x_proj, w_hh, h0, c0, bounds, interpret)
+    hs, cs = _fwd(x_proj, w_hh, h0, c0, bounds)
     return ((hs, hs[-1], cs[-1].astype(c0.dtype)),
             (x_proj, w_hh, h0, c0, bounds, hs, cs))
 
@@ -192,11 +210,18 @@ def _fused_fwd(x_proj, w_hh, h0, c0, bounds):
 def _fused_bwd(res, cts):
     x_proj, w_hh, h0, c0, bounds, hs, cs = res
     dhs, dh_last, dc_last = cts
-    interpret = jax.default_backend() != "tpu"
+    dxp, dwhh, dh0, dc0 = _bwd(
+        x_proj, w_hh, w_hh.T, hs, cs, dhs, h0, c0, bounds,
+        jnp.asarray(dh_last), jnp.asarray(dc_last))
+    return (dxp, dwhh.astype(w_hh.dtype), dh0.astype(h0.dtype),
+            dc0.astype(c0.dtype), None)
+
+
+def _bwd(x_proj, w_hh, w_hh_t, hs, cs, dhs, h0, c0, bounds,
+         dh_last, dc_last):
     t, b, g4 = x_proj.shape
     h = g4 // 4
     f32 = jnp.float32
-    w_hh_t = w_hh.T
 
     rev = lambda i: (t - 1 - i, 0, 0)
     # the SAME hs/cs arrays shifted one step back — no concat copies;
@@ -204,28 +229,28 @@ def _fused_bwd(res, cts):
     # kernel selects h0/c0 instead (see _bwd_kernel)
     rev_prev = lambda i: (jnp.maximum(t - 2 - i, 0), 0, 0)
     const2 = lambda i: (0, 0)
-    dxp, dwhh, dh0, dc0 = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_bwd_kernel, hidden=h, steps=t),
         grid=(t,),
         in_specs=[
-            _specs((1, b, g4), rev, interpret),          # x_proj
-            _specs((h, g4), const2, interpret),          # w_hh
-            _specs((g4, h), const2, interpret),          # w_hh^T
-            _specs((1, b, h), rev_prev, interpret),      # hs at t-1
-            _specs((1, b, h), rev_prev, interpret),      # cs at t-1
-            _specs((1, b, h), rev, interpret),           # cs
-            _specs((1, b, h), rev, interpret),           # dhs
-            _specs((b, h), const2, interpret),           # h0
-            _specs((b, h), const2, interpret),           # c0
-            _specs((b, 2), const2, interpret),           # bounds
-            _specs((b, h), const2, interpret),           # dh_last
-            _specs((b, h), const2, interpret),           # dc_last
+            _specs((1, b, g4), rev),          # x_proj
+            _specs((h, g4), const2),          # w_hh
+            _specs((g4, h), const2),          # w_hh^T
+            _specs((1, b, h), rev_prev),      # hs at t-1
+            _specs((1, b, h), rev_prev),      # cs at t-1
+            _specs((1, b, h), rev),           # cs
+            _specs((1, b, h), rev),           # dhs
+            _specs((b, h), const2),           # h0
+            _specs((b, h), const2),           # c0
+            _specs((b, 2), const2),           # bounds
+            _specs((b, h), const2),           # dh_last
+            _specs((b, h), const2),           # dc_last
         ],
         out_specs=[
-            _specs((1, b, g4), rev, interpret),
-            _specs((h, g4), const2, interpret),
-            _specs((b, h), const2, interpret),
-            _specs((b, h), const2, interpret),
+            _specs((1, b, g4), rev),
+            _specs((h, g4), const2),
+            _specs((b, h), const2),
+            _specs((b, h), const2),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((t, b, g4), x_proj.dtype),
@@ -233,11 +258,11 @@ def _fused_bwd(res, cts):
             jax.ShapeDtypeStruct((b, h), f32),
             jax.ShapeDtypeStruct((b, h), f32),
         ],
-        interpret=interpret,
+        compiler_params=_time_loop_params(),
+        interpret=pallas_util.interpret(),
+        name="fused_lstm_bwd",
     )(x_proj, w_hh, w_hh_t, hs, cs, cs, dhs, h0, c0, bounds,
-      jnp.asarray(dh_last), jnp.asarray(dc_last))
-    return (dxp, dwhh.astype(w_hh.dtype), dh0.astype(h0.dtype),
-            dc0.astype(c0.dtype), None)
+      dh_last, dc_last)
 
 
 fused_lstm.defvjp(_fused_fwd, _fused_bwd)
@@ -256,12 +281,5 @@ def make_bounds(b: int, t: int, lengths, reverse: bool):
     return jnp.concatenate([lo, hi], axis=1)
 
 
-def fits_vmem(b: int, hidden: int) -> bool:
-    """Conservative residency check for the WORST pass (backward):
-    W_hh (bf16) + W_hh^T (bf16) + dW accumulator (f32) stay resident,
-    plus a handful of [B,4H] f32 gate tiles and [B,H] f32 carries,
-    against a ~12 MB budget of the ~16 MB VMEM. h=512 fits at B<=64;
-    h=256 at B<=256."""
-    whh_bytes = hidden * 4 * hidden * (2 + 2 + 4)
-    tiles = 4 * (b * 4 * hidden) * 4 + 8 * (b * hidden) * 4
-    return whh_bytes + tiles < 12 * 1024 * 1024
+def fits_vmem(b: int, hidden: int, w_itemsize: int) -> bool:
+    return fused_fits_vmem(b, hidden, 4, w_itemsize)

@@ -13,14 +13,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from paddle_tpu.ops import pallas_util
 from paddle_tpu.ops.pallas_lstm import (  # shared plumbing
-    _specs, _step_mask, pl, pltpu)
+    _specs, _step_mask, _time_loop_params, fused_fits_vmem, pl, pltpu)
 
 
-def fits_vmem(b: int, hidden: int) -> bool:
-    whh_bytes = hidden * hidden * (2 + 2 + 4)
-    tiles = 4 * (b * hidden) * 4 + 8 * (b * hidden) * 4
-    return whh_bytes + tiles < 12 * 1024 * 1024
+def fits_vmem(b: int, hidden: int, w_itemsize: int) -> bool:
+    return fused_fits_vmem(b, hidden, 1, w_itemsize)
 
 
 def _fwd_kernel(xp_ref, whh_ref, h0_ref, bounds_ref, hs_ref, h_scr):
@@ -72,70 +71,75 @@ def _bwd_kernel(whht_ref, hs_ref, hsp_ref, dhs_ref, h0_ref, bounds_ref,
 @jax.custom_vjp
 def fused_simple_rnn(x_proj, w_hh, h0, bounds):
     """Fused scan: returns (hs [T,B,H] f32, h_last [B,H])."""
-    interpret = jax.default_backend() != "tpu"
-    hs = _run_fwd(x_proj, w_hh, h0, bounds, interpret)
+    hs = _run_fwd(x_proj, w_hh, h0, bounds)
     return hs, hs[-1].astype(h0.dtype)
 
 
-def _run_fwd(x_proj, w_hh, h0, bounds, interpret):
+def _run_fwd(x_proj, w_hh, h0, bounds):
     t, b, h = x_proj.shape
     return pl.pallas_call(
         _fwd_kernel,
         grid=(t,),
         in_specs=[
-            _specs((1, b, h), lambda i: (i, 0, 0), interpret),
-            _specs((h, h), lambda i: (0, 0), interpret),
-            _specs((b, h), lambda i: (0, 0), interpret),
-            _specs((b, 2), lambda i: (0, 0), interpret),
+            _specs((1, b, h), lambda i: (i, 0, 0)),
+            _specs((h, h), lambda i: (0, 0)),
+            _specs((b, h), lambda i: (0, 0)),
+            _specs((b, 2), lambda i: (0, 0)),
         ],
-        out_specs=_specs((1, b, h), lambda i: (i, 0, 0), interpret),
+        out_specs=_specs((1, b, h), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((t, b, h), jnp.float32),
         scratch_shapes=[pltpu.VMEM((b, h), jnp.float32)],
-        interpret=interpret,
+        compiler_params=_time_loop_params(),
+        interpret=pallas_util.interpret(),
+        name="fused_rnn_fwd",
     )(x_proj, w_hh, h0, bounds)
 
 
 def _fused_fwd(x_proj, w_hh, h0, bounds):
-    interpret = jax.default_backend() != "tpu"
-    hs = _run_fwd(x_proj, w_hh, h0, bounds, interpret)
+    hs = _run_fwd(x_proj, w_hh, h0, bounds)
     return (hs, hs[-1].astype(h0.dtype)), (x_proj, w_hh, h0, bounds, hs)
 
 
 def _fused_bwd(res, cts):
     x_proj, w_hh, h0, bounds, hs = res
     dhs, dh_last = cts
-    interpret = jax.default_backend() != "tpu"
-    t, b, h = x_proj.shape
-    w_hh_t = w_hh.T
+    dxp, dwhh, dh0 = _run_bwd(w_hh.T, hs, dhs, h0, bounds,
+                              jnp.asarray(dh_last), xp_dtype=x_proj.dtype)
+    return dxp, dwhh.astype(w_hh.dtype), dh0.astype(h0.dtype), None
+
+
+def _run_bwd(w_hh_t, hs, dhs, h0, bounds, dh_last, *, xp_dtype):
+    t, b, h = hs.shape
 
     rev = lambda i: (t - 1 - i, 0, 0)
     rev_prev = lambda i: (jnp.maximum(t - 2 - i, 0), 0, 0)
     const2 = lambda i: (0, 0)
-    dxp, dwhh, dh0 = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_bwd_kernel, steps=t),
         grid=(t,),
         in_specs=[
-            _specs((h, h), const2, interpret),       # w_hh^T
-            _specs((1, b, h), rev, interpret),       # hs
-            _specs((1, b, h), rev_prev, interpret),  # hs at t-1
-            _specs((1, b, h), rev, interpret),       # dhs
-            _specs((b, h), const2, interpret),       # h0
-            _specs((b, 2), const2, interpret),       # bounds
-            _specs((b, h), const2, interpret),       # dh_last
+            _specs((h, h), const2),       # w_hh^T
+            _specs((1, b, h), rev),       # hs
+            _specs((1, b, h), rev_prev),  # hs at t-1
+            _specs((1, b, h), rev),       # dhs
+            _specs((b, h), const2),       # h0
+            _specs((b, 2), const2),       # bounds
+            _specs((b, h), const2),       # dh_last
         ],
         out_specs=[
-            _specs((1, b, h), rev, interpret),
-            _specs((h, h), const2, interpret),
-            _specs((b, h), const2, interpret),
+            _specs((1, b, h), rev),
+            _specs((h, h), const2),
+            _specs((b, h), const2),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((t, b, h), x_proj.dtype),
+            jax.ShapeDtypeStruct((t, b, h), xp_dtype),
             jax.ShapeDtypeStruct((h, h), jnp.float32),
             jax.ShapeDtypeStruct((b, h), jnp.float32),
         ],
-        interpret=interpret,
-    )(w_hh_t, hs, hs, dhs, h0, bounds, jnp.asarray(dh_last))
-    return dxp, dwhh.astype(w_hh.dtype), dh0.astype(h0.dtype), None
+        compiler_params=_time_loop_params(),
+        interpret=pallas_util.interpret(),
+        name="fused_rnn_bwd",
+    )(w_hh_t, hs, hs, dhs, h0, bounds, dh_last)
 
 
 fused_simple_rnn.defvjp(_fused_fwd, _fused_bwd)

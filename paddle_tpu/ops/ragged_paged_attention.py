@@ -32,14 +32,26 @@ concurrent users per chip) no longer forfeit the fused read.
 
 Parity contract: `ragged_reference` below IS the jnp oracle — the same
 gather + `grouped_masked_attention` the engine has always run (its
-int8 branch is the gather+`kv_dequantize` read) — and the kernel must
-match it BIT-FOR-BIT for float AND int8 arenas
-(tests/test_ragged_attention.py, tests/test_ragged_int8.py; run in
-interpret mode on CPU since the bench chip gate is wedged: the
-interpret path executes the same XLA CPU primitives as the oracle, so
-bit-identity is meaningful evidence, not a tolerance check). The jnp
-path stays the default fallback off-TPU and whenever the walk (int8
-data + scale planes + dequant scratch included) would overflow VMEM.
+int8 branch is the gather+`kv_dequantize` read) — and in interpret mode
+the kernel matches it BIT-FOR-BIT for float AND int8 arenas
+(tests/test_ragged_attention.py, tests/test_ragged_int8.py): the
+interpret path executes the same XLA CPU primitives as the oracle.
+
+Status on the chip: DESELECTED. Mosaic refuses this kernel on a TPU
+v5e (jax 0.9.0 / libtpu 0.0.34; docs/KERNELS.md has the messages): the
+attend body is the oracle's 5-D grouped einsum, which `tpu.matmul`
+rejects ("Expected matmul acc to be 32-bit" for bf16, "Up to 1 batch
+dim supported" for f32), and with per-KV-head 2-D dots in its place the
+page DMA itself is refused at head_dim 64 ("Slice shape along dimension
+3 must be aligned to tiling (128), but is 64"): the `[P, page, Hkv,
+Dh]` arena puts (Hkv, Dh) on the tiled minor dims. So
+`ragged_attention(impl=None)` is the jnp gather on every backend, by a
+static decision, and `impl="pallas"` on a TPU fails at trace time with
+the compiler's own words. The kernel that replaces this one (ROADMAP
+A2a: a blocked walk with online softmax over a head-major arena) takes
+over the `impl=None` slot; until then the kernel stays as the
+interpret-mode reference for the walk's addressing and the fused int8
+dequant.
 
 Writes are NOT fused: scatters through the page table are cheap
 (`write_kv` is a drop-mode scatter of a few rows — it already
@@ -55,53 +67,19 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from paddle_tpu.ops import pallas_util
 from paddle_tpu.ops.paged_attention import (
     gather_kv,
     grouped_masked_attention,
 )
 
-try:  # pallas ships with jax, but keep the jnp oracle importable without it
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    PALLAS_AVAILABLE = True
-except Exception:  # pragma: no cover - exercised only on pallas-less builds
-    pl = None
-    pltpu = None
-    PALLAS_AVAILABLE = False
-
-# scratch budget: K and V page walks both live in VMEM at once; leave
-# headroom under the ~16 MB/core ceiling for the q/out blocks and the
-# score intermediates (same gate idiom as ops.pallas_lstm.fits_vmem)
-_VMEM_BUDGET_BYTES = 12 * 1024 * 1024
-
-
 def _num_key_blocks(page_size: int, max_len: int, max_pages: int) -> int:
     """Blocks that can hold keys the `max_len` slice exposes — the walk
     never fetches pages entirely beyond the oracle's static slice."""
     return min(max_pages, -(-max_len // page_size))
-
-
-def fits_vmem(k_arena, page_table, *, page_size: int, max_len: int) -> bool:
-    """True when both per-row page walks fit the VMEM scratch budget.
-
-    Float arenas cost one data block per page per side. Int8 `(s8,
-    scale)` pairs cost the s8 data block + the f32 scale plane + the
-    dequantized block (budgeted at f32 — the widest dtype the engine
-    dequantizes to, so the gate can't admit a walk a bf16 engine fits
-    but an f32 one doesn't)."""
-    nblk = _num_key_blocks(page_size, max_len, page_table.shape[1])
-    if isinstance(k_arena, tuple):
-        data, scale = k_arena
-        _, page, hkv, dh = data.shape
-        per_walk = nblk * page * hkv * (
-            dh * data.dtype.itemsize        # s8 arena block
-            + scale.dtype.itemsize          # per-(position, head) scale
-            + dh * 4)                       # dequant scratch (f32 bound)
-    else:
-        _, page, hkv, dh = k_arena.shape
-        per_walk = nblk * page * hkv * dh * k_arena.dtype.itemsize
-    return 2 * per_walk <= _VMEM_BUDGET_BYTES
 
 
 # -- the jnp oracle ------------------------------------------------------
@@ -231,16 +209,11 @@ def _walk_kernel_int8(page_size, max_len, nblk,
 
 
 def ragged_pallas(q, k_arena, v_arena, page_table, pos0, active, *,
-                  page_size: int, max_len: int, interpret=None):
-    """The fused launch. interpret=None follows the repo's Pallas idiom
-    (interpret everywhere except a real TPU backend). Accepts float
-    arenas AND int8 `(s8, scale)` pairs — dispatch through
-    `ragged_attention` for the general case."""
-    if not PALLAS_AVAILABLE:  # pragma: no cover
-        raise RuntimeError("pallas is unavailable on this build; "
-                           "use ragged_attention (jnp fallback)")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+                  page_size: int, max_len: int):
+    """The fused launch (interpreted everywhere except on a TPU
+    backend, see `pallas_util`). Accepts float arenas AND int8
+    `(s8, scale)` pairs."""
+    interpret = pallas_util.interpret()
     r, tq, h, dh = q.shape
     quantized = isinstance(k_arena, tuple)
     k_data = k_arena[0] if quantized else k_arena
@@ -250,7 +223,7 @@ def ragged_pallas(q, k_arena, v_arena, page_table, pos0, active, *,
     meta = jnp.stack([pos0.astype(jnp.int32),
                       active.astype(jnp.int32)], axis=1)
     q_spec = pl.BlockSpec((1, tq, h, dh), lambda i, pt, mt: (i, 0, 0, 0))
-    hbm = pl.BlockSpec(memory_space=pltpu.ANY)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     if quantized:
         (kd, ks), (vd, vs) = k_arena, v_arena
         grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -300,23 +273,15 @@ def ragged_pallas(q, k_arena, v_arena, page_table, pos0, active, *,
 
 def ragged_attention(q, k_arena, v_arena, page_table, pos0, active, *,
                      page_size: int, max_len: int, impl=None):
-    """Dispatch: impl in {None, "jnp", "pallas"}. None auto-selects the
-    kernel only where it genuinely wins — a real TPU backend and a
-    walk that fits VMEM (float arenas and int8 `(s8, scale)` pairs
-    alike; the int8 gate budgets data + scale planes + dequant
-    scratch) — and the jnp oracle everywhere else, so CPU tier-1 is
-    byte-for-byte unchanged. impl="pallas" forces the kernel
-    (interpret mode off-TPU — the parity suite's and the int8 serving
-    parity tests' lever); impl="jnp" forces the oracle."""
-    if impl == "jnp":
-        pass
-    elif impl is None:
-        on_tpu = PALLAS_AVAILABLE and jax.default_backend() == "tpu"
-        impl = "pallas" if on_tpu and fits_vmem(
-            k_arena, page_table, page_size=page_size,
-            max_len=max_len) else "jnp"
-    elif impl == "pallas" and not PALLAS_AVAILABLE:  # pragma: no cover
-        impl = "jnp"
+    """Dispatch: impl in {None, "jnp", "pallas"}. None and "jnp" are
+    the gather-then-attend oracle on every backend: the fused kernel is
+    deselected statically because the TPU compiler refuses it (module
+    docstring). impl="pallas" forces the kernel — interpret mode
+    off-TPU, which is the parity suites' lever; on a TPU it raises the
+    compiler's error at trace time, never a quiet substitute."""
+    if impl not in (None, "jnp", "pallas"):
+        raise ValueError(f"impl must be None|jnp|pallas, got {impl!r}")
+    pallas_util.note_traced("ragged_attention", impl or "jnp")
     if impl == "pallas":
         return ragged_pallas(q, k_arena, v_arena, page_table, pos0,
                              active, page_size=page_size,

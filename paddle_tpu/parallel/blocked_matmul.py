@@ -35,7 +35,7 @@ dense layer — the opt-in consumer seam used by `parallel.pipeline`'s
 `tp_axis` flag.
 
 All primitives are plain jnp + lax collectives called INSIDE
-`compat.shard_map`, so they run on the 8-virtual-device CPU mesh
+`jax.shard_map`, so they run on the 8-virtual-device CPU mesh
 exactly as on a TPU ring; `matmul_reference` is the pure-jnp oracle
 every parity test compares against (allclose, not bit-equal: ring
 accumulation orders differ from XLA's single-matmul reduction).
@@ -53,7 +53,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from paddle_tpu.parallel import compat
 
 
 def _acc_dtype(x, w):
@@ -74,7 +73,7 @@ def matmul_reference(x, w):
 
 
 # ---------------------------------------------------------------------------
-# in-shard_map primitives (call these inside compat.shard_map)
+# in-shard_map primitives (call these inside jax.shard_map)
 # ---------------------------------------------------------------------------
 
 
@@ -93,7 +92,7 @@ def ring_matmul_gather(x_loc, w_loc, *, axis: str, overlap: bool = True):
     overlap=False is the naive arm: all_gather(x) then one matmul —
     the comm fully serialised before any compute (the bench baseline).
     """
-    p = compat.axis_size(axis)
+    p = jax.lax.axis_size(axis)
     acc = _acc_dtype(x_loc, w_loc)
     out_dtype = jnp.result_type(x_loc.dtype, w_loc.dtype)
     if not overlap or p == 1:
@@ -143,7 +142,7 @@ def ring_matmul_reduce(x_loc, w_loc, *, axis: str, overlap: bool = True):
     p stops exactly at device c. overlap=False is the naive arm: the
     full [M, N] partial product, then one blocking psum_scatter.
     """
-    p = compat.axis_size(axis)
+    p = jax.lax.axis_size(axis)
     big_m = x_loc.shape[0]
     if big_m % p != 0:
         raise ValueError(
@@ -190,7 +189,7 @@ def stream_matmul(x, w_loc, *, axis: str):
     bytes vs the |W| of all_gather(w). Returns the full [B, N] on
     every device (globally replicated).
     """
-    p = compat.axis_size(axis)
+    p = jax.lax.axis_size(axis)
     me = lax.axis_index(axis)
     k = w_loc.shape[0]
     acc_dtype = _acc_dtype(x, w_loc)
@@ -221,7 +220,7 @@ def tp_dense(x, w_loc, *, axis: str, overlap: bool = True):
     the row blocks back; needs B % p == 0 and p | B, so it falls back
     to the psum form when the batch doesn't tile.
     """
-    p = compat.axis_size(axis)
+    p = jax.lax.axis_size(axis)
     me = lax.axis_index(axis)
     k = w_loc.shape[0]
     x_me = lax.dynamic_slice_in_dim(x, me * k, k, axis=1)
@@ -260,7 +259,7 @@ def collective_matmul(mesh: Mesh, *, axis: str, mode: str = "reduce",
     else:
         raise ValueError(
             f"unknown mode {mode!r}: expected 'gather' or 'reduce'")
-    return compat.shard_map(inner, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(inner, mesh=mesh, in_specs=in_specs,
                             out_specs=out_specs, check_vma=False)
 
 
@@ -269,6 +268,6 @@ def blocked_matmul(mesh: Mesh, *, axis: str) -> Callable:
     (`stream_matmul` per shard): the weight never materialises whole on
     any device; x and the result are replicated."""
     inner = functools.partial(stream_matmul, axis=axis)
-    return compat.shard_map(inner, mesh=mesh,
+    return jax.shard_map(inner, mesh=mesh,
                             in_specs=(P(None, None), P(axis, None)),
                             out_specs=P(None, None), check_vma=False)

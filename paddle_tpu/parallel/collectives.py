@@ -25,7 +25,6 @@ from __future__ import annotations
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from paddle_tpu.parallel import compat
 
 from paddle_tpu.core.mesh import DATA_AXIS
 
@@ -58,7 +57,7 @@ def all_to_all(x, axis: str = DATA_AXIS, *, split_axis: int = 0,
 def ppermute_ring(x, axis: str = DATA_AXIS, *, shift: int = 1):
     """Rotate shards around the ring by `shift` (reference:
     MultiGradientMachine.h:61-95 neighbor-thread ring copy)."""
-    n = compat.axis_size(axis)
+    n = jax.lax.axis_size(axis)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return jax.lax.ppermute(x, axis_name=axis, perm=perm)
 
@@ -70,7 +69,7 @@ def axis_index(axis: str = DATA_AXIS):
 # ---- whole-array wrappers (build the shard_map for you) ----
 
 def _shmap(mesh: Mesh, fn, in_spec: P, out_spec: P):
-    return compat.shard_map(fn, mesh=mesh, in_specs=(in_spec,),
+    return jax.shard_map(fn, mesh=mesh, in_specs=(in_spec,),
                          out_specs=out_spec)
 
 
@@ -89,7 +88,7 @@ def device_broadcast_from(x, mesh: Mesh, axis: str = DATA_AXIS,
 
     def body(s):
         idx = jax.lax.axis_index(axis)
-        n = compat.axis_size(axis)
+        n = jax.lax.axis_size(axis)
         mask = (idx == source).astype(s.dtype)
         return jax.lax.psum(s * mask, axis_name=axis)
 

@@ -1,80 +1,14 @@
-"""JAX API compatibility shims for the parallel stack.
-
-`shard_map` moved twice across JAX releases: it grew up in
-`jax.experimental.shard_map.shard_map` (where the replication-check
-kwarg is spelled `check_rep`) and graduated to `jax.shard_map` (where
-the same kwarg is `check_vma`). The parallel modules (sparse,
-ring_attention, pipeline, moe, collectives) target the graduated API;
-this shim lets them run unmodified on environments that only ship the
-experimental one — the tier-1 CPU env among them — instead of failing
-at first call with AttributeError.
-
-One definition on purpose: every shard_map call in this package routes
-through here, so a third relocation is a one-line fix.
-"""
+"""Backend differences the parallel stack has to ask about."""
 
 from __future__ import annotations
 
-import jax
-
-__all__ = ["shard_map", "axis_size", "pcast", "memory_kind"]
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-    """`jax.shard_map` with graceful fallback to the experimental API.
-
-    `check_vma` follows the graduated spelling; on the experimental
-    API it is forwarded as `check_rep` (same semantics: disable the
-    per-output replication/varying-axes check for bodies whose
-    collectives the checker cannot type)."""
-    impl = getattr(jax, "shard_map", None)
-    if impl is not None:
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return impl(f, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as exp_impl
-
-    if check_vma is not None:
-        kw["check_rep"] = check_vma
-    return exp_impl(f, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs, **kw)
-
-
-def axis_size(axis):
-    """`jax.lax.axis_size` where it exists; the classic
-    `psum(1, axis)` idiom (constant-folded to a Python int at trace
-    time) everywhere else. Call inside shard_map/pmap only."""
-    import jax.lax as lax
-
-    impl = getattr(lax, "axis_size", None)
-    if impl is not None:
-        return impl(axis)
-    return lax.psum(1, axis)
-
-
-def pcast(x, axis, *, to):
-    """`jax.lax.pcast` (varying-axes retyping for the shard_map vma
-    checker) where it exists; `lax.pvary` on the releases that only
-    have the one-way cast; identity on releases with neither — those
-    predate the vma type system entirely, so there is nothing to
-    retype and the value is already correct."""
-    import jax.lax as lax
-
-    impl = getattr(lax, "pcast", None)
-    if impl is not None:
-        return impl(x, axis, to=to)
-    pvary = getattr(lax, "pvary", None)
-    if pvary is not None and to == "varying":
-        return pvary(x, axis)
-    return x
+__all__ = ["memory_kind"]
 
 
 def memory_kind(device, kind):
     """`kind` when `device` can address that memory space, else None
-    (= the device's default space). XLA:CPU has no pinned_host/device
-    kinds, only unpinned_host — shardings built with the TPU kinds
-    must degrade rather than fail at device_put."""
+    (= the device's default space): a sharding built with a kind the
+    backend lacks must degrade rather than fail at device_put."""
     try:
         kinds = {m.kind for m in device.addressable_memories()}
     except Exception:

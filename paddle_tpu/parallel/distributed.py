@@ -21,6 +21,7 @@ from typing import Optional
 
 import jax
 import numpy as np
+from jax._src import xla_bridge
 
 _initialized = False
 
@@ -49,13 +50,7 @@ def initialize(coordinator_address: Optional[str] = None,
         return
     auto = (coordinator_address is None and num_processes is None
             and process_id is None)
-    try:
-        from jax._src import xla_bridge
-
-        backend_up = xla_bridge.backends_are_initialized()
-    except Exception:  # private API moved: fall back to attempting init
-        backend_up = False
-    if backend_up:
+    if xla_bridge.backends_are_initialized():
         if jax.process_count() > 1:
             _initialized = True
             return  # already joined
@@ -90,7 +85,7 @@ def initialize(coordinator_address: Optional[str] = None,
 
 
 def _enable_cpu_collectives() -> None:
-    """When the job is pinned to the CPU backend (scripts/cpu_guard, CI
+    """When the job is pinned to the CPU backend (JAX_PLATFORMS=cpu: CI
     gangs), XLA:CPU refuses multi-process computations unless a
     cross-process collectives transport is configured — the default is
     none, and every collective then dies with INVALID_ARGUMENT
@@ -98,19 +93,10 @@ def _enable_cpu_collectives() -> None:
     Selecting jax's bundled gloo TCP transport before the coordinator
     handshake makes CPU gangs first-class. TPU/GPU paths are untouched
     (their collectives ride ICI/DCN/NCCL and ignore this flag)."""
-    platforms = os.environ.get("JAX_PLATFORMS", "")
-    try:
-        cfg = jax.config.jax_platforms  # set by scripts/cpu_guard
-    except AttributeError:
-        cfg = None
-    if "cpu" not in (cfg or platforms or ""):
+    # jax reads JAX_PLATFORMS into this config value at import
+    if "cpu" not in (jax.config.jax_platforms or ""):
         return
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        logging.getLogger(__name__).warning(
-            "could not enable gloo CPU collectives; multi-process CPU "
-            "collectives will fail", exc_info=True)
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def process_count() -> int:

@@ -185,7 +185,7 @@ spec:
 # elastic local gang: spec + worker + supervisor
 # ---------------------------------------------------------------------------
 
-#: repo root, for child PYTHONPATH/cwd (scripts.cpu_guard lives there)
+#: repo root, for child PYTHONPATH/cwd
 _REPO_ROOT = pathlib.Path(__file__).resolve().parents[2]
 
 
@@ -411,7 +411,6 @@ class GangSupervisor:
                  heartbeat_timeout_s: float = 60.0,
                  boot_timeout_s: float = 300.0,
                  grace_s: float = 5.0, poll_s: float = 0.25,
-                 pin_cpu: bool = True,
                  extra_env: Optional[Dict[str, str]] = None,
                  flight: Optional[Any] = None,
                  membership: Optional[Any] = None,
@@ -432,7 +431,6 @@ class GangSupervisor:
         self.boot_timeout_s = boot_timeout_s
         self.grace_s = grace_s
         self.poll_s = poll_s
-        self.pin_cpu = pin_cpu
         self.extra_env = dict(extra_env or {})
         self.flight = flight
         # optional membership mirror (MembershipService or -Client
@@ -499,14 +497,15 @@ class GangSupervisor:
             watchdog_timeout_s=self.watchdog_timeout_s)
         spec_path = self.workdir / f"spec_{self.gang_epoch}.json"
         spec_path.write_text(spec.to_json())
-        # children must pick their platform BEFORE distributed init:
-        # scripts.cpu_guard pins cpu config-only (local gangs / CI);
-        # pin_cpu=False leaves jax's TPU auto-detection alone
-        prelude = "import scripts.cpu_guard; " if self.pin_cpu else ""
-        code = (prelude + "from paddle_tpu.parallel.launch import "
+        # children take their platform from JAX_PLATFORMS in the
+        # environment they inherit (cpu for local gangs / CI; unset =
+        # jax's own detection, i.e. the chip — one member per chip,
+        # and this supervisor itself never touches jax). XLA_FLAGS is
+        # dropped so a parent's virtual-device count does not multiply
+        # into every member; pass one through extra_env if wanted.
+        code = ("from paddle_tpu.parallel.launch import "
                 "gang_child_main; gang_child_main()")
-        env = {k: v for k, v in os.environ.items()
-               if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
+        env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
         env["PYTHONPATH"] = (str(_REPO_ROOT) + os.pathsep
                              + env.get("PYTHONPATH", ""))
         env.update(self.extra_env)

@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from paddle_tpu.parallel import compat
 
 from paddle_tpu.core.mesh import MODEL_AXIS
 from paddle_tpu.nn import initializers
@@ -426,7 +425,7 @@ def make_expert_parallel_ffn(mesh: Mesh, *, axis: str = MODEL_AXIS,
 
     pspec = {"router": {"kernel": P()},
              "w1": P(axis), "b1": P(axis), "w2": P(axis), "b2": P(axis)}
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspec, dspec, P()),
         out_specs=MoEOutput(dspec, P(), P()),

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from paddle_tpu.parallel import blocked_matmul, compat
+from paddle_tpu.parallel import blocked_matmul
 
 PIPE_AXIS = "pipe"
 
@@ -109,7 +109,7 @@ def make_pipeline_forward(stage_fn: Callable, mesh: Mesh, *,
         # pvary: the carry is device-VARYING over the pipe axis (each
         # stage holds a different activation), so the initial zeros must
         # carry that type too or scan rejects the carry
-        act0 = compat.pcast(jnp.zeros_like(micro_x[0]), axis,
+        act0 = jax.lax.pcast(jnp.zeros_like(micro_x[0]), axis,
                             to='varying')
         perm = [(i, (i + 1) % n_stage) for i in range(n_stage)]
 
@@ -141,7 +141,7 @@ def make_pipeline_forward(stage_fn: Callable, mesh: Mesh, *,
         # varying-manifest checker can't type that, so it's off there —
         # the default branch keeps the strict check it always had
         kw = {} if tp_axis is None else {"check_vma": False}
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(param_specs, P()),
             out_specs=P(),

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from paddle_tpu.parallel import compat
 
 from paddle_tpu.core.mesh import SEQ_AXIS
 
@@ -108,7 +107,7 @@ def ring_attention(q, k, v, *, axis: str = SEQ_AXIS, causal: bool = False):
     K/V blocks rotate around the ring once; a streaming softmax merges
     block partials, so peak memory is O(T_local^2) scores per device.
     """
-    n = compat.axis_size(axis)
+    n = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     t_local = q.shape[1]
     scale = (1.0 / jnp.sqrt(q.shape[-1])).astype(q.dtype)
@@ -150,7 +149,7 @@ def ulysses_attention(q, k, v, *, axis: str = SEQ_AXIS,
     H % axis_size == 0. Each device sees the FULL sequence for H/n heads,
     runs dense attention, and all-to-alls back to sequence sharding.
     """
-    n = compat.axis_size(axis)
+    n = jax.lax.axis_size(axis)
     # [B, T/n, H, D] -> gather seq, split heads -> [B, T, H/n, D]
     qh = jax.lax.all_to_all(q, axis, split_axis=2, concat_axis=1, tiled=True)
     kh = jax.lax.all_to_all(k, axis, split_axis=2, concat_axis=1, tiled=True)
@@ -182,7 +181,7 @@ def make_sequence_parallel_attention(
     spec = P(batch_axis, axis, None, None)
 
     @functools.partial(
-        compat.shard_map, mesh=mesh, in_specs=(spec, spec, spec),
+        jax.shard_map, mesh=mesh, in_specs=(spec, spec, spec),
         out_specs=spec, check_vma=False)
     def fn(q, k, v):
         return inner(q, k, v, axis=axis, causal=causal)

@@ -21,6 +21,7 @@ parameter/FirstOrderOptimizer.h SparseMomentum analog).
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Protocol, runtime_checkable
 
 import jax
@@ -109,7 +110,7 @@ def sharded_lookup(table, ids, mesh: Mesh, *, axis: str = MODEL_AXIS):
         vecs = jnp.where(in_range[..., None], vecs, 0)
         return jax.lax.psum(vecs, axis_name=axis)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis, None), P()),
         out_specs=P(),
@@ -204,7 +205,7 @@ def alltoall_lookup(table, ids, mesh: Mesh, *, axis: str = MODEL_AXIS,
         out = jnp.zeros((k_loc, dim), got.dtype).at[order].set(got)
         return out, jax.lax.psum(overflow, axis_name=axis)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis, None), P(axis)),
         out_specs=(P(axis, None), P()),
@@ -251,7 +252,7 @@ def alltoall_push_row_grads(table, ids, row_grads, lr,
         return tab_shard.at[safe].add(
             -lr * contrib.astype(tab_shard.dtype))
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis, None), P(axis), P(axis, None)),
         out_specs=P(axis, None),
@@ -310,7 +311,7 @@ def rowwise_sgd_update(table, ids, row_grads, lr, mesh: Optional[Mesh] = None,
         contrib = jnp.where(in_range[:, None], grads_g, 0)
         return tab_shard.at[safe].add(-lr * contrib.astype(tab_shard.dtype))
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis, None), P(), P()),
         out_specs=P(axis, None),
@@ -403,6 +404,26 @@ class ShardedEmbedding:
 # ---------------------------------------------------------------------
 
 
+@functools.partial(jax.jit, static_argnames=("vocab", "host_sh", "dev_sh"))
+def _host_gather(table, ids, *, vocab: int, host_sh, dev_sh):
+    """HostOffloadEmbedding.lookup's body: clip + gather in the host
+    region, rows to device memory, out-of-range rows zeroed there."""
+    from jax.experimental.compute_on import compute_on
+
+    in_range = (ids >= 0) & (ids < vocab)
+    ids_h = jax.device_put(jnp.clip(ids, 0, vocab - 1), host_sh)
+    with compute_on("device_host"):
+        dnums = lax.GatherDimensionNumbers(
+            offset_dims=(1,), collapsed_slice_dims=(0,),
+            start_index_map=(0,))
+        rows = lax.gather(
+            table, ids_h[:, None], dnums,
+            slice_sizes=(1, table.shape[1]),
+            mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+    rows_d = jax.device_put(rows, dev_sh)
+    return jnp.where(in_range[:, None], rows_d, 0.0)
+
+
 class HostOffloadEmbedding:
     """Embedding table stored in HOST memory, touched rows DMA'd to the
     device per step.
@@ -477,22 +498,12 @@ class HostOffloadEmbedding:
         """ids [K] -> rows [K, D] on DEVICE; the gather itself runs on
         host so only K*D floats move to HBM. Out-of-range ids (e.g. -1
         padding) return ZERO vectors — the same contract as
-        sharded_lookup."""
-        from jax.experimental.compute_on import compute_on
-
-        host_sh = self._host_sharding(table)
-        in_range = (ids >= 0) & (ids < self.vocab)
-        ids_h = jax.device_put(jnp.clip(ids, 0, self.vocab - 1), host_sh)
-        with compute_on("device_host"):
-            dnums = lax.GatherDimensionNumbers(
-                offset_dims=(1,), collapsed_slice_dims=(0,),
-                start_index_map=(0,))
-            rows = lax.gather(
-                table, ids_h[:, None], dnums,
-                slice_sizes=(1, table.shape[1]),
-                mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)
-        rows_d = jax.device_put(rows, self._dev_sharding(table))
-        return jnp.where(in_range[:, None], rows_d, 0.0)
+        sharded_lookup. Always traced (`_host_gather` is jitted): an
+        eager `compute_on` result keeps a host-space aval, which the
+        device-side masking then refuses to mix with."""
+        return _host_gather(table, jnp.asarray(ids), vocab=self.vocab,
+                            host_sh=self._host_sharding(table),
+                            dev_sh=self._dev_sharding(table))
 
     def apply_row_grads(self, table, ids, row_grads, lr):
         """Row-sparse SGD on the host copy: [K, D] grads cross PCIe,

@@ -24,7 +24,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from paddle_tpu.core.mesh import DATA_AXIS
 from paddle_tpu.nn.module import Layer, merge_state
 from paddle_tpu.optim.optimizers import Optimizer
-from paddle_tpu.parallel import compat
 from paddle_tpu.parallel import sharding as shard_lib
 from paddle_tpu.train.state import TrainState
 from paddle_tpu.train.trainer import make_train_step
@@ -378,7 +377,7 @@ def make_zero_train_step(
         opt_specs = jax.tree.map(
             lambda x: zero_leaf_spec(x, n) if zero_update else P(),
             state.opt_state)
-        sharded = compat.shard_map(
+        sharded = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P(), opt_specs, P(), P(),
                       jax.tree.map(lambda _: P(axis), inputs),

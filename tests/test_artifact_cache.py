@@ -236,10 +236,84 @@ def test_corrupt_cache_entry_degrades_to_miss(tmp_path):
         compilation_cache.reset_counters()
 
 
+_PLACEMENT_CHILD = """
+import json, os
+import jax
+from jax._src import xla_bridge
+from paddle_tpu import compilation_cache
+
+written = []
+real_update = jax.config.update
+def spy(name, value):
+    written.append(name)
+    return real_update(name, value)
+jax.config.update = spy
+path = compilation_cache.enable()
+print(json.dumps({
+    "path": path, "config": jax.config.jax_compilation_cache_dir,
+    "wrote_dir": "jax_compilation_cache_dir" in written,
+    "backend_up": xla_bridge.backends_are_initialized()}))
+"""
+
+
+def _run_placement_child(env_dir):
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=str(ROOT) + os.pathsep
+               + os.environ.get("PYTHONPATH", ""))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    out = subprocess.run([sys.executable, "-c", _PLACEMENT_CHILD],
+                         capture_output=True, text=True, timeout=120,
+                         env=env)
+    assert out.returncode == 0, out.stderr[-2000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_cache_dir_from_environment_is_left_alone(tmp_path):
+    """Where JAX_COMPILATION_CACHE_DIR is set, jax has already read it:
+    enable() writes no directory of its own (only thresholds and
+    listeners), and it never initialises the backend — a fleet parent
+    calls it and must stay off the chip its children need."""
+    d = str(tmp_path / "from-env")
+    got = _run_placement_child(d)
+    assert got["path"] == d and got["config"] == d
+    assert not got["wrote_dir"]
+    assert not got["backend_up"]
+
+
+def test_cache_dir_defaults_into_the_checkout():
+    """Unset, the directory is the fixed, gitignored
+    <checkout>/.jax_cache (the path is part of jax's cache key, so it
+    must not move), again with the backend untouched."""
+    got = _run_placement_child(None)
+    want = str(ROOT / ".jax_cache")
+    assert got["path"] == want and got["config"] == want
+    assert got["wrote_dir"]
+    assert not got["backend_up"]
+    ignored = (ROOT / ".gitignore").read_text().split()
+    assert ".jax_cache/" in ignored
+
+
+def test_environment_wins_over_an_explicit_dir(tmp_path, monkeypatch):
+    """An explicit directory that disagrees with the environment is
+    ignored with a warning, not quietly honoured."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        with pytest.warns(UserWarning, match="ignoring the requested"):
+            d = compilation_cache.enable(str(tmp_path / "explicit"))
+        assert d == str(tmp_path / "env")
+        assert jax.config.jax_compilation_cache_dir == before
+        assert not (tmp_path / "explicit").exists()
+    finally:
+        compilation_cache.disable()
+        compilation_cache.reset_counters()
+
+
 _WARM_CHILD = """
 import json, sys
 import jax
-jax.config.update("jax_platforms", "cpu")
 import numpy as np
 from paddle_tpu import compilation_cache
 from paddle_tpu.analysis.guards import RecompileGuard

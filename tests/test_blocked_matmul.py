@@ -124,9 +124,8 @@ class TestTpDense:
         ref = BM.matmul_reference(x, w)
 
         from jax.sharding import PartitionSpec as P
-        from paddle_tpu.parallel import compat
 
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             lambda a, b: BM.tp_dense(a, b, axis="x", overlap=overlap),
             mesh=mesh, in_specs=(P(None, None), P("x", None)),
             out_specs=P(None, None), check_vma=False)
@@ -142,9 +141,8 @@ class TestTpDense:
         ref = BM.matmul_reference(x, w)
 
         from jax.sharding import PartitionSpec as P
-        from paddle_tpu.parallel import compat
 
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             lambda a, b: BM.tp_dense(a, b, axis="x", overlap=True),
             mesh=mesh, in_specs=(P(None, None), P("x", None)),
             out_specs=P(None, None), check_vma=False)

@@ -1,0 +1,50 @@
+"""Keep `chip_smoke.py` from rotting, without a chip.
+
+`--tiny` runs the phases at toy size on the CPU backend — on two
+virtual devices, so the sharded train branch is covered. The real
+contract — exit 0 and a result line only on a TPU — is checked from the
+other side: a plain run under JAX_PLATFORMS=cpu must fail, name the
+missing chip, and print no result. Both runs get their compile cache
+from the environment, so neither fills the checkout's `.jax_cache`."""
+
+import json
+import os
+import subprocess
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[1]
+
+
+def _run(tmp_path, *args, devices=1):
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
+    return subprocess.run(
+        [sys.executable, str(ROOT / "chip_smoke.py"), *args],
+        capture_output=True, text=True, timeout=300, env=env, cwd=tmp_path)
+
+
+def test_tiny_runs_every_phase(tmp_path):
+    out = _run(tmp_path, "--tiny", devices=2)
+    assert out.returncode == 0, out.stderr[-3000:]
+    lines = [json.loads(l) for l in out.stdout.splitlines()
+             if l.startswith("{")]
+    assert lines[0]["compile_cache_dir"] == str(tmp_path / "cache")
+    phases = {l["phase"]: l for l in lines if "phase" in l}
+    assert list(phases) == ["train_resnet50", "train_transformer",
+                            "serve_http"]
+    assert phases["train_resnet50"]["sharded_over"] == 2
+    serve = phases["serve_http"]
+    assert serve["traced"]["ragged_attention=jnp"] > 0
+    assert serve["edge"]["completed"] == serve["requests"] == 8
+    assert lines[-1] == {"ok": True, "chip": False,
+                         "device": {"platform": "cpu", "kind": "cpu",
+                                    "count": 2}}
+
+
+def test_without_a_chip_it_fails_and_prints_no_result(tmp_path):
+    out = _run(tmp_path)
+    assert out.returncode != 0
+    assert "no TPU" in out.stderr
+    assert '"ok"' not in out.stdout

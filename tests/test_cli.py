@@ -250,7 +250,6 @@ def test_serve_verb(tmp_path, capsys):
     completions matching generate() (greedy default)."""
     cfg_src = """
 import jax
-jax.config.update("jax_platforms", "cpu")
 
 
 def get_serve_config():
@@ -298,7 +297,6 @@ def get_serve_config():
 
 SERVE_CFG = """
 import jax
-jax.config.update("jax_platforms", "cpu")
 
 
 def get_serve_config():
@@ -412,3 +410,32 @@ def test_serve_http_sigterm_drains_fleet(tmp_path):
     assert payload["edge"]["requests"] == 1
     assert payload["fleet"]["completed"] >= 1
     assert "edge_requests" in metrics.read_text()
+
+
+def test_fleet_procs_parent_does_not_build_the_model(tmp_path, monkeypatch):
+    """One process per chip: `serve --fleet-procs` must not call
+    get_serve_config() in the parent — building params there would
+    initialise the backend and hold the chip the replica children need.
+    The children run the config themselves."""
+    from paddle_tpu import cli
+
+    cfg = tmp_path / "cfg.py"
+    cfg.write_text(
+        "def get_serve_config():\n"
+        "    raise AssertionError('parent built the model')\n")
+    prompts = tmp_path / "prompts.txt"
+    prompts.write_text("1 2 3\n")
+    seen = {}
+
+    def fake_fleet(args, prompts, sampling, buckets, sink):
+        seen["prompts"] = [p.tolist() for p in prompts]
+        return 0
+
+    monkeypatch.setattr(cli, "_serve_fleet_procs", fake_fleet)
+    assert main(["serve", "--config", str(cfg), "--fleet-procs", "1",
+                 "--prompts", str(prompts), "--no-compile-cache"]) == 0
+    assert seen["prompts"] == [[1, 2, 3]]
+    # without the flag the same config IS called, here
+    with pytest.raises(AssertionError, match="parent built the model"):
+        main(["serve", "--config", str(cfg), "--prompts", str(prompts),
+              "--no-compile-cache"])

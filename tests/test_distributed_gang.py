@@ -35,7 +35,6 @@ REPO = pathlib.Path(__file__).resolve().parent.parent
 
 CHILD = r"""
 import json, os, sys
-import scripts.cpu_guard  # pins cpu; config-only, backend stays cold
 
 from paddle_tpu.parallel import distributed as D
 
@@ -99,7 +98,6 @@ D.sync_hosts("done")
 
 CTR_CHILD = r"""
 import json, os, sys
-import scripts.cpu_guard  # pins cpu; config-only, backend stays cold
 
 from paddle_tpu.parallel import distributed as D
 
@@ -158,7 +156,6 @@ D.sync_hosts("done")
 
 MOE_CHILD = r"""
 import json, os, sys
-import scripts.cpu_guard  # pins cpu; config-only, backend stays cold
 
 from paddle_tpu.parallel import distributed as D
 
@@ -220,8 +217,10 @@ def _run_gang(tmp_path, child_src):
     addr = f"127.0.0.1:{_free_port()}"
     script = tmp_path / "gang_child.py"
     script.write_text(child_src)
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
+    # one CPU device per member: the platform comes from the
+    # environment, the 8-device flag of the test session does not
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = str(REPO) + os.pathsep + env.get("PYTHONPATH", "")
     procs = [subprocess.Popen(
         [sys.executable, str(script), addr, str(pid)],

@@ -106,7 +106,7 @@ class TestConv:
     def test_max_pool_tie_split_shares_gradient(self):
         # a 4-way tie gets dy/4 each (XLA native would give one element
         # 1); explicit opt-in — the DEFAULT is the native formulation
-        # until the on-chip A/B clears the custom VJP (probe_pool.py)
+        # (the custom VJP measured slower on the chip, ROADMAP C5)
         x = jnp.ones((1, 2, 2, 1), jnp.float32)
         g = jax.grad(lambda x: jnp.sum(
             C.max_pool2d(x, 2, tie_split=True)))(x)

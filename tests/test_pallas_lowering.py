@@ -1,0 +1,127 @@
+"""Lower every Pallas kernel `auto` dispatch can select for the TPU,
+from the CPU host: interpret off (`pallas_util.on_tpu` patched to
+True), forward and backward, at the consumer's shapes and dtypes,
+through `jit(f).trace(...).lower(lowering_platforms=("tpu",))`. That
+runs the whole Pallas->Mosaic-MLIR stage — block-shape rules, SMEM
+operands, matmul forms — which is where the compiler first refused the
+flash and ragged kernels, in seconds and with no chip. The Mosaic
+compile proper and the numerics only happen on a chip
+(benchmarks/kernel_check.py, chip_smoke.py)."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from paddle_tpu.core import dtypes
+from paddle_tpu.models import transformer as T
+from paddle_tpu.ops import pallas_util, rnn
+from paddle_tpu.ops import ragged_paged_attention as RPA
+
+pytestmark = pytest.mark.pallas
+
+
+@pytest.fixture(autouse=True)
+def _as_if_on_one_chip(monkeypatch):
+    monkeypatch.setattr(pallas_util, "on_tpu", lambda: True)
+    monkeypatch.setattr(jax, "device_count", lambda *a: 1)
+    prev = dtypes.default_policy()
+    # conftest turns x64 on for the numeric-gradient suites; the chip
+    # runs without it, and Mosaic has no float64
+    with jax.enable_x64(False):
+        yield
+    dtypes.set_default_policy(prev)
+
+
+def _lower_for_tpu(fn, *args) -> int:
+    """Lower; return how many Mosaic kernels the program holds."""
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    return text.count("tpu_custom_call")
+
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+@pytest.mark.parametrize("heads,head_dim,window,lens", [
+    (8, 64, None, False),       # the serving/training width (dim 512)
+    (4, 128, None, False),
+    (8, 64, 512, False),        # sliding-window configs
+    (8, 64, None, True),        # the engine's bucket-padded prefill
+])
+def test_flash_fwd_bwd_lowers(heads, head_dim, window, lens):
+    cfg = T.TransformerConfig(vocab=128, dim=heads * head_dim,
+                              n_heads=heads, n_layers=1,
+                              attn_impl="auto", attn_window=window)
+    x = _sds((2, 2048, heads, head_dim), jnp.bfloat16)
+    key_lens = jnp.asarray([2048, 300], jnp.int32) if lens else None
+
+    def loss(q, k, v):
+        o = T._attention(cfg, q, k, v, causal=True, key_lens=key_lens)
+        return jnp.sum(o.astype(jnp.float32))
+
+    # one kernel: the forward (the backward is blockwise jnp)
+    assert _lower_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), x, x, x) == 1
+
+
+@pytest.mark.parametrize("name,run,init,hidden,t", [
+    ("gru", rnn.gru, rnn.init_gru_params, 512, 30),          # seq2seq
+    ("lstm", rnn.lstm, rnn.init_lstm_params, 256, 100),      # bench_lstm
+    ("lstm", rnn.lstm, rnn.init_lstm_params, 512, 100),
+    ("rnn", rnn.simple_rnn, rnn.init_rnn_params, 512, 100),
+])
+@pytest.mark.parametrize("bf16", [True, False])
+def test_fused_rnn_fwd_bwd_lowers(name, run, init, hidden, t, bf16):
+    if bf16:
+        dtypes.set_default_policy(dtypes.bf16_compute_policy())
+    b = 64
+    params = jax.eval_shape(lambda: init(jax.random.key(0), hidden, hidden))
+    lens = jnp.full((b,), t, jnp.int32)
+
+    def loss(p, x):
+        out, _ = run(p, x, lens)                 # impl="auto"
+        return jnp.sum(out.astype(jnp.float32))
+
+    n = _lower_for_tpu(jax.grad(loss), params,
+                       _sds((b, t, hidden), jnp.float32))
+    assert n == 2, f"{name}: expected fwd+bwd kernels, got {n}"
+
+
+def test_ragged_auto_lowers_without_the_kernel():
+    """The ragged kernel is deselected: the serving read at the
+    benchmark width lowers for TPU as plain XLA, no Mosaic call."""
+    page, hkv, dh, rows = 16, 8, 64, 8
+    arena = _sds((rows * 128, page, hkv, dh), jnp.bfloat16)
+
+    def read(q, ka, va, pt, pos0, active):
+        return RPA.ragged_attention(q, ka, va, pt, pos0, active,
+                                    page_size=page, max_len=2048)
+
+    n = _lower_for_tpu(read, _sds((rows, 1, 8, dh), jnp.bfloat16), arena,
+                       arena, _sds((rows, 128), jnp.int32),
+                       _sds((rows,), jnp.int32), _sds((rows,), jnp.bool_))
+    assert n == 0
+
+
+
+def test_auto_keeps_kernels_out_of_partitioned_programs(monkeypatch):
+    """XLA cannot split a Mosaic call. On a host with several devices
+    `auto` selects a kernel only inside a shard_map over the whole mesh
+    (per-device shapes); in a plain jit, which may be partitioned, it
+    takes the XLA path."""
+    import numpy as np
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    monkeypatch.setattr(jax, "device_count", lambda *a: 4)
+    params = jax.eval_shape(
+        lambda: rnn.init_gru_params(jax.random.key(0), 128, 128))
+    x = _sds((8, 6, 128), jnp.float32)
+
+    def run(p, x):
+        return rnn.gru(p, x)[0]
+
+    assert _lower_for_tpu(run, params, x) == 0
+    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+    inside = jax.shard_map(run, mesh=mesh, in_specs=(P(), P("data")),
+                           out_specs=P("data"), check_vma=False)
+    assert _lower_for_tpu(inside, params, x) == 1

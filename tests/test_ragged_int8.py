@@ -157,29 +157,6 @@ class TestInt8RaggedParity:
 
 
 class TestInt8Dispatch:
-    def test_fits_vmem_accounts_scale_and_scratch(self):
-        # per key-block the int8 walk stages data (1B/elem) + scale
-        # plane (4B/row) + the f32 dequant scratch (4B/elem) — MORE
-        # than the same logical window in f32 (4B/elem), so a geometry
-        # can fit as float and NOT fit as int8. Shape-only probes:
-        # fits_vmem reads .shape/.dtype, never the bytes.
-        pt = jnp.zeros((1, 8), jnp.int32)
-        kw = dict(page_size=128, max_len=1024)
-        # sized so the f32 walk is ~10.5MB of the 12MB budget: int8's
-        # ~1.26x factor (1B data + scale + 4B scratch vs plain 4B)
-        # pushes the SAME window over the line
-        shape = (16, 128, 10, 128)
-        kf = jax.ShapeDtypeStruct(shape, jnp.float32)
-        k8 = (jax.ShapeDtypeStruct(shape, jnp.int8),
-              jax.ShapeDtypeStruct(shape[:-1], jnp.float32))
-        assert RPA.fits_vmem(kf, pt, **kw)
-        assert not RPA.fits_vmem(k8, pt, **kw)
-        # and a small int8 walk fits — the dispatch gate is open
-        small = ((jax.ShapeDtypeStruct((6, PAGE, HKV, DH), jnp.int8),
-                  jax.ShapeDtypeStruct((6, PAGE, HKV), jnp.float32)))
-        assert RPA.fits_vmem(small, jnp.zeros((2, 3), jnp.int32),
-                             page_size=PAGE, max_len=12)
-
     def test_verify_tq1_is_decode_int8(self, np_rng):
         """The spec path's K=0 degenerate is a plain decode step on
         int8 arenas too — through the forced kernel on both sides."""

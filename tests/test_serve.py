@@ -104,7 +104,6 @@ class TestDecoderArtifact:
         code = f"""
 import sys
 sys.path.insert(0, {REPO!r})
-import scripts.cpu_guard  # the ONE cpu-pin implementation
 import numpy as np
 from paddle_tpu.serve.artifact import load_compiled_model
 m = load_compiled_model({path!r})
@@ -219,8 +218,6 @@ def test_artifact_needs_no_model_code(tmp_path):
     path = str(tmp_path / "mlp.ptc")
     _, x = _export_mlp(path)
     code = f"""
-import jax
-jax.config.update("jax_platforms", "cpu")
 import numpy as np
 from paddle_tpu.serve import load_compiled_model
 m = load_compiled_model({path!r})
@@ -302,8 +299,9 @@ def test_pjrt_serve_library_builds():
 
 @pytest.mark.skipif(
     os.environ.get("PADDLE_TPU_RUN_PJRT_TEST") != "1",
-    reason="needs a live PJRT plugin device (the single-claim TPU); "
-           "set PADDLE_TPU_RUN_PJRT_TEST=1 on a TPU host")
+    reason="needs a live PJRT plugin device; set "
+           "PADDLE_TPU_RUN_PJRT_TEST=1 on a TPU host (one process per "
+           "chip: nothing else may hold it)")
 def test_pjrt_serve_end_to_end(tmp_path):
     """Full Python-free TPU serving: export artifact, extract raw
     StableHLO, compile+run it through libtpu's PJRT C API from C."""

@@ -433,8 +433,7 @@ class TestCliServeReliable:
         from paddle_tpu.cli import main
 
         cfg_src = (
-            "import jax\n"
-            "jax.config.update('jax_platforms', 'cpu')\n\n\n"
+            "import jax\n\n\n"
             "def get_serve_config():\n"
             "    from paddle_tpu.models import transformer as T\n"
             "    cfg = T.TransformerConfig(vocab=61, dim=32,"

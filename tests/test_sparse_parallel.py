@@ -12,7 +12,6 @@ from paddle_tpu.core import mesh as mesh_lib
 from paddle_tpu.parallel import (
     ShardedEmbedding,
     collectives,
-    compat,
     rowwise_sgd_update,
     shard_rows,
     sharded_embedding_bag,
@@ -172,7 +171,7 @@ def test_collectives_in_shard_map(mesh):
         rs = collectives.reduce_scatter(x, "data")
         return collectives.all_gather(rs, "data")
 
-    fn = compat.shard_map(body, mesh=mesh, in_specs=(P("data"),),
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P("data"),),
                           out_specs=P("data"))
     x = jnp.arange(32, dtype=jnp.float32).reshape(4, 8)
     got = fn(x)
@@ -186,7 +185,7 @@ def test_ppermute_ring(mesh):
     def body(x):
         return collectives.ppermute_ring(x, "data", shift=1)
 
-    fn = compat.shard_map(body, mesh=mesh, in_specs=(P("data"),),
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P("data"),),
                           out_specs=P("data"))
     x = jnp.asarray([[1.0], [2.0]])
     got = np.asarray(fn(x)).reshape(-1)

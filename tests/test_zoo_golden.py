@@ -108,7 +108,6 @@ def test_zoo_topology_matches_golden(name):
 
 if __name__ == "__main__":
     if "--regen" in sys.argv:
-        jax.config.update("jax_platforms", "cpu")
         os.makedirs(os.path.dirname(GOLDEN), exist_ok=True)
         with open(GOLDEN, "w") as f:
             json.dump({name: _topology(b) for name, b in _cases().items()},
