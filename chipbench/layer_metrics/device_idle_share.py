@@ -1,0 +1,9 @@
+"""Share of the traced steady window in which no operation ran on the
+device, in percent. Source: the device trace."""
+
+
+def read(ctx):
+    t = ctx["trace"]
+    if not t or not t["window_s"]:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
