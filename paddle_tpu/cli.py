@@ -93,6 +93,19 @@ def _obs_stack(metrics_out=None, flight_dir=None):
     return registry, Tracer(sink=flight.note_span), flight
 
 
+def _timeline_source(timeline):
+    """The training timeline as a registry source: its counters by
+    name and each span's summary as `<span>.<total_s|count|...>`."""
+    def read():
+        out = dict(timeline.counters())
+        for name, row in timeline.summary().items():
+            for key, value in row.items():
+                out[f"{name}.{key}"] = value
+        return out
+
+    return read
+
+
 def _write_metrics(registry, path: str) -> None:
     """Export a registry snapshot: .json/.jsonl gets the JSON-lines
     form, anything else Prometheus text exposition."""
@@ -371,15 +384,20 @@ def cmd_train(args) -> int:
     # explicit --num-passes wins over the config's num_passes
     num_passes = (args.num_passes if args.num_passes is not None
                   else cfg.get("num_passes", 1))
+    # obs stack only when asked: flight dumps land beside the
+    # checkpoints (ResilientTrainer's flight_dir default)
+    registry, tracer, flight = _obs_stack(args.metrics_out)
+    if registry is not None:
+        # the loop and the feeder time themselves whether or not anyone
+        # asked (obs.trace.Timeline); this is the operator's reading
+        registry.register_source("train_timeline",
+                                 _timeline_source(trainer.timeline))
     if args.checkpoint_dir:
         # fault-tolerant path: auto-restore + preemption drain +
         # divergence guard + optional watchdog (docs/RELIABILITY.md)
         from paddle_tpu.train.resilience import (Preempted,
                                                  ResilientTrainer)
 
-        # obs stack only when asked: flight dumps land beside the
-        # checkpoints (ResilientTrainer's flight_dir default)
-        registry, tracer, flight = _obs_stack(args.metrics_out)
         manager = step_builder = None
         if zero_mesh is not None:
             # reshard-on-restore: a ZeRO checkpoint written at one
@@ -420,6 +438,7 @@ def cmd_train(args) -> int:
             state = trainer.train(
                 state, batches, num_passes=num_passes,
                 event_handler=handler)
+        _write_metrics(registry, args.metrics_out)
     if args.save_dir:
         import os
 
@@ -1186,9 +1205,10 @@ def build_parser() -> argparse.ArgumentParser:
     t.add_argument("--metrics-out", default=None,
                    help="write an obs metrics snapshot here at exit "
                         "(.json/.jsonl -> JSON lines, else Prometheus "
-                        "text); with --checkpoint-dir also enables "
-                        "step tracing + the flight recorder "
-                        "(docs/OBSERVABILITY.md)")
+                        "text), the training timeline's spans and "
+                        "counters among it (train_timeline_*); with "
+                        "--checkpoint-dir also enables step tracing + "
+                        "the flight recorder (docs/OBSERVABILITY.md)")
     t.add_argument("--compile-cache", default=None, metavar="DIR",
                    help="persistent XLA compile-cache directory "
                         "(default <checkout>/.jax_cache; "

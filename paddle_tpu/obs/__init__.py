@@ -10,7 +10,8 @@ keys):
                   exported metrics and `reconcile()` invariants read
                   the same numbers.
   - `trace`     — per-request / per-step spans with exactly-once
-                  terminal outcomes.
+                  terminal outcomes (`Tracer`), and the always-on
+                  interval recorder of the training loop (`Timeline`).
   - `flight`    — ring-buffer flight recorder, dumped on faults
                   (replica death, breaker-open, divergence rollback,
                   SIGTERM, steady-state recompiles).
@@ -24,11 +25,12 @@ from paddle_tpu.obs.flight import (FlightRecorder, get_default,
 from paddle_tpu.obs.registry import (Counter, Gauge, Histogram,
                                      MetricsRegistry, default_registry,
                                      sanitize_value)
-from paddle_tpu.obs.trace import Span, Tracer
+from paddle_tpu.obs.trace import (Span, Timeline, Tracer,
+                                  default_timeline)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "default_registry", "sanitize_value",
-    "Span", "Tracer",
+    "Span", "Tracer", "Timeline", "default_timeline",
     "FlightRecorder", "get_default", "peek_default", "set_default",
 ]

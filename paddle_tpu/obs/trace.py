@@ -1,4 +1,15 @@
-"""Span tracing: per-request / per-step audit trail.
+"""Span tracing: the per-request / per-step audit trail (`Tracer`)
+and the hot loop's interval recorder (`Timeline`).
+
+Two recorders for two jobs. `Tracer` answers "what happened to request
+rr7": one mutable span per id, point events, exactly one terminal
+outcome, a lock and a dict per span. `Timeline` answers "where did the
+last steps' time go": a bounded ring of closed intervals, no lock, no
+ids, about a microsecond a span, always on. Use `Tracer` where a unit
+of work has an identity and an outcome to audit; use `Timeline` inside
+a loop that runs every step.
+
+The audit trail first.
 
 Request-ids are minted once — at `ServingRouter.submit` (`rr<N>`) or
 by a standalone `ServingServer` (`req<N>`) — and the id rides the
@@ -24,12 +35,13 @@ must not take the server down); the second end is recorded in
 
 from __future__ import annotations
 
+import bisect
 import collections
 import threading
 import time
 from typing import Callable, Deque, Dict, List, Optional
 
-__all__ = ["Span", "Tracer"]
+__all__ = ["Span", "Tracer", "Timeline", "default_timeline"]
 
 #: finished spans kept in the tracer ring (flight recorder keeps its
 #: own, possibly longer, ring)
@@ -90,8 +102,9 @@ class Span:
 
 
 class Tracer:
-    """Mints and finishes spans; forwards finished spans to an
-    optional sink (the flight recorder's `note_span`).
+    """Mints and finishes audit spans; forwards finished spans to an
+    optional sink (the flight recorder's `note_span`). For intervals
+    of a hot loop (no id, no outcome) use `Timeline`.
 
     Live spans are indexed by trace_id so instrumentation points deep
     in the stack (PagePool hooks, pserver client) can attach events
@@ -205,3 +218,149 @@ class Tracer:
                 "double_ends": self.double_ends,
                 "late_events": self.late_events,
             }
+
+
+# -- the step timeline -----------------------------------------------------
+
+#: rows the timeline ring keeps: a 20 s window of the image cell is
+#: about 115 steps of 10 rows
+TIMELINE_KEEP = 8192
+
+
+class _Stacks(threading.local):
+    """Each thread's stack of the names of its open spans."""
+
+    def __init__(self):
+        self.stack: List[str] = []
+
+
+class _OpenInterval:
+    """One `with timeline.span(...)`: pushes its name on the thread's
+    stack, and appends the closed row on the way out, error or not."""
+
+    __slots__ = ("_tl", "_name", "_seq", "_stack", "_start", "_keep")
+
+    def __init__(self, tl: "Timeline", name: str, seq: Optional[int]):
+        self._tl, self._name, self._seq = tl, name, seq
+        self._keep = True
+
+    def discard(self) -> None:
+        """Close without a row: for the `next()` that found the end of
+        its iterator, which is no batch's interval."""
+        self._keep = False
+
+    def __enter__(self) -> "_OpenInterval":
+        self._stack = self._tl._open.stack
+        self._stack.append(self._name)
+        self._start = self._tl.clock_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        end = self._tl.clock_ns()
+        stack = self._stack
+        stack.pop()
+        if self._keep:
+            self._tl._rows.append(
+                (self._name, self._start, end, self._seq,
+                 stack[-1] if stack else None))
+        return False
+
+
+class Timeline:
+    """Interval recorder for a hot loop: a bounded ring of closed rows
+    `(name, start_ns, end_ns, seq, parent)` plus named counters.
+
+    `name` is the span, `seq` the batch ordinal that every span of one
+    batch shares, `parent` the name of the span that encloses it on the
+    same thread (`None` at the root). The clock is
+    `time.perf_counter_ns` unless injected: the clock of the chip
+    benchmark's own spans, which a traced run ties to the device clock,
+    so these rows and the device's idle gaps lie on one timeline.
+
+    The record path takes no lock (`deque.append` is atomic; a counter
+    belongs to the one thread that counts it), imports no jax, holds no
+    device value, and closes its span in `__exit__` whatever the body
+    raised. Reading (`rows`, `counters`, `summary`) copies first and
+    may run on any thread. For per-id audit spans with outcomes use
+    `Tracer`.
+    """
+
+    def __init__(self, *, clock_ns: Optional[Callable[[], int]] = None,
+                 keep: int = TIMELINE_KEEP):
+        self.clock_ns = (clock_ns if clock_ns is not None
+                         else time.perf_counter_ns)
+        self._rows: Deque[tuple] = collections.deque(maxlen=keep)
+        self._counters: Dict[str, int] = {}
+        self._open = _Stacks()
+
+    # -- record ------------------------------------------------------------
+
+    def span(self, name: str, seq: Optional[int] = None) -> _OpenInterval:
+        """`with timeline.span("trainer.dispatch", batch_id): ...`"""
+        return _OpenInterval(self, name, seq)
+
+    def count(self, name: str, n: int = 1) -> None:
+        """Add `n` to counter `name`. One thread owns each name."""
+        self._counters[name] = self._counters.get(name, 0) + n
+
+    # -- read --------------------------------------------------------------
+
+    def rows(self, since_ns: Optional[int] = None) -> List[tuple]:
+        """The ring's rows in the order they closed, or those that
+        started at or after `since_ns`."""
+        rows = list(self._rows)
+        if since_ns is None:
+            return rows
+        return [r for r in rows if r[1] >= since_ns]
+
+    def counters(self) -> Dict[str, int]:
+        return dict(self._counters)
+
+    def summary(self, since_ns: Optional[int] = None
+                ) -> Dict[str, Dict[str, float]]:
+        """Per span name `total_s / count / mean_ms / max_ms / self_s`:
+        the per-scope timer table of a pass. Self time is a span's
+        duration minus what the rows that name it as `parent`, and lie
+        inside it, cover."""
+        rows = self.rows(since_ns)
+        out: Dict[str, Dict[str, float]] = {}
+        by_name: Dict[str, List[tuple]] = {}
+        for name, start, end, _seq, _parent in rows:
+            e = out.setdefault(name, {"total_s": 0.0, "count": 0,
+                                      "max_ms": 0.0, "self_s": 0.0})
+            dur = end - start
+            e["total_s"] += dur / 1e9
+            e["self_s"] += dur / 1e9
+            e["count"] += 1
+            e["max_ms"] = max(e["max_ms"], dur / 1e6)
+            by_name.setdefault(name, []).append((start, end))
+        starts = {}
+        for name, spans in by_name.items():
+            spans.sort()
+            starts[name] = [s for s, _ in spans]
+        for _name, start, end, _seq, parent in rows:
+            spans = by_name.get(parent)
+            if not spans:
+                continue
+            i = bisect.bisect_right(starts[parent], start) - 1
+            if i >= 0 and end <= spans[i][1]:
+                out[parent]["self_s"] -= (end - start) / 1e9
+        for e in out.values():
+            e["mean_ms"] = 1e3 * e["total_s"] / e["count"]
+        return dict(sorted(out.items()))
+
+
+_default_timeline: Optional[Timeline] = None
+_default_timeline_lock = threading.Lock()
+
+
+def default_timeline() -> Timeline:
+    """The process-wide timeline `Trainer` and `DataFeeder` record on
+    unless handed another: always on, so the last steps are there for a
+    post-mortem (and for the chip benchmark's readers) whether or not
+    anyone asked beforehand. Tests pass their own."""
+    global _default_timeline
+    with _default_timeline_lock:
+        if _default_timeline is None:
+            _default_timeline = Timeline()
+        return _default_timeline

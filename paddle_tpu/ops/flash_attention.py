@@ -320,9 +320,10 @@ def _flash_fwd(q, k, v, lens_f, causal, block_q, block_k, window):
 
 def _flash_bwd(causal, block_q, block_k, window, res, g):
     q, k, v, lens_f, o, lse = res
-    dq, dk, dv = _blockwise_backward(q, k, v, lens_f, o, lse, g,
-                                     causal=causal, block_k=block_k,
-                                     window=window)
+    with jax.named_scope("flash_attention_bwd"):
+        dq, dk, dv = _blockwise_backward(q, k, v, lens_f, o, lse, g,
+                                         causal=causal, block_k=block_k,
+                                         window=window)
     # lens is carried as f32 so the custom_vjp can hand back an ordinary
     # zero cotangent (int operands would need float0 plumbing)
     return dq, dk, dv, jnp.zeros_like(lens_f)

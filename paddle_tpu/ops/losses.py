@@ -186,6 +186,7 @@ def squared_l2_norm(x):
     return jnp.sum(jnp.square(at_least_f32(x)))
 
 
+@jax.named_scope("fused_ce")
 def chunked_lm_head_nll(hidden, kernel, targets, *, chunk: int = 2048,
                         bias=None):
     """Next-token NLL fused with the LM-head matmul, never holding the

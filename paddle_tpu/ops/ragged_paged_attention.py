@@ -249,6 +249,7 @@ def ragged_pallas(q, k_arena, v_arena, page_table, pos0, active, *,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((r, tq, h, dh), q.dtype),
             interpret=interpret,
+            name="ragged_paged_attention_int8",
         )(page_table.astype(jnp.int32), meta, q, kd, ks, vd, vs)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -268,6 +269,7 @@ def ragged_pallas(q, k_arena, v_arena, page_table, pos0, active, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, tq, h, dh), q.dtype),
         interpret=interpret,
+        name="ragged_paged_attention",
     )(page_table.astype(jnp.int32), meta, q, k_arena, v_arena)
 
 

@@ -35,6 +35,13 @@ class Optimizer:
     init: Callable
     update: Callable  # (grads, opt_state, params, step) -> (params, opt_state)
 
+    def __post_init__(self):
+        # every update, whoever builds it and whoever calls it, traces
+        # under one scope: the device trace's op_name then says which
+        # operations are the optimizer's
+        object.__setattr__(
+            self, "update", jax.named_scope("optimizer")(self.update))
+
     def with_transforms(self, *, weight_decay: float = 0.0,
                         clip_global_norm: Optional[float] = None,
                         clip_value: Optional[float] = None) -> "Optimizer":

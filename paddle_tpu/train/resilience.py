@@ -695,15 +695,17 @@ class ResilientTrainer:
                         self.pserver_client.obs_hook = (
                             lambda event, ctx, _s=span:
                             _s.event(event, **ctx))
-                inputs, labels = self.trainer._split_batch(batch)
-                # device_put the fold data EXPLICITLY: a bare python
-                # int here is an implicit h2d transfer every step
-                # (jax.transfer_guard flags it; analysis.guards)
-                step_rng = jax.random.fold_in(
-                    base_rng, jax.device_put(np.uint32(gidx)))
                 prev_state = state
-                state, loss, metrics = self._step(
-                    state, step_rng, inputs, labels)
+                with self.trainer.timeline.span("trainer.dispatch",
+                                                batch_id):
+                    inputs, labels = self.trainer._split_batch(batch)
+                    # device_put the fold data EXPLICITLY: a bare python
+                    # int here is an implicit h2d transfer every step
+                    # (jax.transfer_guard flags it; analysis.guards)
+                    step_rng = jax.random.fold_in(
+                        base_rng, jax.device_put(np.uint32(gidx)))
+                    state, loss, metrics = self._step(
+                        state, step_rng, inputs, labels)
                 # the guard IS a host sync per step — the price of
                 # detecting divergence before it becomes the checkpoint
                 lossf = float(loss)

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu.nn.module import Layer, merge_state
+from paddle_tpu.obs.trace import Timeline, default_timeline
 from paddle_tpu.optim.optimizers import Optimizer
 from paddle_tpu.train import events as E
 from paddle_tpu.train.state import TrainState
@@ -67,7 +68,8 @@ def make_train_step(
     def fwd_bwd(params, mstate, rng, inputs, labels):
         def compute_loss(p):
             out, new_mstate = apply_model(p, mstate, rng, *inputs)
-            loss = loss_fn(out, *labels)
+            with jax.named_scope("loss"):
+                loss = loss_fn(out, *labels)
             if aux_loss_weight:
                 for path, leaf in jax.tree_util.tree_leaves_with_path(
                         new_mstate):
@@ -169,6 +171,11 @@ class Trainer:
     batches are (inputs, labels) pairs or tuples from a DataFeeder; splitting
     a raw tuple is controlled by num_inputs (first num_inputs entries are
     model inputs, the rest go to the loss).
+
+    timeline: where the loop times itself (obs.trace.Timeline; the
+    process default unless given): one `trainer.step` row an iteration
+    with `trainer.next_batch`, `trainer.dispatch` and `trainer.handler`
+    inside it (docs/OBSERVABILITY.md § Training timeline).
     """
 
     def __init__(
@@ -182,6 +189,7 @@ class Trainer:
         seed: int = 0,
         remat: bool = False,
         aux_loss_weight: float = 0.0,
+        timeline: Optional[Timeline] = None,
     ):
         self.model = model
         self.loss_fn = loss_fn
@@ -193,6 +201,8 @@ class Trainer:
         self.remat = remat
         self.aux_loss_weight = aux_loss_weight
         self._rng = jax.random.key(seed)
+        self.timeline = timeline if timeline is not None \
+            else default_timeline()
         self._train_step = make_train_step(
             model, loss_fn, optimizer, metrics_fn=metrics_fn, remat=remat,
             aux_loss_weight=aux_loss_weight,
@@ -300,33 +310,56 @@ class Trainer:
         N batches (reference: show_parameter_stats_period,
         trainer/TrainerInternal.cpp:186 showParameterStats)."""
         handler = event_handler or (lambda ev: None)
+        tl = self.timeline
+        end = object()
+
+        def periodic(state, pass_id, batch_id):
+            # rare; inside `trainer.step`, so it shows as its self time
+            if (parameter_stats_period
+                    and (batch_id + 1) % parameter_stats_period == 0):
+                from paddle_tpu.metrics.printer import (
+                    format_parameter_stats, parameter_stats)
+
+                print(f"--- parameter stats (pass {pass_id} batch "  # graftlint: disable=GL007(user-facing parameter-stats dump, opt-in via parameter_stats_period)
+                      f"{batch_id}) ---")
+                print(format_parameter_stats(  # graftlint: disable=GL007(user-facing parameter-stats dump, opt-in via parameter_stats_period)
+                    parameter_stats(state.params)))
+            if (checkpoint_manager is not None
+                    and checkpoint_every_n_batches
+                    and (batch_id + 1) % checkpoint_every_n_batches == 0):
+                checkpoint_manager.save(state)
+
         for pass_id in range(num_passes):
             handler(E.BeginPass(pass_id))
-            for batch_id, batch in enumerate(batch_iter_factory()):
-                handler(E.BeginIteration(pass_id, batch_id))
-                inputs, labels = self._split_batch(batch)
-                self._rng, step_rng = jax.random.split(self._rng)
-                state, loss, metrics = self._train_step(
-                    state, step_rng, inputs, labels
-                )
-                # loss/metrics stay ON DEVICE: the event materializes
-                # them only if the handler reads .cost/.metrics, so the
-                # hot loop keeps dispatching asynchronously
-                handler(E.EndIteration(pass_id, batch_id, cost=loss,
-                                       metrics=metrics))
-                if (parameter_stats_period
-                        and (batch_id + 1) % parameter_stats_period == 0):
-                    from paddle_tpu.metrics.printer import (
-                        format_parameter_stats, parameter_stats)
-
-                    print(f"--- parameter stats (pass {pass_id} batch "  # graftlint: disable=GL007(user-facing parameter-stats dump, opt-in via parameter_stats_period)
-                          f"{batch_id}) ---")
-                    print(format_parameter_stats(  # graftlint: disable=GL007(user-facing parameter-stats dump, opt-in via parameter_stats_period)
-                        parameter_stats(state.params)))
-                if (checkpoint_manager is not None
-                        and checkpoint_every_n_batches
-                        and (batch_id + 1) % checkpoint_every_n_batches == 0):
-                    checkpoint_manager.save(state)
+            # an explicit next(), so that the wait for a batch has an
+            # interval of its own
+            batches = iter(batch_iter_factory())
+            batch_id = 0
+            while True:
+                with tl.span("trainer.step", batch_id) as step_span:
+                    with tl.span("trainer.next_batch", batch_id) as wait:
+                        batch = next(batches, end)
+                        if batch is end:    # no batch: no row
+                            wait.discard()
+                            step_span.discard()
+                            break
+                    with tl.span("trainer.handler", batch_id):
+                        handler(E.BeginIteration(pass_id, batch_id))
+                    with tl.span("trainer.dispatch", batch_id):
+                        inputs, labels = self._split_batch(batch)
+                        self._rng, step_rng = jax.random.split(self._rng)
+                        state, loss, metrics = self._train_step(
+                            state, step_rng, inputs, labels
+                        )
+                    tl.count("trainer.steps")
+                    # loss/metrics stay ON DEVICE: the event materializes
+                    # them only if the handler reads .cost/.metrics, so
+                    # the hot loop keeps dispatching asynchronously
+                    with tl.span("trainer.handler", batch_id):
+                        handler(E.EndIteration(pass_id, batch_id, cost=loss,
+                                               metrics=metrics))
+                    periodic(state, pass_id, batch_id)
+                batch_id += 1
             if (checkpoint_manager is not None
                     and checkpoint_manager.latest_step() != int(state.step)):
                 checkpoint_manager.save(state)

@@ -1,12 +1,5 @@
-"""Support utilities: timers/stats, profiler hooks, numeric debugging."""
+"""Support utilities: numeric debugging, plots, diagrams, importers."""
 
-from paddle_tpu.utils.stats import Stat, global_stat, timer
-from paddle_tpu.utils.profiler import (
-    debug_nans,
-    named_scope,
-    start_trace,
-    stop_trace,
-    trace,
-)
+from paddle_tpu.utils.profiler import debug_nans
 from paddle_tpu.utils.plot import CostCurve
 from paddle_tpu.utils.diagram import model_to_dot

@@ -1,42 +1,17 @@
-"""Profiler + numeric-debug hooks.
+"""Numeric-debug hook.
 
-TPU-native replacements for the reference's profiling/diagnostic aux
-subsystems: hl_profiler_start/end CUDA hooks (reference:
-cuda/include/hl_cuda.h:338-343) -> jax.profiler traces viewable in
-xprof/tensorboard; per-layer named timers (reference:
-gserver/gradientmachines/NeuralNetwork.cpp:260) -> jax.named_scope
-annotations in the compiled HLO; feenableexcept FP trapping (reference:
-trainer/TrainerMain.cpp:49) -> jax debug_nans.
+feenableexcept FP trapping (reference: trainer/TrainerMain.cpp:49) ->
+jax debug_nans. The reference's profiler hooks (hl_profiler_start/end,
+cuda/include/hl_cuda.h:338-343) and per-layer named timers
+(gserver/gradientmachines/NeuralNetwork.cpp:260) are jax's own
+`jax.profiler.start_trace/stop_trace/trace` and `jax.named_scope`,
+called directly; the host side of a step is timed by
+`paddle_tpu.obs.trace.Timeline`.
 """
 
 from __future__ import annotations
 
-import contextlib
-
 import jax
-
-
-def start_trace(log_dir: str):
-    """Begin a profiler trace (view with xprof/tensorboard)."""
-    jax.profiler.start_trace(log_dir)
-
-
-def stop_trace():
-    jax.profiler.stop_trace()
-
-
-@contextlib.contextmanager
-def trace(log_dir: str):
-    start_trace(log_dir)
-    try:
-        yield
-    finally:
-        stop_trace()
-
-
-def named_scope(name: str):
-    """Annotate ops for the profiler (per-layer timer equivalent)."""
-    return jax.named_scope(name)
 
 
 def debug_nans(enable: bool = True):

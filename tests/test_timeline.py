@@ -1,0 +1,445 @@
+"""The step timeline (obs.trace.Timeline) and its wiring into
+`Trainer.train` and `DataFeeder`, and the names the device trace gets
+(named scopes, `pallas_call` names). Counts and orderings only: the
+clock is injected and nothing here is a time."""
+
+import gc
+import itertools
+import sys
+import threading
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu import data, nn, optim
+from paddle_tpu.analysis.guards import RecompileGuard
+from paddle_tpu.nn.module import ShapeSpec
+from paddle_tpu.obs.trace import TIMELINE_KEEP, Timeline
+from paddle_tpu.ops import losses
+from paddle_tpu.train import Trainer, events as E
+from paddle_tpu.train.trainer import make_train_step
+
+BATCH, FEATURES, N_BATCHES = 4, 6, 5
+
+
+def ticking():
+    """A clock whose every reading is 1000 ns after the last, on
+    whichever thread: orderings are exact and no two stamps are equal."""
+    ticks = itertools.count(0, 1000)
+    return lambda: next(ticks)
+
+
+def reader(n_batches=N_BATCHES, fail_at=None):
+    def read():
+        rng = np.random.RandomState(0)
+        for i in range(n_batches * BATCH):
+            if fail_at is not None and i == fail_at * BATCH:
+                raise OSError("disk gone")
+            x = rng.rand(FEATURES).astype(np.float32)
+            yield x, int(x.sum() > FEATURES / 2)
+    return read
+
+
+def small_trainer(tl):
+    model = nn.Sequential([nn.Dense(8, activation="relu"), nn.Dense(2)])
+    loss_fn = lambda lo, la: jnp.mean(losses.softmax_cross_entropy(lo, la))
+    tr = Trainer(model, loss_fn, optim.momentum(0.1), seed=0, timeline=tl)
+    return tr, tr.init_state(ShapeSpec((BATCH, FEATURES)))
+
+
+def named(rows, name):
+    return [r for r in rows if r[0] == name]
+
+
+def feeder_threads():
+    return [t for t in threading.enumerate()
+            if t.name == "paddle_tpu-feeder"]
+
+
+# -- the recorder ------------------------------------------------------------
+
+def test_ring_keeps_the_newest_and_rows_filters_by_start():
+    tl = Timeline(clock_ns=ticking())
+    for i in range(TIMELINE_KEEP + 10):
+        with tl.span("s", i):
+            pass
+    rows = tl.rows()
+    assert len(rows) == TIMELINE_KEEP == 8192
+    assert [r[3] for r in rows] == list(range(10, TIMELINE_KEEP + 10))
+    since = rows[100][1]
+    assert tl.rows(since_ns=since) == rows[100:]
+    assert tl.rows(since_ns=rows[-1][1] + 1) == []
+
+
+def test_rows_carry_seq_and_the_enclosing_span_of_their_thread():
+    tl = Timeline(clock_ns=ticking())
+    with tl.span("outer", 7):
+        with tl.span("inner", 7):
+            pass
+        with tl.span("gone", 7) as s:
+            s.discard()
+    tl.count("things", 3)
+    tl.count("things")
+    assert tl.rows() == [("inner", 1000, 2000, 7, "outer"),
+                         ("outer", 0, 5000, 7, None)]
+    assert tl.counters() == {"things": 4}
+
+
+def test_summary_totals_counts_and_self_time():
+    tl = Timeline(clock_ns=ticking())
+    for seq in range(3):
+        with tl.span("step", seq):          # 6 ticks: 2 in each child,
+            with tl.span("wait", seq):      # ... 1 + 1 + ... its own
+                pass
+            with tl.span("work", seq):
+                with tl.span("leaf", seq):
+                    pass
+    s = tl.summary()
+    assert list(s) == ["leaf", "step", "wait", "work"]
+    assert {k: v["count"] for k, v in s.items()} == dict.fromkeys(s, 3)
+    # step: 0..9000 ns; wait 1000..2000; work 3000..6000... by ticks:
+    # enter step, enter wait, exit wait, enter work, enter leaf, exit
+    # leaf, exit work, exit step = 7 intervals of 1000 ns
+    assert s["step"]["total_s"] == pytest.approx(3 * 7e-6)
+    assert s["wait"]["total_s"] == pytest.approx(3 * 1e-6)
+    assert s["work"]["total_s"] == pytest.approx(3 * 3e-6)
+    assert s["step"]["self_s"] == pytest.approx(3 * (7 - 1 - 3) * 1e-6)
+    assert s["work"]["self_s"] == pytest.approx(3 * 2e-6)
+    assert s["leaf"]["self_s"] == s["leaf"]["total_s"]
+    assert s["step"]["mean_ms"] == pytest.approx(7e-3)
+    assert s["step"]["max_ms"] == pytest.approx(7e-3)
+    # a child whose parent started before `since_ns` takes nothing off
+    # a parent row that is not there
+    first_wait = named(tl.rows(), "wait")[0]
+    late = tl.summary(since_ns=first_wait[1])
+    assert late["step"]["count"] == 2 and late["wait"]["count"] == 3
+    assert late["step"]["self_s"] == pytest.approx(2 * 3e-6)
+
+
+def test_two_threads_lose_no_rows():
+    n = 10_000
+    tl = Timeline(clock_ns=ticking(), keep=2 * n)
+
+    def record(name):
+        for i in range(n):
+            with tl.span(name, i):
+                tl.count(name)
+
+    prev = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=record, args=(name,))
+                   for name in ("a", "b")]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60.0)
+    finally:
+        sys.setswitchinterval(prev)
+    assert not any(t.is_alive() for t in threads)
+    rows = tl.rows()
+    assert len(rows) == 2 * n
+    for name in ("a", "b"):
+        assert [r[3] for r in named(rows, name)] == list(range(n))
+    assert all(r[4] is None for r in rows)      # each thread its own stack
+    assert tl.counters() == {"a": n, "b": n}
+
+
+# -- the feeder ---------------------------------------------------------------
+
+def test_feeder_records_one_row_of_each_span_a_batch():
+    tl = Timeline(clock_ns=ticking())
+    feeder = data.DataFeeder(timeline=tl)
+    batches = list(feeder(data.batch_reader(reader(), BATCH)))
+    assert len(batches) == N_BATCHES
+    rows = tl.rows()
+    for name in ("feeder.read", "feeder.convert", "feeder.queue_put",
+                 "feeder.queue_wait", "feeder.device_put"):
+        assert [r[3] for r in named(rows, name)] == list(range(N_BATCHES))
+        assert all(r[4] is None for r in named(rows, name))
+    c = tl.counters()
+    assert c["feeder.batches"] == N_BATCHES
+    nbytes = BATCH * FEATURES * 4 + BATCH * 8       # float32 x, int64 y
+    assert c["feeder.h2d_bytes"] == N_BATCHES * nbytes == sum(
+        np.asarray(leaf).nbytes for b in batches for leaf in b)
+    assert 0 <= c["feeder.queue_depth_sum"] <= N_BATCHES * feeder.prefetch
+    # of one batch: read, then convert, then the put, then the consumer
+    for seq in range(N_BATCHES):
+        order = [next(r for r in rows if r[0] == name and r[3] == seq)
+                 for name in ("feeder.read", "feeder.convert",
+                              "feeder.queue_put", "feeder.device_put")]
+        assert [r[1] for r in order] == sorted(r[1] for r in order)
+
+
+def test_prefetch_to_device_counts_host_bytes_once():
+    tl = Timeline(clock_ns=ticking())
+    feeder = data.DataFeeder(timeline=tl)
+    ahead = data.feeder.prefetch_to_device(
+        feeder(data.batch_reader(reader(), BATCH)), size=2, timeline=tl)
+    assert len(list(ahead)) == N_BATCHES
+    # the feeder's put and the prefetcher's re-put: two rows a batch,
+    # and the second moved nothing
+    assert len(named(tl.rows(), "feeder.device_put")) == 2 * N_BATCHES
+    assert tl.counters()["feeder.h2d_bytes"] == N_BATCHES * (
+        BATCH * FEATURES * 4 + BATCH * 8)
+
+
+@pytest.mark.parametrize("how", ["closed", "collected"])
+def test_feeder_worker_ends_when_the_consumer_stops_early(how):
+    before = set(feeder_threads())
+    tl = Timeline(clock_ns=ticking())
+    it = data.DataFeeder(timeline=tl)(
+        data.batch_reader(reader(n_batches=10_000), BATCH))
+    next(it)
+    worker, = set(feeder_threads()) - before
+    if how == "closed":
+        it.close()
+    else:
+        del it
+        gc.collect()
+    worker.join(30.0)
+    assert not worker.is_alive()
+    # the put it was blocked in closed its span like any other
+    rows = tl.rows()
+    assert len(named(rows, "feeder.queue_put")) in (
+        len(named(rows, "feeder.convert")),
+        len(named(rows, "feeder.convert")) - 1)
+
+
+# -- the trainer --------------------------------------------------------------
+
+def test_trainer_step_rows_frame_their_children():
+    tl = Timeline(clock_ns=ticking())
+    tr, state = small_trainer(tl)
+    feeder = data.DataFeeder(timeline=tl)
+    seen = []
+    tr.train(state, lambda: feeder(data.batch_reader(reader(), BATCH)),
+             num_passes=2, event_handler=seen.append)
+    rows = tl.rows()
+    steps = named(rows, "trainer.step")
+    assert [r[3] for r in steps] == 2 * list(range(N_BATCHES))
+    assert tl.counters()["trainer.steps"] == 2 * N_BATCHES == sum(
+        isinstance(ev, E.EndIteration) for ev in seen)
+    assert all(r[4] is None for r in steps)
+    for name, parent, per_step in (
+            ("trainer.next_batch", "trainer.step", 1),
+            ("trainer.dispatch", "trainer.step", 1),
+            ("trainer.handler", "trainer.step", 2),
+            ("feeder.queue_wait", "trainer.next_batch", 1),
+            ("feeder.device_put", "trainer.next_batch", 1)):
+        found = named(rows, name)
+        assert len(found) == per_step * len(steps), name
+        assert {r[4] for r in found} == {parent}, name
+    # the worker's rows are roots of their own thread
+    assert {r[4] for r in named(rows, "feeder.convert")} == {None}
+    for step in steps:
+        inside = sorted((r for r in rows if r[4] == "trainer.step"
+                         and step[1] <= r[1] and r[2] <= step[2]),
+                        key=lambda r: r[1])
+        assert [r[0] for r in inside] == [
+            "trainer.next_batch", "trainer.handler", "trainer.dispatch",
+            "trainer.handler"]
+        assert all(r[3] == step[3] for r in inside)
+        for a, b in zip(inside, inside[1:]):        # one after the other
+            assert a[2] < b[1]
+    s = tl.summary()
+    assert s["trainer.step"]["self_s"] < s["trainer.step"]["total_s"]
+
+
+@pytest.mark.parametrize("fault", ["reader", "handler"])
+def test_an_error_closes_every_span_and_reaches_the_caller(fault):
+    tl = Timeline(clock_ns=ticking())
+    tr, state = small_trainer(tl)
+    feeder = data.DataFeeder(timeline=tl)
+
+    class Boom(Exception):
+        pass
+
+    def handler(ev):
+        if isinstance(ev, E.EndIteration) and ev.batch_id == 2:
+            raise Boom("handler")
+
+    if fault == "reader":
+        batches = lambda: feeder(
+            data.batch_reader(reader(fail_at=2), BATCH))
+        with pytest.raises(OSError, match="disk gone"):
+            tr.train(state, batches)
+    else:
+        batches = lambda: feeder(data.batch_reader(reader(), BATCH))
+        with pytest.raises(Boom, match="handler"):
+            tr.train(state, batches, event_handler=handler)
+    rows = tl.rows()
+    # the iteration that failed is a row like the others, closed
+    assert [r[3] for r in named(rows, "trainer.step")] == [0, 1, 2]
+    assert tl.counters()["trainer.steps"] == (2 if fault == "reader" else 3)
+    if fault == "reader":
+        assert [r[3] for r in named(rows, "feeder.read")] == [0, 1, 2]
+        # the wait that met the end of the feed is no batch's row
+        assert [r[3] for r in named(rows, "feeder.queue_wait")] == [0, 1]
+    with tl.span("after") as s:         # nothing left open on this thread
+        pass
+    assert named(tl.rows(), "after")[0][4] is None
+
+
+def test_loop_is_clean_under_transfer_guard_and_compiles_nothing_more():
+    tl = Timeline(clock_ns=ticking())
+    tr, state = small_trainer(tl)
+    feeder = data.DataFeeder(timeline=tl)
+    batches = lambda: feeder(data.batch_reader(reader(), BATCH))
+    read = []
+    handler = lambda ev: read.append(
+        float(ev.cost) if isinstance(ev, E.EndIteration) else None)
+    state = tr.train(state, batches, event_handler=handler)     # warm-up
+    with jax.transfer_guard_host_to_device("disallow"), \
+            RecompileGuard(name="timed train loop") as g:
+        tr.train(state, batches, event_handler=handler)
+    assert g.compiles == 0
+    assert len(named(tl.rows(), "trainer.step")) == 2 * N_BATCHES
+
+
+def test_cmd_train_metrics_out_exports_the_timeline(tmp_path, capsys):
+    from test_cli import CONFIG
+
+    from paddle_tpu.cli import main
+
+    cfg = tmp_path / "config.py"
+    cfg.write_text(CONFIG)
+    out = tmp_path / "metrics.prom"
+    assert main(["train", "--config", str(cfg), "--batch-size", "32",
+                 "--num-passes", "1", "--metrics-out", str(out)]) == 0
+    capsys.readouterr()
+    series = dict(line.rsplit(" ", 1) for line in
+                  out.read_text().splitlines() if not line.startswith("#"))
+    assert float(series["train_timeline_trainer_steps"]) >= 4
+    assert float(series["train_timeline_feeder_batches"]) >= 4
+    for span in ("trainer_step", "trainer_next_batch", "trainer_dispatch",
+                 "trainer_handler", "feeder_read", "feeder_convert",
+                 "feeder_queue_put", "feeder_queue_wait",
+                 "feeder_device_put"):
+        assert float(series[f"train_timeline_{span}_count"]) >= 4
+        assert f"train_timeline_{span}_self_s" in series
+
+
+# -- names on the device -------------------------------------------------------
+
+def _op_names(lowered) -> str:
+    return lowered.as_text(debug_info=True)
+
+
+def _train_step_text():
+    model = nn.Sequential([nn.Dense(8, activation="relu"), nn.Dense(2)])
+    loss_fn = lambda lo, la: jnp.mean(losses.softmax_cross_entropy(lo, la))
+    opt = optim.adam(1e-3).with_transforms(clip_global_norm=1.0)
+    step = make_train_step(model, loss_fn, opt, donate=False)
+    tr = Trainer(model, loss_fn, opt, timeline=Timeline())
+    state = tr.init_state(ShapeSpec((BATCH, FEATURES)))
+    return _op_names(step.lower(
+        state, jax.random.key(0), jnp.ones((BATCH, FEATURES)),
+        jnp.zeros((BATCH,), jnp.int32)))
+
+
+def _bare_adam_text():
+    opt = optim.adam(1e-3)
+    params = {"w": jnp.ones((3,))}
+    return _op_names(jax.jit(opt.update).lower(
+        params, opt.init(params), params, jnp.asarray(0, jnp.int32)))
+
+
+def _fused_ce_text():
+    f = lambda h, k, t: losses.chunked_lm_head_nll(h, k, t, chunk=8).sum()
+    return _op_names(jax.jit(jax.grad(f)).lower(
+        jnp.ones((2, 8, 4)), jnp.ones((4, 16)),
+        jnp.zeros((2, 8), jnp.int32)))
+
+
+def _flash_bwd_text():
+    from paddle_tpu.ops.flash_attention import flash_attention
+
+    q = jnp.ones((1, 256, 2, 64))
+    f = lambda q: flash_attention(q, q, q, causal=True).sum()
+    return _op_names(jax.jit(jax.grad(f)).lower(q))
+
+
+@pytest.mark.parametrize("build,scope", [
+    (_train_step_text, "/optimizer/"),
+    (_train_step_text, "(loss)/"),          # jvp(loss), transpose(jvp(loss))
+    (_bare_adam_text, "jit(update)/optimizer/"),
+    (_fused_ce_text, "transpose(jvp(fused_ce))/"),
+    (_flash_bwd_text, "transpose(jvp(flash_attention_bwd))/"),
+], ids=["train_step-optimizer", "train_step-loss", "bare_adam-optimizer",
+        "fused_ce", "flash_attention_bwd"])
+def test_scope_is_in_the_lowered_op_names(build, scope):
+    assert scope in build()
+
+
+def _rnn_grad_jaxpr(run, init):
+    from paddle_tpu.ops import rnn
+
+    p = init(jax.random.key(0), 128, 128)
+    x = jnp.ones((8, 4, 128))
+    lens = jnp.full((8,), 4, jnp.int32)
+    loss = lambda p, x: run(p, x, lens, impl="pallas")[0].sum()
+    return str(jax.make_jaxpr(jax.grad(loss))(p, x))
+
+
+def _ragged_jaxpr(quantized):
+    from paddle_tpu.ops import ragged_paged_attention as RPA
+
+    rows, page, hkv, dh, pages = 2, 4, 2, 8, 6
+    q = jnp.ones((rows, 1, 2, dh))
+    arena = jnp.ones((pages, page, hkv, dh))
+    if quantized:
+        arena = (jnp.ones((pages, page, hkv, dh), jnp.int8),
+                 jnp.ones((pages, page, hkv)))
+    table = jnp.zeros((rows, 3), jnp.int32)
+    return str(jax.make_jaxpr(
+        lambda *a: RPA.ragged_pallas(*a, page_size=page, max_len=12))(
+            q, arena, arena, table, jnp.zeros((rows,), jnp.int32),
+            jnp.ones((rows,), bool)))
+
+
+def _kernel_jaxpr(kernel: str) -> str:
+    from paddle_tpu.ops import rnn
+    from paddle_tpu.ops.flash_attention import flash_attention
+
+    if kernel == "flash_attention_fwd":
+        q = jnp.ones((1, 256, 2, 64))
+        return str(jax.make_jaxpr(
+            lambda q: flash_attention(q, q, q, causal=True))(q))
+    if kernel.startswith("ragged"):
+        return _ragged_jaxpr(kernel.endswith("int8"))
+    run, init = {"gru": (rnn.gru, rnn.init_gru_params),
+                 "lstm": (rnn.lstm, rnn.init_lstm_params),
+                 "rnn": (rnn.simple_rnn, rnn.init_rnn_params)}[
+                     kernel.split("_")[1]]
+    return _rnn_grad_jaxpr(run, init)
+
+
+@pytest.mark.parametrize("kernel", [
+    "flash_attention_fwd", "fused_gru_fwd", "fused_gru_bwd",
+    "fused_lstm_fwd", "fused_lstm_bwd", "fused_rnn_fwd", "fused_rnn_bwd",
+    "ragged_paged_attention", "ragged_paged_attention_int8"])
+def test_every_pallas_call_has_a_name(kernel):
+    """The name reaches the device trace's event name, which is how a
+    roofline reader finds its kernel."""
+    text = _kernel_jaxpr(kernel)
+    assert "pallas_call" in text
+    assert f"name={kernel}\n" in text or f"name={kernel} " in text
+
+
+def test_no_pallas_call_in_ops_is_unnamed():
+    """Nine `pallas_call`s in `ops/`, nine `name=`: a tenth brings its
+    own."""
+    import pathlib
+    import re
+
+    import paddle_tpu.ops as ops
+
+    calls = names = 0
+    for path in pathlib.Path(ops.__file__).parent.glob("*.py"):
+        src = path.read_text()
+        calls += len(re.findall(r"\bpl\.pallas_call\(", src))
+        names += len(re.findall(r"^\s+name=\"\w+\",$", src, re.M))
+    assert calls == names == 9

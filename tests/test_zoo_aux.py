@@ -11,7 +11,7 @@ from paddle_tpu.models import gan as gan_mod, vae as vae_mod
 from paddle_tpu.nn.module import ShapeSpec
 from paddle_tpu.ops import losses
 from paddle_tpu.train import Trainer, events as E
-from paddle_tpu.utils import Stat, global_stat, named_scope, timer
+from paddle_tpu.obs.trace import Timeline
 
 
 # ---- dataset zoo schemas (reference: v2/dataset/*) ----
@@ -188,30 +188,21 @@ def test_vae_trains():
 # ---- stats / profiler / checkgrad ----
 
 def test_stat_timers():
-    s = Stat()
-    with s.timer("fwd"):
+    """The per-scope timer table (reference: utils/Stat.h globalStat)
+    is `Timeline.summary()`."""
+    ticks = iter(range(0, 10_000_000, 1_000_000))
+    s = Timeline(clock_ns=lambda: next(ticks))
+    with s.span("fwd"):
         pass
-    with s.timer("fwd"):
+    with s.span("fwd"):
         pass
-    with s.timer("bwd"):
+    with s.span("bwd"):
         pass
     summ = s.summary()
     assert summ["fwd"]["count"] == 2 and summ["bwd"]["count"] == 1
-    assert "fwd" in s.report()
-    s.reset("fwd")
-    assert "fwd" not in s.summary()
-    with timer("global"):
-        pass
-    assert global_stat.summary()["global"]["count"] >= 1
-
-
-def test_named_scope_compiles():
-    @jax.jit
-    def f(x):
-        with named_scope("layer1"):
-            return x * 2
-
-    assert float(f(jnp.asarray(3.0, jnp.float32))) == 6.0
+    assert summ["fwd"]["total_s"] == 0.002 and summ["fwd"]["mean_ms"] == 1.0
+    assert list(summ) == ["bwd", "fwd"]
+    assert "fwd" not in s.summary(since_ns=4_000_000)
 
 
 def test_trainer_checkgrad():
