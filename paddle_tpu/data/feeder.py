@@ -18,7 +18,7 @@ from typing import Any, Callable, Iterator, Optional
 
 import jax
 
-from paddle_tpu.data.batch import stack_columns
+from paddle_tpu.data.batch import stack_columns_counted
 from paddle_tpu.obs.trace import Timeline, default_timeline
 
 
@@ -40,10 +40,13 @@ def _put(batch, sharding, timeline: Timeline, seq: Optional[int]):
 class DataFeeder:
     """Iterate device-ready batches from a batch-reader.
 
-    convert_fn: list-of-samples -> pytree of np arrays (default: stack
-    tuple columns). sharding: optional jax.sharding.Sharding applied on
-    device_put (the data-parallel split, replacing MultiGradientMachine's
-    per-thread batch slicing, reference: MultiGradientMachine.h:73).
+    convert_fn: list-of-samples -> pytree of np arrays (default:
+    `stack_columns`, which copies a large dense column on several
+    threads; the batches where it did are counted as
+    `feeder.parallel_stacks`). sharding: optional jax.sharding.Sharding
+    applied on device_put (the data-parallel split, replacing
+    MultiGradientMachine's per-thread batch slicing, reference:
+    MultiGradientMachine.h:73).
     timeline: where the feed times itself (obs.trace.Timeline; the
     process default unless given): `feeder.read` / `feeder.convert` /
     `feeder.queue_put` on the worker thread, `feeder.queue_wait` /
@@ -58,17 +61,23 @@ class DataFeeder:
         prefetch: int = 2,
         timeline: Optional[Timeline] = None,
     ):
-        self.convert_fn = convert_fn or stack_columns
+        self.convert_fn = convert_fn
         self.sharding = sharding
         self.prefetch = prefetch
         self.timeline = timeline if timeline is not None \
             else default_timeline()
+
+    def _stack_columns(self, samples):
+        cols, sliced = stack_columns_counted(samples)
+        self.timeline.count("feeder.parallel_stacks", 1 if sliced else 0)
+        return cols
 
     def __call__(self, batch_reader) -> Iterator[Any]:
         end = object()
         q: queue_mod.Queue = queue_mod.Queue(maxsize=self.prefetch)
         errors = []
         tl = self.timeline
+        convert = self.convert_fn or self._stack_columns
         # set when the consumer stops, early or not: the worker then
         # puts nothing more (the consumer drains what a put may be
         # blocked on), so it ends instead of holding its batches
@@ -86,7 +95,7 @@ class DataFeeder:
                     if raw is end:
                         break
                     with tl.span("feeder.convert", seq):
-                        host_batch = self.convert_fn(raw)
+                        host_batch = convert(raw)
                     if stopped.is_set():
                         break
                     with tl.span("feeder.queue_put", seq):

@@ -15,6 +15,7 @@ import pytest
 
 from paddle_tpu import data, nn, optim
 from paddle_tpu.analysis.guards import RecompileGuard
+from paddle_tpu.data import batch as B
 from paddle_tpu.nn.module import ShapeSpec
 from paddle_tpu.obs.trace import TIMELINE_KEEP, Timeline
 from paddle_tpu.ops import losses
@@ -173,6 +174,119 @@ def test_feeder_records_one_row_of_each_span_a_batch():
         assert [r[1] for r in order] == sorted(r[1] for r in order)
 
 
+class ThreadNoting(Timeline):
+    """A timeline that also notes which thread opened each span."""
+
+    def __init__(self, **kw):
+        super().__init__(**kw)
+        self.threads = {}
+
+    def span(self, name, seq=None):
+        self.threads.setdefault(name, set()).add(
+            threading.current_thread().name)
+        return super().span(name, seq)
+
+
+SAMPLE_SIDE = 128      # 128 x 128 float32: `SLICED_MIN_SAMPLE_BYTES`
+
+
+@pytest.fixture
+def large_batches(monkeypatch):
+    """A batch of BATCH samples of the least size counts as a large
+    column, so that `image_reader`'s batches take the sliced copy."""
+    assert SAMPLE_SIDE * SAMPLE_SIDE * 4 == B.SLICED_MIN_SAMPLE_BYTES
+    monkeypatch.setattr(B, "SLICED_MIN_BYTES",
+                        BATCH * B.SLICED_MIN_SAMPLE_BYTES)
+
+
+def image_reader(n_batches=N_BATCHES):
+    pool = np.random.RandomState(0).rand(
+        BATCH, SAMPLE_SIDE, SAMPLE_SIDE).astype(np.float32)
+
+    def read():
+        for i in range(n_batches * BATCH):
+            yield pool[i % BATCH], i % 3
+    return read
+
+
+def stack_threads():
+    return {t for t in threading.enumerate()
+            if t.name.startswith("paddle_tpu-stack")}
+
+
+@pytest.mark.parametrize("columns,sliced", [("large", 1), ("small", 0)])
+def test_feeder_counts_the_batches_it_stacked_in_slices(
+        columns, sliced, large_batches):
+    tl = ThreadNoting(clock_ns=ticking())
+    read = image_reader() if columns == "large" else reader()
+    batches = list(data.DataFeeder(timeline=tl)(
+        data.batch_reader(read, BATCH)))
+    assert len(batches) == N_BATCHES
+    c = tl.counters()
+    assert c["feeder.batches"] == N_BATCHES
+    assert c["feeder.parallel_stacks"] == sliced * N_BATCHES
+    # however the column was copied, the convert is one row a batch, a
+    # root of the worker's thread; the copying threads record nothing
+    converts = named(tl.rows(), "feeder.convert")
+    assert [r[3] for r in converts] == list(range(N_BATCHES))
+    assert all(r[4] is None for r in converts)
+    assert tl.threads["feeder.convert"] == {"paddle_tpu-feeder"}
+    assert not any(name.startswith("paddle_tpu-stack")
+                   for names in tl.threads.values() for name in names)
+    assert len(tl.rows()) == 5 * N_BATCHES
+    if sliced:
+        want = np.stack([x for x, _ in itertools.islice(
+            image_reader()(), BATCH)])
+        for x, y in batches:
+            np.testing.assert_array_equal(np.asarray(x), want)
+
+
+def test_a_custom_convert_fn_is_left_alone_and_counts_nothing(large_batches):
+    tl = Timeline(clock_ns=ticking())
+    seen = []
+
+    def convert(raw):
+        seen.append(len(raw))
+        return B.stack_columns(raw)
+
+    feeder = data.DataFeeder(convert_fn=convert, timeline=tl)
+    assert len(list(feeder(data.batch_reader(image_reader(), BATCH)))) \
+        == N_BATCHES
+    assert seen == [BATCH] * N_BATCHES
+    assert "feeder.parallel_stacks" not in tl.counters()
+
+
+def test_two_feeders_share_the_copying_threads(large_batches):
+    tl_a, tl_b = Timeline(clock_ns=ticking()), Timeline(clock_ns=ticking())
+    a = data.DataFeeder(timeline=tl_a)(
+        data.batch_reader(image_reader(), BATCH))
+    b = data.DataFeeder(timeline=tl_b)(
+        data.batch_reader(image_reader(), BATCH))
+    assert len(list(zip(a, b))) == N_BATCHES    # both feeds in flight
+    assert 1 <= len(stack_threads()) <= B.STACK_SLICES - 1
+    before = stack_threads()
+    list(data.DataFeeder(timeline=tl_a)(
+        data.batch_reader(image_reader(), BATCH)))
+    assert stack_threads() == before        # a third feeder adds none
+    for tl in (tl_a, tl_b):
+        assert tl.counters()["feeder.parallel_stacks"] >= N_BATCHES
+
+
+def test_an_error_in_a_slice_reaches_the_consumer(
+        monkeypatch, large_batches):
+    def failing(out, col, lo, hi):
+        if lo:
+            raise MemoryError("no room for the slice")
+
+    monkeypatch.setattr(B, "_copy_rows", failing)
+    tl = Timeline(clock_ns=ticking())
+    it = data.DataFeeder(timeline=tl)(
+        data.batch_reader(image_reader(), BATCH))
+    with pytest.raises(MemoryError, match="no room for the slice"):
+        next(it)
+    assert len(named(tl.rows(), "feeder.convert")) == 1     # closed
+
+
 def test_prefetch_to_device_counts_host_bytes_once():
     tl = Timeline(clock_ns=ticking())
     feeder = data.DataFeeder(timeline=tl)
@@ -186,12 +300,15 @@ def test_prefetch_to_device_counts_host_bytes_once():
         BATCH * FEATURES * 4 + BATCH * 8)
 
 
+@pytest.mark.parametrize("columns", ["small", "large"])
 @pytest.mark.parametrize("how", ["closed", "collected"])
-def test_feeder_worker_ends_when_the_consumer_stops_early(how):
+def test_feeder_worker_ends_when_the_consumer_stops_early(
+        how, columns, large_batches):
     before = set(feeder_threads())
     tl = Timeline(clock_ns=ticking())
+    read = reader if columns == "small" else image_reader
     it = data.DataFeeder(timeline=tl)(
-        data.batch_reader(reader(n_batches=10_000), BATCH))
+        data.batch_reader(read(n_batches=10_000), BATCH))
     next(it)
     worker, = set(feeder_threads()) - before
     if how == "closed":
@@ -314,6 +431,7 @@ def test_cmd_train_metrics_out_exports_the_timeline(tmp_path, capsys):
                   out.read_text().splitlines() if not line.startswith("#"))
     assert float(series["train_timeline_trainer_steps"]) >= 4
     assert float(series["train_timeline_feeder_batches"]) >= 4
+    assert float(series["train_timeline_feeder_parallel_stacks"]) == 0
     for span in ("trainer_step", "trainer_next_batch", "trainer_dispatch",
                  "trainer_handler", "feeder_read", "feeder_convert",
                  "feeder_queue_put", "feeder_queue_wait",
