@@ -246,7 +246,15 @@ def _attention(cfg: TransformerConfig, q, k, v, causal: bool,
     """key_lens [B] describes RIGHT-padded rows (keys [0, lens[b]) are
     real) and rides the flash kernel's per-row bound; key_mask [B, Tk]
     is an arbitrary mask and forces the dense path. They are two
-    encodings of a mask, not composable — pass exactly one."""
+    encodings of a mask, not composable — pass exactly one.
+
+    q, k, v are cast to the policy's compute dtype here, at the one
+    door of both implementations: a biased `dense` returns float32
+    under a bf16 policy (float32 bias), which no other matmul of the
+    model is handed. Before the expansion, so the head repeat, the
+    transposes, the pad and the kernel's k/v fetches move the narrow
+    bytes too; the caches keep what `_block_parts` returns."""
+    q, k, v = default_policy().cast_to_compute(q, k, v)
     k, v = _expand_kv(q, k, v)
     if key_mask is not None and key_lens is not None:
         raise ValueError("pass key_mask or key_lens, not both — the "
@@ -264,6 +272,7 @@ def _attention(cfg: TransformerConfig, q, k, v, causal: bool,
     if impl == "flash" and key_mask is not None:
         impl = "dense"      # arbitrary masks: the ONE dense path below
     pallas_util.note_traced("transformer.attention", impl)
+    pallas_util.note_traced("transformer.attention.operands", str(q.dtype))
     if impl == "flash":
         if key_lens is not None:
             # right-padded variable-length rows ride the kernel's

@@ -8,6 +8,8 @@ flash and ragged kernels, in seconds and with no chip. The Mosaic
 compile proper and the numerics only happen on a chip
 (benchmarks/kernel_check.py, chip_smoke.py)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -32,36 +34,84 @@ def _as_if_on_one_chip(monkeypatch):
     dtypes.set_default_policy(prev)
 
 
+def _lowered_text(fn, *args) -> str:
+    return jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+
+
 def _lower_for_tpu(fn, *args) -> int:
     """Lower; return how many Mosaic kernels the program holds."""
-    text = jax.jit(fn).trace(*args).lower(
-        lowering_platforms=("tpu",)).as_text()
-    return text.count("tpu_custom_call")
+    return _lowered_text(fn, *args).count("tpu_custom_call")
+
+
+def _kernel_operands(text: str) -> list:
+    """The operand types of every Mosaic call in a lowered program,
+    one list per call: ['tensor<4xi32>', 'tensor<4x256x128xbf16>', ...]."""
+    calls = [ln for ln in text.splitlines() if "@tpu_custom_call" in ln]
+    return [re.findall(r"tensor<[^>]*>",
+                       re.search(r" : \((.*?)\) -> ", ln).group(1))
+            for ln in calls]
 
 
 def _sds(shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-@pytest.mark.parametrize("heads,head_dim,window,lens", [
-    (8, 64, None, False),       # the serving/training width (dim 512)
-    (4, 128, None, False),
-    (8, 64, 512, False),        # sliding-window configs
-    (8, 64, None, True),        # the engine's bucket-padded prefill
+@pytest.mark.parametrize("batch,t,heads,head_dim,window,lens", [
+    (2, 2048, 8, 64, None, False),   # the serving/training width (dim 512)
+    (2, 2048, 4, 128, None, False),
+    (2, 2048, 8, 64, 512, False),    # sliding-window configs
+    (2, 2048, 8, 64, None, True),    # the engine's bucket-padded prefill
+    # starcoder2_3b_l4.train_seq4k: BH 48, 4095 padded to 4096, window inert
+    (2, 4095, 24, 128, 4096, False),
 ])
-def test_flash_fwd_bwd_lowers(heads, head_dim, window, lens):
+def test_flash_fwd_bwd_lowers(batch, t, heads, head_dim, window, lens):
+    """float32 q, k, v under the bf16 policy: what a biased qkv
+    projection hands `_attention` (float32 bias promotes the product).
+    The kernel must still get the policy's bf16."""
+    dtypes.set_default_policy(dtypes.bf16_compute_policy())
     cfg = T.TransformerConfig(vocab=128, dim=heads * head_dim,
                               n_heads=heads, n_layers=1,
                               attn_impl="auto", attn_window=window)
-    x = _sds((2, 2048, heads, head_dim), jnp.bfloat16)
-    key_lens = jnp.asarray([2048, 300], jnp.int32) if lens else None
+    x = _sds((batch, t, heads, head_dim), jnp.float32)
+    key_lens = jnp.asarray([t, 300], jnp.int32) if lens else None
 
     def loss(q, k, v):
         o = T._attention(cfg, q, k, v, causal=True, key_lens=key_lens)
         return jnp.sum(o.astype(jnp.float32))
 
+    text = _lowered_text(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
     # one kernel: the forward (the backward is blockwise jnp)
-    assert _lower_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), x, x, x) == 1
+    (operands,) = _kernel_operands(text)
+    bh, t_pad = batch * heads, -(-t // 256) * 256
+    assert operands == [f"tensor<{bh}xi32>"] + [
+        f"tensor<{bh}x{t_pad}x{head_dim}xbf16>"] * 3
+
+
+@pytest.mark.parametrize("bf16", [True, False])
+def test_model_hands_flash_the_policys_dtype(bf16):
+    """The consumer's real path: grad of `T.loss` on the default block,
+    whose qkv, proj, fc1 and fc2 all carry float32 biases. Under the
+    bf16 policy every tensor operand of the flash kernel is bf16, as
+    the policy promises for every matmul's inputs."""
+    if bf16:
+        dtypes.set_default_policy(dtypes.bf16_compute_policy())
+    # `jax.checkpoint` keeps its traces by (function, static args,
+    # avals) and cannot see the policy: block 2's float32 input under
+    # bf16 would hand the float32 case the bf16 case's trace
+    jax.clear_caches()
+    cfg = T.TransformerConfig(vocab=128, dim=256, n_heads=2, n_kv_heads=1,
+                              n_layers=2, attn_impl="auto", remat=True)
+    params = jax.eval_shape(lambda: T.init_params(jax.random.key(0), cfg))
+    assert params["blocks"][0]["qkv"]["bias"].dtype == jnp.float32
+    text = _lowered_text(jax.grad(lambda p, toks: T.loss(p, cfg, toks)),
+                         params, _sds((2, 257), jnp.int32))
+    calls = _kernel_operands(text)
+    assert len(calls) == 4      # 2 layers, each again under remat
+    want = "bf16" if bf16 else "f32"
+    for operands in calls:
+        assert operands == ["tensor<4xi32>"] + [
+            f"tensor<4x256x128x{want}>"] * 3
 
 
 @pytest.mark.parametrize("name,run,init,hidden,t", [

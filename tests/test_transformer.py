@@ -12,7 +12,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paddle_tpu.core import dtypes
 from paddle_tpu.models import transformer as T
+from paddle_tpu.ops import pallas_util
+from paddle_tpu.ops.flash_attention import flash_attention
 
 
 CFG = T.TransformerConfig(vocab=61, dim=32, n_layers=2, n_heads=4,
@@ -1242,3 +1245,108 @@ class TestInt8KVCache:
             T.generate(params,
                        dataclasses.replace(CFG, kv_cache_dtype="fp4"),
                        prompt, steps=2)
+
+
+# ---- `_attention` hands both implementations the policy's compute dtype
+
+@pytest.fixture(params=["float32", "bfloat16"])
+def policy(request):
+    """Each compute policy in turn, the default restored afterwards."""
+    old = dtypes.default_policy()
+    if request.param == "bfloat16":
+        dtypes.set_default_policy(dtypes.bf16_compute_policy())
+    yield request.param
+    dtypes.set_default_policy(old)
+
+
+def _gqa_qkv(seed=0, b=2, t=48, h=4, hkv=2, d=16):
+    """float32 q, k, v with compact K/V heads: what `_block_parts`
+    hands `_attention` under either policy (a float32 bias promotes the
+    biased qkv projection)."""
+    rs = np.random.RandomState(seed)
+    return (jnp.asarray(rs.randn(b, t, h, d), jnp.float32),
+            jnp.asarray(rs.randn(b, t, hkv, d), jnp.float32),
+            jnp.asarray(rs.randn(b, t, hkv, d), jnp.float32))
+
+
+def _attn_cfg(impl, window=None):
+    return T.TransformerConfig(vocab=61, dim=64, n_layers=1, n_heads=4,
+                               n_kv_heads=2, attn_impl=impl,
+                               attn_window=window)
+
+
+@pytest.mark.parametrize("impl", ["dense", "flash"])
+def test_attention_output_is_in_the_compute_dtype(policy, impl):
+    q, k, v = _gqa_qkv()
+    out = T._attention(_attn_cfg(impl), q, k, v, causal=True)
+    assert out.dtype == jnp.dtype(policy)
+    assert out.shape == q.shape
+
+
+@pytest.mark.parametrize("window", [None, 16])
+def test_attention_impls_agree_under_each_policy(policy, window):
+    q, k, v = _gqa_qkv(1)
+    dense = T._attention(_attn_cfg("dense", window), q, k, v, causal=True)
+    flash = T._attention(_attn_cfg("flash", window), q, k, v, causal=True)
+    tol = 3e-2 if policy == "bfloat16" else 2e-5
+    np.testing.assert_allclose(np.asarray(flash, np.float32),
+                               np.asarray(dense, np.float32),
+                               rtol=tol, atol=tol)
+    if policy == "bfloat16":
+        # and both stay within bf16 round-off of the float32 result
+        dtypes.set_default_policy(dtypes.Policy())
+        exact = T._attention(_attn_cfg("dense", window), q, k, v,
+                             causal=True)
+        np.testing.assert_allclose(np.asarray(flash, np.float32),
+                                   np.asarray(exact), rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("impl", ["dense", "flash"])
+def test_attention_cotangents_keep_the_inputs_dtype(policy, impl):
+    """The cast's transpose returns float32 cotangents to the float32
+    projection whatever the policy; under bf16 they stay within its
+    round-off of the float32 policy's."""
+    q, k, v = _gqa_qkv(2)
+
+    def loss(q, k, v):
+        out = T._attention(_attn_cfg(impl), q, k, v, causal=True)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    grads = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    for g, x in zip(grads, (q, k, v)):
+        assert g.dtype == jnp.float32 and g.shape == x.shape
+    if policy == "bfloat16":
+        dtypes.set_default_policy(dtypes.Policy())
+        exact = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        for g, e in zip(grads, exact):
+            assert float(jnp.max(jnp.abs(g - e))) <= 3e-2 * float(
+                jnp.max(jnp.abs(e)))
+
+
+@pytest.mark.parametrize("impl,window", [("dense", None), ("dense", 16),
+                                         ("flash", None), ("flash", 16)])
+def test_attention_is_bit_equal_under_the_default_policy(impl, window):
+    """float32 policy: the cast is the identity, so `_attention` is the
+    implementation called directly on the expanded heads."""
+    q, k, v = _gqa_qkv(3)
+    out = T._attention(_attn_cfg(impl, window), q, k, v, causal=True)
+    ke, ve = T._expand_kv(q, k, v)
+    if impl == "dense":
+        direct = T._dense_attention(q, ke, ve, True, None, window)
+    else:
+        direct = flash_attention(q, ke, ve, causal=True, window=window)
+    assert out.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(direct))
+
+
+def test_attention_notes_its_operands_dtype(policy, params):
+    """The counter a chip run prints: which dtype the attention's
+    matmuls were traced with, once per layer."""
+    key = f"transformer.attention.operands={policy}"
+    toks = jnp.zeros((2, 9), jnp.int32)
+    before = pallas_util.traced()
+    jax.make_jaxpr(lambda p, t: T.loss(p, CFG, t))(params, toks)
+    after = pallas_util.traced()
+    grew = {k for k in after if after[k] != before.get(k, 0)}
+    assert grew == {key, "transformer.attention=dense"}
+    assert after[key] - before.get(key, 0) == CFG.n_layers
