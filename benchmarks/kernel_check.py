@@ -149,7 +149,8 @@ def main() -> int:
     policies = (dtypes.bf16_compute_policy(), dtypes.Policy())
     for policy in policies:
         checks += [
-            # seq2seq-attention encoder/decoder cell (suite.bench_seq2seq)
+            # seq2seq-attention encoder/decoder cell (ROADMAP workload
+            # 4: hidden 512, batch 64, 30 tokens)
             functools.partial(check_rnn, "fused_gru", rnn.gru,
                               rnn.init_gru_params, hidden=512, b=64, t=30,
                               policy=policy),
@@ -157,7 +158,7 @@ def main() -> int:
                               rnn.init_rnn_params, hidden=512, b=64, t=100,
                               policy=policy),
         ]
-    # the LSTM text classifier (suite.bench_lstm), h256 then h512, last
+    # the LSTM text classifier (ROADMAP workload 4), h256 then h512, last
     checks += [
         functools.partial(check_rnn, "fused_lstm", rnn.lstm,
                           rnn.init_lstm_params, hidden=hidden, b=64, t=100,

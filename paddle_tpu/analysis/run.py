@@ -49,8 +49,7 @@ def _default_paths() -> List[str]:
     (tests included — discipline is repo-wide; a sloppy test is how
     the next engineer learns the sloppy idiom)."""
     out = [_PKG_ROOT]
-    for name in ("tests", "examples", "benchmarks", "scripts",
-                 "bench.py"):
+    for name in ("tests", "examples", "benchmarks", "scripts"):
         p = os.path.join(_REPO_ROOT, name)
         if os.path.exists(p):
             out.append(p)

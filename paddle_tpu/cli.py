@@ -10,7 +10,6 @@ Subcommands:
   infer        — run a compiled artifact on .npy inputs
   serve        — continuous-batching LM serving (token ids in/out)
   master       — serve a task-queue master over a recordio dataset
-  bench        — run the benchmark entry
 
 A config script is a Python file defining `get_config()` returning a dict:
   model      (nn.Layer, required)
@@ -1119,18 +1118,6 @@ def _exists(p: str) -> bool:
     return os.path.exists(p)
 
 
-def cmd_bench(_args) -> int:
-    import os
-    import runpy
-
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "bench.py")
-    if not _exists(bench):
-        raise SystemExit("bench.py not found beside the package")
-    runpy.run_path(bench, run_name="__main__")
-    return 0
-
-
 def cmd_make_diagram(args) -> int:
     from paddle_tpu.utils.diagram import model_to_dot
 
@@ -1404,8 +1391,6 @@ def build_parser() -> argparse.ArgumentParser:
         "schema",
         help="validate the metrics-export schema (exit 1 on drift)")
     ob.set_defaults(fn=cmd_obs)
-
-    sub.add_parser("bench").set_defaults(fn=cmd_bench)
 
     md = sub.add_parser(
         "make-diagram",

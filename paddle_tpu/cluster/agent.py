@@ -25,7 +25,7 @@ above the host boundary sees only membership state:
   its writes could not land anyway (the epoch fence refuses them).
 
 Multi-host on one box: N agent processes with distinct fake host-ids
-— exactly how the chaos suite and `bench.py --cluster-only` run it.
+— exactly how the chaos suite (`tests/test_cluster.py`) runs it.
 
 The agent process itself never imports jax (its replica CHILDREN
 do, in their own address spaces).

@@ -24,15 +24,14 @@ it, with three production requirements the raw knobs don't enforce:
   process or a garbage file costs one recompile, not an outage.
 - **Observable.** `install_listeners()` hooks jax.monitoring's
   cache events; `counters()` reports `compile_cache_hits` /
-  `compile_cache_misses` for the obs registry, the serving server,
-  and the cold-start bench (docs/OBSERVABILITY.md).
+  `compile_cache_misses` for the obs registry and the serving
+  server (docs/OBSERVABILITY.md).
 
 Everything the CLI compiles — serve engine bodies, the train step,
 infer forwards — flows through XLA's one compile entry point, so a
-single `enable()` near process start covers all of them. The serving
-cold-start numbers live in `bench.py --serving-only` (cold-start
-stage); docs/SERVING.md "AOT artifacts & compile cache" is the
-operational guide.
+single `enable()` near process start covers all of them.
+docs/SERVING.md "AOT artifacts & compile cache" is the operational
+guide; what a warm cache saved on the chip is in PERF.md (`setup_s`).
 """
 
 from __future__ import annotations

@@ -1,4 +1,4 @@
-"""Per-chip peaks, keyed by `device_kind`, and analytic model FLOPs.
+"""Per-chip peaks, keyed by `device_kind`.
 
 ONE table: every utilization or roofline share in the repo divides by a
 row of `PEAKS`, looked up by the `device_kind` JAX reports for the
@@ -37,13 +37,3 @@ def peaks(device_kind: str) -> ChipPeaks:
             "published peaks with their source before reporting a "
             "utilization on it") from None
 
-
-# Analytic forward GFLOPs per image at 224x224 (2*MACs), for MFU
-# reporting. Train MFU = 3x forward (fwd + ~2x bwd) — remat variants
-# report MODEL-flops MFU like everything else (the recompute FLOPs are
-# implementation cost, not model work).
-FWD_GFLOPS = {
-    "resnet50": 8.2, "resnet50_s2d": 8.2, "resnet50_remat": 8.2,
-    "resnet50_remat_full": 8.2, "vgg19": 39.0,
-    "alexnet": 1.4, "googlenet": 3.0,
-}

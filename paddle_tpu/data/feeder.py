@@ -113,7 +113,7 @@ class DataFeeder:
         try:
             while True:
                 with tl.span("feeder.queue_wait", seq) as wait:
-                    tl.count("feeder.queue_depth_sum", q.qsize())
+                    depth = q.qsize()
                     host_batch = q.get()
                     if host_batch is end:
                         wait.discard()
@@ -121,6 +121,9 @@ class DataFeeder:
                     if errors:
                         raise errors[0]
                     return
+                # the reading before the sentinel's get belongs to no
+                # batch: the sum stays within batches x prefetch
+                tl.count("feeder.queue_depth_sum", depth)
                 tl.count("feeder.batches")
                 yield _put(host_batch, self.sharding, tl, seq)
                 seq += 1

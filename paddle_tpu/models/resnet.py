@@ -3,8 +3,9 @@
 Parity target: the reference's ResNet benchmark config (reference:
 benchmark/paddle/image/resnet.py — layer_num in {50,101,152} built from
 conv_bn_layer + bottleneck/basic blocks; also the model-zoo resnet in
-v1_api_demo/model_zoo/resnet/resnet.py). This is the flagship image model
-the driver benches (BASELINE.json: ResNet-50 imgs/sec/chip).
+v1_api_demo/model_zoo/resnet/resnet.py). This is the image model of
+the benchmark's `resnet50.train_bs256` cell (BASELINE.json: ResNet-50
+imgs/sec/chip).
 
 TPU notes: NHWC keeps the channel dim minor for the MXU; BN statistics are
 computed in f32 while conv math can run bf16 via the dtype policy.
@@ -75,15 +76,15 @@ def resnet(depth: int = 50, num_classes: int = 1000, *, width: int = 64,
 
     s2d_stem=True computes the 7x7/s2 stem on a 2x2 space-to-depth
     blocking of the input — same math, same parameters, but the conv
-    streams C_in=12 instead of 3, which the TPU tiles far better
-    (benchmarks/PROFILE_NOTES.md item 3).
+    streams C_in=12 instead of 3, which the TPU tiles better.
 
     remat wraps every residual block in nn.Remat (same params, same
     math): "conv_out" saves only conv outputs and recomputes BN/ReLU in
-    the backward; "full" saves nothing inside a block. Both REDUCE the
-    HBM bytes each train step streams — the binding resource for this
-    net on TPU (PROFILE_NOTES roofline: 57.6 GiB/step ≈ 7.8 passes over
-    the activation set; the MXU idles at ~39% waiting on those bytes).
+    the backward; "full" saves nothing inside a block. Both reduce the
+    residual bytes a train step keeps for the backward (counted in
+    tests/test_compiled_cost.py). Neither variant nor the s2d stem has
+    been timed on the image cell, whose step is device-bound (PERF.md
+    section 5); ROADMAP A3 decides whether they stay.
     """
     if remat not in (None, "conv_out", "full"):
         raise ValueError(

@@ -88,11 +88,10 @@ class Remat(Layer):
 
     The reference had no activation checkpointing (SURVEY §5 — its
     long-sequence memory grew linearly); on TPU remat is also a
-    BANDWIDTH tool: ResNet-50 training is HBM-bound at ~7.8 passes over
-    the activation set (benchmarks/PROFILE_NOTES.md), so re-computing
-    cheap VPU ops (BN normalize, ReLU) in the backward instead of
-    streaming their saved outputs trades idle MXU FLOPs for the scarce
-    resource, bytes.
+    bandwidth tool: re-computing cheap VPU ops (BN normalize, ReLU) in
+    the backward instead of streaming their saved outputs trades MXU
+    FLOPs for bytes (where the image cell's time goes: PERF.md
+    section 5).
 
     policy:
       None        — save nothing inside the block; the backward re-runs

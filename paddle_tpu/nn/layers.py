@@ -148,27 +148,23 @@ class Conv2D(Layer):
             )
         # inert tag unless an nn.Remat(policy="conv_out") ancestor is
         # active, in which case ONLY these outputs are saved for the
-        # backward (BN/activations recompute — bytes, not FLOPs, bound
-        # conv nets on TPU; benchmarks/PROFILE_NOTES.md)
+        # backward (BN/activations recompute)
         y = checkpoint_name(y, "conv_out")
         return self.activation(y), {}
 
 
 class MaxPool2D(Layer):
     def __init__(self, window=2, *, stride=None, padding="VALID", name=None,
-                 tie_split=None):
+                 tie_split=False):
         self.window = conv_ops._pair(window)
         self.stride = conv_ops._pair(stride if stride is not None else window)
         self.padding = padding
         self.name = name
         # tie_split routes grads through the select-and-scatter-free
-        # custom VJP (ops.conv._max_pool2d_ts). Set False if the layer
-        # must be forward-mode differentiable (jvp/jacfwd): custom_vjp
-        # functions reject jvp. None defers to ops.conv.max_pool2d's
-        # env-read default (PADDLE_TPU_POOL_TIE_SPLIT), read at TRACE
-        # time — one jit compile freezes the choice, so flip the env
-        # only across processes, not between jitted calls in one
-        # process.
+        # custom VJP (ops.conv._max_pool2d_ts); the default keeps the
+        # layer forward-mode differentiable (jvp/jacfwd), which
+        # custom_vjp functions reject, and is the faster backward
+        # (ops.conv.max_pool2d).
         self.tie_split = tie_split
 
     def _out_hw(self, h, w):

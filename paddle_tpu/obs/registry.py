@@ -25,8 +25,8 @@ Design constraints (ISSUE 8 overhead gate):
     label like raw request-ids cannot OOM the server).
 
 Exporters: `to_prometheus()` (text exposition format) and
-`to_jsonl()` (one JSON object per series — the bench stages embed
-these snapshots into `BENCH_*.json`).
+`to_jsonl()` (one JSON object per series: what `--metrics-out`
+appends).
 """
 
 from __future__ import annotations
@@ -362,7 +362,7 @@ class MetricsRegistry:
 
     def to_jsonl(self) -> str:
         """One JSON object per series (plus a trailing meta line) —
-        the form bench stages embed and `--metrics-out` appends."""
+        the form `--metrics-out` appends."""
         snap = self.snapshot()
         lines = [json.dumps({"ts": snap["ts"], **row}, sort_keys=True)
                  for row in snap["series"]]
@@ -388,7 +388,7 @@ _default_lock = threading.Lock()
 
 def default_registry() -> MetricsRegistry:
     """Process-wide registry for call sites with no better scope
-    (CLI, bench). Components under test should take an explicit
+    (the CLI). Components under test should take an explicit
     registry instead — tests then never share state."""
     global _default
     with _default_lock:

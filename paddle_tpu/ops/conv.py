@@ -11,7 +11,6 @@ Layout is NHWC/HWIO (TPU-preferred), not the reference's NCHW.
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional, Sequence, Tuple, Union
 
 import jax
@@ -157,10 +156,10 @@ def conv2d_space_to_depth(
     transform of the input — mathematically IDENTICAL output (the
     kernel is re-laid with s2d_kernel; extra kernel rows are zero).
 
-    Motivation (benchmarks/PROFILE_NOTES.md): a small-C large-spatial
-    conv like ResNet's 7x7/s2 stem on C_in=3 streams mostly padding —
-    the 8-sublane tile is 5/8 zeros and its weight-grad fusion measures
-    406 GiB/s vs ~700 for well-shaped convs. Blocking 2x2 turns
+    Motivation: a small-C large-spatial conv like ResNet's 7x7/s2 stem
+    on C_in=3 streams mostly padding — the 8-sublane tile is 5/8
+    zeros. No chip run on the current stack has timed it (ROADMAP A3
+    decides whether it stays). Blocking 2x2 turns
     [N,224,224,3] into [N,112,112,12] with the same FLOPs. The kernel
     PARAMETER stays in its original [kh,kw,C,O] layout so checkpoints
     and the torch importer are unaffected; the re-lay is a tiny
@@ -255,11 +254,11 @@ def _max_pool2d_raw(x, window, stride, pad2):
 def _max_pool2d_ts(x, window, stride, pad2):
     """Max pool whose VJP splits gradient equally among tied maxima.
 
-    The default VJP of reduce_window is a select-and-scatter — the
-    slowest op family on TPU (1.74 ms of the ResNet-50 step, see
-    benchmarks/PROFILE_NOTES.md). This formulation expresses the
-    backward as per-offset strided slices + compares + dilated pads,
-    which XLA fuses into plain streaming loops. At ties it divides the
+    The default VJP of reduce_window is a select-and-scatter (1.9% of
+    the image cell's busy time: ledger, PR 27). This formulation
+    expresses the backward as per-offset strided slices + compares +
+    dilated pads, which XLA fuses into plain streaming loops (and
+    which measured slower: see max_pool2d). At ties it divides the
     cotangent equally among the tied maxima — a symmetric element of
     the subgradient set, where select-and-scatter picks a single
     winner. (No choice matches central differences at a >2-way tie;
@@ -317,23 +316,20 @@ _max_pool2d_ts.defvjp(_max_pool2d_ts_fwd, _max_pool2d_ts_bwd)
 
 
 def max_pool2d(x, window: IntOr2 = 2, *, stride: Optional[IntOr2] = None,
-               padding="VALID", tie_split: Optional[bool] = None):
+               padding="VALID", tie_split: bool = False):
     """Max pooling (reference: gserver/layers/PoolLayer.cpp MaxPooling,
     paddle/operators/pool_op.cc).
 
     tie_split=True (floats only) routes the gradient through the
     select-and-scatter-free custom VJP above; tie_split=False keeps
     XLA's native pick-first semantics AND forward-mode (jvp/jacfwd)
-    differentiability, which custom_vjp functions reject. The default
-    (None) reads env PADDLE_TPU_POOL_TIE_SPLIT so the two backward
-    formulations can be A/B-benchmarked on the chip without a code
-    edit. Default OFF: an earlier builder's same-protocol A/B on a v5e
-    (resnet bs64: select_and_scatter 28.17 ms vs tie-split 40.18 ms,
-    ROADMAP C5) had the custom VJP cost +43% on the full step, so the
-    default is the faster formulation.
+    differentiability, which custom_vjp functions reject. Default
+    False: an earlier builder's same-protocol A/B on a v5e (resnet
+    bs64: select_and_scatter 28.17 ms vs tie-split 40.18 ms, ROADMAP
+    C5) had the custom VJP cost +43% on the full step, so the default
+    is the faster formulation; the arm exists for gradient-semantics
+    parity (ties split vs pick-first), not speed.
     """
-    if tie_split is None:
-        tie_split = os.environ.get("PADDLE_TPU_POOL_TIE_SPLIT", "0") != "0"
     win = _pair(window)
     strd = _pair(stride if stride is not None else window)
     pad2 = explicit_pad(x.shape[1], x.shape[2], win, strd, padding)

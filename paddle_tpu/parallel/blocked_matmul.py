@@ -90,7 +90,7 @@ def ring_matmul_gather(x_loc, w_loc, *, axis: str, overlap: bool = True):
     block arrive while the previous pair's products run; an even ring
     finishes with a single extra forward hop for the antipodal block.
     overlap=False is the naive arm: all_gather(x) then one matmul —
-    the comm fully serialised before any compute (the bench baseline).
+    the comm fully serialised before any compute (the baseline).
     """
     p = jax.lax.axis_size(axis)
     acc = _acc_dtype(x_loc, w_loc)

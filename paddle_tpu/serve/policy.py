@@ -198,7 +198,7 @@ class SchedulerPolicy:
 
 class RandomRoutingPolicy(SchedulerPolicy):
     """Affinity-blind control arm: route every request to a
-    seeded-uniform random candidate. Exists for the router bench's
+    seeded-uniform random candidate. Exists for the router tests'
     affinity-vs-random prefix-hit comparison — NOT a production
     policy (it scatters hot prefixes across the fleet, so every
     replica pays the prefill the affinity map would have saved)."""

@@ -56,7 +56,7 @@ class QuantizedTensor(NamedTuple):
     scale: jnp.ndarray   # f32
 
 
-# the kernel paths export_decoder / the suite bench / tests all share —
+# the kernel paths export_decoder and the tests share —
 # matmul weights only; the embedding table is deliberately excluded (a
 # gather, not a matmul; its rows feed rope/layernorm where quantization
 # error compounds)

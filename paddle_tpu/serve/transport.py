@@ -486,7 +486,8 @@ class ReplicaClient:
         self._sleep = sleep
         import random
         self._rng = random.Random(seed)
-        # io accounting for the data-plane A/B bench: frames that
+        # io accounting (tests/test_data_plane.py compares the arena's
+        # wire bytes with the pickle path's): frames that
         # completed, and payload bytes either way (headers excluded)
         self.frames = 0
         self.bytes_sent = 0

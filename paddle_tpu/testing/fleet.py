@@ -1,10 +1,10 @@
-"""Fleet test/bench support: replica builders a CHILD PROCESS can
+"""Fleet test support: replica builders a CHILD PROCESS can
 import by name.
 
 A `serve.fleet.ReplicaSpec` carries a `"module:function"` builder
 string across the spawn boundary — the child imports it and calls it
 to construct its `ServingServer`. This module is where the repo's
-own tests and `bench.py --fleet-only` keep those builders:
+own tests keep those builders:
 
 - `build_tiny_server` — the chaos-suite replica: the same tiny
   deterministic transformer every serving test uses (vocab=61,
@@ -50,7 +50,7 @@ def build_tiny_server(*, slots: int = 2, max_len: int = 32,
                       page_size: int = 4, seed: int = 0,
                       max_queue: int = 64, max_retries: int = 1,
                       buckets=(16,), artifact: Optional[str] = None):
-    """Replica builder for fleet tests/bench: tiny deterministic
+    """Replica builder for fleet tests: tiny deterministic
     transformer behind a `ServingServer`. Pass `artifact` (written
     by `save_tiny_artifact` with the SAME seed/geometry/buckets) to
     boot from the AOT bundle — the cheap-replica path autoscaling

@@ -1,11 +1,10 @@
-"""Gang test/bench support: training-job builders a CHILD PROCESS can
+"""Gang test support: training-job builders a CHILD PROCESS can
 import by name.
 
 A `parallel.launch.GangSpec` carries a `"module:function"` builder
 string across the spawn boundary — each gang member imports it and
 calls it to construct its model/loss/optimizer/batch stream. This
-module is where the repo's own tests and `bench.py --elastic-only`
-keep those builders:
+module is where the repo's own tests keep those builders:
 
 - `build_tiny_job` — the chaos-suite trainer job: a tiny deterministic
   MLP classifier with a momentum optimizer (so the ZeRO-sharded

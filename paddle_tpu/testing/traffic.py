@@ -1,6 +1,6 @@
 """Traffic harness for the HTTP front door: realistic load shapes,
 a raw-socket streaming client, closed- and open-loop generators, and
-the SLO report `bench.py --edge-only` gates on.
+the SLO report that rolls a run up.
 
 The shapes replay what production LLM traffic actually looks like
 (ROADMAP item 1 — "heavy traffic from millions of users" as a
@@ -316,8 +316,7 @@ def slo_report(results: Sequence[StreamResult],
                wall_s: float) -> Dict[str, object]:
     """The edge SLO rollup: sustained QPS (completed streams per wall
     second) with client-measured p50/p99 time-to-first-token and
-    p50/p99 inter-token gap, plus the shed/refusal tallies — the
-    numbers `bench.py --edge-only` emits through the obs registry."""
+    p50/p99 inter-token gap, plus the shed/refusal tallies."""
     completed = [r for r in results if r.outcome == "completed"]
     ttfts = sorted(r.ttft_s for r in completed
                    if r.ttft_s is not None)

@@ -21,33 +21,28 @@
 #                                       #   plane lane (lease/epoch
 #                                       #   fencing, agents, standby
 #                                       #   failover, the agent-SIGKILL
-#                                       #   reform chaos case, then
-#                                       #   bench.py --cluster-only)
+#                                       #   reform chaos case)
 #     scripts/fault_smoke.sh elastic    # just the elastic gang-training
 #                                       #   lane (ZeRO parity, reshard
 #                                       #   restore, gang SIGKILL/wedge
-#                                       #   chaos incl. the slow cases,
-#                                       #   then bench.py --elastic-only)
+#                                       #   chaos incl. the slow cases)
 #     scripts/fault_smoke.sh edge       # just the HTTP front-door lane
 #                                       #   (disconnect cancellation,
 #                                       #   overload 429, slow-loris,
 #                                       #   drain, the SIGKILL-under-
-#                                       #   live-HTTP-load chaos case,
-#                                       #   then bench.py --edge-only)
+#                                       #   live-HTTP-load chaos case)
 #     scripts/fault_smoke.sh data       # just the zero-copy data-
 #                                       #   plane lane (shm arena
 #                                       #   SIGKILL source/dst chaos,
 #                                       #   orphan reclaim after
 #                                       #   supervisor death, fallback
-#                                       #   parity, then bench.py
-#                                       #   --data-only)
+#                                       #   parity)
 #     scripts/fault_smoke.sh ctr        # just the embedding-cache
 #                                       #   chaos lane (shard failover
 #                                       #   mid-traffic with the
 #                                       #   staleness bound held,
 #                                       #   reform-mid-stream exactly-
-#                                       #   once, then bench.py
-#                                       #   --ctr-only)
+#                                       #   once)
 #     scripts/fault_smoke.sh -k serve   # just the serving chaos suite
 #
 # CPU-only and deterministic (testing.faults FaultPlan + ManualClock;
@@ -56,57 +51,16 @@
 set -e
 cd "$(dirname "$0")/.."
 marker=faults
-if [ "$1" = "pserver" ] || [ "$1" = "router" ]; then
-    marker=$1
-    shift
-elif [ "$1" = "disagg" ]; then
-    marker="disagg and faults"
-    shift
-elif [ "$1" = "fleet" ]; then
-    marker="fleet and faults"
-    shift
-elif [ "$1" = "cluster" ]; then
-    # the whole multi-host lane, INCLUDING the heavyweight reform
-    # chaos case, then the control-plane latency stage (view
-    # propagation + kill->first recovered completion)
-    shift
-    env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
-        -m "cluster and faults" -p no:cacheprovider "$@"
-    exec env JAX_PLATFORMS=cpu python bench.py --cluster-only
-elif [ "$1" = "edge" ]; then
-    # the whole network-edge lane, INCLUDING the heavyweight
-    # SIGKILL-under-live-HTTP-load chaos case tier-1 excludes, then
-    # the SLO stage (sustained QPS, p99 TTFT/ITG, disconnect and
-    # overload economics)
-    shift
-    env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
-        -m "edge and faults" -p no:cacheprovider "$@"
-    exec env JAX_PLATFORMS=cpu python bench.py --edge-only
-elif [ "$1" = "ctr" ]; then
-    # the embedding-cache chaos lane (shard-failover-mid-traffic,
-    # reform-mid-stream), then the cached-vs-uncached lookup stage
-    # with its >=3x p99 gate and push-ledger reconciliation
-    shift
-    env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
-        -m "ctr and faults" -p no:cacheprovider "$@"
-    exec env JAX_PLATFORMS=cpu python bench.py --ctr-only
-elif [ "$1" = "data" ]; then
-    # the whole zero-copy data-plane lane, INCLUDING the heavyweight
-    # real-process SIGKILL chaos cases tier-1 excludes, then the A/B
-    # stage (bytes-copied + migration latency vs the pickle path,
-    # coalesced per-sweep frame count)
-    shift
-    env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
-        -m "data and faults" -p no:cacheprovider "$@"
-    exec env JAX_PLATFORMS=cpu python bench.py --data-only
-elif [ "$1" = "elastic" ]; then
-    # the whole elastic lane, INCLUDING the slow wedge-fencing case
-    # tier-1 excludes, then the perf stage (memory win, sharded-update
-    # overhead, kill->resume latency)
-    shift
-    env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m elastic \
-        -p no:cacheprovider "$@"
-    exec env JAX_PLATFORMS=cpu python bench.py --elastic-only
-fi
+case "$1" in
+    pserver|router|elastic)
+        # elastic: INCLUDING the slow wedge-fencing case tier-1 excludes
+        marker=$1
+        shift ;;
+    disagg|fleet|cluster|edge|ctr|data)
+        # each lane whole, INCLUDING the heavyweight real-process chaos
+        # cases tier-1 excludes
+        marker="$1 and faults"
+        shift ;;
+esac
 exec env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m "$marker" \
     -p no:cacheprovider "$@"

@@ -59,10 +59,9 @@ def pytest_configure(config):
         "the faults lane, runs IN tier-1; `-m pserver` (or "
         "`scripts/fault_smoke.sh pserver`) runs it alone")
     config.addinivalue_line(
-        "markers", "perf: CPU-runnable performance smoke lane "
-        "(capacity/throughput assertions, e.g. the paged-pool 2x "
-        "admission bound) — fast, runs IN tier-1; `-m perf` (or "
-        "`scripts/perf_smoke.sh`) runs it alone")
+        "markers", "perf: capacity counts a CPU run can give (e.g. "
+        "the paged-pool 2x admission bound; never a time or a rate) "
+        "— runs IN tier-1; `-m perf` runs it alone")
     config.addinivalue_line(
         "markers", "analysis: static-analysis + compile-discipline "
         "suite (graftlint/locklint rule fixtures, the repo --check "
@@ -83,15 +82,14 @@ def pytest_configure(config):
         "markers", "pallas: interpret-mode Pallas kernel parity suite "
         "(ragged paged-attention vs the jnp oracle, bit-identity "
         "under jit) — fast cases run IN tier-1, the heavy ragged "
-        "sweeps are additionally marked slow; `-m pallas` (or "
-        "`scripts/perf_smoke.sh pallas`) runs the lane alone")
+        "sweeps are additionally marked slow; `-m pallas` runs the "
+        "lane alone")
     config.addinivalue_line(
         "markers", "kernels: sharded-matmul primitive suite "
         "(parallel.blocked_matmul ring/stream forms vs the jnp oracle "
         "across shard counts, pipeline tensor-parallel opt-in parity) "
-        "— fast cases run IN tier-1; `-m kernels` (or "
-        "`scripts/perf_smoke.sh kernels`, which adds the pallas lane "
-        "and `bench.py --kernels-only`) runs the lane alone")
+        "— fast cases run IN tier-1; `-m kernels` runs the lane "
+        "alone")
     config.addinivalue_line(
         "markers", "speculative: speculative-decoding suite (n-gram "
         "draft proposer, verify/commit/rollback, greedy parity vs "
@@ -101,8 +99,7 @@ def pytest_configure(config):
         "markers", "disagg: disaggregated prefill/decode fleet suite "
         "(tiered routing, live KV-block migration, prefix seeding, "
         "migration chaos) — fast, runs IN tier-1; `-m disagg` (or "
-        "`scripts/fault_smoke.sh disagg` / `scripts/perf_smoke.sh "
-        "disagg`) runs it alone")
+        "`scripts/fault_smoke.sh disagg`) runs it alone")
     config.addinivalue_line(
         "markers", "fleet: cross-process serving-fleet suite "
         "(serve.fleet/serve.transport: socket-transport replicas, "
@@ -116,8 +113,7 @@ def pytest_configure(config):
         "overload backpressure, slow-loris hardening, graceful drain) "
         "— fast cases run IN tier-1, the live-load SIGKILL chaos case "
         "is heavyweight/slow; `-m edge` (or `scripts/fault_smoke.sh "
-        "edge`, which runs -m 'edge and faults' plus `bench.py "
-        "--edge-only`) runs the lane alone")
+        "edge`, which runs -m 'edge and faults') runs the lane alone")
     config.addinivalue_line(
         "markers", "heavyweight: the ONE deliberate chaos heavyweight "
         "a suite may carry — exempt from the tier-1 budget guard "
@@ -127,8 +123,8 @@ def pytest_configure(config):
         "markers", "aot: AOT serving-artifact + persistent "
         "compile-cache suite (engine bundle round-trip parity, "
         "manifest-mismatch fallback, corrupt-entry miss, subprocess "
-        "cache-warm restart) — fast, runs IN tier-1; `-m aot` (or "
-        "`scripts/perf_smoke.sh aot`) runs it alone")
+        "cache-warm restart) — fast, runs IN tier-1; `-m aot` runs "
+        "it alone")
     config.addinivalue_line(
         "markers", "cluster: multi-host control-plane suite "
         "(cluster.membership lease/epoch fencing, per-host agents, "
@@ -149,8 +145,7 @@ def pytest_configure(config):
         "batched control RPC) — fast cases run IN tier-1, the "
         "real-process SIGKILL chaos cases are heavyweight/slow; "
         "`-m data` (or `scripts/fault_smoke.sh data`, which runs "
-        "-m 'data and faults' plus `bench.py --data-only`) runs the "
-        "lane alone")
+        "-m 'data and faults') runs the lane alone")
     config.addinivalue_line(
         "markers", "locks: graftlock concurrency suite (locklint "
         "LK002-LK005 rule fixtures, the LockOrderGuard runtime "
@@ -163,9 +158,8 @@ def pytest_configure(config):
         "(serve.embed_cache staleness bounds / batched miss-fill / "
         "zero-recompile gather, train.online streaming exactly-once, "
         "shard-failover + reform-mid-stream chaos) — fast cases run "
-        "IN tier-1; `-m ctr` (or `scripts/perf_smoke.sh ctr` / "
-        "`scripts/fault_smoke.sh ctr`, which add `bench.py "
-        "--ctr-only`) runs the lane alone")
+        "IN tier-1; `-m ctr` (or `scripts/fault_smoke.sh ctr`, "
+        "which runs -m 'ctr and faults') runs the lane alone")
 
 
 def pytest_runtest_logreport(report):

@@ -9,8 +9,8 @@ chip question (ROADMAP A1), and none of these is a device metric.
 Three claims:
 
   (a) ResNet remat shrinks the fwd->bwd residual set (the HBM-resident
-      activations PROFILE_NOTES' 57.6 GiB/step roofline is made of) —
-      measured abstractly via eval_shape of the vjp closure, which is
+      activations the backward reads) — measured abstractly via
+      eval_shape of the vjp closure, which is
       exact at any batch size without materializing anything.
   (b) The int8 decode loop STREAMS s8 weights: the compiled while
       state carries s8 tensors (dequant traced inside the body, pinned
@@ -238,11 +238,10 @@ class TestFusedCEResiduals:
     """Claim (d), r5: fused chunked cross-entropy removes the [N, vocab]
     logits tensor from the fwd->bwd residual set of the flagship LM.
 
-    Measured AT the transformer bench config (B4 T8192 D512 L8 V32000,
-    flash attention + per-block remat — benchmarks/suite.py
-    bench_transformer_lm): 4.81 GiB of residuals plain -> 0.91 GiB
-    fused (-81%); the f32 logits (4*8191*32000*4 B = 4.19 GiB) were 87%
-    of the set. eval_shape makes the big shape free on CPU."""
+    Counted at batch 4 x 8192 tokens, dim 512, 8 layers, vocab 32000
+    (flash attention + per-block remat): 4.81 GiB of residuals plain
+    -> 0.91 GiB fused (-81%); the f32 logits (4*8191*32000*4 B = 4.19
+    GiB) were 87% of the set. eval_shape makes the big shape free on CPU."""
 
     def test_fused_ce_drops_logits_residual(self):
         import dataclasses

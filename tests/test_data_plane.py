@@ -646,6 +646,66 @@ class TestBatchedControlPlane:
         finally:
             ts.shutdown()
 
+    def test_tickets_cross_the_wire_in_place_of_kv_bytes(
+            self, params, engines, mk_arena):
+        """The same migrations over real socket transport, once with
+        the KV payloads pickled onto the control frame and once with
+        the arena: every request completes with the same tokens, no
+        migration falls back, the arena is empty again after the
+        ACKs, and the export + import calls of a migration moved
+        fewer bytes over the sockets, the frame carrying a ticket."""
+        prompts = prompts_for(3, seed=7)
+
+        def arm(arena):
+            servers = [ServingServer(eng, role=role, max_queue=16,
+                                     buckets=BUCKETS, max_retries=2,
+                                     data_plane=arena)
+                       for eng, role in zip(
+                           engines, ("prefill", "decode", "decode"))]
+            transports = [ReplicaTransportServer(s).start()
+                          for s in servers]
+            try:
+                clients = [ReplicaClient(ts.addr, connect_timeout=2.0,
+                                         io_timeout=30.0)
+                           for ts in transports]
+                reps = [ProcessReplica(c) for c in clients]
+                wire = [0]
+                for rep, client in zip(reps, clients):
+                    for name in ("export_request", "import_request"):
+                        def counted(*a, _call=getattr(rep, name),
+                                    _c=client, **k):
+                            b0 = _c.bytes_sent + _c.bytes_recv
+                            try:
+                                return _call(*a, **k)
+                            finally:
+                                wire[0] += (_c.bytes_sent + _c.bytes_recv
+                                            - b0)
+                        setattr(rep, name, counted)
+                router = ServingRouter(reps, probe_interval_s=1e9)
+                ids = [router.submit(p, max_new=5) for p in prompts]
+                res = router.run()
+                router.reconcile()
+                assert all(res[i].outcome == "completed" for i in ids)
+                c = router.counters()
+                assert c["migrations"] == len(prompts)
+                assert c.get("fleet_data_plane_fallbacks", 0) == 0
+                return [res[i].tokens for i in ids], wire[0]
+            finally:
+                for ts in transports:
+                    ts.shutdown()
+
+        toks_pickled, wire_pickled = arm(None)
+        arena = mk_arena(seg_size=4096, n_segs=32)
+        toks_arena, wire_arena = arm(arena)
+        assert toks_arena == toks_pickled == [
+            ref_tokens(params, p, 5) for p in prompts]
+        assert arena.scatters == arena.frees == len(prompts)
+        assert arena.segments_live() == 0
+        arena.reconcile()
+        assert 0 < wire_arena < wire_pickled
+        # the KV bytes themselves went through the arena, not the wire
+        assert wire_pickled - wire_arena >= arena.bytes_scattered // 2
+
     def test_partials_ride_the_sweep_frame(self, transport, params):
         ts, srv, client = transport
         rep = ProcessReplica(client)

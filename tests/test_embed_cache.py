@@ -420,6 +420,57 @@ def _expected_table(init, tasks, lr=1.0):
     return out
 
 
+def test_watermarks_match_the_push_ledger_under_streaming_pushes():
+    """The read path beside a trainer that keeps pushing, over real
+    shards: a `StreamingTrainer` pushes sparse deltas between lookups
+    of a hot set, the push ACKs feed the cache's ledger, and the
+    maintenance tick refreshes stale rows ahead of the reads. At the
+    end every served row is accounted for by one of the hit / miss /
+    stale counters, the hot set was served from the device tier, and
+    the cache's per-shard watermark equals each shard's applied-update
+    `version`: the push ledger is the invalidation feed."""
+    vocab, shards, n_req, push_every = 64, 2, 24, 4
+    r = np.random.RandomState(5)
+    hot = r.randint(0, vocab, (8,)).astype(np.int64)
+    with PServerGroup(vocab, DIM, n_shards=shards,
+                      replicated=False) as grp:
+        push = PServerClient(grp.specs, DIM, trainer_id=0)
+        push.register()
+        push_emb = PServerEmbedding(push)
+        table = push_emb.init(jax.random.key(3))
+        q = TaskQueue(timeout_ms=5000, max_retries=3)
+        for i in range(n_req // push_every):
+            q.add_task(json.dumps({"seed": i, "batch": 4, "slots": 4,
+                                   "vocab": vocab}).encode())
+        trainer = StreamingTrainer(q, push_emb, table, lr=0.05)
+
+        read = PServerClient(grp.specs, DIM, trainer_id=1)
+        read.register()
+        read_emb = PServerEmbedding(read)
+        cache = TieredEmbedCache(read_emb, table, hot_rows=16,
+                                 host_rows=32, max_staleness=0)
+        cache.bind_push_feed(push)    # same thread: reentrant-safe
+        for i in range(n_req):
+            if i % push_every == 0:
+                assert trainer.step()
+                cache.refresh_stale()
+            got = np.asarray(cache.lookup(hot))
+            assert np.array_equal(got, read.get_rows(hot))
+        assert trainer.stats["tasks_done"] == n_req // push_every
+        cache.refresh()
+        rec = cache.reconcile([p.stats() for p in grp.primaries])
+        assert rec["ok"], rec
+        assert rec["serves_accounted"]
+        assert rec["watermarks_match_push_ledger"]
+        c = cache.counters()
+        assert c["rows_served"] == n_req * len(hot)
+        assert c["misses"] == len(set(hot.tolist()))
+        assert c["refresh_rows"] > 0 and c["stale_refills"] == 0
+        assert (c["hits_device"] + c["hits_host"]
+                == c["rows_served"] - c["misses"])
+        assert c["hits_device"] > 0
+
+
 @pytest.mark.faults
 @pytest.mark.pserver
 def test_shard_failover_never_serves_stale_beyond_bound():

@@ -20,6 +20,7 @@ depths:
   bit-exact greedy tokens — the failover is invisible on the wire.
 """
 
+import itertools
 import json
 import socket
 import threading
@@ -79,6 +80,29 @@ def wait_idle(edge, router, timeout_s=20.0):
             return True
         time.sleep(0.02)
     return False
+
+
+def hold_fleet(edge, while_):
+    """Replace the edge's drive tick with one under which the fleet
+    stands still for as long as `while_()` holds: the tick then reports
+    no work, so the drive loop parks OUTSIDE the edge's lock and the
+    connection handlers run. What the tests below raced the wall
+    clock for (a client's FIN against the last token, a burst against
+    the fleet's speed) is so decided by an event the edge counts.
+    `while_` runs under the edge's lock, like the tick: it reads
+    `edge._stats`, not `edge.counters()`, which would take the lock
+    again. Returns an Event set when the fleet is first held."""
+    real_tick = edge._sweep_fn
+    held = threading.Event()
+
+    def tick():
+        if while_():
+            held.set()
+            return False
+        return real_tick()
+
+    edge._sweep_fn = tick
+    return held
 
 
 def raw_exchange(addr, blob, timeout_s=5.0):
@@ -191,25 +215,51 @@ def test_stream_bit_exact_across_many_chunks(params,
                                              lock_order_guard):
     """The LK003 hot-path contract: `_snapshot` reads the partial
     tokens UNDER the router lock and the chunked socket write happens
-    OUTSIDE it. Throttled steps force the stream through many
-    snapshot/write cycles (one or two tokens per chunk), and the
-    concatenation of every chunk must still be bit-exact against the
+    OUTSIDE it. The stream goes through many snapshot/write cycles,
+    and the concatenation of every chunk must still be bit-exact against the
     solo greedy decode — proving the restructure drops the lock
     without ever tearing or reordering the stream. Runs under
     LockOrderGuard so a regression that re-nests the write under the
-    lock shows up as an order violation, not just a slow stream."""
-    edge, router, srv = mk_stack(params)
-    throttle_steps(srv, delay_s=0.03)
+    lock shows up as an order violation, not just a slow stream.
+
+    The fleet moves in lockstep with the stream: it emits again only
+    once the edge has stamped a chunk for what it emitted last (the
+    edge reads its injectable clock once at submit and once a chunk),
+    so every emission is a snapshot/write cycle of its own however the
+    threads are scheduled."""
+    stamps = itertools.count()
+    seen = [0]                  # readings of the edge's clock so far
+
+    def edge_clock():
+        seen[0] += 1
+        return float(next(stamps))
+
+    edge, router, srv = mk_stack(params, clock=edge_clock)
+    emitted = [0, 0]            # tokens of request 0, sweeps that added
+    real_tick = edge._sweep_fn
+
+    def counting_tick():
+        busy = real_tick()
+        n = len(router.partial_tokens(0))
+        if n > emitted[0]:
+            emitted[:] = n, emitted[1] + 1
+        return busy
+
+    edge._sweep_fn = counting_tick
+    hold_fleet(edge, lambda: seen[0] - 1 < emitted[1])
     try:
         prompt = [2, 4, 6]
         want = ref_tokens(params, prompt, 8)
-        r = stream_generate(edge.addr, prompt, 8)
+        ticks = itertools.count()
+        r = stream_generate(edge.addr, prompt, 8,
+                            clock=lambda: float(next(ticks)))
         assert r.status == 200 and r.outcome == "completed"
         assert r.tokens == want         # bit-exact, in order
-        # the throttle spread the stream over several chunks: some
+        # the lockstep spread the stream over several chunks: some
         # inter-token gap is nonzero, so parity was across REAL
         # snapshot/write cycles, not one lucky final chunk
         assert any(g > 0 for g in r.gaps_s)
+        assert emitted[1] > 1 and seen[0] - 1 == emitted[1]
         assert wait_idle(edge, router)
     finally:
         edge.close()
@@ -323,20 +373,6 @@ def test_deadline_header_expires_request(params):
 # disconnect cancellation
 
 
-def throttle_steps(srv, delay_s=0.03):
-    """Slow every decode sweep. The disconnect tests race a client's
-    FIN against generation finishing; on an idle box the FIN always
-    wins, but on a loaded 1-vCPU runner the tiny model can emit every
-    token before the EOF probe gets scheduled — then router.cancel
-    correctly finds a terminal request and counts nothing. Pinning a
-    floor on step wall-time makes the race deterministic."""
-    orig = srv.step
-    def slow_step():
-        time.sleep(delay_s)
-        return orig()
-    srv.step = slow_step
-
-
 @pytest.mark.locks      # chaos lane re-run under LockOrderGuard
 def test_disconnect_mid_stream_frees_slot_and_pages(
         params, lock_order_guard):
@@ -344,9 +380,14 @@ def test_disconnect_mid_stream_frees_slot_and_pages(
     the fleet NOTHING durable — the in-flight request is force-
     expired through the deadline/retire path, its slot and pages
     free (pages still resident are cache-only and evictable), the
-    books reconcile, and the next client is served normally."""
+    books reconcile, and the next client is served normally.
+
+    The client's FIN does not race the last token: once the request
+    has the two tokens the client leaves after, the fleet stands still
+    until the edge has counted the disconnect."""
     edge, router, srv = mk_stack(params)
-    throttle_steps(srv)
+    hold_fleet(edge, lambda: (len(router.partial_tokens(0)) >= 2
+                              and not edge._stats["disconnect_cancels"]))
     try:
         r = stream_generate(edge.addr, [1, 2, 3, 4], 12,
                             abort_after_tokens=2)
@@ -374,9 +415,15 @@ def test_disconnect_mid_stream_frees_slot_and_pages(
 
 def test_disconnect_while_queued_cancels_before_decode(params):
     """A client that vanishes while its request is still QUEUED
-    (both slots busy) is cancelled before it ever takes a slot."""
+    (both slots busy) is cancelled before it ever takes a slot: the
+    fleet starts once both holders are in, and with both decoding it
+    stands still until the edge has counted the disconnect, so no slot
+    comes free before it."""
     edge, router, srv = mk_stack(params)
-    throttle_steps(srv)
+    hold_fleet(edge, lambda: edge._stats["requests"] < 2)
+    held = hold_fleet(edge, lambda: (
+        all(router.partial_tokens(i) for i in (0, 1))
+        and not edge._stats["disconnect_cancels"]))
     try:
         holders = [
             threading.Thread(
@@ -386,10 +433,9 @@ def test_disconnect_while_queued_cancels_before_decode(params):
         ]
         for t in holders:
             t.start()
-        deadline = time.monotonic() + 10.0
-        while (time.monotonic() < deadline
-               and edge.counters()["requests"] < 2):
-            time.sleep(0.01)
+        assert held.wait(30.0)
+        assert edge.counters()["requests"] == 2
+        assert all(req is not None for req in srv._slot_req)
         # both slots busy: this one queues (the edge sends NOTHING
         # until tokens flow), then its client leaves without ever
         # reading a byte
@@ -422,7 +468,9 @@ def test_disconnect_while_queued_cancels_before_decode(params):
 def test_overload_sheds_429_and_bounds_the_queue(params):
     """An open-loop burst far beyond capacity sheds 429 + Retry-After
     AT THE EDGE; the admission queue never grows past its bound, and
-    every admitted request still completes."""
+    every admitted request still completes. Beyond capacity whatever
+    the machine's load: the fleet stands still until the edge has
+    given each arrival of the burst its verdict."""
     edge, router, srv = mk_stack(params, max_queue=3)
     depth = [0]
     real_sweep = router.sweep
@@ -435,6 +483,9 @@ def test_overload_sheds_429_and_bounds_the_queue(params):
     try:
         # warm the decode path so the burst meets a live fleet
         stream_generate(edge.addr, [1, 2], 2)
+        verdicts = lambda c: c["requests"] + c["shed_429"]
+        all_in = verdicts(edge.counters()) + 30
+        hold_fleet(edge, lambda: verdicts(edge._stats) < all_in)
         shape = TrafficShape(out_base=6, out_cap=10)
         burst = open_loop(edge.addr, shape, phases=((200.0, 30),),
                           seed=7)
@@ -479,10 +530,13 @@ def test_drain_503_in_flight_finishes_report_lands(params, tmp_path):
     """The SIGTERM sequence without the signal: drain() stops
     admission (newcomers answer 503 + Retry-After), the in-flight
     stream runs to its natural end, wait_drained() goes idle and the
-    drain report lands atomically."""
+    drain report lands atomically. In flight means decoding: from its
+    first token on the fleet stands still until the drain is in."""
     report = tmp_path / "drain.json"
     edge, router, srv = mk_stack(params,
                                  drain_report_path=str(report))
+    held = hold_fleet(edge, lambda: (router.partial_tokens(0)
+                                     and not edge.draining))
     try:
         got = {}
 
@@ -492,10 +546,7 @@ def test_drain_503_in_flight_finishes_report_lands(params, tmp_path):
         t = threading.Thread(target=one, args=("inflight",),
                              daemon=True)
         t.start()
-        deadline = time.monotonic() + 10.0
-        while (time.monotonic() < deadline
-               and edge.counters()["requests"] < 1):
-            time.sleep(0.01)
+        assert held.wait(30.0)
         edge.drain(reason="test drain")
         late = stream_generate(edge.addr, [4, 5], 2)
         assert late.status == 503

@@ -422,8 +422,24 @@ def test_engine_stats_pool_fields(params):
 # -- the capacity acceptance bound ---------------------------------------
 
 
+def _peak_concurrency(params, cfg, prompts, max_new, **pool):
+    """Serve `prompts` at once through one slot each; return the most
+    requests that held a slot together, the results, the counters."""
+    eng = DecodeEngine(params, cfg, slots=len(prompts), **pool)
+    # retries: an oversubscribed pool preempts and requeues
+    srv = ServingServer(eng, max_queue=len(prompts), max_retries=8)
+    peak = [0]
+    srv.on_step.append(lambda s, _: peak.__setitem__(
+        0, max(peak[0], sum(r is not None for r in s._slot_req))))
+    for p in prompts:
+        srv.submit(p, max_new=max_new)
+    results = srv.run()
+    srv.reconcile()
+    assert all(r.outcome == "completed" for r in results.values())
+    return peak[0], results, srv.counters()
+
+
 @pytest.mark.perf
-@pytest.mark.slow  # tier-1 budget guard: >10s-class test, slow lane
 def test_paged_admits_2x_dense_slots_at_equal_budget(params):
     """ISSUE 4 acceptance: at EQUAL HBM budget the paged pool admits
     >= 2x the dense layout's concurrent requests on a mixed-length
@@ -449,20 +465,35 @@ def test_paged_admits_2x_dense_slots_at_equal_budget(params):
         fit += 1
     assert fit >= 2 * s_dense, (fit, need, budget_pages)
 
-    eng = DecodeEngine(params, CFG, slots=len(prompts),
-                       max_len=max_len, page_size=page,
-                       num_pages=budget_pages)
-    srv = ServingServer(eng, max_queue=len(prompts))
-    peak = {"active": 0}
-    srv.on_step.append(lambda s, _: peak.__setitem__(
-        "active", max(peak["active"],
-                      sum(r is not None for r in s._slot_req))))
-    for p in prompts:
-        srv.submit(p, max_new=max_new)
-    results = srv.run()
-    srv.reconcile()
-    assert all(r.outcome == "completed" for r in results.values())
+    peak, results, c = _peak_concurrency(
+        params, CFG, prompts, max_new, max_len=max_len, page_size=page,
+        num_pages=budget_pages)
     for p, rid in zip(prompts, range(len(prompts))):
         assert results[rid].tokens == ref_tokens(params, p, max_new)
-    assert peak["active"] >= 2 * s_dense, (peak, srv.counters())
-    assert srv.counters()["peak_pages_in_use"] <= budget_pages
+    assert peak >= 2 * s_dense, (peak, c)
+    assert c["peak_pages_in_use"] <= budget_pages
+
+
+@pytest.mark.perf
+def test_int8_pool_admits_2x_float_pool_at_equal_bytes(params):
+    """At equal HBM bytes the int8 pool (s8 data and one f32 scale per
+    position and head, against plain f32) holds `bytes_f / bytes_8`
+    times the float pool's pages, and the same oversubscribed traffic
+    runs at least twice as many requests at once through it."""
+    max_len, page, max_new = 64, 8, 4
+    dh = CFG.dim // CFG.n_heads
+    bytes_f, bytes_8 = dh * 4, dh * 1 + 4
+    pages_f = 8
+    pages_8 = pages_f * bytes_f // bytes_8
+    assert pages_8 * bytes_8 <= pages_f * bytes_f
+    prompts = [rng_tokens(n, seed=200 + i)
+               for i, n in enumerate([9, 12, 10, 14, 9, 11, 13, 10, 12, 9])]
+    geom = dict(max_len=max_len, page_size=page)
+    peak_f, _, c_f = _peak_concurrency(
+        params, CFG, prompts, max_new, num_pages=pages_f, **geom)
+    cfg8 = dataclasses.replace(CFG, kv_cache_dtype="int8")
+    peak_8, _, c_8 = _peak_concurrency(
+        params, cfg8, prompts, max_new, num_pages=pages_8, **geom)
+    assert c_f["peak_pages_in_use"] <= pages_f
+    assert c_8["peak_pages_in_use"] <= pages_8
+    assert peak_8 >= 2 * peak_f > 0, (peak_8, peak_f, c_8, c_f)

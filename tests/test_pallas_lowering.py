@@ -116,7 +116,7 @@ def test_model_hands_flash_the_policys_dtype(bf16):
 
 @pytest.mark.parametrize("name,run,init,hidden,t", [
     ("gru", rnn.gru, rnn.init_gru_params, 512, 30),          # seq2seq
-    ("lstm", rnn.lstm, rnn.init_lstm_params, 256, 100),      # bench_lstm
+    ("lstm", rnn.lstm, rnn.init_lstm_params, 256, 100),      # classifier
     ("lstm", rnn.lstm, rnn.init_lstm_params, 512, 100),
     ("rnn", rnn.simple_rnn, rnn.init_rnn_params, 512, 100),
 ])

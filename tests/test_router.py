@@ -181,7 +181,7 @@ class TestRouting:
         assert router.stats["shed"] == 0
 
     def test_random_policy_scatters(self, engines):
-        """The bench's control arm: RandomRoutingPolicy ignores the
+        """The control arm: RandomRoutingPolicy ignores the
         affinity map, so even a single shared-prefix family lands on
         several replicas (several cold caches pay the prefill the
         affinity map would have saved)."""

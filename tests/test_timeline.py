@@ -174,6 +174,47 @@ def test_feeder_records_one_row_of_each_span_a_batch():
         assert [r[1] for r in order] == sorted(r[1] for r in order)
 
 
+class FullQueue(Timeline):
+    """The consumer opens `feeder.queue_wait` only once the worker has
+    put all that fits: every `get` finds the queue as full as it can
+    be, the one that finds the end sentinel included."""
+
+    def __init__(self, prefetch, **kw):
+        super().__init__(**kw)
+        self.prefetch = prefetch
+        self.put = 0                # batches known to be in or through
+        self.moved = threading.Condition()
+
+    def span(self, name, seq=None):
+        if name == "feeder.read":
+            # the worker reads batch `seq` (or finds the end) after
+            # its put of batch `seq - 1` has returned
+            with self.moved:
+                self.put = seq
+                self.moved.notify_all()
+        elif name == "feeder.queue_wait":
+            want = min(seq + self.prefetch, N_BATCHES)
+            with self.moved:
+                assert self.moved.wait_for(lambda: self.put >= want, 30.0)
+            if seq + self.prefetch > N_BATCHES:
+                # the sentinel fits too: it is in once the worker ends
+                for t in feeder_threads():
+                    t.join(30.0)
+                    assert not t.is_alive()
+        return super().span(name, seq)
+
+
+def test_queue_depth_sum_counts_no_reading_for_the_sentinel():
+    tl = FullQueue(2, clock_ns=ticking())
+    feeder = data.DataFeeder(prefetch=tl.prefetch, timeline=tl)
+    batches = list(feeder(data.batch_reader(reader(), BATCH)))
+    assert len(batches) == N_BATCHES
+    c = tl.counters()
+    assert c["feeder.batches"] == N_BATCHES
+    # the queue was full at each of the N_BATCHES + 1 gets
+    assert c["feeder.queue_depth_sum"] == N_BATCHES * feeder.prefetch
+
+
 class ThreadNoting(Timeline):
     """A timeline that also notes which thread opened each span."""
 
