@@ -145,6 +145,10 @@ def main() -> int:
         functools.partial(check_flash, jnp.bfloat16, b=2, t=1024, h=8, d=64,
                           lens=[1024, 300]),
         functools.partial(check_flash, jnp.float32, b=1, t=1024, h=8, d=64),
+        # starcoder2_3b_l4.train_seq4k's attention: 4095 positions, head
+        # 128, window inert; four backward blocks of 1024 on each axis
+        functools.partial(check_flash, jnp.bfloat16, b=1, t=4095, h=12,
+                          d=128, window=4096),
     ]
     policies = (dtypes.bf16_compute_policy(), dtypes.Policy())
     for policy in policies:

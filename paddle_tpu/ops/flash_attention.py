@@ -9,8 +9,14 @@ framework makes fused O(T) -memory attention a first-class op:
     streaming-softmax accumulation over k/v blocks) that never
     materialises the [T, T] score matrix and also emits the row
     log-sum-exp needed by the backward;
-  * backward: blockwise recomputation in plain JAX (lax.scan over k
-    blocks) — O(T·block) memory, XLA-fused matmuls;
+  * backward: two Pallas kernels of the same shape, which recompute
+    the probabilities from q, k and the saved log-sum-exp block by
+    block: `flash_attention_bwd_dkv` accumulates dk and dv over the q
+    blocks of one k block, `flash_attention_bwd_dq` accumulates dq over
+    the k blocks of one q block. O(T·block) memory, no score-shaped
+    array in HBM, operands in the dtype they arrive in. All three
+    kernels take the block predicate and the element mask from one
+    helper each (`_block_needed`, `_pair_mask`);
   * composes with the mesh: wrap in shard_map and the seq axis via
     parallel.ring_attention for context parallelism, or shard heads.
 
@@ -38,7 +44,83 @@ NEG_INF = -1e30
 
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 512
+# the backward kernels' own blocks (q rows x k rows of one grid step),
+# chosen by timing the pair alone on the v5e at bf16[48, 4096, 128],
+# causal (PERF.md section 6, PR 32); the forward's are not theirs
+BWD_BLOCK_Q = 1024
+BWD_BLOCK_K = 1024
 _LANE = 128  # TPU minimum tile width (lane count)
+
+
+def _block_needed(qi, j, n_keys, *, block_q: int, block_k: int,
+                  causal: bool, window):
+    """Does (q block qi, k block j) hold any pair `_pair_mask` admits?
+    The one block predicate of the forward and both backward kernels:
+    a k block entirely past the row's key length, entirely above the
+    causal diagonal or entirely below the band is skipped (a fully
+    invalid block is a no-op anyway: p = 0 — skipping saves the dead
+    MXU work; a short row in a long padded batch touches ~len/BK
+    blocks, not ~T/BK)."""
+    needed = j * block_k < n_keys
+    if causal:
+        needed = needed & (j * block_k <= (qi + 1) * block_q - 1)
+    if window is not None:
+        # sliding window: the block's newest key must reach the oldest
+        # key the block's oldest query may see (qpos - window + 1) —
+        # blocks entirely below the band skip, so long-T cost is
+        # O(T * window), not O(T^2)
+        needed = needed & (
+            (j + 1) * block_k - 1 >= qi * block_q - window + 1)
+    return needed
+
+
+def _pair_mask(qi, j, n_keys, *, block_q: int, block_k: int, causal: bool,
+               window, k_major: bool = False):
+    """The one element mask: which (query, key) pairs of block (qi, j)
+    attend — key inside the row's length (tail padding and right-padded
+    variable-length rows are the SAME mask), on or below the diagonal,
+    inside the band. [BQ, BK] bool, or [BK, BQ] with `k_major` (the
+    dk/dv kernel works on transposed scores)."""
+    shape, q_axis = (((block_k, block_q), 1) if k_major
+                     else ((block_q, block_k), 0))
+    kpos = j * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, shape, 1 - q_axis)
+    valid = kpos < n_keys                      # tail padding / key mask
+    if causal:
+        qpos = qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, shape, q_axis)
+        valid = valid & (qpos >= kpos)
+        if window is not None:
+            valid = valid & (qpos - kpos < window)
+    return valid
+
+
+def _needed_k_blocks(qi, n_keys, *, block_q: int, block_k: int,
+                     causal: bool, window):
+    """(first, last) k block `_block_needed` admits for q block qi, in
+    closed form, for an `index_map` to clamp to: a skipped grid step
+    then names the block the pipeline already holds and copies nothing.
+    With no key at all (n_keys 0) it names block 0."""
+    last = (jnp.maximum(n_keys, 1) - 1) // block_k
+    if causal:
+        last = jnp.minimum(last, ((qi + 1) * block_q - 1) // block_k)
+    first = 0
+    if window is not None:
+        first = jnp.maximum(qi * block_q - window + 1, 0) // block_k
+    return first, last
+
+
+def _needed_q_blocks(j, n_keys, n_q_blocks, *, block_q: int, block_k: int,
+                     causal: bool, window):
+    """(first, last) q block `_block_needed` admits for k block j; a k
+    block past the row's length needs none and names `first` alone."""
+    first = (j * block_k) // block_q if causal else 0
+    last = n_q_blocks - 1
+    if window is not None:
+        last = jnp.minimum(last, ((j + 1) * block_k + window - 2) // block_q)
+    last = jnp.where(j * block_k < n_keys, last, first)
+    return first, last
+
 
 
 def _attn_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
@@ -68,35 +150,15 @@ def _attn_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    # skip k blocks entirely above the causal diagonal or entirely past
-    # this row's key length (a fully-invalid block is a no-op anyway:
-    # p=0, alpha=1 — skipping just saves the dead MXU work; a short row
-    # in a long padded batch touches ~len/BK blocks, not ~T/BK)
-    needed = j * block_k < n_keys
-    if causal:
-        needed = needed & (j * block_k <= (qi + 1) * bq - 1)
-    if window is not None:
-        # sliding window: the block's newest key must reach the oldest
-        # key the block's oldest query may see (qpos - window + 1) —
-        # blocks entirely below the band skip, so long-T cost is
-        # O(T * window), not O(T^2)
-        needed = needed & ((j + 1) * block_k - 1 >= qi * bq - window + 1)
+    masks = dict(block_q=bq, block_k=block_k, causal=causal, window=window)
 
-    @pl.when(needed)
+    @pl.when(_block_needed(qi, j, n_keys, **masks))
     def _compute():
         # native-dtype (e.g. bf16) operands on the MXU, f32 accumulation
         s = jax.lax.dot_general(
             q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [BQ, BK]
-        kpos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, block_k), 1)
-        valid = kpos < n_keys                  # tail padding / key mask
-        if causal:
-            qpos = qi * bq + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 0)
-            valid = valid & (qpos >= kpos)
-            if window is not None:
-                valid = valid & (qpos - kpos < window)
+        valid = _pair_mask(qi, j, n_keys, **masks)
         s = jnp.where(valid, s, NEG_INF)
         m_prev = m_ref[:, :1]                          # [BQ, 1]
         l_prev = l_ref[:, :1]
@@ -185,145 +247,211 @@ def _flash_forward(q, k, v, lens, *, causal: bool, block_q: int,
     return o[:, :t], lse[:, :t, 0]
 
 
-def _windowed_backward(q, k, v, lens, o, lse, g, *, block_k: int,
-                       window: int):
-    """Sliding-window flash backward with real block skipping.
+_NT = (((1,), (1,)), ((), ()))    # a @ b.T: contract the minor dims
 
-    k-block j (keys [j·bk, (j+1)·bk)) only ever interacts with queries
-    in [j·bk, j·bk + bk + window - 1) — causal (qpos >= kpos, and
-    window requires causal with Tq == Tkv) bounds it below, the band
-    (qpos - kpos < window) bounds it above. So instead of sweeping all
-    T queries per k-block (the O(T²) cost the r4 verdict flagged), the
-    scan gathers just that L = bk + window - 1 query window per block:
-    O(T·(block+window)) total compute and memory traffic, matching the
-    forward kernel's out-of-band block skip."""
+
+def _recompute(q, k, v, g, lse, delta, valid, *, scale: float,
+               k_major: bool):
+    """One block's probabilities and score gradients from what the
+    forward saved: p = exp(q kT * scale - lse), ds = p * (g vT - delta),
+    float32 out of operand-dtype matmuls. [BQ, BK] with lse / delta as
+    [BQ, 1] columns or, `k_major`, [BK, BQ] with [1, BQ] rows. The mask
+    lands on p, not on the scores: a query with no valid key has lse =
+    NEG_INF, and exp(NEG_INF - NEG_INF) would be 1."""
+    if k_major:
+        q, k, g, v = k, q, v, g
+    s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32)
+    dp = jax.lax.dot_general(g, v, _NT, preferred_element_type=jnp.float32)
+    p = jnp.where(valid, jnp.exp(s * scale - lse), 0.0)
+    return p, p * (dp - delta)
+
+
+def _bwd_dkv_kernel(len_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref,
+                    v_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
+                    scale: float, causal: bool, window):
+    """One (batch*head, k-block, q-block) grid step: the innermost dim
+    walks the q blocks, float32 VMEM scratch carries the k block's dk
+    and dv across them. Scores are held transposed, [BK, BQ], so both
+    accumulating matmuls are plain (pT @ g, dsT @ q) and lse / delta
+    enter as [1, BQ] rows, which broadcast along sublanes.
+
+    Refs: len [BH] i32, scalar-prefetched; q/g [1,BQ,D]; lse/delta
+    [1,1,BQ] f32; k/v [1,BK,D]; dk/dv [1,BK,D]; scratch [BK,D] f32."""
+    n_keys = len_ref[pl.program_id(0)]
+    j = pl.program_id(1)
+    qi = pl.program_id(2)
+    masks = dict(block_q=q_ref.shape[1], block_k=k_ref.shape[1],
+                 causal=causal, window=window)
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    @pl.when(_block_needed(qi, j, n_keys, **masks))
+    def _compute():
+        q, g = q_ref[0], g_ref[0]
+        p, ds = _recompute(
+            q, k_ref[0], v_ref[0], g, lse_ref[0], delta_ref[0],
+            _pair_mask(qi, j, n_keys, k_major=True, **masks),
+            scale=scale, k_major=True)
+        dv_acc[:] += jnp.dot(p.astype(g.dtype), g,
+                             preferred_element_type=jnp.float32)
+        dk_acc[:] += jnp.dot(ds.astype(q.dtype), q,
+                             preferred_element_type=jnp.float32)
+
+    @pl.when(qi == pl.num_programs(2) - 1)
+    def _finish():
+        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _bwd_dq_kernel(len_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref,
+                   v_ref, dq_ref, dq_acc, *, scale: float, causal: bool,
+                   window):
+    """One (batch*head, q-block, k-block) grid step, the forward's own
+    order: float32 VMEM scratch carries the q block's dq across the k
+    blocks. Refs as `_bwd_dkv_kernel`'s; dq [1,BQ,D], scratch [BQ,D]."""
+    n_keys = len_ref[pl.program_id(0)]
+    qi = pl.program_id(1)
+    j = pl.program_id(2)
+    masks = dict(block_q=q_ref.shape[1], block_k=k_ref.shape[1],
+                 causal=causal, window=window)
+
+    @pl.when(j == 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    @pl.when(_block_needed(qi, j, n_keys, **masks))
+    def _compute():
+        k = k_ref[0]
+        _, ds = _recompute(
+            q_ref[0], k, v_ref[0], g_ref[0], lse_ref[0, 0][:, None],
+            delta_ref[0, 0][:, None], _pair_mask(qi, j, n_keys, **masks),
+            scale=scale, k_major=False)
+        dq_acc[:] += jnp.dot(ds.astype(k.dtype), k,
+                             preferred_element_type=jnp.float32)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finish():
+        dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
+
+
+def _flash_backward(q, k, v, lens, o, lse, g, *, causal: bool,
+                    block_q: int, block_k: int, window):
+    """q, o, g: [BH, T, D]; k, v: [BH, Tkv, D]; lens: [BH] valid key
+    counts; lse: [BH, T] f32 -> (dq, dk, dv) in the operands' dtypes.
+
+    delta = rowsum(g * o) is one XLA pass over g and o; it and lse reach
+    both kernels as [BH, 1, T] float32 rows (4 bytes a query, where a
+    lane-broadcast block would move 512). Padded query rows have q = g
+    = 0 and add nothing; padded keys are past every row's length."""
     bh, t, d = q.shape
     t_kv = k.shape[1]
-    scale = 1.0 / (d ** 0.5)
-    qf = q.astype(jnp.float32) * scale
-    gf = g.astype(jnp.float32)
-    delta = jnp.sum(gf * o.astype(jnp.float32), axis=-1)   # [BH, T]
-
-    # a window wider than the sequence is exactly full-causal (the band
-    # can never exclude a causal pair) — clamp so span/memory scale
-    # with T, not the nominal window
-    window = min(window, t)
+    if window is not None and window >= t:
+        window = None   # causal, Tq == Tkv: the band excludes nothing
+    # a sequence shorter than a block takes one block of whole lane tiles
+    block_q = min(block_q, pl.cdiv(t, _LANE) * _LANE)
+    block_k = min(block_k, pl.cdiv(t_kv, _LANE) * _LANE)
+    tq_pad = pl.cdiv(t, block_q) * block_q
     tk_pad = pl.cdiv(t_kv, block_k) * block_k
-    span = block_k + window - 1    # max queries one k-block can touch
-    kp = _pad_to(k.astype(jnp.float32), tk_pad, 1)
-    vp = _pad_to(v.astype(jnp.float32), tk_pad, 1)
-    kb = kp.reshape(bh, tk_pad // block_k, block_k, d).transpose(1, 0, 2, 3)
-    vb = vp.reshape(bh, tk_pad // block_k, block_k, d).transpose(1, 0, 2, 3)
-    # pad the q-side arrays so the per-block dynamic_slice at start
-    # j*bk, length `span`, is always in-bounds; qpos >= t is masked out
-    qp = _pad_to(qf, tk_pad + span, 1)
-    gp = _pad_to(gf, tk_pad + span, 1)
-    deltap = _pad_to(delta, tk_pad + span, 1)
-    lsep = _pad_to(lse, tk_pad + span, 1)
-    kpos_base = jnp.arange(block_k, dtype=jnp.int32)
-    qwin_base = jnp.arange(span, dtype=jnp.int32)
+    nq, nk = tq_pad // block_q, tk_pad // block_k
+    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    operands = (lens.astype(jnp.int32),
+                _pad_to(q, tq_pad, 1), _pad_to(g, tq_pad, 1),
+                _pad_to(lse, tq_pad, 1)[:, None, :],
+                _pad_to(delta, tq_pad, 1)[:, None, :],
+                _pad_to(k, tk_pad, 1), _pad_to(v, tk_pad, 1))
+    masks = dict(block_q=block_q, block_k=block_k, causal=causal,
+                 window=window)
+    static = dict(scale=1.0 / (d ** 0.5), causal=causal, window=window)
+    vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
 
-    def step(dq_pad, blk):
-        j, kj, vj = blk                                   # kj/vj [BH,BK,D]
-        start = j * block_k
-        qs = jax.lax.dynamic_slice_in_dim(qp, start, span, axis=1)
-        gs = jax.lax.dynamic_slice_in_dim(gp, start, span, axis=1)
-        dls = jax.lax.dynamic_slice_in_dim(deltap, start, span, axis=1)
-        lss = jax.lax.dynamic_slice_in_dim(lsep, start, span, axis=1)
-        kpos = start + kpos_base
-        qpos = start + qwin_base
-        s = jnp.einsum("bqd,bkd->bqk", qs, kj)
-        valid = kpos[None, None, :] < lens[:, None, None]
-        valid = valid & (qpos[:, None] >= kpos[None, :])[None]
-        valid = valid & ((qpos[:, None] - kpos[None, :]) < window)[None]
-        valid = valid & (qpos < t)[None, :, None]
-        p = jnp.where(valid, jnp.exp(s - lss[..., None]), 0.0)
-        dv = jnp.einsum("bqk,bqd->bkd", p, gs)
-        dp = jnp.einsum("bqd,bkd->bqk", gs, vj)
-        ds = p * (dp - dls[..., None])
-        dk = jnp.einsum("bqk,bqd->bkd", ds, qs)
-        cur = jax.lax.dynamic_slice_in_dim(dq_pad, start, span, axis=1)
-        dq_pad = jax.lax.dynamic_update_slice_in_dim(
-            dq_pad, cur + jnp.einsum("bqk,bkd->bqd", ds, kj), start,
-            axis=1)
-        return dq_pad, (dk, dv)
+    def in_specs(q_block, k_block):
+        """`q_block` / `k_block`: (b, outer, inner, lens) -> the q-side
+        / k-side block a grid step holds. Clamped along the inner axis
+        to the blocks `_block_needed` admits, a skipped step names the
+        block the pipeline already has and fetches nothing."""
+        q_map = lambda *ids: (ids[0], q_block(*ids), 0)
+        row_map = lambda *ids: (ids[0], 0, q_block(*ids))
+        k_map = lambda *ids: (ids[0], k_block(*ids), 0)
+        return [vmem((1, block_q, d), q_map), vmem((1, block_q, d), q_map),
+                vmem((1, 1, block_q), row_map), vmem((1, 1, block_q), row_map),
+                vmem((1, block_k, d), k_map), vmem((1, block_k, d), k_map)]
 
-    nblk = tk_pad // block_k
-    dq_pad, (dks, dvs) = jax.lax.scan(
-        step, jnp.zeros((bh, tk_pad + span, d), jnp.float32),
-        (jnp.arange(nblk, dtype=jnp.int32), kb, vb))
-    dk = dks.transpose(1, 0, 2, 3).reshape(bh, tk_pad, d)[:, :t_kv]
-    dv = dvs.transpose(1, 0, 2, 3).reshape(bh, tk_pad, d)[:, :t_kv]
-    return ((dq_pad[:, :t] * scale).astype(q.dtype), dk.astype(k.dtype),
-            dv.astype(v.dtype))
+    # outputs and their float32 accumulators follow the outer grid axis
+    out_map = lambda b, outer, inner, lens: (b, outer, 0)
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=pallas_util.VMEM_LIMIT_BYTES)
 
+    def dkv_q_block(b, j, i, lens):
+        first, last = _needed_q_blocks(j, lens[b], nq, **masks)
+        return jnp.clip(i, first, last)
 
-def _blockwise_backward(q, k, v, lens, o, lse, g, *, causal: bool,
-                        block_k: int, window):
-    """Recompute-based flash backward in plain JAX, O(T·block) memory.
-    Sliding-window calls take the band-skipping path (O(T·window))."""
-    if window is not None:
-        return _windowed_backward(q, k, v, lens, o, lse, g,
-                                  block_k=block_k, window=window)
-    bh, t, d = q.shape
-    t_kv = k.shape[1]
-    scale = 1.0 / (d ** 0.5)
-    qf = q.astype(jnp.float32) * scale
-    gf = g.astype(jnp.float32)
-    delta = jnp.sum(gf * o.astype(jnp.float32), axis=-1)   # [BH, T]
+    def dkv_k_block(b, j, i, lens):
+        # a k block past the row's length: its dk, dv are zeros, and it
+        # names the last block that holds a key
+        return jnp.minimum(j, (jnp.maximum(lens[b], 1) - 1) // block_k)
 
-    tk_pad = pl.cdiv(t_kv, block_k) * block_k
-    kp = _pad_to(k.astype(jnp.float32), tk_pad, 1)
-    vp = _pad_to(v.astype(jnp.float32), tk_pad, 1)
-    kb = kp.reshape(bh, tk_pad // block_k, block_k, d).transpose(1, 0, 2, 3)
-    vb = vp.reshape(bh, tk_pad // block_k, block_k, d).transpose(1, 0, 2, 3)
-    kpos_base = jnp.arange(block_k, dtype=jnp.int32)
-    qpos = jnp.arange(t, dtype=jnp.int32)
+    dk, dv = pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, nk, nq),
+            in_specs=in_specs(dkv_q_block, dkv_k_block),
+            out_specs=[vmem((1, block_k, d), out_map)] * 2,
+            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32)] * 2,
+        ),
+        out_shape=[jax.ShapeDtypeStruct((bh, tk_pad, d), k.dtype),
+                   jax.ShapeDtypeStruct((bh, tk_pad, d), v.dtype)],
+        compiler_params=params,
+        interpret=pallas_util.interpret(),
+        name="flash_attention_bwd_dkv",
+    )(*operands)
 
-    def step(dq_acc, blk):
-        j, kj, vj = blk                                    # kj/vj [BH,BK,D]
-        s = jnp.einsum("bqd,bkd->bqk", qf, kj)
-        kpos = j * block_k + kpos_base
-        valid = kpos[None, None, :] < lens[:, None, None]
-        if causal:
-            valid = valid & (qpos[None, :, None] >= kpos[None, None, :])
-        p = jnp.where(valid, jnp.exp(s - lse[..., None]), 0.0)  # [BH,Tq,BK]
-        dv = jnp.einsum("bqk,bqd->bkd", p, gf)
-        dp = jnp.einsum("bqd,bkd->bqk", gf, vj)
-        ds = p * (dp - delta[..., None])
-        dq_acc = dq_acc + jnp.einsum("bqk,bkd->bqd", ds, kj)
-        dk = jnp.einsum("bqk,bqd->bkd", ds, qf)
-        return dq_acc, (dk, dv)
+    def dq_k_block(b, i, j, lens):
+        first, last = _needed_k_blocks(i, lens[b], **masks)
+        return jnp.clip(j, first, last)
 
-    nblk = tk_pad // block_k
-    dq, (dks, dvs) = jax.lax.scan(
-        step, jnp.zeros((bh, t, d), jnp.float32),
-        (jnp.arange(nblk, dtype=jnp.int32), kb, vb))
-    dk = dks.transpose(1, 0, 2, 3).reshape(bh, tk_pad, d)[:, :t_kv]
-    dv = dvs.transpose(1, 0, 2, 3).reshape(bh, tk_pad, d)[:, :t_kv]
-    return ((dq * scale).astype(q.dtype), dk.astype(k.dtype),
-            dv.astype(v.dtype))
+    dq = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, nq, nk),
+            in_specs=in_specs(lambda b, i, j, lens: i, dq_k_block),
+            out_specs=vmem((1, block_q, d), out_map),
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((bh, tq_pad, d), q.dtype),
+        compiler_params=params,
+        interpret=pallas_util.interpret(),
+        name="flash_attention_bwd_dq",
+    )(*operands)
+    return dq[:, :t], dk[:, :t_kv], dv[:, :t_kv]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash(q, k, v, lens_f, causal, block_q, block_k, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash(q, k, v, lens_f, causal, block_q, block_k, window, bwd_blocks):
     o, _ = _flash_forward(q, k, v, lens_f, causal=causal, block_q=block_q,
                           block_k=block_k, window=window)
     return o
 
 
-def _flash_fwd(q, k, v, lens_f, causal, block_q, block_k, window):
+def _flash_fwd(q, k, v, lens_f, causal, block_q, block_k, window,
+               bwd_blocks):
     o, lse = _flash_forward(q, k, v, lens_f, causal=causal, block_q=block_q,
                             block_k=block_k, window=window)
     return o, (q, k, v, lens_f, o, lse)
 
 
-def _flash_bwd(causal, block_q, block_k, window, res, g):
+def _flash_bwd(causal, block_q, block_k, window, bwd_blocks, res, g):
     q, k, v, lens_f, o, lse = res
+    pallas_util.note_traced("flash_attention.backward", "pallas")
     with jax.named_scope("flash_attention_bwd"):
-        dq, dk, dv = _blockwise_backward(q, k, v, lens_f, o, lse, g,
-                                         causal=causal, block_k=block_k,
-                                         window=window)
+        dq, dk, dv = _flash_backward(
+            q, k, v, lens_f, o, lse, g, causal=causal,
+            block_q=bwd_blocks[0], block_k=bwd_blocks[1], window=window)
     # lens is carried as f32 so the custom_vjp can hand back an ordinary
     # zero cotangent (int operands would need float0 plumbing)
     return dq, dk, dv, jnp.zeros_like(lens_f)
@@ -335,7 +463,9 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 def flash_attention(q, k, v, *, causal: bool = False,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K,
-                    key_lens=None, window=None):
+                    key_lens=None, window=None,
+                    bwd_block_q: int = BWD_BLOCK_Q,
+                    bwd_block_k: int = BWD_BLOCK_K):
     """Fused scaled-dot-product attention.
 
     q: [B, Tq, H, D]; k, v: [B, Tkv, H, D]. Returns [B, Tq, H, D].
@@ -348,9 +478,15 @@ def flash_attention(q, k, v, *, causal: bool = False,
 
     window: optional int — sliding-window (local) attention: query t
     attends keys (t-window, t]. Requires causal=True. BOTH directions
-    skip out-of-band k-blocks: the forward kernel's grid predicate and
-    the backward's per-block query-window gather make training cost
-    O(T*window) instead of O(T^2).
+    skip out-of-band blocks by the one grid predicate (`_block_needed`:
+    past the row's length, above the diagonal, below the band), so
+    training costs O(T*window) instead of O(T^2); the backward kernels
+    do not fetch a skipped step's blocks either.
+
+    block_q, block_k: the forward kernel's blocks. bwd_block_q,
+    bwd_block_k: the two backward kernels' (dk/dv and dq), their own
+    because what suits them differs; a sequence shorter than a block
+    takes one block.
     """
     if q.ndim != 4:
         raise ValueError(f"expected [B, T, H, D], got {q.shape}")
@@ -384,5 +520,5 @@ def flash_attention(q, k, v, *, causal: bool = False,
         return x.transpose(0, 2, 1, 3).reshape(b * h, tt, d)
 
     o = _flash(flat(q, t), flat(k, t_kv), flat(v, t_kv), lens, causal,
-               block_q, block_k, window)
+               block_q, block_k, window, (bwd_block_q, bwd_block_k))
     return o.reshape(b, h, t, d).transpose(0, 2, 1, 3)

@@ -202,35 +202,38 @@ class TestRollingSWACache:
 
 class TestSWAFlopScaling:
     @staticmethod
-    def _bwd_body_flops(T, window):
-        """XLA cost analysis counts a scan's body ONCE (trip count is
-        not multiplied in), so body-FLOPs-vs-T is the scaling law of
-        the per-block work: constant body => linear total, linear body
-        => quadratic total."""
-        from paddle_tpu.ops.flash_attention import flash_attention
+    def _bwd_computed_steps(T, window, block=128):
+        """Grid steps of one head that compute in each backward kernel:
+        the (q block, k block) pairs the kernels' one predicate admits
+        (the rest are skipped and, their `index_map`s clamped, fetch
+        nothing). Each costs the same five-plus matmuls of a block, so
+        the count is the scaling law of the backward's work. Until PR
+        32 this read the FLOPs of the `jnp` scan's body from XLA's cost
+        analysis, which cannot see inside a Mosaic call."""
+        from paddle_tpu.ops import flash_attention as FA
 
-        rng = np.random.RandomState(0)
-        q, k, v = (jnp.asarray(rng.randn(1, T, 2, 32), jnp.float32)
-                   for _ in range(3))
-        f = jax.jit(jax.grad(
-            lambda q, k, v: flash_attention(
-                q, k, v, causal=True, window=window,
-                block_q=128, block_k=128).sum(),
-            argnums=(0, 1, 2)))
-        ca = f.lower(q, k, v).compile().cost_analysis()
-        if isinstance(ca, (list, tuple)):     # older jax: one entry
-            ca = ca[0]                        # per computation
-        return float(ca["flops"])
+        n = T // block
+        qi, j = np.meshgrid(np.arange(n), np.arange(n), indexing="ij")
+        needed = FA._block_needed(qi, j, T, block_q=block, block_k=block,
+                                  causal=True, window=window)
+        # the ranges the index maps clamp to admit the same steps
+        for i in (0, n // 2, n - 1):
+            first, last = FA._needed_k_blocks(
+                i, T, block_q=block, block_k=block, causal=True,
+                window=window)
+            assert int(needed[i].sum()) == int(last) - int(first) + 1
+        return int(needed.sum())
 
     def test_swa_backward_linear_in_t(self):
-        """Measured: full backward body 3.45e8 -> 6.87e8 FLOPs as T
-        doubles 4096 -> 8192 (ratio 1.99: quadratic total); windowed
-        (w=256) body 3.53e7 -> 3.61e7 (ratio 1.02: linear total, and
-        ~19x less per-block work at T=8192)."""
-        full = [self._bwd_body_flops(t, None) for t in (4096, 8192)]
-        sw = [self._bwd_body_flops(t, 256) for t in (4096, 8192)]
-        assert full[1] / full[0] > 1.7, full     # body linear in T
-        assert sw[1] / sw[0] < 1.2, sw           # body constant in T
+        """Counted: full causal backward 528 -> 2080 computed steps a
+        kernel as T doubles 4096 -> 8192 (ratio 3.94: quadratic);
+        windowed (w=256) 93 -> 189 (ratio 2.03: linear, and 11x fewer
+        at T=8192)."""
+        full = [self._bwd_computed_steps(t, None) for t in (4096, 8192)]
+        sw = [self._bwd_computed_steps(t, 256) for t in (4096, 8192)]
+        assert full == [528, 2080] and sw == [93, 189]
+        assert full[1] / full[0] > 3.5, full     # quadratic in T
+        assert sw[1] / sw[0] < 2.2, sw           # linear in T
         assert sw[1] < full[1] / 4, (sw, full)   # and much cheaper
 
 

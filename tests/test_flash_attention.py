@@ -49,7 +49,8 @@ def test_grads_match_dense(np_rng, causal):
 
     def loss_flash(q, k, v):
         return jnp.sum(flash_attention(q, k, v, causal=causal,
-                                       block_q=8, block_k=8) ** 2)
+                                       block_q=8, block_k=8,
+                                       bwd_block_q=8, bwd_block_k=8) ** 2)
 
     def loss_dense(q, k, v):
         return jnp.sum(dense_attention(q, k, v, causal=causal) ** 2)
@@ -107,7 +108,8 @@ def test_key_lens_grads_match_masked_dense(np_rng):
     lens = jnp.asarray([16, 7], jnp.int32)
 
     gf = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
-        q, k, v, causal=True, block_q=8, block_k=8, key_lens=lens) ** 2),
+        q, k, v, causal=True, block_q=8, block_k=8, key_lens=lens,
+        bwd_block_q=8, bwd_block_k=8) ** 2),
         argnums=(0, 1, 2))(q, k, v)
     gd = jax.grad(lambda q, k, v: jnp.sum(
         _masked_dense(q, k, v, lens, True) ** 2),
@@ -160,8 +162,9 @@ class TestSlidingWindow:
     def test_grads_match_windowed_dense(self, np_rng):
         q, k, v = _qkv(np_rng, b=1, t=24, h=1, d=8)
         gf = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
-            q, k, v, causal=True, block_q=8, block_k=8,
-            window=6) ** 2), argnums=(0, 1, 2))(q, k, v)
+            q, k, v, causal=True, block_q=8, block_k=8, window=6,
+            bwd_block_q=8, bwd_block_k=8) ** 2),
+            argnums=(0, 1, 2))(q, k, v)
         gd = jax.grad(lambda q, k, v: jnp.sum(
             self._windowed_dense(q, k, v, 6) ** 2),
             argnums=(0, 1, 2))(q, k, v)
@@ -207,3 +210,114 @@ def test_key_lens_shape_validated(np_rng):
     q, k, v = _qkv(np_rng, b=2, t=8, h=1, d=8)
     with pytest.raises(ValueError, match="key_lens"):
         flash_attention(q, k, v, key_lens=jnp.asarray([8, 8, 8]))
+
+
+# -- the backward kernels (PR 32) against dense attention's autodiff ------
+
+def _pair_mask_dense(t, t_kv, causal, window, lens):
+    """[B, Tq, Tkv] bool: the three masks of the kernels, densely."""
+    qpos = jnp.arange(t, dtype=jnp.int32)[:, None]
+    kpos = jnp.arange(t_kv, dtype=jnp.int32)[None, :]
+    mask = jnp.ones((t, t_kv), bool)
+    if causal:
+        mask = mask & (qpos >= kpos)
+    if window is not None:
+        mask = mask & (qpos - kpos < window)
+    return mask[None] & (kpos[None] < jnp.asarray(lens)[:, None, None])
+
+
+_BWD_CASES = [
+    # t, t_kv, causal, window, key_lens, bwd blocks (q, k)
+    (21, 37, False, None, None, (8, 16)),
+    (37, 37, True, None, None, (8, 16)),
+    (37, 37, True, None, None, (16, 8)),
+    (29, 29, False, None, (29, 13), (8, 8)),
+    (29, 29, True, None, (0, 13), (8, 16)),     # a row with no key at all
+    (29, 29, False, None, (0, 29), (16, 8)),
+    (40, 40, True, 1, None, (8, 8)),
+    (40, 40, True, 5, None, (8, 16)),
+    (40, 40, True, 16, None, (16, 8)),
+    (27, 27, True, 6, (20, 7), (8, 8)),         # queries past len + window
+    (27, 27, True, 100, (27, 7), (8, 16)),      # a window wider than t
+]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("t,t_kv,causal,window,lens,blocks", _BWD_CASES)
+def test_backward_kernels_match_dense_autodiff(np_rng, t, t_kv, causal,
+                                               window, lens, blocks, dtype):
+    """dq, dk, dv of the Pallas backward over several blocks on both
+    axes, non-divisible lengths, each mask and their combinations,
+    against autodiff through dense attention on the same operands (in
+    float32). A query with no valid key outputs 0 by the kernel's
+    contract (dense softmax would average v), so its cotangent is left
+    out of the dense loss; a row with no key has zero gradients."""
+    q, k, v = (x.astype(dtype) for x in _qkv(np_rng, b=2, t=t, t_kv=t_kv,
+                                             h=1, d=8))
+    w = jnp.asarray(np_rng.randn(*q.shape), jnp.float32)
+    key_lens = None if lens is None else jnp.asarray(lens, jnp.int32)
+    mask = _pair_mask_dense(t, t_kv, causal, window,
+                            (t_kv, t_kv) if lens is None else lens)
+
+    def loss_flash(q, k, v):
+        o = flash_attention(q, k, v, causal=causal, window=window,
+                            key_lens=key_lens, block_q=8, block_k=8,
+                            bwd_block_q=blocks[0], bwd_block_k=blocks[1])
+        return jnp.sum(o.astype(jnp.float32) * w)
+
+    def loss_dense(q, k, v):
+        o = dense_attention(q, k, v, mask=mask)
+        return jnp.sum(o * w * jnp.any(mask, -1)[:, :, None, None])
+
+    got = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss_dense, argnums=(0, 1, 2))(
+        *(x.astype(jnp.float32) for x in (q, k, v)))
+    # bf16: p and ds are rounded to the operands' dtype for their
+    # matmuls and the gradients leave in it (2^-9 a rounding)
+    tol = 2e-4 if dtype == jnp.float32 else 5e-2
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        assert a.dtype == dtype, name
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b),
+                                   rtol=tol, atol=tol, err_msg=name)
+    if lens is not None and 0 in lens:
+        row = lens.index(0)
+        for a in got:
+            np.testing.assert_array_equal(np.asarray(a[row], np.float32), 0.0)
+
+
+def test_backward_is_traced_as_the_pallas_kernels(np_rng):
+    from paddle_tpu.ops import pallas_util
+
+    before = pallas_util.traced().get("flash_attention.backward=pallas", 0)
+    q, k, v = _qkv(np_rng, b=1, t=16, h=1, d=8)
+    jax.grad(lambda q: jnp.sum(flash_attention(
+        q, k, v, causal=True, block_q=8, block_k=8)))(q)
+    assert pallas_util.traced()["flash_attention.backward=pallas"] > before
+
+
+@pytest.mark.parametrize("causal,window", [(False, None), (True, None),
+                                           (True, 1), (True, 5), (True, 20)])
+@pytest.mark.parametrize("block_q,block_k", [(8, 8), (8, 16), (16, 8)])
+def test_needed_block_ranges_are_the_block_predicate(causal, window,
+                                                     block_q, block_k):
+    """The closed forms the backward's `index_map`s clamp to admit
+    exactly the blocks `_block_needed` admits, for every row length."""
+    from paddle_tpu.ops import flash_attention as FA
+
+    t = 48
+    nq, nk = t // block_q, t // block_k
+    masks = dict(block_q=block_q, block_k=block_k, causal=causal,
+                 window=window)
+    for n_keys in (0, 1, 7, 8, 9, 30, 48):
+        needed = np.array([[bool(FA._block_needed(i, j, n_keys, **masks))
+                            for j in range(nk)] for i in range(nq)])
+        for i in range(nq):
+            first, last = FA._needed_k_blocks(i, n_keys, **masks)
+            want = [int(first) <= j <= int(last) and n_keys > 0
+                    for j in range(nk)]
+            assert want == list(needed[i]), (n_keys, i)
+        for j in range(nk):
+            first, last = FA._needed_q_blocks(j, n_keys, nq, **masks)
+            want = [int(first) <= i <= int(last) and j * block_k < n_keys
+                    for i in range(nq)]
+            assert want == list(needed[:, j]), (n_keys, j)

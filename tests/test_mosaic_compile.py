@@ -1,0 +1,70 @@
+"""Compile the flash-attention kernels for the TPU v5e without one.
+
+`tests/test_pallas_lowering.py` stops at Mosaic's MLIR; the chip's
+compiler proper is installed here too and compiles for a chip that is
+described and not attached, which is where it refuses a misaligned
+slice, a matmul form or too much VMEM. Nothing runs: no result, no
+time. The kernels of the LM cell's attention at its real widths, a
+second or two each, all in this one file (only one process may hold the
+TPU library: the topology is described inside a fixture, never at
+import, so every xdist worker collects the same tests and only the
+worker given this file loads it).
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from paddle_tpu.ops import flash_attention as FA
+from paddle_tpu.ops import pallas_util
+
+pytestmark = pytest.mark.pallas
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _compiled_not_interpreted(monkeypatch):
+    monkeypatch.setattr(pallas_util, "on_tpu", lambda: True)
+    # conftest turns x64 on; the chip runs without it, Mosaic has no f64
+    with jax.enable_x64(False):
+        yield
+
+
+@pytest.mark.parametrize("bh,t,d,dtype,window,lens", [
+    # starcoder2_3b_l4.train_seq4k: 4095 positions, window inert
+    (48, 4095, 128, jnp.bfloat16, 4096, False),
+    (24, 8192, 128, jnp.bfloat16, 4096, False),   # the band active
+    (16, 2048, 64, jnp.bfloat16, 512, True),      # the serving width
+    (16, 2048, 128, jnp.float32, None, False),    # the float32 policy
+    (8, 100, 64, jnp.bfloat16, None, True),       # shorter than a tile
+])
+def test_flash_forward_and_backward_compile_for_v5e(one_chip, bh, t, d,
+                                                    dtype, window, lens):
+    x = jax.ShapeDtypeStruct((1, t, bh, d), dtype, sharding=one_chip)
+    key_lens = (jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+                if lens else None)
+
+    def loss(q, k, v, key_lens):
+        o = FA.flash_attention(q, k, v, causal=True, window=window,
+                               key_lens=key_lens)
+        return jnp.sum(o.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x, key_lens).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    # under `grad` the forward's instruction is the jvp's
+    for name in ("jvp_flash_attention_fwd_", "flash_attention_bwd_dkv",
+                 "flash_attention_bwd_dq"):
+        assert f"%{name}" in text, name

@@ -16,6 +16,7 @@ import pytest
 
 from paddle_tpu.core import dtypes
 from paddle_tpu.models import transformer as T
+from paddle_tpu.ops import flash_attention as FA
 from paddle_tpu.ops import pallas_util, rnn
 from paddle_tpu.ops import ragged_paged_attention as RPA
 
@@ -44,12 +45,14 @@ def _lower_for_tpu(fn, *args) -> int:
     return _lowered_text(fn, *args).count("tpu_custom_call")
 
 
-def _kernel_operands(text: str) -> list:
-    """The operand types of every Mosaic call in a lowered program,
-    one list per call: ['tensor<4xi32>', 'tensor<4x256x128xbf16>', ...]."""
+def _kernels(text: str) -> list:
+    """(kernel name, operand types) of every Mosaic call in a lowered
+    program, in program order: ('flash_attention_fwd', ['tensor<4xi32>',
+    'tensor<4x256x128xbf16>', ...])."""
     calls = [ln for ln in text.splitlines() if "@tpu_custom_call" in ln]
-    return [re.findall(r"tensor<[^>]*>",
-                       re.search(r" : \((.*?)\) -> ", ln).group(1))
+    return [(re.search(r'kernel_name = "([^"]*)"', ln).group(1),
+             re.findall(r"tensor<[^>]*>",
+                        re.search(r" : \((.*?)\) -> ", ln).group(1)))
             for ln in calls]
 
 
@@ -81,11 +84,22 @@ def test_flash_fwd_bwd_lowers(batch, t, heads, head_dim, window, lens):
         return jnp.sum(o.astype(jnp.float32))
 
     text = _lowered_text(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
-    # one kernel: the forward (the backward is blockwise jnp)
-    (operands,) = _kernel_operands(text)
+    # three kernels: the forward, and the backward's dk/dv and dq
+    # kernels on their own blocks; every tensor operand that carries q,
+    # k, v or the output's cotangent is bf16, lse and delta float32 rows
+    (fwd_name, fwd), (dkv_name, dkv), (dq_name, dq) = _kernels(text)
+    assert (fwd_name, dkv_name, dq_name) == (
+        "flash_attention_fwd", "flash_attention_bwd_dkv",
+        "flash_attention_bwd_dq")
     bh, t_pad = batch * heads, -(-t // 256) * 256
-    assert operands == [f"tensor<{bh}xi32>"] + [
+    assert fwd == [f"tensor<{bh}xi32>"] + [
         f"tensor<{bh}x{t_pad}x{head_dim}xbf16>"] * 3
+    block = max(FA.BWD_BLOCK_Q, FA.BWD_BLOCK_K)
+    t_bwd = -(-t // block) * block
+    wide = f"tensor<{bh}x{t_bwd}x{head_dim}xbf16>"
+    row = f"tensor<{bh}x1x{t_bwd}xf32>"
+    assert dkv == dq == [f"tensor<{bh}xi32>", wide, wide, row, row,
+                         wide, wide]
 
 
 @pytest.mark.parametrize("bf16", [True, False])
@@ -106,12 +120,19 @@ def test_model_hands_flash_the_policys_dtype(bf16):
     assert params["blocks"][0]["qkv"]["bias"].dtype == jnp.float32
     text = _lowered_text(jax.grad(lambda p, toks: T.loss(p, cfg, toks)),
                          params, _sds((2, 257), jnp.int32))
-    calls = _kernel_operands(text)
-    assert len(calls) == 4      # 2 layers, each again under remat
+    calls = _kernels(text)
+    # 2 layers: the forward, again under remat, and the two backward
+    # kernels of each
+    names = [name for name, _ in calls]
+    assert sorted(names) == sorted(
+        ["flash_attention_fwd", "flash_attention_fwd",
+         "flash_attention_bwd_dkv", "flash_attention_bwd_dq"] * 2)
     want = "bf16" if bf16 else "f32"
-    for operands in calls:
-        assert operands == ["tensor<4xi32>"] + [
-            f"tensor<4x256x128x{want}>"] * 3
+    wide, row = f"tensor<4x256x128x{want}>", "tensor<4x1x256xf32>"
+    for name, operands in calls:
+        assert operands == ["tensor<4xi32>"] + (
+            [wide, wide, row, row, wide, wide] if "bwd" in name
+            else [wide] * 3), name
 
 
 @pytest.mark.parametrize("name,run,init,hidden,t", [
