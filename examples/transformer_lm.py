@@ -2,6 +2,12 @@
 flagship's user flow (dense or MoE, long-context ready).
 
 Run: python examples/transformer_lm.py [--steps 200] [--moe]
+     python examples/transformer_lm.py --block-diffusion [--steps 200]
+
+`--block-diffusion` trains the same toy task with the block-diffusion
+objective (`T.block_diffusion_loss`) on a toy SDAR-style block: RMSNorm,
+bias-free projections, QK-norm, heads of their own size, and a dropless
+mixture of 8 gated-SiLU experts, top 2.
 
 The task is character-level copy-structure text (synthetic, zero
 egress): sequences follow an order-1 Markov chain, so a small model
@@ -32,6 +38,45 @@ def make_batch(rng, vocab, batch, seq_len):
     return jnp.asarray(toks)
 
 
+def train_block_diffusion(args, block_length=4):
+    """The block-diffusion objective: the noise is drawn a step, the
+    loss returns the expert layer's counts beside its value, and the
+    loop that reads the loss adds them to the default timeline."""
+    from paddle_tpu.obs.trace import default_timeline
+    from paddle_tpu.parallel import moe
+
+    cfg = T.TransformerConfig(
+        vocab=args.vocab + 1, dim=args.dim, n_layers=args.layers, n_heads=4,
+        n_kv_heads=2, head_size=32, norm="rms", bias=False, qk_norm=True,
+        moe_router="dropless", moe_experts=8, moe_every=1, moe_k=2,
+        moe_dim=2 * args.dim, attn_impl="auto")      # last id: the mask
+    params = T.init_params(jax.random.key(0), cfg)
+    opt = optim.adam(3e-3)
+    opt_state = opt.init(params)
+
+    @jax.jit
+    def step(params, opt_state, toks, rng, i):
+        masked, p = T.block_diffusion_noise(rng, toks, block_length)
+        (loss, stats), grads = jax.value_and_grad(
+            lambda q: T.block_diffusion_loss(
+                q, cfg, toks, masked, p, block_length=block_length),
+            has_aux=True)(params)
+        params, opt_state = opt.update(grads, opt_state, params, i)
+        return params, opt_state, loss, stats
+
+    r = np.random.RandomState(0)
+    for i in range(args.steps):
+        toks = make_batch(r, args.vocab, args.batch, args.seq_len)
+        params, opt_state, loss, stats = step(
+            params, opt_state, toks, jax.random.key(i), jnp.asarray(i))
+        if i % 50 == 0 or i == args.steps - 1:
+            moe.count_dropless_stats(stats, positions=2 * toks.size)
+            print(f"step {i:4d}  loss {float(loss):.4f}")
+    print("expert layer counters:", {
+        k: v for k, v in default_timeline().counters().items()
+        if k.startswith("moe.")})
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
@@ -42,7 +87,11 @@ def main():
     ap.add_argument("--layers", type=int, default=2)
     ap.add_argument("--moe", action="store_true",
                     help="sparse FFN blocks (4 experts, top-2)")
+    ap.add_argument("--block-diffusion", action="store_true",
+                    help="block-diffusion objective on a dropless-MoE block")
     args = ap.parse_args()
+    if args.block_diffusion:
+        return train_block_diffusion(args)
 
     cfg = T.TransformerConfig(
         vocab=args.vocab, dim=args.dim, n_layers=args.layers, n_heads=4,

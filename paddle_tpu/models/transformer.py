@@ -53,8 +53,11 @@ TP_RULES = list(MEGATRON_RULES) + [(r"lm_head/kernel$", P(None, MODEL_AXIS))]
 # otherwise catch the substring in "r-out-er" and shard the router's
 # d_model dim (the router is replicated by design — the EP path's
 # shard_map pspec pins it P()).
+# The dropless layer's leaves (w_gate / w_up / w_down, stacked over the
+# experts held) split the same way; the q/k norm weights are replicated.
 TP_MOE_RULES = ([(r"moe/router/kernel$", P())] + TP_RULES +
-                [(r"moe/(w1|b1|w2|b2)$", P(MODEL_AXIS))])
+                [(r"moe/(w1|b1|w2|b2|w_gate|w_up|w_down)$", P(MODEL_AXIS)),
+                 (r"(q_norm|k_norm)/scale$", P())])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,13 +117,62 @@ class TransformerConfig:
     moe_k: int = 2
     moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
-    # "topk" (GShard token-choice, needs the aux loss) or
-    # "expert_choice" (experts pick tokens: perfect balance, no aux)
+    # "topk" (GShard token-choice, needs the aux loss),
+    # "expert_choice" (experts pick tokens: perfect balance, no aux) or
+    # "dropless" (softmax over all `moe_experts`, top-`moe_k`
+    # renormalised, gated-SiLU experts of width `moe_dim`, no capacity
+    # and no aux term: parallel.moe.dropless_ffn). A dropless layer may
+    # hold a share of the experts, `moe_held` of them from
+    # `moe_held_first` (an expert-parallel chip's): it routes over all
+    # and computes the terms of the experts it holds.
     moe_router: str = "topk"
+    moe_dim: Optional[int] = None
+    moe_held: Optional[int] = None
+    moe_held_first: int = 0
+    # descriptors of the block, read at trace time. norm: "layer"
+    # (biased LayerNorm, eps 1e-5) or "rms" (RMSNorm, weight only, eps
+    # 1e-6);
+    # bias: whether the projections and the MLP carry one; head_size:
+    # the width of a head where it is not dim // n_heads; qk_norm: an
+    # RMSNorm over each head's lanes of q and of k before the rotation
+    # (one weight vector each, shared by the heads).
+    norm: str = "layer"
+    bias: bool = True
+    head_size: Optional[int] = None
+    qk_norm: bool = False
+
+    def __post_init__(self):
+        if self.norm not in ("layer", "rms"):
+            raise ValueError(f"norm must be 'layer' or 'rms', got "
+                             f"{self.norm!r}")
+        if self.moe_router == "dropless":
+            if not (self.moe_dim and 0 < self.experts_held
+                    and 0 <= self.moe_held_first
+                    and self.moe_held_first + self.experts_held
+                    <= self.moe_experts and self.moe_k <= self.moe_experts):
+                raise ValueError(
+                    "a dropless MoE needs moe_dim, moe_k <= moe_experts and "
+                    "the experts held inside [0, moe_experts)")
+        elif (self.moe_held is not None or self.moe_held_first
+              or self.moe_dim is not None):
+            raise ValueError("moe_dim / moe_held describe the dropless "
+                             "layer (moe_router='dropless')")
 
     @property
     def head_dim(self) -> int:
+        if self.head_size is not None:
+            return self.head_size
         return self.dim // self.n_heads
+
+    @property
+    def attn_dim(self) -> int:
+        """Width of the concatenated heads: what the output projection
+        reads (dim itself unless `head_size` says otherwise)."""
+        return self.n_heads * self.head_dim
+
+    @property
+    def experts_held(self) -> int:
+        return self.moe_experts if self.moe_held is None else self.moe_held
 
     @property
     def kv_heads(self) -> int:
@@ -144,23 +196,38 @@ def init_params(rng, cfg: TransformerConfig):
     # values are narrower when n_kv_heads < n_heads; 3*d exactly for MHA)
     qkv_w = (cfg.n_heads + 2 * cfg.kv_heads) * cfg.head_dim
 
+    def norm(width):
+        if cfg.norm == "rms":
+            return {"scale": jnp.ones((width,))}
+        return {"scale": jnp.ones((width,)), "offset": jnp.zeros((width,))}
+
+    def dense(key, shape):
+        if cfg.bias:
+            return {"kernel": smart(key, shape),
+                    "bias": jnp.zeros((shape[1],))}
+        return {"kernel": smart(key, shape)}
+
     def block_params(i, k1, k2, k3, k4):
         p = {
-            "ln1": {"scale": jnp.ones((d,)), "offset": jnp.zeros((d,))},
-            "qkv": {"kernel": smart(k1, (d, qkv_w)),
-                    "bias": jnp.zeros((qkv_w,))},
-            "proj": {"kernel": smart(k2, (d, d)), "bias": jnp.zeros((d,))},
-            "ln2": {"scale": jnp.ones((d,)), "offset": jnp.zeros((d,))},
+            "ln1": norm(d),
+            "qkv": dense(k1, (d, qkv_w)),
+            "proj": dense(k2, (cfg.attn_dim, d)),
+            "ln2": norm(d),
         }
+        if cfg.qk_norm:
+            p["q_norm"] = {"scale": jnp.ones((cfg.head_dim,))}
+            p["k_norm"] = {"scale": jnp.ones((cfg.head_dim,))}
         if cfg.is_moe_block(i):
             from paddle_tpu.parallel import moe
 
-            p["moe"] = moe.init_moe_params(k3, cfg.moe_experts, d, h)
+            if cfg.moe_router == "dropless":
+                p["moe"] = moe.init_dropless_params(
+                    k3, cfg.moe_experts, cfg.experts_held, d, cfg.moe_dim)
+            else:
+                p["moe"] = moe.init_moe_params(k3, cfg.moe_experts, d, h)
         else:
-            p["fc1"] = {"kernel": smart(k3, (d, h)),
-                        "bias": jnp.zeros((h,))}
-            p["fc2"] = {"kernel": smart(k4, (h, d)),
-                        "bias": jnp.zeros((d,))}
+            p["fc1"] = dense(k3, (d, h))
+            p["fc2"] = dense(k4, (h, d))
         return p
 
     return {
@@ -168,9 +235,34 @@ def init_params(rng, cfg: TransformerConfig):
                                                      (cfg.vocab, d))},
         "blocks": [block_params(i, next(ks), next(ks), next(ks), next(ks))
                    for i in range(cfg.n_layers)],
-        "ln_f": {"scale": jnp.ones((d,)), "offset": jnp.zeros((d,))},
+        "ln_f": norm(d),
         "lm_head": {"kernel": smart(next(ks), (d, cfg.vocab))},
     }
+
+
+def _norm(cfg: TransformerConfig, p, x):
+    """The block's normalisation over the last axis, in float32: biased
+    LayerNorm, or RMSNorm x * rsqrt(mean(x^2) + eps) * scale."""
+    if cfg.norm == "rms":
+        x32 = at_least_f32(x)
+        y = x32 * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + 1e-6)
+        return (y * p["scale"]).astype(x.dtype)
+    return norm_ops.layer_norm(x, p["scale"], p["offset"])
+
+
+def require_decodable(cfg: TransformerConfig) -> None:
+    """The decode helpers (generate, beam, speculative, the serving
+    engine) serve the biased-LayerNorm block with dim // n_heads heads:
+    their head and caches are written for it. A config they cannot
+    serve yet is refused here rather than mis-shaped."""
+    if (cfg.norm != "layer" or not cfg.bias or cfg.qk_norm
+            or cfg.head_size is not None or cfg.moe_router == "dropless"):
+        raise NotImplementedError(
+            "decoding is not implemented for this block (RMSNorm, "
+            "bias-free projections, QK-norm, an explicit head size or a "
+            "dropless / partly held MoE): it trains through loss() and "
+            "block_diffusion_loss() only")
 
 
 def _rope(x, positions, base: float, scaling: str = "none",
@@ -201,11 +293,23 @@ def _rope(x, positions, base: float, scaling: str = "none",
     return out.reshape(x.shape)
 
 
+def block_diffusion_mask(length: int, bd: int):
+    """[2L, 2L] bool, rows queries: the block-diffusion training mask
+    over [noised copy ; clean copy] (ops.flash_attention._pair_mask
+    has the table). For the dense path and small sizes."""
+    pos = jnp.arange(2 * length, dtype=jnp.int32)
+    noised, blk = pos < length, (pos % length) // bd
+    qn, kn = noised[:, None], noised[None, :]
+    qb, kb = blk[:, None], blk[None, :]
+    return jnp.where(kn, qn & (kb == qb), jnp.where(qn, kb < qb, kb <= qb))
+
+
 def _dense_attention(q, k, v, causal: bool, key_mask=None,
-                     window=None):
+                     window=None, block_diffusion=None):
     """Exact reference attention; [B,T,H,Dh] in/out, f32 scores.
     key_mask: optional [B, Tk] bool, False keys are never attended.
-    window: sliding-window band (causal only)."""
+    window: sliding-window band (causal only). block_diffusion: (L, Bd),
+    the mask of `block_diffusion_mask` in place of the causal one."""
     if window is not None and not causal:
         # identical failure to ops.flash_attention's — the two backends
         # must not disagree for the same config (r4 advisor finding:
@@ -223,6 +327,9 @@ def _dense_attention(q, k, v, causal: bool, key_mask=None,
             mask = mask & (qpos - jnp.arange(
                 tk, dtype=jnp.int32)[None, :] < window)
         scores = jnp.where(mask, scores, -1e30)
+    if block_diffusion is not None:
+        scores = jnp.where(block_diffusion_mask(*block_diffusion), scores,
+                           -1e30)
     if key_mask is not None:
         scores = jnp.where(key_mask[:, None, None, :], scores, -1e30)
     w = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
@@ -242,7 +349,7 @@ def _expand_kv(q, k, v):
 
 
 def _attention(cfg: TransformerConfig, q, k, v, causal: bool,
-               key_mask=None, key_lens=None):
+               key_mask=None, key_lens=None, block_diffusion=None):
     """key_lens [B] describes RIGHT-padded rows (keys [0, lens[b]) are
     real) and rides the flash kernel's per-row bound; key_mask [B, Tk]
     is an arbitrary mask and forces the dense path. They are two
@@ -253,7 +360,10 @@ def _attention(cfg: TransformerConfig, q, k, v, causal: bool,
     under a bf16 policy (float32 bias), which no other matmul of the
     model is handed. Before the expansion, so the head repeat, the
     transposes, the pad and the kernel's k/v fetches move the narrow
-    bytes too; the caches keep what `_block_parts` returns."""
+    bytes too; the caches keep what `_block_parts` returns.
+
+    block_diffusion (L, Bd): the training mask over [noised ; clean]
+    copies in place of the causal one (`causal` is then not read)."""
     q, k, v = default_policy().cast_to_compute(q, k, v)
     k, v = _expand_kv(q, k, v)
     if key_mask is not None and key_lens is not None:
@@ -273,6 +383,14 @@ def _attention(cfg: TransformerConfig, q, k, v, causal: bool,
         impl = "dense"      # arbitrary masks: the ONE dense path below
     pallas_util.note_traced("transformer.attention", impl)
     pallas_util.note_traced("transformer.attention.operands", str(q.dtype))
+    if block_diffusion is not None:
+        if key_mask is not None or key_lens is not None or window is not None:
+            raise ValueError("block_diffusion attention takes no key mask, "
+                             "key lengths or window")
+        if impl == "flash":
+            return flash_attention(q, k, v, block_diffusion=block_diffusion)
+        return _dense_attention(q, k, v, False,
+                                block_diffusion=block_diffusion)
     if impl == "flash":
         if key_lens is not None:
             # right-padded variable-length rows ride the kernel's
@@ -300,6 +418,12 @@ def _ffn(cfg: TransformerConfig, p, y, token_mask=None):
 
         b, t, d = y.shape
         flat_mask = None if token_mask is None else token_mask.reshape(b * t)
+        if cfg.moe_router == "dropless":
+            pallas_util.note_traced("transformer.ffn", "moe_dropless")
+            out = moe.dropless_ffn(
+                p["moe"], y.reshape(b * t, d), k=cfg.moe_k,
+                first_held=cfg.moe_held_first, token_mask=flat_mask)
+            return out.y.reshape(b, t, d), out.stats
         if cfg.moe_router == "expert_choice":
             out = moe.expert_choice_ffn(
                 p["moe"], y.reshape(b * t, d),
@@ -311,11 +435,11 @@ def _ffn(cfg: TransformerConfig, p, y, token_mask=None):
                               token_mask=flat_mask)
         else:
             raise ValueError(
-                f"moe_router must be 'topk' or 'expert_choice', got "
-                f"{cfg.moe_router!r}")
+                f"moe_router must be 'topk', 'expert_choice' or "
+                f"'dropless', got {cfg.moe_router!r}")
         return out.y.reshape(b, t, d), out.aux_loss
-    y = jax.nn.gelu(linalg.dense(y, p["fc1"]["kernel"], p["fc1"]["bias"]))
-    return (linalg.dense(y, p["fc2"]["kernel"], p["fc2"]["bias"]),
+    y = jax.nn.gelu(linalg.dense(y, p["fc1"]["kernel"], p["fc1"].get("bias")))
+    return (linalg.dense(y, p["fc2"]["kernel"], p["fc2"].get("bias")),
             jnp.zeros((), jnp.float32))
 
 
@@ -333,26 +457,31 @@ def _block_parts(cfg: TransformerConfig, p, x, positions, attn_fn,
     their own entry (`_expand_kv`)."""
     b, t, d = x.shape
     h, hkv, dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
-    y = norm_ops.layer_norm(x, p["ln1"]["scale"], p["ln1"]["offset"])
-    qkv = linalg.dense(y, p["qkv"]["kernel"], p["qkv"]["bias"])
+    y = _norm(cfg, p["ln1"], x)
+    qkv = linalg.dense(y, p["qkv"]["kernel"], p["qkv"].get("bias"))
     q = qkv[..., :h * dh].reshape(b, t, h, dh)
     k = qkv[..., h * dh:(h + hkv) * dh].reshape(b, t, hkv, dh)
     v = qkv[..., (h + hkv) * dh:].reshape(b, t, hkv, dh)
+    if cfg.qk_norm:
+        q, k = _norm(cfg, p["q_norm"], q), _norm(cfg, p["k_norm"], k)
     q = _rope(q, positions, cfg.rope_base, cfg.rope_scaling,
               cfg.rope_factor)
     k = _rope(k, positions, cfg.rope_base, cfg.rope_scaling,
               cfg.rope_factor)
-    a = attn_fn(q, k, v).reshape(b, t, d)
-    x = x + linalg.dense(a, p["proj"]["kernel"], p["proj"]["bias"])
-    y = norm_ops.layer_norm(x, p["ln2"]["scale"], p["ln2"]["offset"])
+    a = attn_fn(q, k, v).reshape(b, t, cfg.attn_dim)
+    x = x + linalg.dense(a, p["proj"]["kernel"], p["proj"].get("bias"))
+    y = _norm(cfg, p["ln2"], x)
     out, aux = _ffn(cfg, p, y, token_mask)
     return x + out, k, v, aux
 
 
 def _block(cfg: TransformerConfig, p, x, positions, token_mask=None,
-           attn_fn=None):
+           attn_fn=None, block_diffusion=None):
     if attn_fn is None:
-        attn_fn = lambda q, k, v: _attention(cfg, q, k, v, causal=True)
+        attn_fn = lambda q, k, v: _attention(
+            cfg, q, k, v, causal=True, block_diffusion=block_diffusion)
+    elif block_diffusion is not None:
+        raise ValueError("block_diffusion rides the config's own attention")
     else:
         # external impls (ring/Ulysses context parallelism) expect
         # matching head counts — expand compact GQA K/V at their door
@@ -364,8 +493,10 @@ def _block(cfg: TransformerConfig, p, x, positions, token_mask=None,
 
 
 def _forward(params, cfg: TransformerConfig, tokens, positions=None,
-             token_mask=None, attn_fn=None, return_hidden=False):
-    """tokens [B,T] int32 -> (logits [B,T,V], summed MoE aux loss).
+             token_mask=None, attn_fn=None, return_hidden=False,
+             block_diffusion=None):
+    """tokens [B,T] int32 -> (logits [B,T,V], summed MoE aux loss; for
+    a dropless MoE its `DroplessStats`, stacked over the layers).
     token_mask [B,T] bool marks real (non-padding) positions for MoE
     capacity accounting. attn_fn overrides the config's attention (the
     context-parallel builder injects ring/Ulysses attention here).
@@ -380,14 +511,19 @@ def _forward(params, cfg: TransformerConfig, tokens, positions=None,
             jnp.arange(tokens.shape[1], dtype=jnp.int32), tokens.shape)
     blk = _block
     if cfg.remat:
-        # cfg and attn_fn are static (non-pytree) arguments
-        blk = jax.checkpoint(_block, static_argnums=(0, 5))
-    aux = jnp.zeros((), jnp.float32)
+        # cfg, attn_fn and the mask are static (non-pytree) arguments
+        blk = jax.checkpoint(_block, static_argnums=(0, 5, 6))
+    auxes = []
     for p in params["blocks"]:
-        x, a = blk(cfg, p, x, positions, token_mask, attn_fn)
-        aux = aux + a
-    x = norm_ops.layer_norm(x, params["ln_f"]["scale"],
-                            params["ln_f"]["offset"])
+        x, a = blk(cfg, p, x, positions, token_mask, attn_fn,
+                   block_diffusion)
+        auxes.append(a)
+    if cfg.moe_experts > 0 and cfg.moe_router == "dropless":
+        moe_blocks = [a for i, a in enumerate(auxes) if cfg.is_moe_block(i)]
+        aux = jax.tree.map(lambda *xs: jnp.stack(xs), *moe_blocks)
+    else:
+        aux = sum(auxes, jnp.zeros((), jnp.float32))
+    x = _norm(cfg, params["ln_f"], x)
     if return_hidden:
         return x, aux
     return linalg.matmul(x, params["lm_head"]["kernel"]), aux
@@ -427,9 +563,67 @@ def loss(params, cfg: TransformerConfig, tokens, lengths=None,
         mask = jnp.arange(
             1, tokens.shape[1], dtype=jnp.int32)[None, :] < lengths[:, None]
         ce = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1)
-    if cfg.moe_experts > 0:
+    if cfg.moe_experts > 0 and cfg.moe_router != "dropless":
         ce = ce + cfg.moe_aux_weight * aux
     return ce
+
+
+def block_diffusion_noise(rng, tokens, block_length: int, *,
+                          eps: float = 1e-3):
+    """Draws the noise of `block_diffusion_loss` for tokens [B, L]: one
+    t ~ U(0, 1) for each (sequence, block of `block_length` tokens),
+    p = eps + (1 - eps) t (the linear schedule), and each token masked
+    independently with its block's p. -> (masked [B, L] bool,
+    p [B, L] float32)."""
+    b, length = tokens.shape
+    if length % block_length:
+        raise ValueError(f"block_length {block_length} must divide the "
+                         f"sequence length {length}")
+    k_t, k_m = jax.random.split(rng)
+    t = jax.random.uniform(k_t, (b, length // block_length), jnp.float32)
+    p = jnp.repeat(eps + (1.0 - eps) * t, block_length, axis=1)
+    return jax.random.uniform(k_m, (b, length), jnp.float32) < p, p
+
+
+def block_diffusion_loss(params, cfg: TransformerConfig, tokens, masked, p,
+                         *, block_length: int, mask_id: Optional[int] = None):
+    """The vectorised block-diffusion objective (BD3-LM, Arriola et al.
+    2025; what SDAR trains with). tokens [B, L] is the clean sequence
+    x0, masked [B, L] bool which tokens are replaced by `mask_id`
+    (default: the last id of the vocabulary) in the noised copy xt, and
+    p [B, L] float the masking probability each token was drawn with
+    (constant over a block). The model sees 2L positions, ids [xt ; x0]
+    at position ids [0..L-1 ; 0..L-1], under the block-diffusion
+    attention mask, and the loss reads the noised half only, at the
+    masked positions, each weighted 1/p, with no shift:
+
+        sum over masked (b, i) of nll(b, i) / p(b, i)  /  (B L)
+
+    The noise is data: deterministic in its arguments
+    (`block_diffusion_noise` draws it). -> (loss, aux); aux is the
+    forward's: for a dropless MoE its per-layer `DroplessStats`."""
+    b, length = tokens.shape
+    if mask_id is None:
+        mask_id = cfg.vocab - 1
+    with jax.named_scope("block_diffusion_loss"):
+        noised = jnp.where(masked, jnp.asarray(mask_id, tokens.dtype), tokens)
+        ids = jnp.concatenate([noised, tokens], axis=1)
+        pos = jnp.broadcast_to(
+            jnp.tile(jnp.arange(length, dtype=jnp.int32), 2), ids.shape)
+        bd = (length, block_length)
+        if cfg.fused_ce_chunk:
+            hid, aux = _forward(params, cfg, ids, pos, return_hidden=True,
+                                block_diffusion=bd)
+            nll = losses_ops.chunked_lm_head_nll(
+                hid[:, :length], params["lm_head"]["kernel"], tokens,
+                chunk=cfg.fused_ce_chunk)
+        else:
+            logits, aux = _forward(params, cfg, ids, pos, block_diffusion=bd)
+            logits = at_least_f32(logits[:, :length])
+            nll = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
+                logits, tokens[..., None], axis=-1)[..., 0]
+        weight = masked.astype(jnp.float32) / p.astype(jnp.float32)
+        return jnp.sum(nll * weight) / (b * length), aux
 
 
 def score(params, cfg: TransformerConfig, tokens, lengths=None):
@@ -536,6 +730,7 @@ def _prefill_kv(params, cfg: TransformerConfig, toks, total: int):
     and return per-block `total`-slot K/V buffers filled at [:, :W] —
     the shared prefill of the speculative and beam decoders (generate's
     prefill stays separate: it also threads prompt_lens/MoE masks)."""
+    require_decodable(cfg)
     policy = default_policy()
     b, w = toks.shape
     x = jnp.take(params["embed"]["table"], toks, axis=0)
@@ -709,6 +904,7 @@ def generate(params, cfg: TransformerConfig, prompt, steps: int, *,
     only the dense impl materializes [B,H,Tq,Tk] scores, so prefer
     attn_impl "auto"/"flash" for long variable-length prompts.
     """
+    require_decodable(cfg)
     b, t0 = prompt.shape
     if cfg.attn_window is not None and prompt_lens is not None:
         raise ValueError(
@@ -898,6 +1094,8 @@ def speculative_generate(params, cfg: TransformerConfig,
     finishes `steps` tokens in ceil(steps / (draft_k+1)) rounds, a
     hopeless one in `steps`.
     """
+    require_decodable(cfg)
+    require_decodable(draft_cfg)
     if cfg.kv_cache_dtype != "compute" or \
             draft_cfg.kv_cache_dtype != "compute":
         raise ValueError(
@@ -1066,6 +1264,8 @@ def speculative_sample(params, cfg: TransformerConfig,
 
     return_stats=True also returns per-row round counts [B].
     """
+    require_decodable(cfg)
+    require_decodable(draft_cfg)
     if cfg.kv_cache_dtype != "compute" or \
             draft_cfg.kv_cache_dtype != "compute":
         raise ValueError(
@@ -1232,6 +1432,7 @@ def beam_decode(params, cfg: TransformerConfig, prompt, steps: int,
     [B, K, T0+steps], scores [B, K]) sorted best-first; without an
     eos_id every beam runs the full `steps`.
     """
+    require_decodable(cfg)
     if cfg.kv_cache_dtype != "compute":
         raise ValueError(
             "kv_cache_dtype='int8' covers generate()/sample() and the "

@@ -49,19 +49,55 @@ DEFAULT_BLOCK_K = 512
 # causal (PERF.md section 6, PR 32); the forward's are not theirs
 BWD_BLOCK_Q = 1024
 BWD_BLOCK_K = 1024
+# the forward's blocks under the block-diffusion mask, chosen by timing
+# the kernel alone on the v5e at bf16[64, 8192, 128], L 4096, Bd 4
+# (PERF.md section 6, PR 34); they need `VMEM_LIMIT_BYTES`
+BD_BLOCK_Q = 1024
+BD_BLOCK_K = 1024
 _LANE = 128  # TPU minimum tile width (lane count)
 
 
+def _div(a, b: int):
+    """a // b for a non-negative int32 scalar or vector and a static b:
+    a shift where b is a power of two, a truncating divide elsewhere
+    (`jnp.floor_divide` adds a sign correction nothing here needs)."""
+    a = jnp.asarray(a, jnp.int32)
+    if b & (b - 1) == 0:
+        return jax.lax.shift_right_logical(a, jnp.int32(b.bit_length() - 1))
+    return jax.lax.div(a, jnp.int32(b))
+
+
+def _bd_spans(i, block: int, block_diffusion):
+    """What block i (of `block` rows) of a block-diffusion sequence
+    [noised copy ; clean copy] of 2L positions holds: (has a noised
+    part, first and last Bd-block of it, has a clean part, first and
+    last Bd-block of that). Scalars; the Bd-block numbers of an absent
+    part mean nothing."""
+    length, bd = block_diffusion
+    lo = i * block
+    hi = jnp.minimum(lo + block, 2 * length) - 1
+    return (lo < length, _div(lo, bd), _div(jnp.minimum(hi, length - 1), bd),
+            hi >= length, _div(jnp.maximum(lo, length) - length, bd),
+            _div(jnp.maximum(hi, length) - length, bd))
+
+
 def _block_needed(qi, j, n_keys, *, block_q: int, block_k: int,
-                  causal: bool, window):
+                  causal: bool, window, block_diffusion=None):
     """Does (q block qi, k block j) hold any pair `_pair_mask` admits?
     The one block predicate of the forward and both backward kernels:
     a k block entirely past the row's key length, entirely above the
     causal diagonal or entirely below the band is skipped (a fully
     invalid block is a no-op anyway: p = 0 — skipping saves the dead
     MXU work; a short row in a long padded batch touches ~len/BK
-    blocks, not ~T/BK)."""
+    blocks, not ~T/BK). Under `block_diffusion` (L, Bd): noised
+    queries meet the noised keys of their own Bd-blocks and the clean
+    keys of earlier ones, clean queries the clean keys up to their own."""
     needed = j * block_k < n_keys
+    if block_diffusion is not None:
+        qn, qn0, qn1, qc, _, qc1 = _bd_spans(qi, block_q, block_diffusion)
+        kn, kn0, kn1, kc, kc0, _ = _bd_spans(j, block_k, block_diffusion)
+        return needed & ((qn & kn & (kn0 <= qn1) & (qn0 <= kn1))
+                         | (qn & kc & (kc0 < qn1)) | (qc & kc & (kc0 <= qc1)))
     if causal:
         needed = needed & (j * block_k <= (qi + 1) * block_q - 1)
     if window is not None:
@@ -75,14 +111,44 @@ def _block_needed(qi, j, n_keys, *, block_q: int, block_k: int,
 
 
 def _pair_mask(qi, j, n_keys, *, block_q: int, block_k: int, causal: bool,
-               window, k_major: bool = False):
+               window, block_diffusion=None, k_major: bool = False):
     """The one element mask: which (query, key) pairs of block (qi, j)
     attend — key inside the row's length (tail padding and right-padded
     variable-length rows are the SAME mask), on or below the diagonal,
     inside the band. [BQ, BK] bool, or [BK, BQ] with `k_major` (the
-    dk/dv kernel works on transposed scores)."""
+    dk/dv kernel works on transposed scores).
+
+    `block_diffusion` (L, Bd) over 2L positions [noised ; clean], with
+    blk(i) = (i mod L) // Bd: a noised query attends the noised keys of
+    its own Bd-block and the clean keys of earlier Bd-blocks; a clean
+    query the clean keys of its own and earlier Bd-blocks, and no
+    noised key. Worked on one column of queries and one row of keys;
+    the block-shaped part is two products, two compares and an and."""
     shape, q_axis = (((block_k, block_q), 1) if k_major
                      else ((block_q, block_k), 0))
+    if block_diffusion is not None:
+        length, bd = block_diffusion
+        q_shape = (1, block_q) if k_major else (block_q, 1)
+        k_shape = (block_k, 1) if k_major else (1, block_k)
+        qpos = qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, q_shape, q_axis)
+        kpos = j * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, k_shape, 1 - q_axis)
+        q_noised, k_noised = qpos < length, kpos < length
+        # start of the query's Bd-block, and the key, inside their halves
+        q_start = _div(jnp.where(q_noised, qpos, qpos - length), bd) * bd
+        k_in = jnp.where(k_noised, kpos, kpos - length)
+        k_in = jnp.where(kpos < n_keys, k_in, 4 * length)   # tail padding
+        # a key is admitted inside [low, high): clean keys from 0 to the
+        # Bd-block of a noised query, or through that of a clean one;
+        # noised keys inside the Bd-block of a noised query, and of no
+        # clean one. Integers all the way: Mosaic selects no booleans.
+        clean_high = jnp.where(q_noised, q_start, q_start + bd)
+        noised_low = jnp.where(q_noised, q_start, 4 * length)
+        is_noised = k_noised.astype(jnp.int32)
+        low = is_noised * noised_low
+        high = clean_high + is_noised * (q_start + bd - clean_high)
+        return (k_in >= low) & (k_in < high)
     kpos = j * block_k + jax.lax.broadcasted_iota(
         jnp.int32, shape, 1 - q_axis)
     valid = kpos < n_keys                      # tail padding / key mask
@@ -122,10 +188,54 @@ def _needed_q_blocks(j, n_keys, n_q_blocks, *, block_q: int, block_k: int,
     return first, last
 
 
+def _bd_needed_k_runs(qi, *, block_q: int, block_k: int, block_diffusion):
+    """The k blocks `_block_needed` admits for q block qi under the
+    block-diffusion mask, as two runs (first, last, first, last): the
+    noised keys of the block's own Bd-blocks, then the clean keys from
+    the start of the clean half. A run that does not exist names the
+    nearest block of the other."""
+    length, bd = block_diffusion
+    qn, qn0, qn1, qc, _, qc1 = _bd_spans(qi, block_q, block_diffusion)
+    a0, a1 = _div(qn0 * bd, block_k), _div(qn1 * bd + bd - 1, block_k)
+    # the last clean Bd-block any query of the block sees; -1: none
+    m = jnp.maximum(jnp.where(qn, qn1 - 1, -1), jnp.where(qc, qc1, -1))
+    b0 = length // block_k
+    b1 = _div(length + jnp.maximum(m, 0) * bd + bd - 1, block_k)
+    a0, a1 = jnp.where(qn, a0, b0), jnp.where(qn, a1, b0)
+    return a0, a1, jnp.where(m >= 0, b0, a1), jnp.where(m >= 0, b1, a1)
+
+
+def _bd_needed_q_runs(j, *, block_q: int, block_k: int, block_diffusion):
+    """The q blocks `_block_needed` admits for k block j under the
+    block-diffusion mask, as two runs: noised queries (of the noised
+    keys' own Bd-blocks and, for clean keys, of every later Bd-block),
+    then clean queries from the clean keys' first Bd-block to the end."""
+    length, bd = block_diffusion
+    kn, kn0, kn1, kc, kc0, _ = _bd_spans(j, block_k, block_diffusion)
+    # noised queries: [own Bd-blocks] and / or [(kc0 + 1) * bd, L)
+    later = (kc0 + 1) * bd
+    has_later = kc & (later < length)
+    lo = jnp.minimum(jnp.where(kn, kn0 * bd, length),
+                     jnp.where(has_later, later, length))
+    hi = jnp.where(has_later, length - 1, kn1 * bd + bd - 1)
+    a0, a1 = _div(lo, block_q), _div(hi, block_q)
+    b0 = _div(length + kc0 * bd, block_q)
+    b1 = (2 * length - 1) // block_q
+    some_noised = kn | has_later
+    a0, a1 = jnp.where(some_noised, a0, b0), jnp.where(some_noised, a1, b0)
+    return a0, a1, jnp.where(kc, b0, a1), jnp.where(kc, b1, a1)
+
+
+def _clamp_to_runs(i, a0, a1, b0, b1):
+    """Grid step i clamped to two runs of blocks: a skipped step names
+    the block the pipeline already holds, or will need next."""
+    return jnp.where(i < b0, jnp.clip(i, a0, a1), jnp.clip(i, b0, b1))
+
+
 
 def _attn_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
                  m_ref, l_ref, *, scale: float, causal: bool,
-                 window):
+                 window, block_diffusion=None):
     """One (batch*head, q-block, k-block) grid step. The innermost grid
     dim walks k/v blocks sequentially (TPU grids are sequential), so
     VMEM scratch (acc/m/l) carries streaming-softmax state across k
@@ -150,7 +260,8 @@ def _attn_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    masks = dict(block_q=bq, block_k=block_k, causal=causal, window=window)
+    masks = dict(block_q=bq, block_k=block_k, causal=causal, window=window,
+                 block_diffusion=block_diffusion)
 
     @pl.when(_block_needed(qi, j, n_keys, **masks))
     def _compute():
@@ -196,7 +307,7 @@ def _pad_to(x, size, axis):
 
 
 def _flash_forward(q, k, v, lens, *, causal: bool, block_q: int,
-                   block_k: int, window):
+                   block_k: int, window, block_diffusion=None):
     """q,k,v: [BH, T, D]; lens: [BH] i32 valid key counts ->
     (o [BH, T, D], lse [BH, T])."""
     bh, t, d = q.shape
@@ -212,11 +323,17 @@ def _flash_forward(q, k, v, lens, *, causal: bool, block_q: int,
 
     q_map = lambda b, i, j, lens: (b, i, 0)
     kv_map = lambda b, i, j, lens: (b, j, 0)
+    if block_diffusion is not None:
+        # about half the k blocks of a row are skipped, in two runs: a
+        # skipped step names a block of a run and fetches nothing
+        kv_map = lambda b, i, j, lens: (b, _clamp_to_runs(
+            j, *_bd_needed_k_runs(i, block_q=block_q, block_k=block_k,
+                                  block_diffusion=block_diffusion)), 0)
     vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
 
     o, lse = pl.pallas_call(
         functools.partial(_attn_kernel, scale=scale, causal=causal,
-                          window=window),
+                          window=window, block_diffusion=block_diffusion),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, tq_pad // block_q, tk_pad // block_k),
@@ -240,7 +357,9 @@ def _flash_forward(q, k, v, lens, *, causal: bool, block_q: int,
             jax.ShapeDtypeStruct((bh, tq_pad, _LANE), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            **({} if block_diffusion is None else
+               {"vmem_limit_bytes": pallas_util.VMEM_LIMIT_BYTES})),
         interpret=pallas_util.interpret(),
         name="flash_attention_fwd",
     )(lens.astype(jnp.int32), qp, kp, vp)
@@ -268,7 +387,8 @@ def _recompute(q, k, v, g, lse, delta, valid, *, scale: float,
 
 def _bwd_dkv_kernel(len_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref,
                     v_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
-                    scale: float, causal: bool, window):
+                    scale: float, causal: bool, window,
+                    block_diffusion=None):
     """One (batch*head, k-block, q-block) grid step: the innermost dim
     walks the q blocks, float32 VMEM scratch carries the k block's dk
     and dv across them. Scores are held transposed, [BK, BQ], so both
@@ -281,7 +401,8 @@ def _bwd_dkv_kernel(len_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref,
     j = pl.program_id(1)
     qi = pl.program_id(2)
     masks = dict(block_q=q_ref.shape[1], block_k=k_ref.shape[1],
-                 causal=causal, window=window)
+                 causal=causal, window=window,
+                 block_diffusion=block_diffusion)
 
     @pl.when(qi == 0)
     def _init():
@@ -308,7 +429,7 @@ def _bwd_dkv_kernel(len_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref,
 
 def _bwd_dq_kernel(len_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref,
                    v_ref, dq_ref, dq_acc, *, scale: float, causal: bool,
-                   window):
+                   window, block_diffusion=None):
     """One (batch*head, q-block, k-block) grid step, the forward's own
     order: float32 VMEM scratch carries the q block's dq across the k
     blocks. Refs as `_bwd_dkv_kernel`'s; dq [1,BQ,D], scratch [BQ,D]."""
@@ -316,7 +437,8 @@ def _bwd_dq_kernel(len_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref,
     qi = pl.program_id(1)
     j = pl.program_id(2)
     masks = dict(block_q=q_ref.shape[1], block_k=k_ref.shape[1],
-                 causal=causal, window=window)
+                 causal=causal, window=window,
+                 block_diffusion=block_diffusion)
 
     @pl.when(j == 0)
     def _init():
@@ -338,7 +460,8 @@ def _bwd_dq_kernel(len_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref,
 
 
 def _flash_backward(q, k, v, lens, o, lse, g, *, causal: bool,
-                    block_q: int, block_k: int, window):
+                    block_q: int, block_k: int, window,
+                    block_diffusion=None):
     """q, o, g: [BH, T, D]; k, v: [BH, Tkv, D]; lens: [BH] valid key
     counts; lse: [BH, T] f32 -> (dq, dk, dv) in the operands' dtypes.
 
@@ -364,7 +487,10 @@ def _flash_backward(q, k, v, lens, o, lse, g, *, causal: bool,
                 _pad_to(k, tk_pad, 1), _pad_to(v, tk_pad, 1))
     masks = dict(block_q=block_q, block_k=block_k, causal=causal,
                  window=window)
-    static = dict(scale=1.0 / (d ** 0.5), causal=causal, window=window)
+    static = dict(scale=1.0 / (d ** 0.5), causal=causal, window=window,
+                  block_diffusion=block_diffusion)
+    runs = dict(block_q=block_q, block_k=block_k,
+                block_diffusion=block_diffusion)
     vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
 
     def in_specs(q_block, k_block):
@@ -386,6 +512,8 @@ def _flash_backward(q, k, v, lens, o, lse, g, *, causal: bool,
         vmem_limit_bytes=pallas_util.VMEM_LIMIT_BYTES)
 
     def dkv_q_block(b, j, i, lens):
+        if block_diffusion is not None:
+            return _clamp_to_runs(i, *_bd_needed_q_runs(j, **runs))
         first, last = _needed_q_blocks(j, lens[b], nq, **masks)
         return jnp.clip(i, first, last)
 
@@ -411,6 +539,8 @@ def _flash_backward(q, k, v, lens, o, lse, g, *, causal: bool,
     )(*operands)
 
     def dq_k_block(b, i, j, lens):
+        if block_diffusion is not None:
+            return _clamp_to_runs(j, *_bd_needed_k_runs(i, **runs))
         first, last = _needed_k_blocks(i, lens[b], **masks)
         return jnp.clip(j, first, last)
 
@@ -431,27 +561,32 @@ def _flash_backward(q, k, v, lens, o, lse, g, *, causal: bool,
     return dq[:, :t], dk[:, :t_kv], dv[:, :t_kv]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash(q, k, v, lens_f, causal, block_q, block_k, window, bwd_blocks):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, lens_f, causal, block_q, block_k, window, bwd_blocks,
+           block_diffusion):
     o, _ = _flash_forward(q, k, v, lens_f, causal=causal, block_q=block_q,
-                          block_k=block_k, window=window)
+                          block_k=block_k, window=window,
+                          block_diffusion=block_diffusion)
     return o
 
 
 def _flash_fwd(q, k, v, lens_f, causal, block_q, block_k, window,
-               bwd_blocks):
+               bwd_blocks, block_diffusion):
     o, lse = _flash_forward(q, k, v, lens_f, causal=causal, block_q=block_q,
-                            block_k=block_k, window=window)
+                            block_k=block_k, window=window,
+                            block_diffusion=block_diffusion)
     return o, (q, k, v, lens_f, o, lse)
 
 
-def _flash_bwd(causal, block_q, block_k, window, bwd_blocks, res, g):
+def _flash_bwd(causal, block_q, block_k, window, bwd_blocks, block_diffusion,
+               res, g):
     q, k, v, lens_f, o, lse = res
     pallas_util.note_traced("flash_attention.backward", "pallas")
     with jax.named_scope("flash_attention_bwd"):
         dq, dk, dv = _flash_backward(
             q, k, v, lens_f, o, lse, g, causal=causal,
-            block_q=bwd_blocks[0], block_k=bwd_blocks[1], window=window)
+            block_q=bwd_blocks[0], block_k=bwd_blocks[1], window=window,
+            block_diffusion=block_diffusion)
     # lens is carried as f32 so the custom_vjp can hand back an ordinary
     # zero cotangent (int operands would need float0 plumbing)
     return dq, dk, dv, jnp.zeros_like(lens_f)
@@ -461,11 +596,12 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q, k, v, *, causal: bool = False,
-                    block_q: int = DEFAULT_BLOCK_Q,
-                    block_k: int = DEFAULT_BLOCK_K,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     key_lens=None, window=None,
                     bwd_block_q: int = BWD_BLOCK_Q,
-                    bwd_block_k: int = BWD_BLOCK_K):
+                    bwd_block_k: int = BWD_BLOCK_K,
+                    block_diffusion=None):
     """Fused scaled-dot-product attention.
 
     q: [B, Tq, H, D]; k, v: [B, Tkv, H, D]. Returns [B, Tq, H, D].
@@ -483,13 +619,36 @@ def flash_attention(q, k, v, *, causal: bool = False,
     training costs O(T*window) instead of O(T^2); the backward kernels
     do not fetch a skipped step's blocks either.
 
-    block_q, block_k: the forward kernel's blocks. bwd_block_q,
+    block_q, block_k: the forward kernel's blocks (default 256 x 512;
+    under `block_diffusion` `BD_BLOCK_Q` x `BD_BLOCK_K`). bwd_block_q,
     bwd_block_k: the two backward kernels' (dk/dv and dq), their own
     because what suits them differs; a sequence shorter than a block
     takes one block.
+
+    block_diffusion: optional (L, Bd) — the block-diffusion training
+    mask (BD3-LM's vectorised form) over T = 2L positions, the noised
+    copy of a sequence and then the clean copy, cut into Bd-token
+    blocks: see `_pair_mask`. Not causal, no window, no key_lens. A
+    kernel block with no admitted pair (about half of them, in two
+    runs a row) is skipped and fetches nothing, in all three kernels.
     """
     if q.ndim != 4:
         raise ValueError(f"expected [B, T, H, D], got {q.shape}")
+    block_q = block_q or (DEFAULT_BLOCK_Q if block_diffusion is None
+                          else BD_BLOCK_Q)
+    block_k = block_k or (DEFAULT_BLOCK_K if block_diffusion is None
+                          else BD_BLOCK_K)
+    if block_diffusion is not None:
+        length, bd = block_diffusion = tuple(int(x) for x in block_diffusion)
+        if causal or window is not None or key_lens is not None:
+            raise ValueError("block_diffusion is a mask of its own: not "
+                             "with causal, window or key_lens")
+        if bd < 1 or length % bd or q.shape[1] != 2 * length \
+                or k.shape[1] != 2 * length:
+            raise ValueError(
+                f"block_diffusion (L={length}, Bd={bd}) needs Bd | L and "
+                f"2L positions, got Tq {q.shape[1]}, Tkv {k.shape[1]}")
+        pallas_util.note_traced("flash_attention.mask", "block_diffusion")
     if window is not None:
         if not causal:
             raise ValueError("window requires causal=True")
@@ -520,5 +679,6 @@ def flash_attention(q, k, v, *, causal: bool = False,
         return x.transpose(0, 2, 1, 3).reshape(b * h, tt, d)
 
     o = _flash(flat(q, t), flat(k, t_kv), flat(v, t_kv), lens, causal,
-               block_q, block_k, window, (bwd_block_q, bwd_block_k))
+               block_q, block_k, window, (bwd_block_q, bwd_block_k),
+               block_diffusion)
     return o.reshape(b, h, t, d).transpose(0, 2, 1, 3)

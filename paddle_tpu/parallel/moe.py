@@ -18,6 +18,18 @@ it is the MXU-native formulation:
 
 Parity of intent: the reference scaled sparse models by sharding
 embedding rows across pservers; this shards expert FFNs across chips.
+
+Which path drops and which does not. `moe_ffn`, `expert_choice_ffn` and
+`make_expert_parallel_ffn` are **capacity-factor** paths: every expert
+gets a fixed [C, D] buffer, and an assignment past its expert's
+capacity is **dropped** (or, under expert choice, a token may be picked
+by no expert). `dropless_ffn` is the **dropless** path: softmax over all
+experts, top-k renormalised, every (position, choice) row computed
+whatever the imbalance, as grouped matrix products over rows ordered by
+expert (`ops.moe_grouped_matmul`); it builds no [T, E, C] or [E, C, D]
+buffer, and it may hold a share of the experts (an expert-parallel
+chip's): it then routes over all of them and computes the terms of its
+own.
 """
 
 from __future__ import annotations
@@ -30,8 +42,11 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
+from paddle_tpu.core.dtypes import default_policy
 from paddle_tpu.core.mesh import MODEL_AXIS
 from paddle_tpu.nn import initializers
+from paddle_tpu.ops import pallas_util
+from paddle_tpu.ops.moe_grouped_matmul import grouped_matmul
 
 
 class MoEOutput(NamedTuple):
@@ -438,3 +453,153 @@ def make_expert_parallel_ffn(mesh: Mesh, *, axis: str = MODEL_AXIS,
         return fn(params, x, rng)
 
     return apply
+
+
+# -- the dropless path ------------------------------------------------------
+
+
+class DroplessStats(NamedTuple):
+    """Counts of one dropless layer's step, int32 scalars."""
+    rows_held: jnp.ndarray          # (position, choice) rows of held experts
+    rows_max_expert: jnp.ndarray    # rows of the fullest held expert
+
+
+class DroplessOutput(NamedTuple):
+    y: jnp.ndarray                  # [T, D] what the held experts add
+    stats: DroplessStats
+
+
+def count_dropless_stats(stats: DroplessStats, positions: int,
+                         timeline=None) -> None:
+    """Host side, where a training loop reads its loss: add a step's
+    counts (the loss's auxiliary output, stacked over the layers) to the
+    timeline's counters `moe.rows_held`, `moe.rows_max_expert` and
+    `moe.positions` (positions routed, a layer each)."""
+    from paddle_tpu.obs.trace import default_timeline
+
+    timeline = timeline if timeline is not None else default_timeline()
+    rows_held = jnp.atleast_1d(stats.rows_held)
+    timeline.count("moe.rows_held", int(jnp.sum(rows_held)))
+    timeline.count("moe.rows_max_expert", int(jnp.sum(stats.rows_max_expert)))
+    timeline.count("moe.positions", positions * rows_held.shape[0])
+
+
+def init_dropless_params(rng, n_experts: int, n_held: int, d_model: int,
+                         d_ff: int, dtype=jnp.float32):
+    """Router over all `n_experts` + the `n_held` gated-SiLU experts this
+    layer holds, stacked [n_held, ...], no bias anywhere."""
+    k_r, k_g, k_u, k_d = jax.random.split(rng, 4)
+    smart = initializers.smart_uniform()
+
+    def stack(key, shape):
+        return jnp.stack([smart(k, shape) for k in
+                          jax.random.split(key, n_held)]).astype(dtype)
+
+    return {
+        "router": {"kernel": smart(k_r, (d_model, n_experts)).astype(dtype)},
+        "w_gate": stack(k_g, (d_model, d_ff)),
+        "w_up": stack(k_u, (d_model, d_ff)),
+        "w_down": stack(k_d, (d_ff, d_model)),
+    }
+
+
+@jax.custom_vjp
+def _take_rows(x, row_of_slot, slot_of_pair, held):
+    """x [T, D] -> [R, D], slot s gets x[row_of_slot[s]]. Its gradient
+    is a gather too: position t collects the slots of its held choices
+    (`slot_of_pair` [T, k]); slots past the held rows carry nothing."""
+    return jnp.take(x, row_of_slot, axis=0)
+
+
+def _take_rows_fwd(x, row_of_slot, slot_of_pair, held):
+    return jnp.take(x, row_of_slot, axis=0), (slot_of_pair, held)
+
+
+def _take_rows_bwd(res, g):
+    slot_of_pair, held = res
+    picked = jnp.take(g, slot_of_pair, axis=0)               # [T, k, D]
+    dx = jnp.sum(jnp.where(held[..., None], picked.astype(jnp.float32), 0.0),
+                 axis=1)
+    return dx.astype(g.dtype), None, None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+@jax.custom_vjp
+def _combine_rows(out, weight, slot_of_pair, held, pair_of_slot):
+    """y[t] = sum over t's held choices c of weight[t, c] * out[slot]:
+    a gather, never a scatter, in float32. Rows of `out` that no expert
+    owns are not read."""
+    picked = jnp.take(out, slot_of_pair, axis=0).astype(jnp.float32)
+    return jnp.sum(jnp.where(held[..., None],
+                             weight[..., None] * picked, 0.0), axis=1)
+
+
+def _combine_rows_fwd(out, weight, slot_of_pair, held, pair_of_slot):
+    return (_combine_rows(out, weight, slot_of_pair, held, pair_of_slot),
+            (out, weight, slot_of_pair, held, pair_of_slot))
+
+
+def _combine_rows_bwd(res, g):
+    out, weight, slot_of_pair, held, pair_of_slot = res
+    k = weight.shape[1]
+    picked = jnp.take(out, slot_of_pair, axis=0).astype(jnp.float32)
+    d_weight = jnp.where(held, jnp.sum(picked * g[:, None, :], axis=-1), 0.0)
+    # slot s holds pair (t, c) = divmod(pair_of_slot[s], k): its row gets
+    # weight[t, c] * g[t]; a slot of an expert not held gets zeros
+    w_slot = jnp.where(held, weight, 0.0).reshape(-1)[pair_of_slot]
+    d_out = w_slot[:, None] * jnp.take(g, pair_of_slot // k, axis=0)
+    return d_out.astype(out.dtype), d_weight, None, None, None
+
+
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+
+def dropless_ffn(params, x, *, k: int, first_held: int = 0,
+                 token_mask=None) -> DroplessOutput:
+    """Dropless token-choice MoE over the experts this layer holds.
+    x: [T, D]. The router is a softmax over all E = router width in
+    float32, the k largest, renormalised over the k chosen (held or
+    not). Of the T*k (position, choice) rows, those whose expert is one
+    of the `params["w_gate"].shape[0]` held, from expert `first_held`,
+    are ordered by expert and computed as grouped products: gate and up,
+    silu(gate) * up, down; each position then adds its rows by its
+    weights. What the experts not held would add is left out: that is
+    another chip's part. No row is dropped whatever the imbalance: the
+    row buffer is sized for every choice (T*k rows), and the kernels
+    visit only the tiles the held rows fill. token_mask [T] bool:
+    positions that route nowhere."""
+    t, d = x.shape
+    n_held = params["w_gate"].shape[0]
+    cd = default_policy().compute_dtype
+    pallas_util.note_traced("moe.expert_matmul", "pallas_grouped")
+    with jax.named_scope("moe/router"):
+        logits = jnp.matmul(x.astype(jnp.float32),
+                            params["router"]["kernel"].astype(jnp.float32),
+                            precision=lax.Precision.HIGHEST)
+        top_p, top_e = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        weight = top_p / jnp.sum(top_p, axis=-1, keepdims=True)   # [T, k]
+    with jax.named_scope("moe/dispatch"):
+        local = top_e.astype(jnp.int32) - first_held
+        held = (local >= 0) & (local < n_held)
+        if token_mask is not None:
+            held = held & token_mask[:, None]
+        # rows of held experts first, by expert; the rest behind them
+        key = jnp.where(held, local, n_held).reshape(-1)          # [T*k]
+        pair_of_slot = jnp.argsort(key, stable=True).astype(jnp.int32)
+        slot_of_pair = jnp.zeros((t * k,), jnp.int32).at[pair_of_slot].set(
+            jnp.arange(t * k, dtype=jnp.int32), unique_indices=True
+        ).reshape(t, k)
+        sizes = jnp.zeros((n_held + 1,), jnp.int32).at[key].add(1)[:n_held]
+        rows = _take_rows(x.astype(cd), pair_of_slot // k, slot_of_pair, held)
+    with jax.named_scope("moe/experts"):
+        gate = grouped_matmul(rows, params["w_gate"].astype(cd), sizes)
+        up = grouped_matmul(rows, params["w_up"].astype(cd), sizes)
+        hidden = (jax.nn.silu(gate.astype(jnp.float32))
+                  * up.astype(jnp.float32)).astype(cd)
+        out = grouped_matmul(hidden, params["w_down"].astype(cd), sizes)
+    with jax.named_scope("moe/combine"):
+        y = _combine_rows(out, weight, slot_of_pair, held, pair_of_slot)
+    stats = DroplessStats(jnp.sum(sizes, dtype=jnp.int32), jnp.max(sizes))
+    return DroplessOutput(y.astype(x.dtype), stats)

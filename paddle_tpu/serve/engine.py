@@ -315,6 +315,7 @@ class DecodeEngine:
             raise ValueError(
                 f"ragged_impl must be None|jnp|pallas, got "
                 f"{ragged_impl!r}")
+        T.require_decodable(cfg)
         if cfg.kv_cache_dtype not in ("compute", "int8"):
             raise ValueError(
                 f"kv_cache_dtype must be compute|int8, got "

@@ -1,0 +1,185 @@
+"""The dropless expert layer (`parallel.moe.dropless_ffn`) against
+"every expert on every position, weighted by that position's w_e or
+zero": values and gradients, the imbalanced corners, the counts, and
+the share test (the eight shares of 16 experts add up to the uncut
+128-expert layer). float32, grouped kernels in interpret mode."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu.ops import moe_grouped_matmul as G
+from paddle_tpu.parallel import moe
+
+
+def every_expert(params, x, k, first_held=0):
+    """No sort, no grouping: each held expert applied to all of x."""
+    probs = jax.nn.softmax(x @ params["router"]["kernel"], axis=-1)
+    top_p, top_e = jax.lax.top_k(probs, k)
+    w = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    y = jnp.zeros_like(x)
+    for e in range(params["w_gate"].shape[0]):
+        w_e = jnp.sum(jnp.where(top_e == first_held + e, w, 0.0), axis=-1)
+        h = jax.nn.silu(x @ params["w_gate"][e]) * (x @ params["w_up"][e])
+        y = y + w_e[:, None] * (h @ params["w_down"][e])
+    return y
+
+
+def rows_of_held(params, x, k, first_held=0):
+    """[n_held] (position, choice) rows of each held expert."""
+    _, top_e = jax.lax.top_k(x @ params["router"]["kernel"], k)
+    n = params["w_gate"].shape[0]
+    return np.bincount(np.asarray(top_e).ravel() - first_held + n * 1000,
+                       minlength=n * 1001)[n * 1000:n * 1001]
+
+
+def _layer(seed, n_experts, n_held, d=32, f=16, t=32):
+    params = moe.init_dropless_params(jax.random.key(seed), n_experts,
+                                      n_held, d, f)
+    # a router with some spread, so the top-k sets are not near ties
+    params["router"]["kernel"] = 3.0 * params["router"]["kernel"]
+    x = jax.random.normal(jax.random.key(seed + 1), (t, d), jnp.float32)
+    return params, x
+
+
+@pytest.mark.parametrize("n_experts,n_held,first,k", [
+    (8, 8, 0, 2),           # top-2 of 8, all held
+    (128, 16, 0, 8),        # the cell's share: top-8 of 128, 16 held
+    (128, 16, 48, 8),       # another chip's share
+])
+def test_values_and_gradients(n_experts, n_held, first, k):
+    params, x = _layer(0, n_experts, n_held)
+    out = moe.dropless_ffn(params, x, k=k, first_held=first)
+    np.testing.assert_allclose(np.asarray(out.y), np.asarray(
+        every_expert(params, x, k, first)), rtol=1e-4, atol=1e-5)
+    rows = rows_of_held(params, x, k, first)
+    assert int(out.stats.rows_held) == rows.sum()       # no row dropped
+    assert int(out.stats.rows_max_expert) == rows.max()
+
+    w = jax.random.normal(jax.random.key(9), x.shape, jnp.float32)
+    got = jax.grad(lambda p, x: jnp.sum(moe.dropless_ffn(
+        p, x, k=k, first_held=first).y * w), argnums=(0, 1))(params, x)
+    want = jax.grad(lambda p, x: jnp.sum(every_expert(p, x, k, first) * w),
+                    argnums=(0, 1))(params, x)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5,
+            err_msg=jax.tree_util.keystr(path))
+
+
+def _biased(params, experts, by=50.0):
+    kernel = params["router"]["kernel"]
+    # every position's logit for `experts` far above the rest
+    bias = jnp.zeros((kernel.shape[1],)).at[jnp.asarray(experts)].set(by)
+    return {**params, "router": {"kernel": jnp.concatenate(
+        [kernel, bias[None]], axis=0)}}
+
+
+def test_every_position_on_one_held_expert():
+    """All T rows land on expert 3: nothing is dropped, whatever a
+    capacity factor would have allowed."""
+    params, x = _layer(1, 8, 8)
+    x1 = jnp.concatenate([x, jnp.ones((x.shape[0], 1))], axis=1)
+    pad = lambda w, axis: jnp.concatenate(
+        [w, jnp.zeros_like(jnp.take(w, jnp.arange(1, dtype=jnp.int32), axis=axis))], axis=axis)
+    p = _biased({**params, "w_gate": pad(params["w_gate"], 1),
+                 "w_up": pad(params["w_up"], 1),
+                 "w_down": pad(params["w_down"], 2)}, [3])
+    out = moe.dropless_ffn(p, x1, k=1)
+    assert int(out.stats.rows_held) == x.shape[0]
+    assert int(out.stats.rows_max_expert) == x.shape[0]
+    np.testing.assert_allclose(np.asarray(out.y), np.asarray(
+        every_expert(p, x1, 1)), rtol=1e-4, atol=1e-5)
+    assert float(jnp.min(jnp.sum(jnp.abs(out.y), axis=1))) > 0.0
+
+
+def test_no_position_on_a_held_expert():
+    """The chosen experts are all another chip's: zero output, finite
+    (zero) gradients, though the kernels wrote no row at all."""
+    params, x = _layer(2, 128, 16)
+    x1 = jnp.concatenate([x, jnp.ones((x.shape[0], 1))], axis=1)
+    pad = lambda w, axis: jnp.concatenate(
+        [w, jnp.ones_like(jnp.take(w, jnp.arange(1, dtype=jnp.int32), axis=axis))], axis=axis)
+    p = _biased({**params, "w_gate": pad(params["w_gate"], 1),
+                 "w_up": pad(params["w_up"], 1),
+                 "w_down": pad(params["w_down"], 2)}, list(range(40, 48)))
+    out = moe.dropless_ffn(p, x1, k=8, first_held=16)
+    assert int(out.stats.rows_held) == 0
+    np.testing.assert_array_equal(np.asarray(out.y), 0.0)
+    grads = jax.grad(lambda p, x: jnp.sum(moe.dropless_ffn(
+        p, x, k=8, first_held=16).y ** 2), argnums=(0, 1))(p, x1)
+    for g in jax.tree.leaves(grads):
+        assert np.isfinite(np.asarray(g)).all()
+        np.testing.assert_array_equal(np.asarray(g), 0.0)
+
+
+def test_masked_positions_route_nowhere():
+    params, x = _layer(3, 8, 8)
+    mask = jnp.arange(x.shape[0], dtype=jnp.int32) % 3 != 0
+    out = moe.dropless_ffn(params, x, k=2, token_mask=mask)
+    want = jnp.where(mask[:, None], every_expert(params, x, 2), 0.0)
+    np.testing.assert_allclose(np.asarray(out.y), np.asarray(want),
+                               rtol=1e-4, atol=1e-5)
+    assert int(out.stats.rows_held) == 2 * int(mask.sum())
+
+
+def test_the_eight_shares_add_up_to_the_whole_layer():
+    """The share test: a chip of an 8-way expert-parallel layer routes
+    over all 128 experts and computes its 16; the eight partial results
+    add up to the uncut layer."""
+    whole, x = _layer(4, 128, 128)
+    uncut = every_expert(whole, x, 8)
+    np.testing.assert_allclose(
+        np.asarray(moe.dropless_ffn(whole, x, k=8).y), np.asarray(uncut),
+        rtol=1e-4, atol=1e-5)
+    total, rows = jnp.zeros_like(x), 0
+    for chip in range(8):
+        share = {"router": whole["router"],
+                 **{name: whole[name][16 * chip:16 * chip + 16]
+                    for name in ("w_gate", "w_up", "w_down")}}
+        out = moe.dropless_ffn(share, x, k=8, first_held=16 * chip)
+        total, rows = total + out.y, rows + int(out.stats.rows_held)
+    np.testing.assert_allclose(np.asarray(total), np.asarray(uncut),
+                               rtol=1e-4, atol=1e-5)
+    assert rows == 8 * x.shape[0]       # every choice computed once
+
+
+@pytest.mark.parametrize("sizes,tm", [
+    ([10, 0, 23, 5], 8), ([0, 0, 0, 0], 8), ([16, 16, 16, 16], 16),
+    ([1, 62, 0, 1], 16), ([0, 3, 0, 0], 32),
+])
+def test_grouped_kernels_against_plain_products(np_rng, sizes, tm):
+    m, k, n, g = 64, 16, 24, len(sizes)
+    lhs = jnp.asarray(np_rng.randn(m, k), jnp.float32)
+    rhs = jnp.asarray(np_rng.randn(g, k, n), jnp.float32)
+    dout = jnp.asarray(np_rng.randn(m, n), jnp.float32)
+    tiling = (tm, 8, 8)
+    out = G.moe_grouped_matmul(lhs, rhs, jnp.asarray(sizes, jnp.int32),
+                               tiling=tiling)
+    back = G.moe_grouped_matmul(dout, rhs, jnp.asarray(sizes, jnp.int32),
+                                tiling=tiling, transpose_rhs=True)
+    dw = G.moe_grouped_matmul_dw(lhs, dout, jnp.asarray(sizes, jnp.int32),
+                                 tiling=tiling)
+    off = np.concatenate([[0], np.cumsum(sizes)])
+    for i in range(g):
+        rows = slice(off[i], off[i + 1])
+        np.testing.assert_allclose(out[rows], lhs[rows] @ rhs[i],
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(back[rows], dout[rows] @ rhs[i].T,
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(dw[i], lhs[rows].T @ dout[rows],
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_counts_reach_the_timeline():
+    from paddle_tpu.obs.trace import Timeline
+
+    tl = Timeline()
+    stats = moe.DroplessStats(jnp.asarray([100, 120], jnp.int32),
+                              jnp.asarray([9, 11], jnp.int32))
+    moe.count_dropless_stats(stats, positions=64, timeline=tl)
+    moe.count_dropless_stats(stats, positions=64, timeline=tl)
+    assert tl.counters() == {"moe.rows_held": 440, "moe.rows_max_expert": 40,
+                             "moe.positions": 256}

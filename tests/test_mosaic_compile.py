@@ -68,3 +68,49 @@ def test_flash_forward_and_backward_compile_for_v5e(one_chip, bh, t, d,
     for name in ("jvp_flash_attention_fwd_", "flash_attention_bwd_dkv",
                  "flash_attention_bwd_dq"):
         assert f"%{name}" in text, name
+
+
+@pytest.mark.parametrize("length,bd,bh", [
+    # sdar_30b_a3b_ep8.train_bd4_seq4k: 2 sequences x 32 heads, 2 x 4096
+    # positions each (noised copy, then clean copy), Bd 4
+    (4096, 4, 64),
+    (1536, 3, 8),       # Bd no power of two, L no multiple of a block
+])
+def test_block_diffusion_flash_compiles_for_v5e(one_chip, length, bd, bh):
+    x = jax.ShapeDtypeStruct((1, 2 * length, bh, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        o = FA.flash_attention(q, k, v, block_diffusion=(length, bd))
+        return jnp.sum(o.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for name in ("jvp_flash_attention_fwd_", "flash_attention_bwd_dkv",
+                 "flash_attention_bwd_dq"):
+        assert f"%{name}" in text, name
+
+
+def test_grouped_expert_products_compile_for_v5e(one_chip):
+    """The dropless layer's three grouped products and their gradients
+    at the cell's shapes: 16 experts held, a buffer of 16,384 positions
+    x 8 choices rows, 2048 x 768."""
+    from paddle_tpu.ops import moe_grouped_matmul as G
+
+    rows, d, f, held = 16384 * 8, 2048, 768, 16
+    spec = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+
+    def loss(x, w_gate, w_up, w_down, sizes):
+        h = (G.grouped_matmul(x, w_gate, sizes).astype(jnp.float32)
+             * G.grouped_matmul(x, w_up, sizes).astype(jnp.float32))
+        out = G.grouped_matmul(h.astype(x.dtype), w_down, sizes)
+        return jnp.sum(jnp.square(out.astype(jnp.float32)))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        spec((rows, d)), spec((held, d, f)), spec((held, d, f)),
+        spec((held, f, d)), spec((held,), jnp.int32)).compile().as_text()
+    # 3 forward products, 3 input gradients, 3 weight gradients
+    assert text.count('custom_call_target="tpu_custom_call"') == 9
+    assert "moe_grouped_matmul_dw" in text

@@ -135,6 +135,53 @@ def test_model_hands_flash_the_policys_dtype(bf16):
             else [wide] * 3), name
 
 
+def test_block_diffusion_model_lowers_with_its_kernels():
+    """The second LM configuration's real path: grad of
+    `T.block_diffusion_loss` on an RMSNorm, bias-free, QK-normed block
+    with heads of their own size and a dropless MoE that holds half of
+    its experts, under the bf16 policy. A layer launches the flash
+    forward (again under remat) and its two backward kernels on bf16
+    operands over 2L positions, and the grouped products: three
+    forward, three again under remat, three input gradients, three
+    weight gradients."""
+    dtypes.set_default_policy(dtypes.bf16_compute_policy())
+    jax.clear_caches()
+    pallas_util._traced.clear()
+    cfg = T.TransformerConfig(
+        vocab=128, dim=256, n_heads=4, n_kv_heads=2, head_size=128,
+        n_layers=2, norm="rms", bias=False, qk_norm=True,
+        moe_router="dropless", moe_experts=8, moe_every=1, moe_k=2,
+        moe_dim=128, moe_held=4, attn_impl="auto", remat=True,
+        fused_ce_chunk=128)
+    params = jax.eval_shape(lambda: T.init_params(jax.random.key(0), cfg))
+    length = 256
+
+    def loss(p, toks, masked, prob):
+        return T.block_diffusion_loss(p, cfg, toks, masked, prob,
+                                      block_length=4)[0]
+
+    text = _lowered_text(
+        jax.grad(loss), params, _sds((2, length), jnp.int32),
+        _sds((2, length), jnp.bool_), _sds((2, length), jnp.float32))
+    calls = _kernels(text)
+    names = [name for name, _ in calls]
+    assert sorted(names) == sorted(
+        (["flash_attention_fwd"] * 2 + ["flash_attention_bwd_dkv",
+                                        "flash_attention_bwd_dq"]
+         + ["moe_grouped_matmul"] * 9 + ["moe_grouped_matmul_dw"] * 3) * 2)
+    wide = f"tensor<8x{2 * length}x128xbf16>"       # B * H, 2L, head size
+    for name, operands in calls:
+        if name.startswith("flash"):
+            assert operands.count(wide) == (4 if "bwd" in name else 3), name
+        else:       # the row buffer holds every choice: 2 * 2L * k rows
+            assert any(op.startswith(f"tensor<{2 * 2 * length * 2}x")
+                       and op.endswith("xbf16>") for op in operands), name
+    traced = pallas_util.traced()
+    assert traced["flash_attention.mask=block_diffusion"] > 0
+    assert traced["transformer.ffn=moe_dropless"] > 0
+    assert traced["moe.expert_matmul=pallas_grouped"] > 0
+
+
 @pytest.mark.parametrize("name,run,init,hidden,t", [
     ("gru", rnn.gru, rnn.init_gru_params, 512, 30),          # seq2seq
     ("lstm", rnn.lstm, rnn.init_lstm_params, 256, 100),      # classifier

@@ -589,8 +589,9 @@ def test_every_pallas_call_has_a_name(kernel):
 
 
 def test_no_pallas_call_in_ops_is_unnamed():
-    """Eleven `pallas_call`s in `ops/` (flash attention's two backward
-    kernels since PR 32), eleven `name=`: a twelfth brings its own."""
+    """Thirteen `pallas_call`s in `ops/` (flash attention's two backward
+    kernels since PR 32, the two grouped expert products since PR 34),
+    thirteen `name=`: a fourteenth brings its own."""
     import pathlib
     import re
 
@@ -601,4 +602,4 @@ def test_no_pallas_call_in_ops_is_unnamed():
         src = path.read_text()
         calls += len(re.findall(r"\bpl\.pallas_call\(", src))
         names += len(re.findall(r"^\s+name=\"\w+\",$", src, re.M))
-    assert calls == names == 11
+    assert calls == names == 13
