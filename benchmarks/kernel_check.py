@@ -37,7 +37,7 @@ from paddle_tpu.core import dtypes
 from paddle_tpu.core.devices import require_chip
 from paddle_tpu.models import transformer as T
 from paddle_tpu.ops import rnn
-from paddle_tpu.ops.flash_attention import flash_attention
+from paddle_tpu.ops.flash_attention import _forward_blocks, flash_attention
 
 #: seconds one check (compile + two runs + reference) may take
 BOUND_S = 300
@@ -73,7 +73,12 @@ def _report(name, dtype, errs, first_s, again_s, **shape) -> bool:
     return ok
 
 
-def check_flash(dtype, *, b, t, h, d, window=None, lens=None) -> bool:
+def check_flash(dtype, *, b, t, h, d, window=None, lens=None,
+                block_diffusion=None) -> bool:
+    """Forward (on the blocks `_forward_blocks` takes from the shape)
+    and both backward kernels against dense attention: causal, or under
+    the block-diffusion mask (L, Bd) over t = 2L positions."""
+    causal = block_diffusion is None
     ks = jax.random.split(jax.random.key(0), 4)
     q, k, v, w = (jax.random.normal(kk, (b, t, h, d), jnp.float32)
                   .astype(dtype) for kk in ks)
@@ -82,12 +87,14 @@ def check_flash(dtype, *, b, t, h, d, window=None, lens=None) -> bool:
         jnp.arange(t, dtype=jnp.int32)[None, :] < key_lens[:, None])
 
     def flash_loss(q, k, v):
-        o = flash_attention(q, k, v, causal=True, window=window,
-                            key_lens=key_lens)
+        o = flash_attention(q, k, v, causal=causal, window=window,
+                            key_lens=key_lens,
+                            block_diffusion=block_diffusion)
         return jnp.sum(o.astype(jnp.float32) * w.astype(jnp.float32)), o
 
     def dense_loss(q, k, v):
-        o = T._dense_attention(q, k, v, True, mask, window)
+        o = T._dense_attention(q, k, v, causal, mask, window,
+                               block_diffusion=block_diffusion)
         return jnp.sum(o.astype(jnp.float32) * w.astype(jnp.float32)), o
 
     f = jax.jit(jax.value_and_grad(flash_loss, argnums=(0, 1, 2),
@@ -102,7 +109,9 @@ def check_flash(dtype, *, b, t, h, d, window=None, lens=None) -> bool:
                  for n, g, gr in zip(("dq", "dk", "dv"), grads, g_ref)})
     return _report("flash_attention", dtype, errs, first_s, again_s,
                    b=b, t=t, heads=h, head_dim=d, window=window,
-                   key_lens=lens is not None)
+                   key_lens=lens is not None,
+                   block_diffusion=block_diffusion,
+                   fwd_blocks=list(_forward_blocks(t, t, d, dtype)))
 
 
 def check_rnn(name, run_fn, init_fn, *, hidden, b, t, policy) -> bool:
@@ -149,6 +158,19 @@ def main() -> int:
         # 128, window inert; four backward blocks of 1024 on each axis
         functools.partial(check_flash, jnp.bfloat16, b=1, t=4095, h=12,
                           d=128, window=4096),
+        # the two LM cells' forward at the chooser's 1024 x 1024 blocks,
+        # all three kinds of block in a row (6 / 4 / 6 and 12 / 12 / 40):
+        # the cells' lengths, head size and dtype on fewer heads, for the
+        # dense reference's [heads, t, t] float32 scores
+        functools.partial(check_flash, jnp.bfloat16, b=1, t=4096, h=6,
+                          d=128),
+        functools.partial(check_flash, jnp.bfloat16, b=1, t=8192, h=2,
+                          d=128, block_diffusion=(4096, 4)),
+        # lengths 1024 would pad further than a 256 x 512 grid: odd blocks
+        functools.partial(check_flash, jnp.bfloat16, b=2, t=1280, h=8,
+                          d=128),
+        functools.partial(check_flash, jnp.bfloat16, b=3, t=1536, h=4,
+                          d=128, lens=[1536, 1024, 1100]),
     ]
     policies = (dtypes.bf16_compute_policy(), dtypes.Policy())
     for policy in policies:

@@ -17,6 +17,16 @@ framework makes fused O(T) -memory attention a first-class op:
     array in HBM, operands in the dtype they arrive in. All three
     kernels take the block predicate and the element mask from one
     helper each (`_block_needed`, `_pair_mask`);
+  * the forward does per block only the VPU work the block needs, on
+    blocks sized from the call's shape: `_block_interior`, the twin of
+    `_block_needed`, names the blocks whose every pair attends, and
+    those run the streaming softmax with no mask at all; the blocks the
+    mask cuts (the diagonal, the band's edge, a row's key length, the
+    two diagonals of the block-diffusion square) run it masked; the
+    rest are skipped and fetch nothing. `_forward_blocks` picks the
+    blocks: 1024 x 1024 where the sequence carries them, smaller where
+    it does not, never padding a sequence further than a 256 x 512
+    grid would;
   * composes with the mesh: wrap in shard_map and the seq axis via
     parallel.ring_attention for context parallelism, or shard heads.
 
@@ -35,6 +45,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -42,25 +53,82 @@ from paddle_tpu.ops import pallas_util
 
 NEG_INF = -1e30
 
-DEFAULT_BLOCK_Q = 256
-DEFAULT_BLOCK_K = 512
 # the backward kernels' own blocks (q rows x k rows of one grid step),
 # chosen by timing the pair alone on the v5e at bf16[48, 4096, 128],
 # causal (PERF.md section 6, PR 32); the forward's are not theirs
 BWD_BLOCK_Q = 1024
 BWD_BLOCK_K = 1024
-# the forward's blocks under the block-diffusion mask, chosen by timing
-# the kernel alone on the v5e at bf16[64, 8192, 128], L 4096, Bd 4
-# (PERF.md section 6, PR 34); they need `VMEM_LIMIT_BYTES`
-BD_BLOCK_Q = 1024
-BD_BLOCK_K = 1024
 _LANE = 128  # TPU minimum tile width (lane count)
+# the forward's blocks come from the call's shape (`_forward_blocks`):
+# the largest it takes on an axis, timed alone on the v5e under both
+# masks (PERF.md section 6, PRs 30, 34 and 35), and the grid no
+# sequence is padded past (the blocks of every PR before 35)
+_FWD_BLOCK_MAX = 1024
+_FWD_GRID_Q = 256
+_FWD_GRID_K = 512
+
+
+def _fit_block(t: int, grid: int, largest: int) -> int:
+    """The forward's block along an axis of t positions: the largest
+    multiple of a lane tile, up to `largest`, whose blocks cover t with
+    no more padding than blocks of `grid` rows would; a sequence no
+    longer than `grid` is one block, itself."""
+    grid = min(grid, largest)
+    if t <= grid:
+        return max(t, 1)
+    limit = pl.cdiv(t, grid) * grid
+    return next(b for b in range(largest, grid - 1, -_LANE)
+                if pl.cdiv(t, b) * b <= limit)
+
+
+def _forward_vmem_bytes(block_q: int, block_k: int, head_dim: int,
+                        itemsize: int) -> int:
+    """What one grid step of the forward keeps in VMEM, from its
+    shapes: q, k, v and o blocks with the pipeline's second buffers,
+    the float32 accumulator, m, l and lse, and six score-shaped float32
+    or int32 temporaries (s, p, the rounded p, the mask's integers)."""
+    return (2 * itemsize * head_dim * (2 * block_q + 2 * block_k)
+            + 4 * block_q * (head_dim + 4 * _LANE)
+            + 6 * 4 * block_q * block_k)
+
+
+def _forward_blocks(t: int, t_kv: int, head_dim: int, dtype):
+    """(block_q, block_k) of the forward kernel for a call's shape,
+    whatever its mask: `_FWD_BLOCK_MAX` on each axis where the sequence
+    carries it (alone on the v5e the causal forward at bf16[48, 4096,
+    128] takes 3.2 ms at 1024 x 1024 for 6.2 at 256 x 512, the
+    block-diffusion one at bf16[64, 8192, 128] 10.1 for 19.6: the
+    float32 softmax on the VPU binds the kernel, and a large block
+    spends less of it on `m`, `l` and the accumulator's rescaling),
+    smaller where padding to it would compute more rows than a 256 x
+    512 grid does (T 1280 stays 1280: 640 x 768), and halved while a
+    step's VMEM (`_forward_vmem_bytes`) would pass `VMEM_BUDGET_BYTES`."""
+    itemsize = jnp.dtype(dtype).itemsize
+    largest = _FWD_BLOCK_MAX
+    while True:
+        blocks = (_fit_block(t, _FWD_GRID_Q, largest),
+                  _fit_block(t_kv, _FWD_GRID_K, largest))
+        fits = _forward_vmem_bytes(
+            *blocks, head_dim, itemsize) <= pallas_util.VMEM_BUDGET_BYTES
+        if fits or largest == _LANE:
+            return blocks
+        largest //= 2
+
+
+def _xp(*xs):
+    """`jax.numpy` where a block index is traced (a kernel body, an
+    `index_map`), `numpy` where all are Python or numpy integers (the
+    counts taken while tracing, the tests): the block predicates are
+    the same arithmetic on both."""
+    return jnp if any(isinstance(x, jax.Array) for x in xs) else np
 
 
 def _div(a, b: int):
     """a // b for a non-negative int32 scalar or vector and a static b:
     a shift where b is a power of two, a truncating divide elsewhere
     (`jnp.floor_divide` adds a sign correction nothing here needs)."""
+    if _xp(a) is np:
+        return a // b
     a = jnp.asarray(a, jnp.int32)
     if b & (b - 1) == 0:
         return jax.lax.shift_right_logical(a, jnp.int32(b.bit_length() - 1))
@@ -74,11 +142,12 @@ def _bd_spans(i, block: int, block_diffusion):
     last Bd-block of that). Scalars; the Bd-block numbers of an absent
     part mean nothing."""
     length, bd = block_diffusion
+    xp = _xp(i)
     lo = i * block
-    hi = jnp.minimum(lo + block, 2 * length) - 1
-    return (lo < length, _div(lo, bd), _div(jnp.minimum(hi, length - 1), bd),
-            hi >= length, _div(jnp.maximum(lo, length) - length, bd),
-            _div(jnp.maximum(hi, length) - length, bd))
+    hi = xp.minimum(lo + block, 2 * length) - 1
+    return (lo < length, _div(lo, bd), _div(xp.minimum(hi, length - 1), bd),
+            hi >= length, _div(xp.maximum(lo, length) - length, bd),
+            _div(xp.maximum(hi, length) - length, bd))
 
 
 def _block_needed(qi, j, n_keys, *, block_q: int, block_k: int,
@@ -108,6 +177,45 @@ def _block_needed(qi, j, n_keys, *, block_q: int, block_k: int,
         needed = needed & (
             (j + 1) * block_k - 1 >= qi * block_q - window + 1)
     return needed
+
+
+def _block_interior(qi, j, n_keys, *, block_q: int, block_k: int,
+                    causal: bool, window, block_diffusion=None):
+    """Does `_pair_mask` admit EVERY pair of (q block qi, k block j)?
+    The twin of `_block_needed`, for the forward: such a block runs
+    with no mask. Every key inside the row's length, the whole block on
+    or below the diagonal, the whole block inside the band. Under
+    `block_diffusion`: clean keys only, of Bd-blocks before those of
+    every noised query of the block and up to those of every clean one
+    (or, where blocks are no larger than a Bd-block, noised queries and
+    noised keys of one Bd-block)."""
+    interior = (j + 1) * block_k <= n_keys
+    if block_diffusion is not None:
+        qn, qn0, qn1, qc, qc0, _ = _bd_spans(qi, block_q, block_diffusion)
+        kn, kn0, kn1, kc, _, kc1 = _bd_spans(j, block_k, block_diffusion)
+        no = _xp(qi, j).logical_not
+        clean = no(kn) & (no(qn) | (kc1 < qn0)) & (no(qc) | (kc1 <= qc0))
+        own = (no(kc) & no(qc) & (qn0 == qn1) & (kn0 == kn1)
+               & (qn0 == kn0))
+        return interior & (clean | own)
+    if causal:
+        interior = interior & ((j + 1) * block_k - 1 <= qi * block_q)
+    if window is not None:
+        interior = interior & (
+            (qi + 1) * block_q - 1 - j * block_k < window)
+    return interior
+
+
+def _block_kinds(nq: int, nk: int, n_keys: int, **masks):
+    """(interior, cut, skipped) grid steps of one (batch x head) row
+    whose keys are all valid, counted from the two block predicates:
+    what the forward runs unmasked, masked and not at all."""
+    qi, j = np.arange(nq)[:, None], np.arange(nk)[None, :]
+    needed = np.broadcast_to(_block_needed(qi, j, n_keys, **masks), (nq, nk))
+    interior = np.broadcast_to(
+        _block_interior(qi, j, n_keys, **masks), (nq, nk))
+    n_interior, n_needed = int(interior.sum()), int(needed.sum())
+    return n_interior, n_needed - n_interior, nq * nk - n_needed
 
 
 def _pair_mask(qi, j, n_keys, *, block_q: int, block_k: int, causal: bool,
@@ -239,7 +347,11 @@ def _attn_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
     """One (batch*head, q-block, k-block) grid step. The innermost grid
     dim walks k/v blocks sequentially (TPU grids are sequential), so
     VMEM scratch (acc/m/l) carries streaming-softmax state across k
-    steps; only one [BK, D] k/v tile is resident at a time.
+    steps; only one [BK, D] k/v tile is resident at a time. A step is
+    one of three kinds: interior (`_block_interior`: no mask), cut
+    (needed and not interior: the mask on the scores and on p), or
+    skipped; an admitted pair goes through the same float32 expression
+    in both bodies.
 
     Refs: len [BH] i32, scalar-prefetched (row b's valid key count —
     t_kv when no key mask; tail padding and right-padded
@@ -262,24 +374,29 @@ def _attn_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
 
     masks = dict(block_q=bq, block_k=block_k, causal=causal, window=window,
                  block_diffusion=block_diffusion)
+    needed = _block_needed(qi, j, n_keys, **masks)
+    interior = _block_interior(qi, j, n_keys, **masks)
 
-    @pl.when(_block_needed(qi, j, n_keys, **masks))
-    def _compute():
+    def step(valid):
+        """One block of the streaming softmax; `valid` None: every pair
+        attends, and the block pays for no mask."""
         # native-dtype (e.g. bf16) operands on the MXU, f32 accumulation
         s = jax.lax.dot_general(
             q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [BQ, BK]
-        valid = _pair_mask(qi, j, n_keys, **masks)
-        s = jnp.where(valid, s, NEG_INF)
+        if valid is not None:
+            s = jnp.where(valid, s, NEG_INF)
         m_prev = m_ref[:, :1]                          # [BQ, 1]
         l_prev = l_ref[:, :1]
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
-        # mask p too: a row with NO valid key would otherwise see
-        # exp(NEG_INF - NEG_INF) = 1 everywhere (NEG_INF is finite) and
-        # return the unweighted mean of v; with p zeroed it returns 0,
-        # matching the backward's zero grads
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        p = jnp.exp(s - m_new)
+        if valid is not None:
+            # mask p too: a row with NO valid key would otherwise see
+            # exp(NEG_INF - NEG_INF) = 1 everywhere (NEG_INF is finite)
+            # and return the unweighted mean of v; with p zeroed it
+            # returns 0, matching the backward's zero grads
+            p = jnp.where(valid, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)                # [BQ, 1]
         l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
@@ -289,6 +406,14 @@ def _attn_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
             m_new[:, 0], m_ref.shape, (0,))
         l_ref[:] = jax.lax.broadcast_in_dim(
             l_new[:, 0], l_ref.shape, (0,))
+
+    @pl.when(interior)
+    def _interior():
+        step(None)
+
+    @pl.when(needed & jnp.logical_not(interior))
+    def _cut():
+        step(_pair_mask(qi, j, n_keys, **masks))
 
     @pl.when(j == nk - 1)
     def _finish():
@@ -313,22 +438,37 @@ def _flash_forward(q, k, v, lens, *, causal: bool, block_q: int,
     bh, t, d = q.shape
     t_kv = k.shape[1]
     scale = 1.0 / (d ** 0.5)
+    if window is not None and window >= t:
+        window = None   # causal, Tq == Tkv: the band excludes nothing
     block_q = min(block_q, max(t, 1))
     block_k = min(block_k, max(t_kv, 1))
     tq_pad = pl.cdiv(t, block_q) * block_q
     tk_pad = pl.cdiv(t_kv, block_k) * block_k
+    nq, nk = tq_pad // block_q, tk_pad // block_k
     qp = _pad_to(q, tq_pad, 1)
     kp = _pad_to(k, tk_pad, 1)
     vp = _pad_to(v, tk_pad, 1)
+    masks = dict(block_q=block_q, block_k=block_k, causal=causal,
+                 window=window)
+    pallas_util.note_traced("flash_attention.fwd_blocks",
+                            f"{block_q}x{block_k}")
+    pallas_util.note_traced(
+        "flash_attention.fwd_block_kinds",
+        "interior:%d,cut:%d,skipped:%d" % _block_kinds(
+            nq, nk, t_kv, block_diffusion=block_diffusion, **masks))
+
+    # a skipped step names a block the row needs (the one the pipeline
+    # already holds, or will need next) and fetches nothing: under block
+    # diffusion about half a row's k blocks, in two runs
+    def k_block(b, i, j, lens):
+        if block_diffusion is not None:
+            return _clamp_to_runs(j, *_bd_needed_k_runs(
+                i, block_q=block_q, block_k=block_k,
+                block_diffusion=block_diffusion))
+        return jnp.clip(j, *_needed_k_blocks(i, lens[b], **masks))
 
     q_map = lambda b, i, j, lens: (b, i, 0)
-    kv_map = lambda b, i, j, lens: (b, j, 0)
-    if block_diffusion is not None:
-        # about half the k blocks of a row are skipped, in two runs: a
-        # skipped step names a block of a run and fetches nothing
-        kv_map = lambda b, i, j, lens: (b, _clamp_to_runs(
-            j, *_bd_needed_k_runs(i, block_q=block_q, block_k=block_k,
-                                  block_diffusion=block_diffusion)), 0)
+    kv_map = lambda b, i, j, lens: (b, k_block(b, i, j, lens), 0)
     vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
 
     o, lse = pl.pallas_call(
@@ -336,7 +476,7 @@ def _flash_forward(q, k, v, lens, *, causal: bool, block_q: int,
                           window=window, block_diffusion=block_diffusion),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bh, tq_pad // block_q, tk_pad // block_k),
+            grid=(bh, nq, nk),
             in_specs=[
                 vmem((1, block_q, d), q_map),
                 vmem((1, block_k, d), kv_map),
@@ -358,8 +498,7 @@ def _flash_forward(q, k, v, lens, *, causal: bool, block_q: int,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-            **({} if block_diffusion is None else
-               {"vmem_limit_bytes": pallas_util.VMEM_LIMIT_BYTES})),
+            vmem_limit_bytes=pallas_util.VMEM_LIMIT_BYTES),
         interpret=pallas_util.interpret(),
         name="flash_attention_fwd",
     )(lens.astype(jnp.int32), qp, kp, vp)
@@ -619,11 +758,15 @@ def flash_attention(q, k, v, *, causal: bool = False,
     training costs O(T*window) instead of O(T^2); the backward kernels
     do not fetch a skipped step's blocks either.
 
-    block_q, block_k: the forward kernel's blocks (default 256 x 512;
-    under `block_diffusion` `BD_BLOCK_Q` x `BD_BLOCK_K`). bwd_block_q,
-    bwd_block_k: the two backward kernels' (dk/dv and dq), their own
-    because what suits them differs; a sequence shorter than a block
-    takes one block.
+    block_q, block_k: the forward kernel's blocks; by default
+    `_forward_blocks` takes them from the shape, for every mask: 1024 x
+    1024 where the sequence carries it, smaller where that would pad it
+    further than a 256 x 512 grid does, one block for a short sequence.
+    The forward runs a block with no mask where every pair attends
+    (`_block_interior`) and masked only where the mask cuts it.
+    bwd_block_q, bwd_block_k: the two backward kernels' (dk/dv and dq),
+    their own because what suits them differs; a sequence shorter than
+    a block takes one block.
 
     block_diffusion: optional (L, Bd) — the block-diffusion training
     mask (BD3-LM's vectorised form) over T = 2L positions, the noised
@@ -634,10 +777,9 @@ def flash_attention(q, k, v, *, causal: bool = False,
     """
     if q.ndim != 4:
         raise ValueError(f"expected [B, T, H, D], got {q.shape}")
-    block_q = block_q or (DEFAULT_BLOCK_Q if block_diffusion is None
-                          else BD_BLOCK_Q)
-    block_k = block_k or (DEFAULT_BLOCK_K if block_diffusion is None
-                          else BD_BLOCK_K)
+    if block_q is None or block_k is None:
+        chosen = _forward_blocks(q.shape[1], k.shape[1], q.shape[3], q.dtype)
+        block_q, block_k = block_q or chosen[0], block_k or chosen[1]
     if block_diffusion is not None:
         length, bd = block_diffusion = tuple(int(x) for x in block_diffusion)
         if causal or window is not None or key_lens is not None:

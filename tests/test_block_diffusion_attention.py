@@ -98,6 +98,56 @@ def test_block_predicate_and_runs_are_the_table(length, bd, block_q,
             assert any_pair[int(FA._clamp_to_runs(qi, *runs)), j]
 
 
+@pytest.mark.parametrize("length,bd,block_q,block_k", SHAPES + [
+    (32, 8, 4, 4), (32, 16, 8, 4),      # blocks smaller than a Bd-block
+    (32, 8, 8, 16), (64, 4, 16, 16),    # equal to one; much larger
+    (12, 4, 4, 8),                      # a k block across the two halves
+])
+def test_block_classifier_is_the_pair_mask(length, bd, block_q, block_k):
+    """The forward's three kinds of block: one `_block_interior` names
+    holds only admitted pairs (it runs unmasked), one `_block_needed`
+    skips holds none; where no block lies across the two halves,
+    `_block_interior` names every block whose mask is all true."""
+    nq, nk = _grid(length, block_q, block_k)
+    kw = dict(block_q=block_q, block_k=block_k, causal=False, window=None,
+              block_diffusion=(length, bd))
+    whole = length % block_q == 0 and length % block_k == 0
+    kinds = {"interior": 0, "cut": 0, "skipped": 0}
+    for qi in range(nq):
+        for j in range(nk):
+            mask = np.broadcast_to(np.asarray(FA._pair_mask(
+                qi, j, 2 * length, **kw)), (block_q, block_k))
+            interior = bool(FA._block_interior(qi, j, 2 * length, **kw))
+            needed = bool(FA._block_needed(qi, j, 2 * length, **kw))
+            rows = min(block_q, 2 * length - qi * block_q)  # real queries
+            assert not interior or mask.all(), (qi, j)
+            assert needed or not mask[:rows].any(), (qi, j)
+            assert needed or not interior
+            if whole:
+                assert interior == mask.all(), (qi, j)
+            kinds["interior" if interior else "cut" if needed
+                  else "skipped"] += 1
+    assert tuple(kinds.values()) == FA._block_kinds(nq, nk, 2 * length, **kw)
+    if whole and bd < block_q < length:      # the mask cuts some block
+        assert min(kinds.values()) > 0, kinds
+
+
+def test_forward_blocks_under_the_mask_are_the_choosers():
+    """No blocks of the mask's own: at the cell's shape the chooser
+    gives what PR 34 timed for it, and a call that names none runs on
+    the chooser's."""
+    from paddle_tpu.ops import pallas_util
+
+    assert FA._forward_blocks(8192, 8192, 128, jnp.bfloat16) == (1024, 1024)
+    x = jax.ShapeDtypeStruct((1, 3072, 2, 128), jnp.bfloat16)
+    key = "flash_attention.fwd_blocks=%dx%d" % FA._forward_blocks(
+        3072, 3072, 128, jnp.bfloat16)
+    before = pallas_util.traced().get(key, 0)
+    jax.eval_shape(lambda q, k, v: FA.flash_attention(
+        q, k, v, block_diffusion=(1536, 3)), x, x, x)
+    assert pallas_util.traced()[key] == before + 1
+
+
 def _dense(q, k, v, length, bd):
     return T._dense_attention(q, k, v, causal=False,
                               block_diffusion=(length, bd))
@@ -115,14 +165,17 @@ def test_dense_path_uses_the_table(np_rng):
         atol=1e-5)
 
 
-@pytest.mark.parametrize("length,bd,bq,bk,bwd", [
-    (32, 4, 16, 16, (16, 16)),
-    (32, 4, 8, 32, (32, 8)),
-    (24, 4, 16, 16, (128, 128)),     # one padded block in the backward
-    (64, 8, 32, 16, (32, 64)),
-    (30, 3, 16, 16, (16, 16)),       # Bd no power of two, ragged tail
+@pytest.mark.parametrize("length,bd,bq,bk,bwd,kinds", [
+    # kinds: forward grid steps of a row (interior, cut, skipped)
+    (32, 4, 16, 16, (16, 16), (2, 6, 8)),
+    (32, 4, 8, 32, (32, 8), (0, 12, 4)),
+    (24, 4, 16, 16, (128, 128), (0, 7, 2)),  # one padded backward block
+    (64, 8, 32, 16, (32, 64), (4, 12, 16)),
+    (30, 3, 16, 16, (16, 16), (1, 11, 4)),   # Bd no power of two, ragged
+    (32, 16, 8, 8, (16, 16), (24, 0, 40)),   # blocks inside a Bd-block
+    (32, 4, 8, 8, (32, 32), (12, 12, 40)),   # the cell's counts, in small
 ])
-def test_flash_kernels_match_dense(np_rng, length, bd, bq, bk, bwd):
+def test_flash_kernels_match_dense(np_rng, length, bd, bq, bk, bwd, kinds):
     q, k, v = (jnp.asarray(np_rng.randn(2, 2 * length, 2, 16), jnp.float32)
                for _ in range(3))
     w = jnp.asarray(np_rng.randn(2, 2 * length, 2, 16), jnp.float32)
@@ -131,6 +184,10 @@ def test_flash_kernels_match_dense(np_rng, length, bd, bq, bk, bwd):
         return FA.flash_attention(
             q, k, v, block_q=bq, block_k=bk, bwd_block_q=bwd[0],
             bwd_block_k=bwd[1], block_diffusion=(length, bd))
+
+    assert kinds == FA._block_kinds(
+        *_grid(length, bq, bk), 2 * length, block_q=bq, block_k=bk,
+        causal=False, window=None, block_diffusion=(length, bd))
 
     np.testing.assert_allclose(np.asarray(flash(q, k, v)),
                                np.asarray(_dense(q, k, v, length, bd)),

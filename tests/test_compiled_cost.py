@@ -236,6 +236,31 @@ class TestSWAFlopScaling:
         assert sw[1] / sw[0] < 2.2, sw           # linear in T
         assert sw[1] < full[1] / 4, (sw, full)   # and much cheaper
 
+    @staticmethod
+    def _fwd_kinds(T, window):
+        """(interior, cut, skipped) grid steps of one head in the
+        forward, on the blocks it takes from the shape (1024 x 1024 at
+        these lengths), from the forward's own classifier."""
+        from paddle_tpu.ops import flash_attention as FA
+
+        bq, bk = FA._forward_blocks(T, T, 128, jnp.bfloat16)
+        assert (bq, bk) == (1024, 1024)
+        return FA._block_kinds(T // bq, T // bk, T, block_q=bq, block_k=bk,
+                               causal=True, window=window)
+
+    def test_swa_forward_linear_in_t(self):
+        """Counted from `_block_kinds`: the full causal forward computes
+        10 -> 36 steps a head as T doubles 4096 -> 8192 (6 -> 28 of them
+        unmasked); under a window of 256 every computed step is cut by
+        the band or the diagonal, 7 -> 15 (linear), and the rest fetch
+        nothing."""
+        assert [self._fwd_kinds(t, None) for t in (4096, 8192)] == [
+            (6, 4, 6), (28, 8, 28)]
+        assert [self._fwd_kinds(t, 256) for t in (4096, 8192)] == [
+            (0, 7, 9), (0, 15, 49)]
+        # a band of two blocks and a half leaves whole blocks unmasked
+        assert self._fwd_kinds(8192, 2560) == (7, 19, 38)
+
 
 class TestFusedCEResiduals:
     """Claim (d), r5: fused chunked cross-entropy removes the [N, vocab]

@@ -321,3 +321,204 @@ def test_needed_block_ranges_are_the_block_predicate(causal, window,
             want = [int(first) <= i <= int(last) and j * block_k < n_keys
                     for i in range(nq)]
             assert want == list(needed[:, j]), (n_keys, j)
+
+
+# -- the forward's three kinds of block and its blocks (PR 35) ------------
+
+_CLASSIFIER_CASES = [
+    # causal, window, block_q, block_k
+    (False, None, 8, 8), (False, None, 8, 16), (False, None, 16, 8),
+    (True, None, 8, 8), (True, None, 8, 16), (True, None, 16, 8),
+    (True, 1, 8, 8), (True, 5, 8, 16),          # narrower than a block
+    (True, 8, 8, 8), (True, 16, 16, 8),         # a block wide
+    (True, 20, 8, 8), (True, 30, 8, 16),        # wider than a block
+    (True, 100, 16, 8),                         # wider than the sequence
+]
+
+
+@pytest.mark.parametrize("causal,window,block_q,block_k", _CLASSIFIER_CASES)
+def test_block_classifier_is_the_pair_mask(causal, window, block_q, block_k):
+    """`_block_interior` is true exactly where `_pair_mask` admits every
+    pair of the block, and a block `_block_needed` skips holds no
+    admitted pair: for every block of a 48 x 48 grid and every row
+    length (none, inside a block, on a block's edge, all)."""
+    from paddle_tpu.ops import flash_attention as FA
+
+    t = 48
+    masks = dict(block_q=block_q, block_k=block_k, causal=causal,
+                 window=window)
+    kinds = set()
+    for n_keys in (0, 1, 7, 8, 9, 16, 30, 32, 47, 48):
+        for qi in range(t // block_q):
+            for j in range(t // block_k):
+                mask = np.broadcast_to(np.asarray(FA._pair_mask(
+                    qi, j, n_keys, **masks)), (block_q, block_k))
+                interior = bool(FA._block_interior(qi, j, n_keys, **masks))
+                needed = bool(FA._block_needed(qi, j, n_keys, **masks))
+                assert interior == mask.all(), (n_keys, qi, j)
+                assert needed or not mask.any(), (n_keys, qi, j)
+                assert needed or not interior
+                kinds.add("interior" if interior else
+                          "cut" if needed else "skipped")
+    # a band narrower than a q block beside a k block cuts every block
+    assert kinds == ({"interior", "cut", "skipped"}
+                     if window is None or window >= block_q + block_k
+                     else {"cut", "skipped"})
+
+
+@pytest.mark.parametrize("predicate", ["_block_needed", "_block_interior"])
+@pytest.mark.parametrize("masks", [
+    dict(block_q=8, block_k=8, causal=True, window=11),
+    dict(block_q=8, block_k=8, causal=False, window=None,
+         block_diffusion=(24, 4)),
+    dict(block_q=4, block_k=4, causal=False, window=None,
+         block_diffusion=(12, 8)),
+], ids=["causal_window", "block_diffusion", "inside_a_bd_block"])
+def test_block_classifier_under_a_trace_is_the_same_arithmetic(predicate,
+                                                               masks):
+    """The predicates take numpy's path for Python integers (the counts,
+    the tests above) and jax.numpy's for traced ones (the kernel): one
+    answer, over every block of a grid."""
+    from paddle_tpu.ops import flash_attention as FA
+
+    fn = getattr(FA, predicate)
+    qi, j = np.meshgrid(np.arange(6), np.arange(6), indexing="ij")
+    traced = jax.jit(lambda qi, j, n: fn(qi, j, n, **masks))(
+        jnp.asarray(qi, jnp.int32), jnp.asarray(j, jnp.int32), jnp.int32(48))
+    np.testing.assert_array_equal(np.asarray(traced), fn(qi, j, 48, **masks))
+
+
+_THREE_KIND_CASES = [
+    # t, causal, window, key_lens, forward blocks (q, k), and each row's
+    # (interior, cut, skipped) grid steps
+    (40, True, None, None, (8, 8), [(10, 5, 10)]),
+    (48, True, None, None, (8, 16), [(6, 6, 6)]),
+    (48, True, None, None, (16, 8), [(6, 6, 6)]),
+    (48, True, 20, None, (8, 8), [(5, 13, 18)]),    # interior in the band
+    (48, True, 5, None, (8, 8), [(0, 11, 25)]),     # a band with none
+    (48, True, 30, (48, 19), (8, 16), [(2, 10, 6), (1, 9, 8)]),
+    # not causal: only the lengths cut, inside a block and on its edge
+    (40, False, None, (40, 21, 0), (8, 8),
+     [(25, 0, 0), (10, 5, 10), (0, 0, 25)]),
+    (40, False, None, (16, 24, 40), (8, 8),
+     [(10, 0, 15), (15, 0, 10), (25, 0, 0)]),
+    (37, True, None, (37, 20), (8, 8), [(10, 5, 10), (7, 5, 13)]),  # tail
+]
+
+
+@pytest.mark.parametrize("t,causal,window,lens,blocks,kinds",
+                         _THREE_KIND_CASES)
+def test_three_kinds_of_block_match_dense(np_rng, t, causal, window, lens,
+                                          blocks, kinds):
+    """Forward and gradients against dense attention on grids that hold
+    interior, cut and skipped blocks at once: the unmasked body and the
+    masked one meet in one row's streaming softmax."""
+    from paddle_tpu.ops import flash_attention as FA
+
+    b = 2 if lens is None else len(lens)
+    q, k, v = _qkv(np_rng, b=b, t=t, h=2, d=8)
+    w = jnp.asarray(np_rng.randn(*q.shape), jnp.float32)
+    key_lens = None if lens is None else jnp.asarray(lens, jnp.int32)
+    mask = _pair_mask_dense(t, t, causal, window,
+                            (t,) * b if lens is None else lens)
+    nq, nk = -(-t // blocks[0]), -(-t // blocks[1])
+    assert kinds == [FA._block_kinds(
+        nq, nk, n_keys, block_q=blocks[0], block_k=blocks[1], causal=causal,
+        window=window) for n_keys in (lens or (t,))]
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, window=window,
+                               key_lens=key_lens, block_q=blocks[0],
+                               block_k=blocks[1], bwd_block_q=8,
+                               bwd_block_k=8)
+
+    some = jnp.any(mask, -1)[:, :, None, None]
+    out = flash(q, k, v)
+    ref = dense_attention(q, k, v, mask=mask) * some
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * w), argnums=(0, 1, 2))(
+        q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(
+        dense_attention(*a, mask=mask) * w * some), argnums=(0, 1, 2))(
+            q, k, v)
+    for a, b_ in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("shape,kw,kinds", [
+    # starcoder2_3b_l4.train_seq4k and sdar_30b_a3b_ep8.train_bd4_seq4k
+    ((2, 4096, 24, 128), dict(causal=True), "interior:6,cut:4,skipped:6"),
+    ((2, 8192, 32, 128), dict(block_diffusion=(4096, 4)),
+     "interior:12,cut:12,skipped:40"),
+    # the dense cell's own call: 4095 positions, its window inert
+    ((2, 4095, 24, 128), dict(causal=True, window=4096),
+     "interior:6,cut:4,skipped:6"),
+])
+def test_forward_counters_at_the_cells_shapes(shape, kw, kinds):
+    """Traced abstractly, the forward says which blocks it took and how
+    many grid steps of a (batch x head) row run unmasked, masked and not
+    at all."""
+    from paddle_tpu.ops import pallas_util
+
+    before = pallas_util.traced()
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    jax.eval_shape(lambda q, k, v: flash_attention(q, k, v, **kw), x, x, x)
+    after = pallas_util.traced()
+    for key in ("flash_attention.fwd_blocks=1024x1024",
+                f"flash_attention.fwd_block_kinds={kinds}"):
+        assert after.get(key, 0) == before.get(key, 0) + 1, (key, after)
+
+
+def _padded(t, block):
+    block = min(block, max(t, 1))
+    return -(-t // block) * block
+
+
+def test_forward_blocks_come_from_the_shape():
+    from paddle_tpu.ops import flash_attention as FA
+    from paddle_tpu.ops import pallas_util
+
+    bf16 = jnp.bfloat16
+    # the two cells, and the dense cell's 4095 positions
+    assert FA._forward_blocks(4096, 4096, 128, bf16) == (1024, 1024)
+    assert FA._forward_blocks(8192, 8192, 128, bf16) == (1024, 1024)
+    assert FA._forward_blocks(4095, 4095, 128, bf16) == (1024, 1024)
+    # no sequence is padded further than 256 x 512 blocks padded it
+    for t in range(1, 4101):
+        bq, bk = FA._forward_blocks(t, t, 128, bf16)
+        assert _padded(t, bq) <= _padded(t, 256), (t, bq)
+        assert _padded(t, bk) <= _padded(t, 512), (t, bk)
+        # whole lane tiles, or the whole (short) sequence in one block
+        assert bq % 128 == 0 or (bq == t and t <= 256), (t, bq)
+        assert bk % 128 == 0 or (bk == t and t <= 512), (t, bk)
+        assert bq <= 1024 and bk <= 1024
+    assert FA._forward_blocks(1280, 1280, 128, bf16) == (640, 768)
+    assert _padded(1280, 640) == 1280          # not 2048
+    for t in (1, 37, 100, 128, 256):           # short: one block
+        assert FA._forward_blocks(t, t, 64, bf16) == (t, t)
+    assert FA._forward_blocks(8, 2048, 64, bf16) == (8, 1024)   # Tq != Tkv
+    # a head so wide that 1024 x 1024 would pass the VMEM budget
+    bq, bk = FA._forward_blocks(4096, 4096, 2048, jnp.float32)
+    assert (bq, bk) == (512, 512)
+    assert FA._forward_vmem_bytes(bq, bk, 2048, 4) \
+        <= pallas_util.VMEM_BUDGET_BYTES \
+        < FA._forward_vmem_bytes(1024, 1024, 2048, 4)
+    assert FA._forward_vmem_bytes(1024, 1024, 128, 2) \
+        <= pallas_util.VMEM_BUDGET_BYTES
+
+
+def test_default_blocks_are_the_choosers(np_rng):
+    """A call that names no blocks runs on the chooser's: a sequence of
+    several blocks of its choice (640 x 768 at T 1280) against dense."""
+    from paddle_tpu.ops import pallas_util
+
+    q, k, v = _qkv(np_rng, b=1, t=1280, h=1, d=8)
+    before = pallas_util.traced().get("flash_attention.fwd_blocks=640x768", 0)
+    out = flash_attention(q, k, v, causal=True)
+    assert pallas_util.traced()["flash_attention.fwd_blocks=640x768"] \
+        == before + 1
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(dense_attention(q, k, v, causal=True)),
+        rtol=2e-5, atol=2e-5)

@@ -4,8 +4,10 @@
 compiler proper is installed here too and compiles for a chip that is
 described and not attached, which is where it refuses a misaligned
 slice, a matmul form or too much VMEM. Nothing runs: no result, no
-time. The kernels of the LM cell's attention at its real widths, a
-second or two each, all in this one file (only one process may hold the
+time. The kernels of the LM cells' attention at their real widths (the
+forward with its two bodies, unmasked and masked, on the blocks
+`_forward_blocks` takes from each shape: PR 35), a second or two each,
+all in this one file (only one process may hold the
 TPU library: the topology is described inside a fixture, never at
 import, so every xdist worker collects the same tests and only the
 worker given this file loads it).
@@ -41,16 +43,24 @@ def _compiled_not_interpreted(monkeypatch):
         yield
 
 
-@pytest.mark.parametrize("bh,t,d,dtype,window,lens", [
+@pytest.mark.parametrize("bh,t,d,dtype,window,lens,fwd_blocks", [
     # starcoder2_3b_l4.train_seq4k: 4095 positions, window inert
-    (48, 4095, 128, jnp.bfloat16, 4096, False),
-    (24, 8192, 128, jnp.bfloat16, 4096, False),   # the band active
-    (16, 2048, 64, jnp.bfloat16, 512, True),      # the serving width
-    (16, 2048, 128, jnp.float32, None, False),    # the float32 policy
-    (8, 100, 64, jnp.bfloat16, None, True),       # shorter than a tile
+    (48, 4095, 128, jnp.bfloat16, 4096, False, (1024, 1024)),
+    (48, 4096, 128, jnp.bfloat16, None, False, (1024, 1024)),
+    (24, 8192, 128, jnp.bfloat16, 4096, False, (1024, 1024)),  # band active
+    (16, 2048, 64, jnp.bfloat16, 512, True, (1024, 1024)),  # serving width
+    (16, 2048, 64, jnp.bfloat16, None, True, (1024, 1024)),  # a prefill
+    (16, 2048, 128, jnp.float32, None, False, (1024, 1024)),  # f32 policy
+    (8, 100, 64, jnp.bfloat16, None, True, (100, 100)),  # shorter than a tile
+    # lengths that 1024 would pad further than 256 x 512: odd blocks
+    (8, 1280, 128, jnp.bfloat16, None, False, (640, 768)),
+    (8, 1536, 64, jnp.bfloat16, 512, True, (768, 768)),
+    (4, 4100, 128, jnp.bfloat16, None, False, (384, 896)),
 ])
 def test_flash_forward_and_backward_compile_for_v5e(one_chip, bh, t, d,
-                                                    dtype, window, lens):
+                                                    dtype, window, lens,
+                                                    fwd_blocks):
+    assert FA._forward_blocks(t, t, d, dtype) == fwd_blocks
     x = jax.ShapeDtypeStruct((1, t, bh, d), dtype, sharding=one_chip)
     key_lens = (jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
                 if lens else None)
@@ -77,6 +87,8 @@ def test_flash_forward_and_backward_compile_for_v5e(one_chip, bh, t, d,
     (1536, 3, 8),       # Bd no power of two, L no multiple of a block
 ])
 def test_block_diffusion_flash_compiles_for_v5e(one_chip, length, bd, bh):
+    assert FA._forward_blocks(2 * length, 2 * length, 128,
+                              jnp.bfloat16) == (1024, 1024)
     x = jax.ShapeDtypeStruct((1, 2 * length, bh, 128), jnp.bfloat16,
                              sharding=one_chip)
 

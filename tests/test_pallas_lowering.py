@@ -91,9 +91,14 @@ def test_flash_fwd_bwd_lowers(batch, t, heads, head_dim, window, lens):
     assert (fwd_name, dkv_name, dq_name) == (
         "flash_attention_fwd", "flash_attention_bwd_dkv",
         "flash_attention_bwd_dq")
-    bh, t_pad = batch * heads, -(-t // 256) * 256
-    assert fwd == [f"tensor<{bh}xi32>"] + [
-        f"tensor<{bh}x{t_pad}x{head_dim}xbf16>"] * 3
+    bh = batch * heads
+    # q padded to whole q blocks, k and v to whole k blocks, of the
+    # chooser's (1024 x 1024 at these lengths: 4095 becomes 4096)
+    tq_pad, tk_pad = (-(-t // block) * block for block in
+                      FA._forward_blocks(t, t, head_dim, jnp.bfloat16))
+    assert fwd == [f"tensor<{bh}xi32>",
+                   f"tensor<{bh}x{tq_pad}x{head_dim}xbf16>"] + [
+        f"tensor<{bh}x{tk_pad}x{head_dim}xbf16>"] * 2
     block = max(FA.BWD_BLOCK_Q, FA.BWD_BLOCK_K)
     t_bwd = -(-t // block) * block
     wide = f"tensor<{bh}x{t_bwd}x{head_dim}xbf16>"
