@@ -15,6 +15,13 @@ weights from a seed), in ONE process:
                      layers x 8 heads, vocab 32000, attn_impl="auto":
                      flash forward and its backward inside a real step,
                      checked against the dense path on the same batch.
+  train_layer_kinds  one `T.loss_and_aux` + grad step on a block whose
+                     layers differ in kind (three sliding-window layers,
+                     one full layer with YaRN) over a dropless expert
+                     layer that holds 4 of its 16 experts, seq 2048,
+                     window 512: the band's kernels beside the full
+                     layer's in one program, checked against the dense
+                     path, and the expert layer's counts.
   serve_http         the same transformer behind HttpEdge ->
                      ServingRouter -> ServingServer -> DecodeEngine as
                      `cli serve --http` wires them (slots 8, max_len
@@ -97,6 +104,8 @@ class Sizes:
     lm: dict                    # TransformerConfig fields
     lm_seq: int
     lm_batch: int
+    kinds_lm: dict              # the block of `train_layer_kinds`
+    kinds_window: int
     slots: int
     max_len: int
     prefill_chunk: int
@@ -109,6 +118,9 @@ FULL = Sizes(
     image_hw=224, image_batch=256, classes=1000, train_steps=5,
     lm=dict(vocab=32000, dim=512, n_layers=8, n_heads=8),
     lm_seq=2048, lm_batch=2,
+    kinds_lm=dict(vocab=32000, dim=512, n_heads=4, n_kv_heads=2,
+                  head_size=128, moe_experts=16, moe_held=4, moe_k=2,
+                  moe_dim=256), kinds_window=512,
     slots=8, max_len=2048, prefill_chunk=256,
     prompt_lens=(16, 48, 100, 200, 300, 500, 777, 1024), max_new=32)
 
@@ -117,6 +129,9 @@ TINY = Sizes(
     image_hw=16, image_batch=8, classes=10, train_steps=1,
     lm=dict(vocab=97, dim=32, n_layers=1, n_heads=4),
     lm_seq=64, lm_batch=2,
+    kinds_lm=dict(vocab=97, dim=32, n_heads=2, n_kv_heads=1, head_size=16,
+                  moe_experts=4, moe_held=2, moe_k=2, moe_dim=16),
+    kinds_window=16,
     slots=4, max_len=96, prefill_chunk=16,
     prompt_lens=(3, 5, 9, 14, 16, 20, 33, 48), max_new=6)
 
@@ -214,6 +229,11 @@ def train_resnet50(sz: Sizes, devices) -> dict:
             "sharded_over": len(devices), "bytes_in_use": live}
 
 
+def _grad_norm(grads) -> float:
+    return float(jnp.sqrt(sum(jnp.sum(jnp.square(x.astype(jnp.float32)))
+                              for x in jax.tree.leaves(grads))))
+
+
 def train_transformer(sz: Sizes, devices) -> dict:
     cfg = T.TransformerConfig(**sz.lm, attn_impl="auto")
     dense_cfg = dataclasses.replace(cfg, attn_impl="dense")
@@ -224,14 +244,10 @@ def train_transformer(sz: Sizes, devices) -> dict:
     def step(c):
         return jax.jit(jax.value_and_grad(lambda p, t: T.loss(p, c, t)))
 
-    def gnorm(g):
-        return float(jnp.sqrt(sum(jnp.sum(jnp.square(x.astype(jnp.float32)))
-                                  for x in jax.tree.leaves(g))))
-
     loss, grads = step(cfg)(params, toks)
     loss_d, grads_d = step(dense_cfg)(params, toks)
     loss, loss_d = float(loss), float(loss_d)
-    gn, gn_d = gnorm(grads), gnorm(grads_d)
+    gn, gn_d = _grad_norm(grads), _grad_norm(grads_d)
     check(np.isfinite(loss) and np.isfinite(gn), f"loss {loss} |g| {gn}")
     # random weights: the loss sits near ln(vocab)
     check(abs(loss - np.log(cfg.vocab)) < 1.0,
@@ -243,6 +259,52 @@ def train_transformer(sz: Sizes, devices) -> dict:
     return {"seq": sz.lm_seq, "batch": sz.lm_batch, "loss_auto": loss,
             "loss_dense": loss_d, "grad_norm_auto": gn,
             "grad_norm_dense": gn_d, "tolerance": TRAIN_TOL}
+
+
+def train_layer_kinds(sz: Sizes, devices) -> dict:
+    """Attention kind by layer: three sliding-window layers and one
+    full layer with YaRN in the one block body, over a dropless expert
+    layer that holds a share of its experts; `T.loss_and_aux` and its
+    gradient, `auto` against dense, and the layer's counts added to the
+    timeline as a training loop would."""
+    from paddle_tpu.obs.trace import default_timeline
+    from paddle_tpu.parallel import moe
+
+    kinds = (("sliding", T.AttentionKind(window=sz.kinds_window)),
+             ("full", T.AttentionKind(rope_scaling="yarn", rope_factor=16.0,
+                                      rope_original=sz.lm_seq // 2)))
+    cfg = T.TransformerConfig(
+        **sz.kinds_lm, n_layers=4, norm="rms", bias=False, qk_norm=True,
+        layer_types=("sliding",) * 3 + ("full",), attention_kinds=kinds,
+        moe_router="dropless", moe_every=1, attn_impl="auto", remat=True)
+    dense_cfg = dataclasses.replace(cfg, attn_impl="dense")
+    params = T.init_params(jax.random.key(0), cfg)
+    toks = jnp.asarray(np.random.default_rng(1).integers(
+        0, cfg.vocab, (sz.lm_batch, sz.lm_seq + 1)), jnp.int32)
+
+    def step(c):
+        return jax.jit(jax.value_and_grad(
+            lambda p, t: T.loss_and_aux(p, c, t), has_aux=True))
+
+    (loss, stats), grads = step(cfg)(params, toks)
+    (loss_d, _), grads_d = step(dense_cfg)(params, toks)
+    loss, loss_d = float(loss), float(loss_d)
+    gn, gn_d = _grad_norm(grads), _grad_norm(grads_d)
+    check(np.isfinite(loss) and np.isfinite(gn), f"loss {loss} |g| {gn}")
+    check(abs(loss - loss_d) <= TRAIN_TOL, f"loss auto {loss} vs dense "
+                                           f"{loss_d}")
+    check(abs(gn - gn_d) <= TRAIN_TOL * gn_d, f"|grad| auto {gn} vs dense "
+                                              f"{gn_d}")
+    before = dict(default_timeline().counters())
+    moe.count_dropless_stats(stats, positions=sz.lm_batch * sz.lm_seq)
+    counters = {k: v - before.get(k, 0)
+                for k, v in default_timeline().counters().items()
+                if k.startswith("moe.")}
+    check(0 < counters["moe.rows_held"] <= cfg.moe_k
+          * counters["moe.positions"], f"rows held {counters}")
+    return {"seq": sz.lm_seq, "window": sz.kinds_window, "loss_auto": loss,
+            "loss_dense": loss_d, "grad_norm_auto": gn,
+            "grad_norm_dense": gn_d, "counters": counters}
 
 
 def serve_http(sz: Sizes, devices) -> dict:
@@ -370,6 +432,7 @@ def main(argv=None) -> int:
     clock = CompileClock()
     phases = [("train_resnet50", train_resnet50),
               ("train_transformer", train_transformer),
+              ("train_layer_kinds", train_layer_kinds),
               ("serve_http", serve_http)]
     if len(devices) > 1 and not args.tiny:
         phases.append(("multichip_dryrun", multichip_dryrun))

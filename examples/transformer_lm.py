@@ -3,11 +3,22 @@ flagship's user flow (dense or MoE, long-context ready).
 
 Run: python examples/transformer_lm.py [--steps 200] [--moe]
      python examples/transformer_lm.py --block-diffusion [--steps 200]
+     python examples/transformer_lm.py --layer-pattern sliding,sliding,sliding,full
 
 `--block-diffusion` trains the same toy task with the block-diffusion
 objective (`T.block_diffusion_loss`) on a toy SDAR-style block: RMSNorm,
 bias-free projections, QK-norm, heads of their own size, and a dropless
 mixture of 8 gated-SiLU experts, top 2.
+
+`--layer-pattern` trains the next-token objective on a block whose
+layers differ in kind: the config's `layer_types` names each layer's
+kind and `attention_kinds` gives a kind its window and its rotary
+scaling (`T.AttentionKind`). Here "sliding" attends the last `--window`
+positions with the plain rotary embedding and "full" attends every
+earlier position with YaRN (`rope_scaling="yarn"`: factor 4 over an
+original context of half the sequence); the FFN is the dropless expert
+layer, whose counts `T.loss_and_aux` hands back beside the loss.
+Decoding such a model is not implemented: the run ends with the loss.
 
 The task is character-level copy-structure text (synthetic, zero
 egress): sequences follow an order-1 Markov chain, so a small model
@@ -77,6 +88,52 @@ def train_block_diffusion(args, block_length=4):
         if k.startswith("moe.")})
 
 
+def train_layer_kinds(args):
+    """Attention kind by layer, read at trace time by the one block
+    body; the loop that reads the loss counts the expert layer's rows."""
+    from paddle_tpu.obs.trace import default_timeline
+    from paddle_tpu.parallel import moe
+
+    pattern = tuple(args.layer_pattern.split(","))
+    kinds = (("sliding", T.AttentionKind(window=args.window)),
+             ("full", T.AttentionKind(rope_scaling="yarn", rope_factor=4.0,
+                                      rope_original=args.seq_len // 2)))
+    cfg = T.TransformerConfig(
+        vocab=args.vocab, dim=args.dim, n_layers=len(pattern), n_heads=4,
+        n_kv_heads=2, head_size=32, norm="rms", bias=False, qk_norm=True,
+        layer_types=pattern,
+        attention_kinds=tuple(k for k in kinds if k[0] in pattern),
+        moe_router="dropless", moe_experts=8, moe_every=1, moe_k=2,
+        moe_dim=2 * args.dim, attn_impl="auto")
+    params = T.init_params(jax.random.key(0), cfg)
+    opt = optim.adam(3e-3)
+    opt_state = opt.init(params)
+
+    @jax.jit
+    def step(params, opt_state, toks, i):
+        (loss, stats), grads = jax.value_and_grad(
+            lambda q: T.loss_and_aux(q, cfg, toks), has_aux=True)(params)
+        params, opt_state = opt.update(grads, opt_state, params, i)
+        return params, opt_state, loss, stats
+
+    r = np.random.RandomState(0)
+    for i in range(args.steps):
+        toks = make_batch(r, args.vocab, args.batch, args.seq_len)
+        params, opt_state, loss, stats = step(params, opt_state, toks,
+                                              jnp.asarray(i))
+        if i % 50 == 0 or i == args.steps - 1:
+            moe.count_dropless_stats(
+                stats, positions=toks.shape[0] * (toks.shape[1] - 1))
+            print(f"step {i:4d}  loss {float(loss):.4f}")
+    print("layer kinds:", ", ".join(
+        f"{i}: {name} (window {cfg.attention_kind(i).window}, rope "
+        f"{cfg.attention_kind(i).rope_scaling})"
+        for i, name in enumerate(pattern)))
+    print("expert layer counters:", {
+        k: v for k, v in default_timeline().counters().items()
+        if k.startswith("moe.")})
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
@@ -89,9 +146,19 @@ def main():
                     help="sparse FFN blocks (4 experts, top-2)")
     ap.add_argument("--block-diffusion", action="store_true",
                     help="block-diffusion objective on a dropless-MoE block")
+    ap.add_argument("--layer-pattern", default=None, metavar="KIND,KIND,...",
+                    help="attention kind by layer, one of sliding | full a "
+                    "layer (TransformerConfig.layer_types): sliding attends "
+                    "the last --window positions, full every earlier one "
+                    "with YaRN rotary scaling; dropless-MoE block, "
+                    "next-token objective, no decoding")
+    ap.add_argument("--window", type=int, default=16,
+                    help="window of the sliding kind of --layer-pattern")
     args = ap.parse_args()
     if args.block_diffusion:
         return train_block_diffusion(args)
+    if args.layer_pattern:
+        return train_layer_kinds(args)
 
     cfg = T.TransformerConfig(
         vocab=args.vocab, dim=args.dim, n_layers=args.layers, n_heads=4,

@@ -23,6 +23,8 @@ framework needs one. TPU-first choices:
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 from typing import Optional
 
 import jax
@@ -61,6 +63,22 @@ TP_MOE_RULES = ([(r"moe/router/kernel$", P())] + TP_RULES +
 
 
 @dataclasses.dataclass(frozen=True)
+class AttentionKind:
+    """What a layer's attention may have of its own where a model mixes
+    kinds of layer: the window (None = full causal) and the rotary
+    scaling, fields as `TransformerConfig`'s of the same names (its
+    `attn_window` is `window` here). Hashable: it rides `jax.checkpoint`
+    as a static argument beside the config."""
+    window: Optional[int] = None
+    rope_scaling: str = "none"
+    rope_factor: float = 1.0
+    rope_original: Optional[int] = None
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_attention_factor: Optional[float] = None
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab: int
     dim: int = 256
@@ -78,19 +96,38 @@ class TransformerConfig:
     # the decode bottleneck) shrink by that factor; the cached-attention
     # einsums read the compact cache directly, never expanding it.
     n_kv_heads: Optional[int] = None
-    # rotary context extension for serving beyond the training length:
-    # "none" | "linear" (positions / rope_factor — Chen et al. 2023) |
-    # "ntk" (base * factor^(dh/(dh-2)) — frequency interpolation that
-    # keeps high-frequency dims intact). factor 1.0 = off either way.
+    # rotary context extension beyond the training length, for every
+    # layer: "none" | "linear" (positions / rope_factor — Chen et al.
+    # 2023) | "ntk" (base * factor^(dh/(dh-2)) — frequency interpolation
+    # that keeps high-frequency dims intact) | "yarn" (Peng et al. 2023:
+    # by lane, a blend of the frequency and the frequency / rope_factor
+    # over a ramp between the lanes that turn `rope_beta_fast` and
+    # `rope_beta_slow` times in `rope_original` positions, and cos and
+    # sin times `rope_attention_factor`, 0.1 ln(rope_factor) + 1 where
+    # None; training only). factor 1.0 = off for linear and ntk.
     rope_scaling: str = "none"
     rope_factor: float = 1.0
-    # sliding-window (local) attention: each position attends the last
-    # `attn_window` positions only (None = full causal). The flash path
-    # skips out-of-band blocks in BOTH directions (O(T*window) training
-    # and prefill); generate() decodes over a ROLLING `window`-slot
-    # cache (O(window) memory and per-step HBM reads, r5); beam and
-    # speculative decode keep full-length band-masked buffers.
+    rope_original: Optional[int] = None
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_attention_factor: Optional[float] = None
+    # sliding-window (local) attention in every layer: each position
+    # attends the last `attn_window` positions only (None = full
+    # causal). The flash path skips out-of-band blocks in BOTH
+    # directions (O(T*window) training and prefill); generate() decodes
+    # over a ROLLING `window`-slot cache (O(window) memory and per-step
+    # HBM reads, r5); beam and speculative decode keep full-length
+    # band-masked buffers.
     attn_window: Optional[int] = None
+    # attention kind by layer, read at trace time by the one block body:
+    # `layer_types` names each layer's kind (one entry a layer) and
+    # `attention_kinds` is the table ((name, AttentionKind), ...) that
+    # gives each kind its window and its rotary scaling. With them the
+    # four fields above that describe every layer stay at their
+    # defaults. None: every layer is the kind those fields describe.
+    # Training only (loss, score, apply): the decode helpers refuse it.
+    layer_types: Optional[tuple] = None
+    attention_kinds: Optional[tuple] = None
     remat: bool = False
     # fused chunked cross-entropy: loss() folds the LM-head matmul into
     # a checkpointed scan over `fused_ce_chunk`-position slices so the
@@ -145,6 +182,23 @@ class TransformerConfig:
         if self.norm not in ("layer", "rms"):
             raise ValueError(f"norm must be 'layer' or 'rms', got "
                              f"{self.norm!r}")
+        if (self.layer_types is None) != (self.attention_kinds is None):
+            raise ValueError("layer_types and attention_kinds go together")
+        if self.layer_types is not None:
+            kinds = dict(self.attention_kinds)
+            if (len(self.layer_types) != self.n_layers
+                    or not set(self.layer_types) <= set(kinds)
+                    or not all(isinstance(k, AttentionKind)
+                               for k in kinds.values())):
+                raise ValueError(
+                    f"layer_types needs one entry a layer ({self.n_layers}), "
+                    f"each a name of attention_kinds {sorted(kinds)}, got "
+                    f"{self.layer_types}")
+            if self.attn_window is not None or self.rope_scaling != "none":
+                raise ValueError(
+                    "with layer_types the window and the rotary scaling "
+                    "are the kinds': leave attn_window and rope_scaling "
+                    "unset")
         if self.moe_router == "dropless":
             if not (self.moe_dim and 0 < self.experts_held
                     and 0 <= self.moe_held_first
@@ -181,6 +235,17 @@ class TransformerConfig:
             raise ValueError(
                 f"n_kv_heads {kv} must divide n_heads {self.n_heads}")
         return kv
+
+    def attention_kind(self, i: Optional[int] = None) -> AttentionKind:
+        """Layer i's window and rotary scaling: its kind's where the
+        config has kinds by layer, else (and for i None) what the
+        config's own fields say of every layer."""
+        if self.layer_types is not None and i is not None:
+            return dict(self.attention_kinds)[self.layer_types[i]]
+        return AttentionKind(
+            self.attn_window, self.rope_scaling, self.rope_factor,
+            self.rope_original, self.rope_beta_fast, self.rope_beta_slow,
+            self.rope_attention_factor)
 
     def is_moe_block(self, i: int) -> bool:
         return self.moe_experts > 0 and i % self.moe_every == (
@@ -253,31 +318,42 @@ def _norm(cfg: TransformerConfig, p, x):
 
 def require_decodable(cfg: TransformerConfig) -> None:
     """The decode helpers (generate, beam, speculative, the serving
-    engine) serve the biased-LayerNorm block with dim // n_heads heads:
-    their head and caches are written for it. A config they cannot
-    serve yet is refused here rather than mis-shaped."""
+    engine) serve the biased-LayerNorm block with dim // n_heads heads,
+    one window and one rotary scaling for every layer: their head and
+    caches are written for it. A config they cannot serve yet is
+    refused here rather than mis-shaped."""
     if (cfg.norm != "layer" or not cfg.bias or cfg.qk_norm
-            or cfg.head_size is not None or cfg.moe_router == "dropless"):
+            or cfg.head_size is not None or cfg.moe_router == "dropless"
+            or cfg.layer_types is not None or cfg.rope_scaling == "yarn"):
         raise NotImplementedError(
             "decoding is not implemented for this block (RMSNorm, "
-            "bias-free projections, QK-norm, an explicit head size or a "
-            "dropless / partly held MoE): it trains through loss() and "
+            "bias-free projections, QK-norm, an explicit head size, a "
+            "dropless / partly held MoE, attention kinds by layer "
+            "(layer_types: caches of two sizes in one model) or YaRN "
+            "rotary scaling): it trains through loss() and "
             "block_diffusion_loss() only")
 
 
 def _rope(x, positions, base: float, scaling: str = "none",
-          factor: float = 1.0):
+          factor: float = 1.0, *, original: Optional[int] = None,
+          beta_fast: float = 32.0, beta_slow: float = 1.0,
+          attention_factor: Optional[float] = None):
     """Rotary embedding. x: [B,T,H,Dh] (Dh even), positions: [B,T].
 
     scaling extends usable context past the training length without new
     parameters: "linear" compresses positions by `factor` (every
     frequency slows uniformly); "ntk" rescales the BASE so low
     frequencies stretch while the highest stay near-intact (usually
-    degrades short-context quality less)."""
+    degrades short-context quality less); "yarn" (Peng et al. 2023)
+    slows by `factor` only the lanes that turn fewer than `beta_slow`
+    times in `original` positions, keeps those that turn more than
+    `beta_fast` times, blends linearly by lane between the two, and
+    multiplies cos and sin by `attention_factor` (0.1 ln(factor) + 1
+    where None), so the scores carry its square."""
     dh = x.shape[-1]
-    if scaling not in ("none", "linear", "ntk"):
+    if scaling not in ("none", "linear", "ntk", "yarn"):
         raise ValueError(
-            f"rope_scaling must be none|linear|ntk, got {scaling!r}")
+            f"rope_scaling must be none|linear|ntk|yarn, got {scaling!r}")
     if factor <= 0:
         raise ValueError(f"rope_factor must be > 0, got {factor}")
     if scaling == "linear" and factor != 1.0:
@@ -285,12 +361,37 @@ def _rope(x, positions, base: float, scaling: str = "none",
     elif scaling == "ntk" and factor != 1.0:
         base = base * factor ** (dh / max(dh - 2, 1))
     freqs = base ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
+    if scaling == "yarn":
+        if not original:
+            raise ValueError("yarn needs rope_original, the context the "
+                             "frequencies were trained at")
+
+        def lane(turns):    # the lane that turns `turns` times in `original`
+            return dh * math.log(original / (2 * math.pi * turns)) / (
+                2 * math.log(base))
+
+        low = max(math.floor(lane(beta_fast)), 0)
+        high = min(math.ceil(lane(beta_slow)), dh - 1)
+        ramp = jnp.clip((jnp.arange(dh // 2, dtype=jnp.float32) - low)
+                        / max(high - low, 1e-3), 0.0, 1.0)
+        freqs = freqs / factor * ramp + freqs * (1.0 - ramp)
+        if attention_factor is None:
+            attention_factor = 0.1 * math.log(max(factor, 1.0)) + 1.0
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B,T,Dh/2]
-    cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)
-    sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    dtype = x.dtype
+    if scaling == "yarn":
+        # float32 through the rotation: bfloat16 has no 1.2773, and on
+        # every lane slow enough that cos is 1 at all positions the
+        # factor would read 1.2734, q and k 0.3% short on half their
+        # lanes in every full layer (PERF.md section 6, PR 36)
+        cos, sin = cos * attention_factor, sin * attention_factor
+        x = at_least_f32(x)
+    cos = cos[:, :, None, :].astype(x.dtype)
+    sin = sin[:, :, None, :].astype(x.dtype)
     x1, x2 = x[..., 0::2], x[..., 1::2]
     out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    return out.reshape(x.shape)
+    return out.reshape(x.shape).astype(dtype)
 
 
 def block_diffusion_mask(length: int, bd: int):
@@ -349,7 +450,8 @@ def _expand_kv(q, k, v):
 
 
 def _attention(cfg: TransformerConfig, q, k, v, causal: bool,
-               key_mask=None, key_lens=None, block_diffusion=None):
+               key_mask=None, key_lens=None, block_diffusion=None,
+               kind: Optional[AttentionKind] = None):
     """key_lens [B] describes RIGHT-padded rows (keys [0, lens[b]) are
     real) and rides the flash kernel's per-row bound; key_mask [B, Tk]
     is an arbitrary mask and forces the dense path. They are two
@@ -363,7 +465,9 @@ def _attention(cfg: TransformerConfig, q, k, v, causal: bool,
     bytes too; the caches keep what `_block_parts` returns.
 
     block_diffusion (L, Bd): the training mask over [noised ; clean]
-    copies in place of the causal one (`causal` is then not read)."""
+    copies in place of the causal one (`causal` is then not read).
+    kind: the layer's own window where the config has kinds by layer
+    (None: `cfg.attn_window`)."""
     q, k, v = default_policy().cast_to_compute(q, k, v)
     k, v = _expand_kv(q, k, v)
     if key_mask is not None and key_lens is not None:
@@ -378,7 +482,7 @@ def _attention(cfg: TransformerConfig, q, k, v, causal: bool,
         # anywhere else interpret-mode emulation would be far slower
         # than dense, and a partitioned jit cannot hold the kernel
         impl = "flash" if pallas_util.auto_kernel() else "dense"
-    window = cfg.attn_window
+    window = cfg.attn_window if kind is None else kind.window
     if impl == "flash" and key_mask is not None:
         impl = "dense"      # arbitrary masks: the ONE dense path below
     pallas_util.note_traced("transformer.attention", impl)
@@ -444,7 +548,7 @@ def _ffn(cfg: TransformerConfig, p, y, token_mask=None):
 
 
 def _block_parts(cfg: TransformerConfig, p, x, positions, attn_fn,
-                 token_mask=None):
+                 token_mask=None, kind: Optional[AttentionKind] = None):
     """One pre-LN block with a pluggable attention: attn_fn(q, k, v) ->
     [B,T,H,Dh]. The ONE definition of the block body — apply(), the
     decode prefill and the KV-cache step all run THIS code, so a model
@@ -454,9 +558,18 @@ def _block_parts(cfg: TransformerConfig, p, x, positions, attn_fn,
     attn_fn and the return see COMPACT K/V ([B,T,Hkv,Dh]): caches store
     that form and the cached attention reads it directly; full-H paths
     (_attention's dense/flash, external ring/Ulysses fns) expand at
-    their own entry (`_expand_kv`)."""
+    their own entry (`_expand_kv`). kind: the layer's rotary scaling
+    where the config has kinds by layer (None: the config's own; the
+    window is `attn_fn`'s business)."""
     b, t, d = x.shape
     h, hkv, dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    kind = kind if kind is not None else cfg.attention_kind()
+    rope = functools.partial(
+        _rope, positions=positions, base=cfg.rope_base,
+        scaling=kind.rope_scaling, factor=kind.rope_factor,
+        original=kind.rope_original, beta_fast=kind.rope_beta_fast,
+        beta_slow=kind.rope_beta_slow,
+        attention_factor=kind.rope_attention_factor)
     y = _norm(cfg, p["ln1"], x)
     qkv = linalg.dense(y, p["qkv"]["kernel"], p["qkv"].get("bias"))
     q = qkv[..., :h * dh].reshape(b, t, h, dh)
@@ -464,10 +577,7 @@ def _block_parts(cfg: TransformerConfig, p, x, positions, attn_fn,
     v = qkv[..., (h + hkv) * dh:].reshape(b, t, hkv, dh)
     if cfg.qk_norm:
         q, k = _norm(cfg, p["q_norm"], q), _norm(cfg, p["k_norm"], k)
-    q = _rope(q, positions, cfg.rope_base, cfg.rope_scaling,
-              cfg.rope_factor)
-    k = _rope(k, positions, cfg.rope_base, cfg.rope_scaling,
-              cfg.rope_factor)
+    q, k = rope(q), rope(k)
     a = attn_fn(q, k, v).reshape(b, t, cfg.attn_dim)
     x = x + linalg.dense(a, p["proj"]["kernel"], p["proj"].get("bias"))
     y = _norm(cfg, p["ln2"], x)
@@ -476,10 +586,12 @@ def _block_parts(cfg: TransformerConfig, p, x, positions, attn_fn,
 
 
 def _block(cfg: TransformerConfig, p, x, positions, token_mask=None,
-           attn_fn=None, block_diffusion=None):
+           attn_fn=None, block_diffusion=None,
+           kind: Optional[AttentionKind] = None):
     if attn_fn is None:
         attn_fn = lambda q, k, v: _attention(
-            cfg, q, k, v, causal=True, block_diffusion=block_diffusion)
+            cfg, q, k, v, causal=True, block_diffusion=block_diffusion,
+            kind=kind)
     elif block_diffusion is not None:
         raise ValueError("block_diffusion rides the config's own attention")
     else:
@@ -488,7 +600,7 @@ def _block(cfg: TransformerConfig, p, x, positions, token_mask=None,
         inner = attn_fn
         attn_fn = lambda q, k, v: inner(q, *_expand_kv(q, k, v))
     out, _, _, aux = _block_parts(cfg, p, x, positions, attn_fn,
-                                  token_mask)
+                                  token_mask, kind)
     return out, aux
 
 
@@ -511,12 +623,21 @@ def _forward(params, cfg: TransformerConfig, tokens, positions=None,
             jnp.arange(tokens.shape[1], dtype=jnp.int32), tokens.shape)
     blk = _block
     if cfg.remat:
-        # cfg, attn_fn and the mask are static (non-pytree) arguments
-        blk = jax.checkpoint(_block, static_argnums=(0, 5, 6))
+        # cfg, attn_fn, the mask and the layer's kind are static
+        # (non-pytree) arguments
+        blk = jax.checkpoint(_block, static_argnums=(0, 5, 6, 7))
+    kinds = [cfg.attention_kind(i) for i in range(cfg.n_layers)]
+    if cfg.layer_types is not None:
+        names = list(dict.fromkeys(cfg.layer_types))
+        pallas_util.note_traced("transformer.layer_kinds", ",".join(
+            f"{n}:{cfg.layer_types.count(n)}" for n in names))
+        pallas_util.note_traced("transformer.rope", ",".join(
+            f"{n}:{dict(cfg.attention_kinds)[n].rope_scaling}"
+            for n in names))
     auxes = []
-    for p in params["blocks"]:
+    for p, kind in zip(params["blocks"], kinds):
         x, a = blk(cfg, p, x, positions, token_mask, attn_fn,
-                   block_diffusion)
+                   block_diffusion, kind)
         auxes.append(a)
     if cfg.moe_experts > 0 and cfg.moe_router == "dropless":
         moe_blocks = [a for i, a in enumerate(auxes) if cfg.is_moe_block(i)]
@@ -534,11 +655,13 @@ def apply(params, cfg: TransformerConfig, tokens, positions=None):
     return _forward(params, cfg, tokens, positions)[0]
 
 
-def loss(params, cfg: TransformerConfig, tokens, lengths=None,
-         attn_fn=None):
-    """Next-token cross entropy (+ weighted MoE load-balance aux when
-    the config has experts); positions >= lengths are masked out of the
-    CE term AND of MoE expert capacity/aux accounting."""
+def loss_and_aux(params, cfg: TransformerConfig, tokens, lengths=None,
+                 attn_fn=None):
+    """`loss()` and the forward's auxiliary output: for a dropless MoE
+    its `DroplessStats`, stacked over the layers (what
+    `moe.count_dropless_stats` adds to the timeline's `moe.*` counters,
+    as `block_diffusion_loss` hands them back); for the other routers
+    the summed load-balance term, already in the loss."""
     tmask = None
     if lengths is not None:
         tmask = jnp.arange(
@@ -565,7 +688,18 @@ def loss(params, cfg: TransformerConfig, tokens, lengths=None,
         ce = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1)
     if cfg.moe_experts > 0 and cfg.moe_router != "dropless":
         ce = ce + cfg.moe_aux_weight * aux
-    return ce
+    return ce, aux
+
+
+def loss(params, cfg: TransformerConfig, tokens, lengths=None,
+         attn_fn=None):
+    """Next-token cross entropy over tokens [B, T+1] (+ the weighted
+    load-balance term where the config has "topk" or "expert_choice"
+    experts; a dropless layer has none); positions >= lengths are masked
+    out of the CE term AND of the experts' routing. Every layer attends
+    by its own kind where the config gives kinds by layer.
+    `loss_and_aux` also returns the forward's auxiliary output."""
+    return loss_and_aux(params, cfg, tokens, lengths, attn_fn)[0]
 
 
 def block_diffusion_noise(rng, tokens, block_length: int, *,
@@ -678,9 +812,11 @@ def make_context_parallel_loss(cfg: TransformerConfig, mesh, *,
     """
     from paddle_tpu import parallel as par
 
-    if cfg.attn_window is not None:
+    if any(cfg.attention_kind(i).window is not None
+           for i in range(cfg.n_layers)):
         raise ValueError(
-            "attn_window is not supported under context parallelism: "
+            "attn_window (of the config or of a layer's kind) is not "
+            "supported under context parallelism: "
             "the ring/Ulysses attention has no sliding-band plumbing, "
             "and silently training full-attention would diverge from "
             "every other (windowed) path")

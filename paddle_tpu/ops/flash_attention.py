@@ -27,6 +27,12 @@ framework makes fused O(T) -memory attention a first-class op:
     blocks: 1024 x 1024 where the sequence carries them, smaller where
     it does not, never padding a sequence further than a 256 x 512
     grid would;
+  * a call whose sliding window cuts (narrower than the sequence)
+    names its three kernels apart, `flash_attention_fwd_window`,
+    `flash_attention_bwd_dkv_window` and `flash_attention_bwd_dq_window`
+    (`_name_suffix`), so the trace of a model that mixes windowed and
+    full layers can tell their events; an inert window keeps the plain
+    names;
   * composes with the mesh: wrap in shard_map and the seq axis via
     parallel.ring_attention for context parallelism, or shard heads.
 
@@ -422,6 +428,14 @@ def _attn_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
         lse_ref[0] = m_ref[:] + jnp.log(jnp.maximum(l_ref[:], 1e-30))
 
 
+def _name_suffix(window) -> str:
+    """A call whose band cuts (a window narrower than the sequence: the
+    callers drop an inert one first) names its three kernels apart from
+    a full-causal call's, so a trace of a model that mixes the two can
+    tell their events."""
+    return "_window" if window is not None else ""
+
+
 def _pad_to(x, size, axis):
     pad = size - x.shape[axis]
     if pad == 0:
@@ -450,6 +464,8 @@ def _flash_forward(q, k, v, lens, *, causal: bool, block_q: int,
     vp = _pad_to(v, tk_pad, 1)
     masks = dict(block_q=block_q, block_k=block_k, causal=causal,
                  window=window)
+    if window is not None:
+        pallas_util.note_traced("flash_attention.mask", "window")
     pallas_util.note_traced("flash_attention.fwd_blocks",
                             f"{block_q}x{block_k}")
     pallas_util.note_traced(
@@ -500,7 +516,7 @@ def _flash_forward(q, k, v, lens, *, causal: bool, block_q: int,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=pallas_util.VMEM_LIMIT_BYTES),
         interpret=pallas_util.interpret(),
-        name="flash_attention_fwd",
+        name="flash_attention_fwd" + _name_suffix(window),
     )(lens.astype(jnp.int32), qp, kp, vp)
     return o[:, :t], lse[:, :t, 0]
 
@@ -674,7 +690,7 @@ def _flash_backward(q, k, v, lens, o, lse, g, *, causal: bool,
                    jax.ShapeDtypeStruct((bh, tk_pad, d), v.dtype)],
         compiler_params=params,
         interpret=pallas_util.interpret(),
-        name="flash_attention_bwd_dkv",
+        name="flash_attention_bwd_dkv" + _name_suffix(window),
     )(*operands)
 
     def dq_k_block(b, i, j, lens):
@@ -695,7 +711,7 @@ def _flash_backward(q, k, v, lens, o, lse, g, *, causal: bool,
         out_shape=jax.ShapeDtypeStruct((bh, tq_pad, d), q.dtype),
         compiler_params=params,
         interpret=pallas_util.interpret(),
-        name="flash_attention_bwd_dq",
+        name="flash_attention_bwd_dq" + _name_suffix(window),
     )(*operands)
     return dq[:, :t], dk[:, :t_kv], dv[:, :t_kv]
 

@@ -21,7 +21,8 @@ The tile plan (which group and which row tile a grid step works on,
 scalar-prefetched) and the two kernels follow the grouped-matmul
 kernels that ship with jax (`jax.experimental.pallas.ops.tpu.megablox`),
 cut to what this layer needs: whole groups on one chip, K and N
-multiples of their tiles, an optional transposed `rhs`.
+multiples of a lane tile (or no larger than their tiles), an optional
+transposed `rhs`.
 `grouped_matmul` ties them into one differentiable function.
 """
 
@@ -37,7 +38,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.ops import pallas_util
 
-# (row tile, K tile, N tile); K and N tiles are clipped to the sizes.
+# (row tile, K tile, N tile); K and N tiles are the largest that divide
+# the sizes (`_tiles`).
 # Timed on the v5e at [16 groups, 16,384 held rows of 131,072,
 # 2048 x 768], a layer's three products forward and backward: 11.25 ms
 # at 256 rows, 11.51 at 512, 12.57 at 1024 (PERF.md section 6, PR 34).
@@ -84,11 +86,23 @@ def _rows_of_group(plan, step, tm: int, width: int):
     return (rows >= offsets[g]) & (rows < offsets[g + 1])
 
 
+_LANE = 128
+
+
 def _tiles(size: int, tile: int, what: str):
-    tile = min(tile, size)
-    if size % tile:
-        raise ValueError(f"{what} {size} is no multiple of its tile {tile}")
-    return tile, size // tile
+    """(tile, number of tiles) along K or N: the size itself where it
+    is no larger than `tile`, `tile` where that divides the size, else
+    the largest whole number of lane tiles under `tile` that does (K
+    2304 under 2048: 1152)."""
+    if size <= tile:
+        return size, 1
+    if size % tile == 0:
+        return tile, size // tile
+    for smaller in range(tile - tile % _LANE, 0, -_LANE):
+        if size % smaller == 0:
+            return smaller, size // smaller
+    raise ValueError(f"{what} {size} is a multiple of neither its tile "
+                     f"{tile} nor a lane tile under it")
 
 
 def _pad_rows(x, tile: int):

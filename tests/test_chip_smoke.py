@@ -33,7 +33,12 @@ def test_tiny_runs_every_phase(tmp_path):
     assert lines[0]["compile_cache_dir"] == str(tmp_path / "cache")
     phases = {l["phase"]: l for l in lines if "phase" in l}
     assert list(phases) == ["train_resnet50", "train_transformer",
-                            "serve_http"]
+                            "train_layer_kinds", "serve_http"]
+    kinds = phases["train_layer_kinds"]
+    assert kinds["traced"]["transformer.layer_kinds=sliding:3,full:1"] > 0
+    assert kinds["traced"]["transformer.rope=sliding:none,full:yarn"] > 0
+    assert kinds["traced"]["transformer.ffn=moe_dropless"] > 0
+    assert kinds["counters"]["moe.positions"] == 4 * 2 * 64
     assert phases["train_resnet50"]["sharded_over"] == 2
     serve = phases["serve_http"]
     assert serve["traced"]["ragged_attention=jnp"] > 0

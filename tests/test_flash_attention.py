@@ -2,6 +2,8 @@
 the cross-backend equivalence strategy of the reference's
 test_NetworkCompare.cpp applied to the TPU kernel."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -171,6 +173,54 @@ class TestSlidingWindow:
         for a, b in zip(gf, gd):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-4, atol=2e-4)
+
+    @pytest.mark.parametrize("window,blocks", [
+        (40, (16, 16)),     # a band that cuts several blocks a row
+        (40, (16, 32)),
+        (33, (32, 16)),
+    ])
+    def test_a_band_over_several_blocks_matches_the_models_dense_path(
+            self, np_rng, window, blocks):
+        """Forward and gradients against `transformer._dense_attention`
+        (what `attn_impl="dense"` runs for a windowed layer), at T 112:
+        rows whose band starts inside one block and ends inside
+        another, with whole blocks between and skipped ones before."""
+        from paddle_tpu.models.transformer import _dense_attention
+
+        q, k, v = _qkv(np_rng, b=1, t=112, h=2, d=8)
+        w = jnp.asarray(np_rng.randn(*q.shape), jnp.float32)
+        flash = lambda q, k, v: flash_attention(
+            q, k, v, causal=True, window=window, block_q=blocks[0],
+            block_k=blocks[1], bwd_block_q=blocks[0], bwd_block_k=blocks[1])
+        dense = lambda q, k, v: _dense_attention(q, k, v, True, None, window)
+        np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                                   np.asarray(dense(q, k, v)),
+                                   rtol=2e-5, atol=2e-5)
+        got = jax.grad(lambda *a: jnp.sum(flash(*a) * w), (0, 1, 2))(q, k, v)
+        want = jax.grad(lambda *a: jnp.sum(dense(*a) * w), (0, 1, 2))(q, k, v)
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-4)
+
+    @pytest.mark.parametrize("window,suffix", [(40, "_window"), (112, ""),
+                                               (None, "")])
+    def test_a_window_that_cuts_names_its_kernels_apart(self, window,
+                                                        suffix):
+        """A trace of a model that mixes band and full layers tells
+        their events by name; an inert window keeps today's names."""
+        from paddle_tpu.ops import pallas_util
+
+        x = jax.ShapeDtypeStruct((1, 112, 2, 8), jnp.float32)
+        before = pallas_util.traced().get("flash_attention.mask=window", 0)
+        text = jax.jit(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, window=window)), (0, 1, 2))).trace(
+                x, x, x).jaxpr.pretty_print(use_color=False)
+        assert re.findall(r"name=(flash\w+)", text) == [
+            kernel + suffix for kernel in (
+                "flash_attention_fwd", "flash_attention_bwd_dkv",
+                "flash_attention_bwd_dq")]
+        after = pallas_util.traced().get("flash_attention.mask=window", 0)
+        assert (after > before) == bool(suffix)
 
     def test_validation(self, np_rng):
         q, k, v = _qkv(np_rng, b=1, t=8, h=1, d=8)
@@ -455,6 +505,11 @@ def test_three_kinds_of_block_match_dense(np_rng, t, causal, window, lens,
     # the dense cell's own call: 4095 positions, its window inert
     ((2, 4095, 24, 128), dict(causal=True, window=4096),
      "interior:6,cut:4,skipped:6"),
+    # mellum2_12b_a2p5b_ep4.train_seq8k: a band of 1024 (every needed
+    # block is cut at 1024 x 1024, two a row) and its full layer
+    ((2, 8192, 32, 128), dict(causal=True, window=1024),
+     "interior:0,cut:15,skipped:49"),
+    ((2, 8192, 32, 128), dict(causal=True), "interior:28,cut:8,skipped:28"),
 ])
 def test_forward_counters_at_the_cells_shapes(shape, kw, kinds):
     """Traced abstractly, the forward says which blocks it took and how

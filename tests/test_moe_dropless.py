@@ -47,6 +47,8 @@ def _layer(seed, n_experts, n_held, d=32, f=16, t=32):
     (8, 8, 0, 2),           # top-2 of 8, all held
     (128, 16, 0, 8),        # the cell's share: top-8 of 128, 16 held
     (128, 16, 48, 8),       # another chip's share
+    (64, 16, 0, 8),         # top-8 of 64, 16 held: a 4-way share
+    (64, 16, 32, 8),        # the third chip of the four
 ])
 def test_values_and_gradients(n_experts, n_held, first, k):
     params, x = _layer(0, n_experts, n_held)
@@ -125,17 +127,18 @@ def test_masked_positions_route_nowhere():
     assert int(out.stats.rows_held) == 2 * int(mask.sum())
 
 
-def test_the_eight_shares_add_up_to_the_whole_layer():
-    """The share test: a chip of an 8-way expert-parallel layer routes
-    over all 128 experts and computes its 16; the eight partial results
-    add up to the uncut layer."""
-    whole, x = _layer(4, 128, 128)
+@pytest.mark.parametrize("n_experts,chips", [(128, 8), (64, 4)])
+def test_the_shares_add_up_to_the_whole_layer(n_experts, chips):
+    """The share test: a chip of an 8-way (4-way) expert-parallel layer
+    routes over all 128 (64) experts and computes its 16; the partial
+    results of all the shares add up to the uncut layer."""
+    whole, x = _layer(4, n_experts, n_experts)
     uncut = every_expert(whole, x, 8)
     np.testing.assert_allclose(
         np.asarray(moe.dropless_ffn(whole, x, k=8).y), np.asarray(uncut),
         rtol=1e-4, atol=1e-5)
     total, rows = jnp.zeros_like(x), 0
-    for chip in range(8):
+    for chip in range(chips):
         share = {"router": whole["router"],
                  **{name: whole[name][16 * chip:16 * chip + 16]
                     for name in ("w_gate", "w_up", "w_down")}}
@@ -150,12 +153,16 @@ def test_the_eight_shares_add_up_to_the_whole_layer():
     ([10, 0, 23, 5], 8), ([0, 0, 0, 0], 8), ([16, 16, 16, 16], 16),
     ([1, 62, 0, 1], 16), ([0, 3, 0, 0], 32),
 ])
-def test_grouped_kernels_against_plain_products(np_rng, sizes, tm):
-    m, k, n, g = 64, 16, 24, len(sizes)
+@pytest.mark.parametrize("k,tiles", [
+    (16, (8, 8)),
+    (384, (256, 8)),    # K no multiple of its tile: three lane tiles of 128
+])
+def test_grouped_kernels_against_plain_products(np_rng, sizes, tm, k, tiles):
+    m, n, g = 64, 24, len(sizes)
     lhs = jnp.asarray(np_rng.randn(m, k), jnp.float32)
     rhs = jnp.asarray(np_rng.randn(g, k, n), jnp.float32)
     dout = jnp.asarray(np_rng.randn(m, n), jnp.float32)
-    tiling = (tm, 8, 8)
+    tiling = (tm,) + tiles
     out = G.moe_grouped_matmul(lhs, rhs, jnp.asarray(sizes, jnp.int32),
                                tiling=tiling)
     back = G.moe_grouped_matmul(dout, rhs, jnp.asarray(sizes, jnp.int32),
@@ -163,14 +170,32 @@ def test_grouped_kernels_against_plain_products(np_rng, sizes, tm):
     dw = G.moe_grouped_matmul_dw(lhs, dout, jnp.asarray(sizes, jnp.int32),
                                  tiling=tiling)
     off = np.concatenate([[0], np.cumsum(sizes)])
+    # float32 sums in another order: the rounding grows with the root
+    # of the terms summed (1e-5 at the 16 of the first case)
+    close = dict(rtol=1e-5, atol=1e-5 * (k / 16) ** 0.5)
     for i in range(g):
         rows = slice(off[i], off[i + 1])
-        np.testing.assert_allclose(out[rows], lhs[rows] @ rhs[i],
-                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(out[rows], lhs[rows] @ rhs[i], **close)
         np.testing.assert_allclose(back[rows], dout[rows] @ rhs[i].T,
-                                   rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(dw[i], lhs[rows].T @ dout[rows],
-                                   rtol=1e-5, atol=1e-5)
+                                   **close)
+        np.testing.assert_allclose(dw[i], lhs[rows].T @ dout[rows], **close)
+
+
+@pytest.mark.parametrize("size,tile,want", [
+    (768, 1024, (768, 1)),      # no larger than its tile: itself
+    (2048, 1024, (1024, 2)),    # a multiple of its tile
+    (2304, 2048, (1152, 2)),    # neither: the largest lane multiple under it
+    (2304, 1024, (768, 3)),
+    (896, 1024, (896, 1)),
+    (48, 2048, (48, 1)),        # the tiny sizes of the tests
+])
+def test_tiles_divide_the_size(size, tile, want):
+    assert G._tiles(size, tile, "K") == want
+
+
+def test_a_size_no_lane_tile_divides_is_refused():
+    with pytest.raises(ValueError, match="multiple of neither"):
+        G._tiles(2000, 1024, "N")
 
 
 def test_counts_reach_the_timeline():

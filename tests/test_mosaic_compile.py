@@ -48,6 +48,10 @@ def _compiled_not_interpreted(monkeypatch):
     (48, 4095, 128, jnp.bfloat16, 4096, False, (1024, 1024)),
     (48, 4096, 128, jnp.bfloat16, None, False, (1024, 1024)),
     (24, 8192, 128, jnp.bfloat16, 4096, False, (1024, 1024)),  # band active
+    # mellum2_12b_a2p5b_ep4.train_seq8k: 2 x 32 heads, a band of 1024
+    # (every needed block cut), and its full-causal layer
+    (64, 8192, 128, jnp.bfloat16, 1024, False, (1024, 1024)),
+    (64, 8192, 128, jnp.bfloat16, None, False, (1024, 1024)),
     (16, 2048, 64, jnp.bfloat16, 512, True, (1024, 1024)),  # serving width
     (16, 2048, 64, jnp.bfloat16, None, True, (1024, 1024)),  # a prefill
     (16, 2048, 128, jnp.float32, None, False, (1024, 1024)),  # f32 policy
@@ -74,10 +78,13 @@ def test_flash_forward_and_backward_compile_for_v5e(one_chip, bh, t, d,
         x, x, x, key_lens).compile()
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
-    # under `grad` the forward's instruction is the jvp's
+    # under `grad` the forward's instruction is the jvp's; a window
+    # that cuts names its kernels apart, an inert one does not
+    cut = window is not None and window < t
     for name in ("jvp_flash_attention_fwd_", "flash_attention_bwd_dkv",
                  "flash_attention_bwd_dq"):
         assert f"%{name}" in text, name
+        assert (f"%{name}_window" in text or f"%{name}window" in text) == cut
 
 
 @pytest.mark.parametrize("length,bd,bh", [
@@ -104,13 +111,17 @@ def test_block_diffusion_flash_compiles_for_v5e(one_chip, length, bd, bh):
         assert f"%{name}" in text, name
 
 
-def test_grouped_expert_products_compile_for_v5e(one_chip):
+@pytest.mark.parametrize("d,f", [
+    (2048, 768),        # sdar_30b_a3b_ep8
+    (2304, 896),        # mellum2_12b_a2p5b_ep4: K 2304 in two tiles of 1152
+])
+def test_grouped_expert_products_compile_for_v5e(one_chip, d, f):
     """The dropless layer's three grouped products and their gradients
-    at the cell's shapes: 16 experts held, a buffer of 16,384 positions
-    x 8 choices rows, 2048 x 768."""
+    at the cells' shapes: 16 experts held, a buffer of 16,384 positions
+    x 8 choices rows."""
     from paddle_tpu.ops import moe_grouped_matmul as G
 
-    rows, d, f, held = 16384 * 8, 2048, 768, 16
+    rows, held = 16384 * 8, 16
     spec = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
         shape, dtype, sharding=one_chip)
 

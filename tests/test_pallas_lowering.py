@@ -88,9 +88,11 @@ def test_flash_fwd_bwd_lowers(batch, t, heads, head_dim, window, lens):
     # kernels on their own blocks; every tensor operand that carries q,
     # k, v or the output's cotangent is bf16, lse and delta float32 rows
     (fwd_name, fwd), (dkv_name, dkv), (dq_name, dq) = _kernels(text)
+    # a window that cuts names its kernels apart; an inert one does not
+    cut = "_window" if window is not None and window < t else ""
     assert (fwd_name, dkv_name, dq_name) == (
-        "flash_attention_fwd", "flash_attention_bwd_dkv",
-        "flash_attention_bwd_dq")
+        "flash_attention_fwd" + cut, "flash_attention_bwd_dkv" + cut,
+        "flash_attention_bwd_dq" + cut)
     bh = batch * heads
     # q padded to whole q blocks, k and v to whole k blocks, of the
     # chooser's (1024 x 1024 at these lengths: 4095 becomes 4096)
@@ -185,6 +187,46 @@ def test_block_diffusion_model_lowers_with_its_kernels():
     assert traced["flash_attention.mask=block_diffusion"] > 0
     assert traced["transformer.ffn=moe_dropless"] > 0
     assert traced["moe.expert_matmul=pallas_grouped"] > 0
+
+
+def test_model_with_kinds_by_layer_lowers_with_its_kernels():
+    """The third LM configuration's real path: grad of `T.loss_and_aux`
+    on a block whose layers are sliding, sliding, sliding, full, with
+    YaRN on the full one and a dropless MoE that holds a quarter of its
+    experts, under the bf16 policy. The three band layers launch the
+    flash kernels under their `_window` names, the full layer under the
+    plain ones: three to one, the forward twice (remat)."""
+    dtypes.set_default_policy(dtypes.bf16_compute_policy())
+    jax.clear_caches()
+    pallas_util._traced.clear()
+    kinds = (("sliding", T.AttentionKind(window=128)),
+             ("full", T.AttentionKind(rope_scaling="yarn", rope_factor=16.0,
+                                      rope_original=256)))
+    cfg = T.TransformerConfig(
+        vocab=128, dim=256, n_heads=4, n_kv_heads=2, head_size=128,
+        n_layers=4, norm="rms", bias=False, qk_norm=True,
+        layer_types=("sliding",) * 3 + ("full",), attention_kinds=kinds,
+        moe_router="dropless", moe_experts=16, moe_every=1, moe_k=2,
+        moe_dim=128, moe_held=4, attn_impl="auto", remat=True,
+        fused_ce_chunk=128)
+    params = jax.eval_shape(lambda: T.init_params(jax.random.key(0), cfg))
+    text = _lowered_text(
+        jax.grad(lambda p, toks: T.loss_and_aux(p, cfg, toks)[0]), params,
+        _sds((2, 513), jnp.int32))
+    names = [name for name, _ in _kernels(text)]
+    band = ["flash_attention_fwd_window"] * 2 + [
+        "flash_attention_bwd_dkv_window", "flash_attention_bwd_dq_window"]
+    full = ["flash_attention_fwd"] * 2 + ["flash_attention_bwd_dkv",
+                                          "flash_attention_bwd_dq"]
+    experts = ["moe_grouped_matmul"] * 9 + ["moe_grouped_matmul_dw"] * 3
+    assert sorted(names) == sorted(3 * band + full + 4 * experts)
+    traced = pallas_util.traced()
+    assert traced["transformer.layer_kinds=sliding:3,full:1"] > 0
+    assert traced["transformer.rope=sliding:none,full:yarn"] > 0
+    assert traced["flash_attention.mask=window"] > 0
+    # a (batch x head) row of the band call and of the full call
+    assert traced["flash_attention.fwd_block_kinds="
+                  "interior:0,cut:1,skipped:0"] > 0
 
 
 @pytest.mark.parametrize("name,run,init,hidden,t", [

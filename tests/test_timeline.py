@@ -601,5 +601,7 @@ def test_no_pallas_call_in_ops_is_unnamed():
     for path in pathlib.Path(ops.__file__).parent.glob("*.py"):
         src = path.read_text()
         calls += len(re.findall(r"\bpl\.pallas_call\(", src))
-        names += len(re.findall(r"^\s+name=\"\w+\",$", src, re.M))
+        # a flash call whose window cuts adds a suffix to its name
+        names += len(re.findall(
+            r"^\s+name=\"\w+\"( \+ _name_suffix\(window\))?,$", src, re.M))
     assert calls == names == 13
