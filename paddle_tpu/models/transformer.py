@@ -11,8 +11,21 @@ framework needs one. TPU-first choices:
 - Pallas flash attention (`ops.flash_attention`) when requested /
   on TPU, exact dense fallback elsewhere — O(T·block) memory makes
   32k+ contexts feasible on one chip,
-- optional `jax.checkpoint` over each block (remat trades FLOPs for
-  HBM on long sequences),
+- optional `jax.checkpoint` over each block (`remat` trades FLOPs for
+  HBM on long sequences). A checkpointed block keeps its input, 2 x
+  dim bytes a position, and where its attention ran the flash kernel
+  also the kernel's output and row log-sum-exp, by the names the kernel
+  gives them (`flash_attention.REMAT_SAVED`): 2 H Dh + 4 H bytes a
+  position a layer in bf16, 8.3 KB at 32 heads of 128, about twice what
+  the block kept before. The backward pass recomputes the block around
+  the kernel (norms, projections, rotary, transposes, the feed-forward
+  or expert layer) and runs the attention forward once a layer a step;
+  a plain checkpoint ran the kernel twice. The price is memory: a
+  `remat` that was set to fit a long context fits a somewhat shorter
+  one and trains faster (at a stage of 4-6 layers a chip the kept
+  arrays are under 5% of a 16 GB chip). Dense and external (ring /
+  Ulysses) attention name nothing, and such a block keeps its input
+  alone,
 - parameter names line up with `parallel.sharding.MEGATRON_RULES`
   (qkv/fc1 shard output features, proj/fc2 shard input features) so
   the same pytree drives dp x tp through
@@ -37,7 +50,7 @@ from paddle_tpu.ops import losses as losses_ops
 from paddle_tpu.ops import norm as norm_ops
 from paddle_tpu.ops import pallas_util
 from paddle_tpu.ops import sampling as sampling_ops
-from paddle_tpu.ops.flash_attention import flash_attention
+from paddle_tpu.ops.flash_attention import REMAT_SAVED, flash_attention
 from paddle_tpu.parallel.sharding import MEGATRON_RULES, MODEL_AXIS
 
 from jax.sharding import PartitionSpec as P
@@ -604,6 +617,20 @@ def _block(cfg: TransformerConfig, p, x, positions, token_mask=None,
     return out, aux
 
 
+def _remat_block(*args):
+    """`_block` as `cfg.remat` checkpoints it. Notes, where the block
+    is traced, what the checkpoint keeps of it beside its input: that
+    follows from the attention the trace took (the flash kernel names
+    its outputs or nobody does)."""
+    flash = "transformer.attention=flash"
+    before = pallas_util.traced().get(flash, 0)
+    out = _block(*args)
+    ran = pallas_util.traced().get(flash, 0) > before
+    pallas_util.note_traced(
+        "transformer.remat_saved", ",".join(REMAT_SAVED) if ran else "none")
+    return out
+
+
 def _forward(params, cfg: TransformerConfig, tokens, positions=None,
              token_mask=None, attn_fn=None, return_hidden=False,
              block_diffusion=None):
@@ -623,9 +650,14 @@ def _forward(params, cfg: TransformerConfig, tokens, positions=None,
             jnp.arange(tokens.shape[1], dtype=jnp.int32), tokens.shape)
     blk = _block
     if cfg.remat:
-        # cfg, attn_fn, the mask and the layer's kind are static
-        # (non-pytree) arguments
-        blk = jax.checkpoint(_block, static_argnums=(0, 5, 6, 7))
+        # the block keeps its input and what the flash kernel names (its
+        # output and log-sum-exp): the backward pass recomputes the block
+        # around the kernel, not the kernel. cfg, attn_fn, the mask and
+        # the layer's kind are static (non-pytree) arguments
+        blk = jax.checkpoint(
+            _remat_block, static_argnums=(0, 5, 6, 7),
+            policy=jax.checkpoint_policies.save_only_these_names(
+                *REMAT_SAVED))
     kinds = [cfg.attention_kind(i) for i in range(cfg.n_layers)]
     if cfg.layer_types is not None:
         names = list(dict.fromkeys(cfg.layer_types))

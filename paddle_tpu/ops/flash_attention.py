@@ -33,6 +33,12 @@ framework makes fused O(T) -memory attention a first-class op:
     (`_name_suffix`), so the trace of a model that mixes windowed and
     full layers can tell their events; an inert window keeps the plain
     names;
+  * a differentiated call names the forward kernel's two outputs, o
+    and the row log-sum-exp (`REMAT_SAVED`), so a `jax.checkpoint`
+    whose policy saves those names (the transformer's `remat`) keeps
+    them and runs the forward kernel once; a plain checkpoint runs it
+    twice, the second time only to hand the backward kernels the same
+    two arrays;
   * composes with the mesh: wrap in shard_map and the seq axis via
     parallel.ring_attention for context parallelism, or shard heads.
 
@@ -52,12 +58,16 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.ops import pallas_util
 
 NEG_INF = -1e30
+# the names a differentiated call gives the forward kernel's output and
+# its row log-sum-exp, for a checkpoint's policy (see `flash_attention`)
+REMAT_SAVED = ("flash_attention_out", "flash_attention_lse")
 
 # the backward kernels' own blocks (q rows x k rows of one grid step),
 # chosen by timing the pair alone on the v5e at bf16[48, 4096, 128],
@@ -730,6 +740,10 @@ def _flash_fwd(q, k, v, lens_f, causal, block_q, block_k, window,
     o, lse = _flash_forward(q, k, v, lens_f, causal=causal, block_q=block_q,
                             block_k=block_k, window=window,
                             block_diffusion=block_diffusion)
+    # both outputs of the one kernel call: a checkpoint that kept only o
+    # would still run the call for lse when it recomputes
+    o = checkpoint_name(o, REMAT_SAVED[0])
+    lse = checkpoint_name(lse, REMAT_SAVED[1])
     return o, (q, k, v, lens_f, o, lse)
 
 
@@ -790,6 +804,16 @@ def flash_attention(q, k, v, *, causal: bool = False,
     blocks: see `_pair_mask`. Not causal, no window, no key_lens. A
     kernel block with no admitted pair (about half of them, in two
     runs a row) is skipped and fetches nothing, in all three kernels.
+
+    Under `jax.checkpoint`: a differentiated call names its output and
+    its row log-sum-exp (`REMAT_SAVED`), the residuals the backward
+    kernels need beside q, k and v. A checkpoint whose policy is
+    `save_only_these_names(*REMAT_SAVED)` keeps the two, 2 H D + 4 H
+    bytes a position in bf16 (8.3 KB at 32 heads of 128), and its
+    backward pass recomputes what surrounds the forward kernel without
+    running it again; a plain checkpoint runs the kernel twice. Outside
+    a checkpoint the names are the identity, and a call that is not
+    differentiated (inference, a prefill) carries none.
     """
     if q.ndim != 4:
         raise ValueError(f"expected [B, T, H, D], got {q.shape}")

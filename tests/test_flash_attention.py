@@ -30,6 +30,49 @@ def test_matches_dense(np_rng, causal):
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("kw", [
+    dict(causal=True), dict(causal=True, window=20),
+    dict(block_diffusion=(24, 4)),
+], ids=["causal", "window", "block_diffusion"])
+def test_names_for_a_checkpoint_are_the_identity_outside_one(
+        np_rng, monkeypatch, kw):
+    """A differentiated call names the forward kernel's output and
+    log-sum-exp (`REMAT_SAVED`) for a checkpoint's policy; with no
+    checkpoint around it the value and the gradients are those of a
+    call that names nothing, bit for bit, and an undifferentiated call
+    carries no name."""
+    from paddle_tpu.ops import flash_attention as fa
+
+    q, k, v = _qkv(np_rng)
+    w = jnp.asarray(np_rng.randn(*q.shape), jnp.float32)
+    f = jax.value_and_grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, block_q=16, block_k=16, bwd_block_q=16, bwd_block_k=16,
+        **kw) * w), (0, 1, 2))
+
+    def names(jaxpr):
+        return [e.params["name"] for e in jaxpr.eqns
+                if e.primitive.name == "name"] + [
+            n for e in jaxpr.eqns
+            for sub in jax.core.jaxprs_in_params(e.params)
+            for n in names(sub)]
+
+    named = f(q, k, v)
+    assert sorted(names(jax.make_jaxpr(f)(q, k, v).jaxpr)) == sorted(
+        fa.REMAT_SAVED)
+    assert names(jax.make_jaxpr(lambda *a: flash_attention(*a, **kw))(
+        q, k, v).jaxpr) == []
+    monkeypatch.setattr(fa, "checkpoint_name", lambda x, name: x)
+    jax.clear_caches()      # jax caches the traced forward rule
+    try:
+        assert names(jax.make_jaxpr(f)(q, k, v).jaxpr) == []
+        nameless = f(q, k, v)
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    for a, b in zip(jax.tree.leaves(named), jax.tree.leaves(nameless)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_non_divisible_lengths(np_rng):
     # T not a multiple of the block: tail masking must be exact
     q, k, v = _qkv(np_rng, t=37, t_kv=53)
@@ -215,7 +258,10 @@ class TestSlidingWindow:
         text = jax.jit(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
             q, k, v, causal=True, window=window)), (0, 1, 2))).trace(
                 x, x, x).jaxpr.pretty_print(use_color=False)
-        assert re.findall(r"name=(flash\w+)", text) == [
+        # the kernels' names, not those the output and the log-sum-exp
+        # take for a checkpoint (`REMAT_SAVED`)
+        assert re.findall(r"name=(flash_attention_(?:fwd|bwd)\w*)",
+                          text) == [
             kernel + suffix for kernel in (
                 "flash_attention_fwd", "flash_attention_bwd_dkv",
                 "flash_attention_bwd_dq")]

@@ -13,6 +13,8 @@ import, so every xdist worker collects the same tests and only the
 worker given this file loads it).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -137,3 +139,51 @@ def test_grouped_expert_products_compile_for_v5e(one_chip, d, f):
     # 3 forward products, 3 input gradients, 3 weight gradients
     assert text.count('custom_call_target="tpu_custom_call"') == 9
     assert "moe_grouped_matmul_dw" in text
+
+
+@pytest.mark.parametrize("case,kw", [
+    ("causal", dict()),
+    ("window", dict(attn_window=512)),
+    ("block_diffusion", dict()),
+])
+def test_checkpointed_block_runs_the_forward_kernel_once_for_v5e(
+        one_chip, case, kw):
+    """`value_and_grad` of a 2-layer `remat` model at 2 x 2048
+    positions, 4 heads of 128: a checkpointed block keeps the forward
+    kernel's output and log-sum-exp by name, so the compiled program
+    holds n forward kernels and n of each backward kernel; under a
+    plain checkpoint it held 2n forward ones."""
+    from paddle_tpu.core import dtypes
+    from paddle_tpu.models import transformer as T
+
+    cfg = T.TransformerConfig(vocab=512, dim=512, n_layers=2, n_heads=4,
+                              n_kv_heads=2, mlp_ratio=1, attn_impl="flash",
+                              remat=True, **kw)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    shapes = jax.eval_shape(lambda: T.init_params(jax.random.key(0), cfg))
+    if case == "block_diffusion":
+        batch = (sds((2, 1024), jnp.int32), sds((2, 1024), jnp.bool_),
+                 sds((2, 1024), jnp.float32))
+        loss = lambda p, toks, masked, prob: T.block_diffusion_loss(
+            p, cfg, toks, masked, prob, block_length=4)[0]
+    else:
+        batch = (sds((2, 2049), jnp.int32),)
+        loss = lambda p, toks: T.loss(p, cfg, toks)
+    prev = dtypes.default_policy()
+    dtypes.set_default_policy(dtypes.bf16_compute_policy())
+    try:
+        text = jax.jit(jax.value_and_grad(loss)).lower(
+            jax.tree.map(lambda x: sds(x.shape, x.dtype), shapes),
+            *batch).compile().as_text()
+    finally:
+        dtypes.set_default_policy(prev)
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == 3 * cfg.n_layers
+    # the instructions' names: `%[jvp_]flash_attention_<kernel>[_window][_][.n] =`
+    suffix = "_window" if case == "window" else ""
+    kernels = re.findall(
+        r"^\s*%(?:jvp_)?flash_attention_(fwd|bwd_dkv|bwd_dq)" + suffix
+        + r"_?(?:\.\d+)? = ", text, re.M)
+    assert sorted(kernels) == sorted(
+        ["fwd", "bwd_dkv", "bwd_dq"] * cfg.n_layers), kernels

@@ -128,12 +128,13 @@ def test_model_hands_flash_the_policys_dtype(bf16):
     text = _lowered_text(jax.grad(lambda p, toks: T.loss(p, cfg, toks)),
                          params, _sds((2, 257), jnp.int32))
     calls = _kernels(text)
-    # 2 layers: the forward, again under remat, and the two backward
+    # 2 layers: the forward, once (the checkpointed block keeps its
+    # output and log-sum-exp by name, PR 38), and the two backward
     # kernels of each
     names = [name for name, _ in calls]
     assert sorted(names) == sorted(
-        ["flash_attention_fwd", "flash_attention_fwd",
-         "flash_attention_bwd_dkv", "flash_attention_bwd_dq"] * 2)
+        ["flash_attention_fwd", "flash_attention_bwd_dkv",
+         "flash_attention_bwd_dq"] * 2)
     want = "bf16" if bf16 else "f32"
     wide, row = f"tensor<4x256x128x{want}>", "tensor<4x1x256xf32>"
     for name, operands in calls:
@@ -147,10 +148,10 @@ def test_block_diffusion_model_lowers_with_its_kernels():
     `T.block_diffusion_loss` on an RMSNorm, bias-free, QK-normed block
     with heads of their own size and a dropless MoE that holds half of
     its experts, under the bf16 policy. A layer launches the flash
-    forward (again under remat) and its two backward kernels on bf16
-    operands over 2L positions, and the grouped products: three
-    forward, three again under remat, three input gradients, three
-    weight gradients."""
+    forward (once: the checkpointed block keeps what the kernel names)
+    and its two backward kernels on bf16 operands over 2L positions,
+    and the grouped products: three forward, three again under remat,
+    three input gradients, three weight gradients."""
     dtypes.set_default_policy(dtypes.bf16_compute_policy())
     jax.clear_caches()
     pallas_util._traced.clear()
@@ -173,8 +174,8 @@ def test_block_diffusion_model_lowers_with_its_kernels():
     calls = _kernels(text)
     names = [name for name, _ in calls]
     assert sorted(names) == sorted(
-        (["flash_attention_fwd"] * 2 + ["flash_attention_bwd_dkv",
-                                        "flash_attention_bwd_dq"]
+        (["flash_attention_fwd", "flash_attention_bwd_dkv",
+          "flash_attention_bwd_dq"]
          + ["moe_grouped_matmul"] * 9 + ["moe_grouped_matmul_dw"] * 3) * 2)
     wide = f"tensor<8x{2 * length}x128xbf16>"       # B * H, 2L, head size
     for name, operands in calls:
@@ -195,7 +196,8 @@ def test_model_with_kinds_by_layer_lowers_with_its_kernels():
     YaRN on the full one and a dropless MoE that holds a quarter of its
     experts, under the bf16 policy. The three band layers launch the
     flash kernels under their `_window` names, the full layer under the
-    plain ones: three to one, the forward twice (remat)."""
+    plain ones: three to one, the forward once a layer (the
+    checkpointed block keeps its output and log-sum-exp)."""
     dtypes.set_default_policy(dtypes.bf16_compute_policy())
     jax.clear_caches()
     pallas_util._traced.clear()
@@ -214,10 +216,10 @@ def test_model_with_kinds_by_layer_lowers_with_its_kernels():
         jax.grad(lambda p, toks: T.loss_and_aux(p, cfg, toks)[0]), params,
         _sds((2, 513), jnp.int32))
     names = [name for name, _ in _kernels(text)]
-    band = ["flash_attention_fwd_window"] * 2 + [
-        "flash_attention_bwd_dkv_window", "flash_attention_bwd_dq_window"]
-    full = ["flash_attention_fwd"] * 2 + ["flash_attention_bwd_dkv",
-                                          "flash_attention_bwd_dq"]
+    band = ["flash_attention_fwd_window",
+            "flash_attention_bwd_dkv_window", "flash_attention_bwd_dq_window"]
+    full = ["flash_attention_fwd", "flash_attention_bwd_dkv",
+            "flash_attention_bwd_dq"]
     experts = ["moe_grouped_matmul"] * 9 + ["moe_grouped_matmul_dw"] * 3
     assert sorted(names) == sorted(3 * band + full + 4 * experts)
     traced = pallas_util.traced()

@@ -7,6 +7,8 @@ impl-vs-impl equivalence (KV-cache decode vs teacher forcing, sharded vs
 single-device) and gradient checks.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -76,6 +78,103 @@ def test_overfits_tiny_batch(params):
         if first is None:
             first = float(l)
     assert float(l) < first * 0.7, (first, float(l))
+
+
+def _forward_kernel_calls(jaxpr) -> int:
+    """The flash forward kernel's `pallas_call`s of a jaxpr, whichever
+    sub-jaxpr (checkpoint, custom_vjp, jit) holds them."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            n += eqn.params["name"].startswith("flash_attention_fwd")
+        n += sum(_forward_kernel_calls(sub)
+                 for sub in jax.core.jaxprs_in_params(eqn.params))
+    return n
+
+
+def _remat_saved_noted(before: dict) -> list:
+    """The `transformer.remat_saved=` counters written since `before`."""
+    return sorted(k for k, v in pallas_util.traced().items()
+                  if k.startswith("transformer.remat_saved=")
+                  and v > before.get(k, 0))
+
+
+REMAT_NAMES = "transformer.remat_saved=flash_attention_out,flash_attention_lse"
+
+
+@pytest.mark.parametrize("case,kw", [
+    ("causal", dict()),
+    ("window", dict(attn_window=5)),            # cuts the 12 positions
+    ("block_diffusion", dict()),
+])
+def test_remat_keeps_what_the_flash_kernel_names(params, monkeypatch, case,
+                                                 kw):
+    """A checkpointed block keeps the kernel's output and log-sum-exp by
+    name, so `value_and_grad` holds the forward kernel once a layer
+    where a plain `jax.checkpoint` holds it twice, and the kept arrays
+    are the ones the recomputation would produce: loss and gradients
+    equal those of the plain checkpoint and of no checkpoint bit for
+    bit."""
+    jax.clear_caches()      # `jax.checkpoint` keeps a block's trace
+    plain = T.TransformerConfig(vocab=61, dim=32, n_layers=2, n_heads=4,
+                                attn_impl="flash", **kw)
+    remat = dataclasses.replace(plain, remat=True)
+    toks = jnp.asarray(np.random.RandomState(5).randint(0, 60, (2, 13)))
+    if case == "block_diffusion":
+        toks = toks[:, :8]
+        masked, prob = T.block_diffusion_noise(jax.random.key(1), toks, 4)
+        loss = lambda cfg: lambda q: T.block_diffusion_loss(
+            q, cfg, toks, masked, prob, block_length=4)[0]
+    else:
+        loss = lambda cfg: lambda q: T.loss(q, cfg, toks)
+
+    def run(cfg):
+        # compiled unoptimised: optimised, XLA fuses the three programs
+        # apart and a sum's order (its last digit) is then the
+        # compiler's choice, not the checkpoint's
+        traced = jax.jit(jax.value_and_grad(loss(cfg))).trace(params)
+        compiled = traced.lower().compile(
+            compiler_options={"xla_backend_optimization_level": 0})
+        return compiled(params), _forward_kernel_calls(traced.jaxpr.jaxpr)
+
+    before = pallas_util.traced()
+    none, calls_none = run(plain)
+    assert _remat_saved_noted(before) == []
+    named, calls_named = run(remat)
+    assert _remat_saved_noted(before) == [REMAT_NAMES]
+    # the checkpoint of every PR before 38: no policy, the input alone
+    monkeypatch.setattr(jax.checkpoint_policies, "save_only_these_names",
+                        lambda *names: None)
+    kept_input_alone, calls_plain = run(remat)
+    assert (calls_none, calls_named, calls_plain) == (
+        remat.n_layers, remat.n_layers, 2 * remat.n_layers)
+    for other in (none, kept_input_alone):
+        for a, b in zip(jax.tree_util.tree_leaves(named),
+                        jax.tree_util.tree_leaves(other)):
+            np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("case", ["dense", "attn_fn"])
+def test_remat_keeps_the_input_alone_where_no_kernel_names(params, case):
+    """Dense attention and an injected `attn_fn` (ring / Ulysses) name
+    nothing: the checkpointed block keeps its input alone, the counter
+    says `none`, and the gradients are those of no checkpoint."""
+    jax.clear_caches()      # `jax.checkpoint` keeps a block's trace
+    remat = dataclasses.replace(CFG, remat=True)
+    toks = jnp.asarray(np.random.RandomState(5).randint(0, 60, (2, 13)))
+    attn = None
+    if case == "attn_fn":
+        attn = lambda q, k, v: T._dense_attention(q, k, v, True)
+    grad = lambda cfg: jax.jit(jax.value_and_grad(
+        lambda q: T.loss(q, cfg, toks, attn_fn=attn)))(params)
+    before = pallas_util.traced()
+    want = grad(CFG)
+    assert _remat_saved_noted(before) == []
+    got = grad(remat)
+    assert _remat_saved_noted(before) == ["transformer.remat_saved=none"]
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
 
 @pytest.mark.slow  # tier-1 budget guard: >10s-class test, slow lane
