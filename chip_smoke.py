@@ -45,7 +45,9 @@ mean every phase passed ON A TPU. No chip, a device that is not in the
 peaks table (`core.hw.PEAKS`), or any failing phase ends the run
 non-zero with the phase named; nothing here catches a phase's
 exception. Times printed are smoke timings (wall clock split into
-compile and run), not metrics.
+compile and run: the union of the compile recorder's rows in the phase),
+not metrics; after the last phase, the recorder's seconds by phase and
+function for the five functions that cost most.
 
 `--tiny` runs the same phases at toy size on whatever backend is
 present and prints `"chip": false`. It exists so tier-1 keeps this file
@@ -61,7 +63,6 @@ import importlib.metadata
 import json
 import sys
 import threading
-import time
 
 import numpy as np
 
@@ -75,6 +76,7 @@ from paddle_tpu.core.devices import require_chip
 from paddle_tpu.models import transformer as T
 from paddle_tpu.native import build as native_build
 from paddle_tpu.nn.module import ShapeSpec
+from paddle_tpu.obs.trace import default_timeline
 from paddle_tpu.ops import losses, pallas_util
 from paddle_tpu.serve.engine import DecodeEngine
 from paddle_tpu.serve.http_edge import HttpEdge
@@ -136,33 +138,22 @@ TINY = Sizes(
     prompt_lens=(3, 5, 9, 14, 16, 20, 33, 48), max_new=6)
 
 
-class CompileClock:
-    """Seconds jax spent tracing, lowering and compiling (or reading the
-    compile cache), from its own monitoring events — what lets a phase
-    split its wall time without a second, warm pass."""
-
-    def __init__(self):
-        self.seconds = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event: str, duration: float, **_kw) -> None:
-        if event.startswith("/jax/core/compile/"):
-            self.seconds += duration
-
-
-def run_phase(name: str, fn, sz: Sizes, devices,
-              clock: CompileClock) -> None:
+def run_phase(name: str, fn, sz: Sizes, devices) -> None:
     print(f"== phase {name}", flush=True)
     compilation_cache.reset_counters()
     traced0 = collections.Counter(pallas_util.traced())
-    c0, t0 = clock.seconds, time.perf_counter()
+    clock_ns = default_timeline().clock_ns
+    t0_ns = clock_ns()
     try:
         facts = fn(sz, devices)
     except BaseException:
         print(f"PHASE FAILED: {name}", file=sys.stderr, flush=True)
         raise
-    wall = time.perf_counter() - t0
-    compile_s = min(clock.seconds - c0, wall)
+    wall = (clock_ns() - t0_ns) / 1e9
+    # jax tracing, lowering and compiling (or reading the compile
+    # cache), from the recorder's rows: what lets a phase split its
+    # wall time without a second, warm pass
+    compile_s = compilation_cache.compile_seconds(t0_ns)
     traced = collections.Counter(pallas_util.traced()) - traced0
     print(json.dumps({
         "phase": name, "ok": True,
@@ -172,6 +163,29 @@ def run_phase(name: str, fn, sz: Sizes, devices,
         "compile_cache": compilation_cache.counters(),
         # which implementation each dispatch site traced in this phase
         "traced": dict(traced), **facts}), flush=True)
+
+
+def _compiled_fun(row: str) -> str:
+    """`compile.lower:jit(step)` -> `step`: tracing names the function
+    bare, lowering and the backend as jax wraps it."""
+    fun = row.split(":", 1)[1]
+    return fun[4:-1] if fun.startswith("jit(") and fun.endswith(")") else fun
+
+
+def compile_table(top: int = 5) -> dict:
+    """The `compile.*` lines of the timeline's summary for the `top`
+    functions that cost most over their phases (trace, lowering,
+    backend), beside the cache reads and the package's import."""
+    table = {name: row for name, row in default_timeline().summary().items()
+             if name.startswith(("compile.", "import."))}
+    cost = collections.Counter()
+    for name, row in table.items():
+        if ":" in name:
+            cost[_compiled_fun(name)] += row["total_s"]
+    dearest = {fun for fun, _ in cost.most_common(top)}
+    return {name: {k: round(v, 4) for k, v in row.items()}
+            for name, row in table.items()
+            if ":" not in name or _compiled_fun(name) in dearest}
 
 
 def check(cond: bool, what: str) -> None:
@@ -267,7 +281,6 @@ def train_layer_kinds(sz: Sizes, devices) -> dict:
     layer that holds a share of its experts; `T.loss_and_aux` and its
     gradient, `auto` against dense, and the layer's counts added to the
     timeline as a training loop would."""
-    from paddle_tpu.obs.trace import default_timeline
     from paddle_tpu.parallel import moe
 
     kinds = (("sliding", T.AttentionKind(window=sz.kinds_window)),
@@ -429,7 +442,6 @@ def main(argv=None) -> int:
         "native_libraries_loaded": native_build.ensured()}), flush=True)
 
     dtypes.set_default_policy(dtypes.bf16_compute_policy())
-    clock = CompileClock()
     phases = [("train_resnet50", train_resnet50),
               ("train_transformer", train_transformer),
               ("train_layer_kinds", train_layer_kinds),
@@ -437,7 +449,9 @@ def main(argv=None) -> int:
     if len(devices) > 1 and not args.tiny:
         phases.append(("multichip_dryrun", multichip_dryrun))
     for name, fn in phases:
-        run_phase(name, fn, sz, devices, clock)
+        run_phase(name, fn, sz, devices)
+    print(json.dumps({"compile_seconds_by_phase_and_function":
+                      compile_table()}), flush=True)
 
     result = {"ok": True, "device": dev}
     if args.tiny:

@@ -17,7 +17,16 @@ A ground-up rebuild of the capabilities of 2017-era PaddlePaddle
 - padding-free variable-length sequence training + beam-search decoding
   (replaces RecurrentGradientMachine, reference:
   gserver/gradientmachines/RecurrentGradientMachine.cpp:530).
+
+The import times itself: the row `import.paddle_tpu` of
+`obs.trace.default_timeline()` runs from this file's first statement to
+its last, so it covers what this one `import` pulled in. Where jax was
+loaded before (the chip benchmark) that is the package's own modules;
+at the CLI jax loads inside it and is in the row.
 """
+
+import time as _time
+_import_t0_ns = _time.perf_counter_ns()
 
 __version__ = "0.1.0"
 
@@ -30,3 +39,7 @@ from paddle_tpu import train
 from paddle_tpu import parallel
 from paddle_tpu import models
 from paddle_tpu import metrics
+
+from paddle_tpu.obs.trace import default_timeline as _default_timeline
+_default_timeline().add("import.paddle_tpu", _import_t0_ns,
+                        _time.perf_counter_ns())

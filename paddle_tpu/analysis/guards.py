@@ -7,14 +7,14 @@ opted-in around production hot loops (`paddle_tpu serve/train
 --transfer-guard`).
 
 - `RecompileGuard`: counts XLA backend compilations inside a `with`
-  region via `jax.monitoring` duration events
-  (`/jax/core/compile/backend_compile_duration` fires once per real
-  backend compile), falling back to counting the
-  `jax_log_compiles` log stream when the monitoring API is absent.
-  With `jax_log_compiles` available it also records WHAT compiled,
-  so a violation names the offender. `max_compiles=0` (default)
-  makes any compile in the region a `RecompileError` — the
-  steady-state assertion.
+  region and names them, from the compile recorder
+  (`compilation_cache.install_listeners`, the repo's one hook on
+  `jax.monitoring`): the count is the difference of the timeline
+  counter `compile.backend_compiles` (one per real backend compile),
+  the names are the `<fun_name>`s of the `compile.backend:*` rows
+  that closed in the region. No log stream, no jax config flag.
+  `max_compiles=0` (default) makes any compile in the region a
+  `RecompileError` — the steady-state assertion.
 
 - `no_implicit_transfers`: thin wrapper over
   `jax.transfer_guard("disallow")` — implicit host->device transfers
@@ -42,13 +42,15 @@ opted-in around production hot loops (`paddle_tpu serve/train
 from __future__ import annotations
 
 import contextlib
-import logging
 import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
+
+from paddle_tpu import compilation_cache
+from paddle_tpu.obs.trace import default_timeline
 
 
 class RecompileError(RuntimeError):
@@ -60,57 +62,6 @@ class TransferError(RuntimeError):
     transfer violations surface as jax's own XlaRuntimeError from
     `jax.transfer_guard` (re-raised unchanged so the device/runtime
     context is not lost)."""
-
-
-#: process-wide registry of active guards; the monitoring listener is
-#: registered once (jax.monitoring has no per-listener removal) and
-#: fans events out to whoever is currently active
-_active_guards: List["RecompileGuard"] = []
-_registry_lock = threading.Lock()
-_listener_installed = False
-
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-
-
-def _on_event_duration(name: str, duration: float, **kw) -> None:
-    if name != _COMPILE_EVENT:
-        return
-    with _registry_lock:
-        guards = list(_active_guards)
-    for g in guards:
-        g._count += 1
-
-
-def _install_listener() -> bool:
-    """Register the shared monitoring listener once; False when the
-    monitoring API is unavailable (old jax) — callers fall back to
-    log counting."""
-    global _listener_installed
-    with _registry_lock:
-        if _listener_installed:
-            return True
-        reg = getattr(getattr(jax, "monitoring", None),
-                      "register_event_duration_secs_listener", None)
-        if reg is None:
-            return False
-        reg(_on_event_duration)
-        _listener_installed = True
-        return True
-
-
-class _CompileLogHandler(logging.Handler):
-    """Collects `jax_log_compiles` 'Compiling <name> ...' records:
-    the names make RecompileError actionable, and the count is the
-    fallback when jax.monitoring is missing."""
-
-    def __init__(self) -> None:
-        super().__init__(level=logging.DEBUG)
-        self.names: List[str] = []
-
-    def emit(self, record: logging.LogRecord) -> None:
-        msg = record.getMessage()
-        if msg.startswith("Compiling "):
-            self.names.append(msg.split(" ", 2)[1])
 
 
 class RecompileGuard:
@@ -127,9 +78,9 @@ class RecompileGuard:
     `max_compiles` > 0 allows a known number (e.g. a region expected
     to compile exactly once: max_compiles=1 plus asserting
     `g.compiles == 1` afterwards). On violation `__exit__` raises
-    `RecompileError` naming what compiled when jax_log_compiles
-    could see it. Re-entrant use of distinct instances nests fine;
-    one instance is single-use."""
+    `RecompileError` naming what compiled (the `fun_name` jax's
+    compile event carries). Re-entrant use of distinct instances
+    nests fine; one instance is single-use."""
 
     def __init__(self, max_compiles: int = 0, *,
                  name: str = "steady-state region"):
@@ -138,28 +89,37 @@ class RecompileGuard:
                 f"max_compiles must be >= 0, got {max_compiles}")
         self.max_compiles = max_compiles
         self.name = name
-        self._count = 0
         self._entered = False
-        self._log_handler: Optional[_CompileLogHandler] = None
-        self._monitored = False
-        self._prev_log_compiles: Optional[bool] = None
+        #: the counter and the clock at entry
+        self._compiles0 = self._t0_ns = 0
+        #: (compiles, names) while the region is not open: nothing
+        #: before it, what it read once it has ended
+        self._result: Optional[Tuple[int, List[str]]] = (0, [])
 
     # -- results -----------------------------------------------------------
 
+    def _read(self) -> Tuple[int, List[str]]:
+        if self._result is not None:
+            return self._result
+        tl = default_timeline()
+        prefix = compilation_cache.BACKEND_ROW_PREFIX
+        # a counter, so a wrapped ring cannot lose a compile (it can
+        # lose a name)
+        return (tl.counters().get(compilation_cache.BACKEND_COMPILES, 0)
+                - self._compiles0,
+                [r[0][len(prefix):] for r in tl.rows()
+                 if r[0].startswith(prefix) and r[2] >= self._t0_ns])
+
     @property
     def compiles(self) -> int:
-        """Backend compiles observed in the region (monitoring count
-        when available, else the compile-log count)."""
-        if self._monitored:
-            return self._count
-        return len(self.compiled_names)
+        """Backend compiles observed in the region."""
+        return self._read()[0]
 
     @property
     def compiled_names(self) -> List[str]:
-        """Names of computations compiled in the region (needs
-        jax_log_compiles; best-effort)."""
-        return list(self._log_handler.names) if self._log_handler \
-            else []
+        """Names of the computations compiled in the region, in the
+        order their compiles ended."""
+        return list(self._read()[1])
 
     # -- context -----------------------------------------------------------
 
@@ -167,46 +127,16 @@ class RecompileGuard:
         if self._entered:
             raise RuntimeError("RecompileGuard is single-use — make "
                                "a new one per region")
-        self._entered = True
-        self._monitored = _install_listener()
-        # name collection (and the fallback count) via the compile
-        # log; propagation is parked so jax_log_compiles doesn't spam
-        # the caller's console for the duration
-        self._log_handler = _CompileLogHandler()
-        self._logger = logging.getLogger("jax._src.interpreters.pxla")
-        self._quiet = logging.getLogger("jax._src.dispatch")
-        self._prev_level = self._logger.level
-        self._prev_prop = (self._logger.propagate,
-                           self._quiet.propagate)
-        self._logger.addHandler(self._log_handler)
-        self._logger.propagate = False
-        # a cut-off logger with NO handler falls back to lastResort
-        # (stderr) — park a NullHandler so it truly goes quiet
-        self._null = logging.NullHandler()
-        self._quiet.addHandler(self._null)
-        self._quiet.propagate = False
-        if self._logger.level > logging.WARNING or \
-                self._logger.level == logging.NOTSET:
-            self._logger.setLevel(logging.WARNING)
-        self._prev_log_compiles = bool(
-            jax.config.jax_log_compiles)
-        if not self._prev_log_compiles:
-            jax.config.update("jax_log_compiles", True)
-        with _registry_lock:
-            _active_guards.append(self)
+        compilation_cache.install_listeners()
+        tl = default_timeline()
+        self._compiles0 = tl.counters().get(
+            compilation_cache.BACKEND_COMPILES, 0)
+        self._t0_ns = tl.clock_ns()
+        self._entered, self._result = True, None
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        with _registry_lock:
-            if self in _active_guards:
-                _active_guards.remove(self)
-        self._logger.removeHandler(self._log_handler)
-        self._logger.setLevel(self._prev_level)
-        self._logger.propagate = self._prev_prop[0]
-        self._quiet.removeHandler(self._null)
-        self._quiet.propagate = self._prev_prop[1]
-        if not self._prev_log_compiles:
-            jax.config.update("jax_log_compiles", False)
+        self._result = self._read()
         if exc_type is not None:
             return
         if self.compiles > self.max_compiles:
@@ -227,8 +157,7 @@ class RecompileGuard:
                                compiled_names=names)
             except Exception:
                 pass
-            detail = (f": compiled {', '.join(names)}" if names
-                      else " (enable jax_log_compiles for names)")
+            detail = f": compiled {', '.join(names)}" if names else ""
             raise RecompileError(
                 f"{self.name} triggered {self.compiles} XLA "
                 f"compile(s), allowed {self.max_compiles}{detail} — "

@@ -22,10 +22,15 @@ it, with three production requirements the raw knobs don't enforce:
   `jax_raise_persistent_cache_errors` stays False (asserted, not
   assumed: `enable()` pins it), so a truncated write from a killed
   process or a garbage file costs one recompile, not an outage.
-- **Observable.** `install_listeners()` hooks jax.monitoring's
-  cache events; `counters()` reports `compile_cache_hits` /
-  `compile_cache_misses` for the obs registry and the serving
-  server (docs/OBSERVABILITY.md).
+- **Observable.** `install_listeners()` is the repo's one hook on
+  jax.monitoring: every trace, lowering, cache read and backend
+  compile becomes a row of `obs.trace.default_timeline()`, named by
+  phase and function, and the cache's requests, hits and misses its
+  counters (the table at `_PHASE_ROWS`). `counters()` reports
+  `compile_cache_hits` / `compile_cache_misses` for the obs registry
+  and the serving server from them, `compile_seconds()` is what a
+  stretch of the process spent compiling, and `RecompileGuard` reads
+  the same rows (docs/OBSERVABILITY.md).
 
 Everything the CLI compiles — serve engine bodies, the train step,
 infer forwards — flows through XLA's one compile entry point, so a
@@ -44,18 +49,54 @@ from typing import Dict, Optional
 import jax
 from jax._src import compilation_cache as _jax_cc
 
+from paddle_tpu.obs.trace import default_timeline
+
 #: the cache entries written by a *tiny* test model still matter: a
 #: fleet restart wants EVERY jitted body cached, not just the ones XLA
 #: took >1s to compile (the upstream default threshold).
 _MIN_COMPILE_TIME_SECS = 0
 _MIN_ENTRY_SIZE_BYTES = -1
 
-_HIT_EVENT = "/jax/compilation_cache/cache_hits"
-_REQ_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
+#: jax's three compile phases, each fired with `fun_name=` as the phase
+#: ends -> the prefix of the row `<prefix>:<fun_name>`. Traces nest (an
+#: inner jit traced inside an outer one fires inside the outer's
+#: interval), so a reader takes the union of these rows, never their sum.
+#: `compile.lower` holds the Pallas -> Mosaic lowering of a kernel;
+#: `compile.backend` covers `compile_or_get_cached`: the cache key's
+#: hashing, then the read or the XLA compile.
+_TRACE, _BACKEND = "compile.trace", "compile.backend"
+_PHASE_ROWS = {
+    "/jax/core/compile/jaxpr_trace_duration": _TRACE,
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+    "/jax/core/compile/backend_compile_duration": _BACKEND,
+}
+#: jax fires `jaxpr_trace_duration` for every jitted `jnp` wrapper the
+#: tracing of a step passes through: 2,650 of the dense LM cell's 3,184
+#: traces a set-up, 4,240 of the block-diffusion cell's 4,975 took under
+#: 50 us and summed to 0.03-0.06 s, nearly all inside a longer trace's
+#: row (PERF.md section 6, PR 39). They would fill the ring the step
+#: spans live in, so a trace shorter than this leaves no row.
+_TRACE_ROW_FLOOR_S = 1e-4
+#: a successful disk read: no name of its own, it lies inside the
+#: `compile.backend:<fun_name>` row that closes next
+_CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+#: what `RecompileGuard` reads: the names after this prefix, and the
+#: counter of backend compiles, hit or miss
+BACKEND_ROW_PREFIX = _BACKEND + ":"
+BACKEND_COMPILES = "compile.backend_compiles"
+_REQUESTS, _HITS = "compile.cache_requests", "compile.cache_hits"
+#: per cache-eligible compile; per successful read; per entry written
+_COUNT_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": _REQUESTS,
+    "/jax/compilation_cache/cache_hits": _HITS,
+    "/jax/compilation_cache/cache_misses": "compile.cache_misses",
+}
 
 _lock = threading.Lock()
 _listeners_installed = False
-_counts = {"hits": 0, "requests": 0}
+#: what `compile.cache_requests` / `compile.cache_hits` read at the last
+#: `reset_counters()`
+_baseline = {_REQUESTS: 0, _HITS: 0}
 _enabled_dir: Optional[str] = None
 
 #: where entries land when the environment does not say: one fixed,
@@ -66,40 +107,90 @@ DEFAULT_DIR = os.path.join(
 ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 
 
-def _on_event(event: str, **kwargs) -> None:
-    if event == _HIT_EVENT:
-        _counts["hits"] += 1
-    elif event == _REQ_EVENT:
-        _counts["requests"] += 1
+def _on_duration(event: str, duration: float, fun_name=None, **_kw) -> None:
+    """jax fires a duration on leaving the phase, on the thread that
+    ran it: the row ends at the timeline's clock now and started
+    `duration` earlier, under whatever span that thread has open."""
+    prefix = _PHASE_ROWS.get(event)
+    if prefix is not None:
+        if prefix == _TRACE and duration < _TRACE_ROW_FLOOR_S:
+            return
+        name = f"{prefix}:{fun_name}"
+    elif event == _CACHE_READ_EVENT:
+        name = "compile.cache_read"
+    else:
+        return
+    tl = default_timeline()
+    end = tl.clock_ns()
+    tl.add(name, end - int(duration * 1e9), end)
+    if prefix == _BACKEND:
+        _count(tl, BACKEND_COMPILES)
+
+
+def _on_event(event: str, **_kw) -> None:
+    counter = _COUNT_EVENTS.get(event)
+    if counter is not None:
+        _count(default_timeline(), counter)
+
+
+def _count(tl, counter: str) -> None:
+    # compiles come from any thread (the serve path's) and a timeline
+    # counter has one owner: the lock stands in for it. Compile events
+    # are rare and never on a step's path.
+    with _lock:
+        tl.count(counter)
 
 
 def install_listeners() -> None:
-    """Idempotently hook jax.monitoring's persistent-cache events.
-    jax fires `cache_hits` on a successful disk read and
-    `compile_requests_use_cache` per cache-eligible compile; misses
-    are requests minus hits (there is no dedicated miss event)."""
+    """Idempotently hook jax.monitoring: one duration listener and one
+    event listener for the whole repo, for the life of the process.
+    Touches no backend."""
     global _listeners_installed
     with _lock:
         if _listeners_installed:
             return
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
         jax.monitoring.register_event_listener(_on_event)
         _listeners_installed = True
 
 
 def reset_counters() -> None:
-    _counts["hits"] = 0
-    _counts["requests"] = 0
+    """`counters()` counts from here on."""
+    now = default_timeline().counters()
+    for key in _baseline:
+        _baseline[key] = now.get(key, 0)
 
 
 def counters() -> Dict[str, int]:
-    """Hits/misses since the last reset. Keys are bare (`hits`,
+    """Hits/misses since the last reset: the timeline's
+    `compile.cache_hits` and `compile.cache_requests` against their
+    readings then. A miss is a request that was no hit (a corrupt
+    entry, or one never written, counts). Keys are bare (`hits`,
     `misses`): the obs registry prepends its source prefix, so
     registering under "compile_cache" exports the documented
     `compile_cache_hits` / `compile_cache_misses` series
     (docs/OBSERVABILITY.md)."""
-    hits = _counts["hits"]
-    return {"hits": hits,
-            "misses": max(_counts["requests"] - hits, 0)}
+    now = default_timeline().counters()
+    hits = now.get(_HITS, 0) - _baseline[_HITS]
+    requests = now.get(_REQUESTS, 0) - _baseline[_REQUESTS]
+    return {"hits": hits, "misses": max(requests - hits, 0)}
+
+
+def compile_seconds(since_ns: int = 0) -> float:
+    """Seconds of the process, from `since_ns` on the timeline's clock,
+    that lie inside some `compile.*` row: the union of the intervals
+    (they nest and, across threads, overlap), so never more than the
+    wall time."""
+    spans = sorted((max(start, since_ns), end)
+                   for name, start, end, _seq, _parent
+                   in default_timeline().rows()
+                   if name.startswith("compile.") and end > since_ns)
+    total, covered_to = 0, since_ns
+    for start, end in spans:
+        if end > covered_to:
+            total += end - max(start, covered_to)
+            covered_to = end
+    return total / 1e9
 
 
 def enabled_dir() -> Optional[str]:

@@ -299,6 +299,16 @@ class Timeline:
         """`with timeline.span("trainer.dispatch", batch_id): ...`"""
         return _OpenInterval(self, name, seq)
 
+    def add(self, name: str, start_ns: int, end_ns: int,
+            seq: Optional[int] = None) -> None:
+        """Append one closed row that was timed elsewhere (a jax
+        compile event hands its duration over as it ends): its parent
+        is the innermost span open on the calling thread, as for a row
+        that `span` closes."""
+        stack = self._open.stack
+        self._rows.append((name, start_ns, end_ns, seq,
+                           stack[-1] if stack else None))
+
     def count(self, name: str, n: int = 1) -> None:
         """Add `n` to counter `name`. One thread owns each name."""
         self._counters[name] = self._counters.get(name, 0) + n

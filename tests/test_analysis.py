@@ -1162,6 +1162,50 @@ class TestRecompileGuardUnit:
                 f(x)
         assert g.compiles == 0
 
+    def test_names_the_offender_with_log_compiles_off(self):
+        assert not jax.config.jax_log_compiles
+
+        def offender_of_the_region(x):
+            return x * 7 - 1
+
+        f = jax.jit(offender_of_the_region)
+        x3, x5 = jnp.ones((3,), jnp.float32), jnp.ones((5,), jnp.float32)
+        f(x3)
+        with pytest.raises(RecompileError,
+                           match=r"compiled jit\(offender_of_the_region\)"):
+            with RecompileGuard(name="unit") as g:
+                f(x5)                            # new shape: compile
+        assert g.compiles == 1
+        assert g.compiled_names == ["jit(offender_of_the_region)"]
+        f(jnp.ones((6,), jnp.float32))           # after the region:
+        assert g.compiles == 1                   # ... not the guard's
+
+    def test_leaves_jax_loggers_and_config_as_it_found_them(self):
+        import logging
+
+        loggers = [logging.getLogger(n) for n in
+                   ("jax._src.interpreters.pxla", "jax._src.dispatch")]
+        look = lambda: [(lg.level, lg.propagate, list(lg.handlers))
+                        for lg in loggers] + [jax.config.jax_log_compiles]
+        before = look()
+        f = jax.jit(lambda x: x - 4)
+        x = jnp.ones((2,), jnp.float32)
+        with RecompileGuard(max_compiles=1, name="unit") as g:
+            during = look()
+            f(x)
+        assert g.compiles == 1
+        assert before == during == look()
+
+    def test_nested_guards_each_count_their_own_region(self):
+        f = jax.jit(lambda x: x / 3)
+        x2, x3 = jnp.ones((2,), jnp.float32), jnp.ones((3,), jnp.float32)
+        with RecompileGuard(max_compiles=2, name="outer") as outer:
+            f(x2)
+            with RecompileGuard(max_compiles=1, name="inner") as inner:
+                f(x3)
+        assert (outer.compiles, inner.compiles) == (2, 1)
+        assert inner.compiled_names == outer.compiled_names[1:]
+
     def test_transfer_guard_bites_on_implicit_h2d(self):
         f = jax.jit(lambda x: x + 1)
         f(jnp.ones((4,), jnp.float32))

@@ -244,6 +244,13 @@ class TestBatch:
     def test_many_callers_share_one_small_pool(self, column_of_16):
         """More callers than cores, one pool: every result whole, and no
         more copying threads than the slices need."""
+        # an executor's threads take the daemon flag of the thread that
+        # first gave them work, and in this process that may have been a
+        # feeder's worker (another test file's): start from no pool
+        with B._pool_lock:
+            old, B._pool = B._pool, None
+        if old is not None:
+            old.shutdown(wait=True)
         samples, _ = STACK_CASES["image_and_label"]()
         want = np.stack([s[0] for s in samples])
         bad, deadline = [], time.monotonic() + 20.0

@@ -148,6 +148,203 @@ def test_two_threads_lose_no_rows():
     assert tl.counters() == {"a": n, "b": n}
 
 
+def test_add_takes_a_closed_row_under_the_threads_open_span():
+    tl = Timeline(clock_ns=ticking())
+    tl.add("timed.elsewhere", 10, 20)
+    with tl.span("outer", 3):
+        tl.add("timed.elsewhere", 30, 40, seq=3)
+        with tl.span("inner", 3):
+            tl.add("timed.elsewhere", 50, 60)
+    other = threading.Thread(target=tl.add, args=("other.thread", 70, 80))
+    with tl.span("held"):       # another thread's open span is no parent
+        other.start()
+        other.join(60.0)
+    rows = tl.rows()
+    assert named(rows, "timed.elsewhere") == [
+        ("timed.elsewhere", 10, 20, None, None),
+        ("timed.elsewhere", 30, 40, 3, "outer"),
+        ("timed.elsewhere", 50, 60, None, "inner")]
+    assert named(rows, "other.thread") == [("other.thread", 70, 80, None,
+                                            None)]
+    # a row's place in the ring is when it was added, whatever its stamps
+    assert [r[0] for r in rows] == ["timed.elsewhere"] * 3 + [
+        "inner", "outer", "other.thread", "held"]
+    # and the summary takes an added row off its parent like any other
+    assert tl.summary()["outer"]["self_s"] < tl.summary()["outer"]["total_s"]
+
+
+def test_add_keeps_the_rings_bound():
+    tl = Timeline(clock_ns=ticking(), keep=16)
+    for i in range(40):
+        tl.add("r", i, i + 1, seq=i)
+    assert [r[3] for r in tl.rows()] == list(range(24, 40))
+
+
+# -- the compile recorder -------------------------------------------------------
+
+@pytest.fixture
+def compile_cache(tmp_path):
+    """The persistent cache in a directory of the test's own, and the
+    default timeline (where the recorder writes) with the stamp to read
+    it from."""
+    from paddle_tpu import compilation_cache
+    from paddle_tpu.obs.trace import default_timeline
+
+    compilation_cache.enable(str(tmp_path / "xla"))
+    compilation_cache.reset_counters()
+    tl = default_timeline()
+    try:
+        yield compilation_cache, tl, tl.clock_ns()
+    finally:
+        compilation_cache.disable()
+        compilation_cache.reset_counters()
+
+
+def compile_rows(tl, since, fun):
+    """The recorder's rows since `since` that carry `fun`'s name (tracing
+    names it bare, lowering and the backend `jit(<fun>)`), and the cache
+    reads, in the order they closed."""
+    return [r for r in tl.rows()
+            if r[2] >= since and (r[0] == "compile.cache_read"
+                                  or r[0].endswith((":" + fun,
+                                                    f":jit({fun})")))]
+
+
+def test_a_fresh_jit_writes_its_phases_by_name_and_a_miss(compile_cache):
+    cc, tl, _ = compile_cache
+
+    def fresh_for_the_recorder(x):
+        return jnp.tanh(x) * 3.0
+
+    # compiles too: made before the readings
+    x = jnp.arange(7.0, dtype=jnp.float32)
+    cc.reset_counters()
+    before = tl.counters()
+    since = tl.clock_ns()
+    jax.jit(fresh_for_the_recorder)(x)
+    rows = compile_rows(tl, since, "fresh_for_the_recorder")
+    assert [r[0] for r in rows] == [
+        "compile.trace:fresh_for_the_recorder",
+        "compile.lower:jit(fresh_for_the_recorder)",
+        "compile.backend:jit(fresh_for_the_recorder)"]
+    # each row ends when its event fired and is as long as jax said: the
+    # phases follow one another on the timeline's own clock
+    assert all(since <= r[1] <= r[2] for r in rows)
+    assert rows[0][2] <= rows[1][2] <= rows[2][2]
+    assert rows[1][1] >= rows[0][1] and rows[2][1] >= rows[1][1]
+    delta = {k: v - before.get(k, 0) for k, v in tl.counters().items()
+             if k.startswith("compile.")}
+    assert delta == {"compile.cache_requests": 1, "compile.cache_misses": 1,
+                     "compile.backend_compiles": 1}
+    assert cc.counters() == {"hits": 0, "misses": 1}
+
+
+def test_a_cached_jit_writes_its_read_inside_its_backend_row(compile_cache):
+    cc, tl, _ = compile_cache
+
+    def cached_for_the_recorder(x):
+        return jnp.cos(x) - 2.0
+
+    x = jnp.arange(9.0, dtype=jnp.float32)
+    f = jax.jit(cached_for_the_recorder)
+    f(x)                        # the miss that writes the entry
+    jax.clear_caches()
+    cc.reset_counters()
+    before = tl.counters()
+    since = tl.clock_ns()
+    f(x)
+    rows = compile_rows(tl, since, "cached_for_the_recorder")
+    assert [r[0] for r in rows] == [
+        "compile.trace:cached_for_the_recorder",
+        "compile.lower:jit(cached_for_the_recorder)",
+        "compile.cache_read",
+        "compile.backend:jit(cached_for_the_recorder)"]
+    read, backend = rows[2], rows[3]
+    assert backend[1] <= read[1] <= read[2] <= backend[2]
+    assert cc.counters() == {"hits": 1, "misses": 0}
+    now = tl.counters()
+    assert now["compile.cache_hits"] - before.get("compile.cache_hits", 0) == 1
+    assert now.get("compile.cache_misses", 0) \
+        == before.get("compile.cache_misses", 0)
+    # the phase's seconds are the union of its rows: the read lies in the
+    # backend's row and adds nothing
+    covered = cc.compile_seconds(since)
+    assert covered <= (tl.clock_ns() - since) / 1e9
+    assert covered <= sum(r[2] - r[1] for r in rows) / 1e9 \
+        - (read[2] - read[1]) / 1e9 + 1e-9
+
+
+def test_a_compile_inside_a_span_names_it_as_parent(compile_cache):
+    _, tl, _ = compile_cache
+
+    def compiled_under_a_span(x):
+        return x + 5.0
+
+    x = jnp.arange(3.0, dtype=jnp.float32)
+    since = tl.clock_ns()
+    with tl.span("test.dispatch"):
+        jax.jit(compiled_under_a_span)(x)
+    rows = compile_rows(tl, since, "compiled_under_a_span")
+    assert len(rows) == 3
+    assert {r[4] for r in rows} == {"test.dispatch"}
+
+
+@pytest.mark.parametrize("event,seconds,row", [
+    ("/jax/core/compile/jaxpr_trace_duration", 0.25, "compile.trace:f"),
+    # a jitted jnp wrapper passed through while tracing: thousands a step
+    ("/jax/core/compile/jaxpr_trace_duration", 0.00002, None),
+    ("/jax/core/compile/jaxpr_to_mlir_module_duration", 0.00002,
+     "compile.lower:f"),
+    ("/jax/core/compile/backend_compile_duration", 0.00002,
+     "compile.backend:f"),
+    ("/jax/compilation_cache/cache_retrieval_time_sec", 0.5,
+     "compile.cache_read"),
+    ("/jax/compilation_cache/compile_time_saved_sec", 0.5, None),
+    ("/jax/core/some_other_duration", 0.5, None),
+])
+def test_the_listener_rows_an_event_from_its_end_back(event, seconds, row):
+    from paddle_tpu import compilation_cache as cc
+    from paddle_tpu.obs.trace import default_timeline
+
+    tl = default_timeline()
+    since = tl.clock_ns()
+    cc._on_duration(event, seconds, **({} if "cache" in event
+                                       else {"fun_name": "f"}))
+    now = tl.clock_ns()
+    added = [r for r in tl.rows() if r[2] >= since]
+    if row is None:
+        assert added == []
+        return
+    (name, start, end, seq, parent), = added
+    assert (name, seq, parent) == (row, None, None)
+    assert since <= end <= now and end - start == int(seconds * 1e9)
+
+
+def test_install_listeners_twice_registers_once():
+    from jax._src import monitoring
+
+    from paddle_tpu import compilation_cache as cc
+
+    cc.install_listeners()
+    cc.install_listeners()
+    assert monitoring.get_event_duration_listeners().count(
+        cc._on_duration) == 1
+    assert monitoring.get_event_listeners().count(cc._on_event) == 1
+
+
+def test_cache_counters_count_from_the_last_reset(compile_cache):
+    cc, tl, _ = compile_cache
+    x = jnp.arange(5.0, dtype=jnp.float32)
+    jax.jit(lambda v: v * 11.0)(x)
+    assert cc.counters()["misses"] >= 1
+    cc.reset_counters()
+    assert cc.counters() == {"hits": 0, "misses": 0}
+    # the timeline's counters run on: the reset moved a baseline only
+    assert tl.counters()["compile.cache_requests"] >= 1
+    jax.jit(lambda v: v * 13.0)(x)
+    assert cc.counters() == {"hits": 0, "misses": 1}
+
+
 # -- the feeder ---------------------------------------------------------------
 
 def test_feeder_records_one_row_of_each_span_a_batch():
