@@ -47,6 +47,7 @@ from paddle_tpu.core.mesh import MODEL_AXIS
 from paddle_tpu.nn import initializers
 from paddle_tpu.ops import pallas_util
 from paddle_tpu.ops.moe_grouped_matmul import grouped_matmul
+from paddle_tpu.ops.moe_rows import moe_sum_held_rows, moe_take_held_rows
 
 
 class MoEOutput(NamedTuple):
@@ -505,21 +506,20 @@ def init_dropless_params(rng, n_experts: int, n_held: int, d_model: int,
 
 @jax.custom_vjp
 def _take_rows(x, row_of_slot, slot_of_pair, held):
-    """x [T, D] -> [R, D], slot s gets x[row_of_slot[s]]. Its gradient
-    is a gather too: position t collects the slots of its held choices
-    (`slot_of_pair` [T, k]); slots past the held rows carry nothing."""
-    return jnp.take(x, row_of_slot, axis=0)
+    """x [T, D] -> [R, D], slot s gets x[row_of_slot[s]]; slots past the
+    held rows are not written (`ops.moe_rows`). Its gradient gathers
+    too: position t collects the slots of its held choices
+    (`slot_of_pair` [T, k])."""
+    return moe_take_held_rows(x, row_of_slot, jnp.sum(held, dtype=jnp.int32))
 
 
 def _take_rows_fwd(x, row_of_slot, slot_of_pair, held):
-    return jnp.take(x, row_of_slot, axis=0), (slot_of_pair, held)
+    return _take_rows(x, row_of_slot, slot_of_pair, held), (slot_of_pair, held)
 
 
 def _take_rows_bwd(res, g):
     slot_of_pair, held = res
-    picked = jnp.take(g, slot_of_pair, axis=0)               # [T, k, D]
-    dx = jnp.sum(jnp.where(held[..., None], picked.astype(jnp.float32), 0.0),
-                 axis=1)
+    dx = moe_sum_held_rows(g, slot_of_pair, held)
     return dx.astype(g.dtype), None, None, None
 
 
@@ -531,9 +531,7 @@ def _combine_rows(out, weight, slot_of_pair, held, pair_of_slot):
     """y[t] = sum over t's held choices c of weight[t, c] * out[slot]:
     a gather, never a scatter, in float32. Rows of `out` that no expert
     owns are not read."""
-    picked = jnp.take(out, slot_of_pair, axis=0).astype(jnp.float32)
-    return jnp.sum(jnp.where(held[..., None],
-                             weight[..., None] * picked, 0.0), axis=1)
+    return moe_sum_held_rows(out, slot_of_pair, held, weight)
 
 
 def _combine_rows_fwd(out, weight, slot_of_pair, held, pair_of_slot):
@@ -544,13 +542,15 @@ def _combine_rows_fwd(out, weight, slot_of_pair, held, pair_of_slot):
 def _combine_rows_bwd(res, g):
     out, weight, slot_of_pair, held, pair_of_slot = res
     k = weight.shape[1]
-    picked = jnp.take(out, slot_of_pair, axis=0).astype(jnp.float32)
-    d_weight = jnp.where(held, jnp.sum(picked * g[:, None, :], axis=-1), 0.0)
     # slot s holds pair (t, c) = divmod(pair_of_slot[s], k): its row gets
-    # weight[t, c] * g[t]; a slot of an expert not held gets zeros
-    w_slot = jnp.where(held, weight, 0.0).reshape(-1)[pair_of_slot]
-    d_out = w_slot[:, None] * jnp.take(g, pair_of_slot // k, axis=0)
-    return d_out.astype(out.dtype), d_weight, None, None, None
+    # weight[t, c] * g[t], and weight[t, c] gets <out[s], g[t]>; slots
+    # past the held rows are not written
+    d_out, dot = moe_take_held_rows(
+        g, pair_of_slot // k, jnp.sum(held, dtype=jnp.int32),
+        scale=weight.reshape(-1)[pair_of_slot], other=out,
+        out_dtype=out.dtype)
+    d_weight = jnp.where(held, jnp.take(dot, slot_of_pair), 0.0)
+    return d_out, d_weight, None, None, None
 
 
 _combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
@@ -574,6 +574,7 @@ def dropless_ffn(params, x, *, k: int, first_held: int = 0,
     n_held = params["w_gate"].shape[0]
     cd = default_policy().compute_dtype
     pallas_util.note_traced("moe.expert_matmul", "pallas_grouped")
+    pallas_util.note_traced("moe.row_gather", "held_rows")
     with jax.named_scope("moe/router"):
         logits = jnp.matmul(x.astype(jnp.float32),
                             params["router"]["kernel"].astype(jnp.float32),

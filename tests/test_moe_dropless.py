@@ -208,3 +208,208 @@ def test_counts_reach_the_timeline():
     moe.count_dropless_stats(stats, positions=64, timeline=tl)
     assert tl.counters() == {"moe.rows_held": 440, "moe.rows_max_expert": 40,
                              "moe.positions": 256}
+
+
+# -- the row kernels (`ops.moe_rows`) against the jnp gathers they replace --
+
+
+def _oracle_take(x, row_of_slot):
+    return jnp.take(x, row_of_slot, axis=0)
+
+
+def _oracle_sum(src, slot_of_pair, held, weight=None):
+    picked = jnp.take(src, slot_of_pair, axis=0).astype(jnp.float32)
+    if weight is not None:
+        picked = weight[..., None] * picked
+    return jnp.sum(jnp.where(held[..., None], picked, 0.0), axis=1)
+
+
+def _routing(np_rng, t, k, share):
+    """held [T, k] with about `share` of the choices held, and the
+    layer's slot order: held slots first."""
+    held = jnp.asarray(np_rng.rand(t, k) < share)
+    key = jnp.where(held, 0, 1).reshape(-1)
+    pair_of_slot = jnp.argsort(key, stable=True).astype(jnp.int32)
+    slot_of_pair = jnp.zeros((t * k,), jnp.int32).at[pair_of_slot].set(
+        jnp.arange(t * k, dtype=jnp.int32)).reshape(t, k)
+    return held, pair_of_slot, slot_of_pair
+
+
+ROW_CASES = [
+    # (positions, k, width, share held, row tile, position tile)
+    (64, 8, 256, 0.2, 64, 16),      # several tiles of each; a partial last
+    (48, 2, 33, 1.0, 16, 16),       # every choice held; an odd width
+    (16, 4, 64, 0.0, 8, 8),         # no held row: no tile visited
+    (40, 8, 128, 0.35, 24, 8),      # rows held no multiple of the tile
+]
+
+
+@pytest.mark.parametrize("t,k,d,share,tm,tt", ROW_CASES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_take_held_rows_against_the_gather(np_rng, t, k, d, share, tm, tt,
+                                           dtype):
+    from paddle_tpu.ops import moe_rows as M
+
+    held, pair_of_slot, _ = _routing(np_rng, t, k, share)
+    n = int(held.sum())
+    x = jnp.asarray(np_rng.randn(t, d), dtype)
+    got = M.moe_take_held_rows(x, pair_of_slot // k, jnp.int32(n), tile=tm)
+    want = _oracle_take(x, pair_of_slot // k)
+    np.testing.assert_array_equal(np.asarray(got[:n], np.float32),
+                                  np.asarray(want[:n], np.float32))
+    # scaled, with the dot: the combine's backward (g float32)
+    g = jnp.asarray(np_rng.randn(t, d), jnp.float32)
+    scale = jnp.asarray(np_rng.randn(t * k), jnp.float32)
+    other = jnp.asarray(np_rng.randn(t * k, d), dtype)
+    dst, dot = M.moe_take_held_rows(g, pair_of_slot // k, jnp.int32(n),
+                                    scale=scale, other=other,
+                                    out_dtype=dtype, tile=tm)
+    rows = _oracle_take(g, pair_of_slot // k)
+    np.testing.assert_array_equal(
+        np.asarray(dst[:n], np.float32),
+        np.asarray((scale[:, None] * rows).astype(dtype)[:n], np.float32))
+    np.testing.assert_allclose(
+        np.asarray(dot[:n]),
+        np.asarray(jnp.sum(other.astype(jnp.float32) * rows, axis=1)[:n]),
+        rtol=1e-6, atol=1e-6 * d ** 0.5)
+
+
+@pytest.mark.parametrize("t,k,d,share,tm,tt", ROW_CASES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("weighted", [True, False])
+def test_sum_held_rows_against_the_gather(np_rng, t, k, d, share, tm, tt,
+                                          dtype, weighted):
+    from paddle_tpu.ops import moe_rows as M
+
+    held, _, slot_of_pair = _routing(np_rng, t, k, share)
+    src = jnp.asarray(np_rng.randn(t * k, d), dtype)
+    weight = (jnp.asarray(np_rng.rand(t, k), jnp.float32) if weighted
+              else None)
+    got = M.moe_sum_held_rows(src, slot_of_pair, held, weight, tile=tt,
+                              row_tile=tm)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(_oracle_sum(src, slot_of_pair, held,
+                                                weight)),
+        rtol=1e-6, atol=1e-6)
+
+
+@jax.custom_vjp
+def _jnp_take_rows(x, row_of_slot, slot_of_pair, held):
+    return jnp.take(x, row_of_slot, axis=0)
+
+
+def _jnp_take_rows_fwd(x, row_of_slot, slot_of_pair, held):
+    return jnp.take(x, row_of_slot, axis=0), (slot_of_pair, held)
+
+
+def _jnp_take_rows_bwd(res, g):
+    slot_of_pair, held = res
+    return _oracle_sum(g, slot_of_pair, held).astype(g.dtype), None, None, None
+
+
+_jnp_take_rows.defvjp(_jnp_take_rows_fwd, _jnp_take_rows_bwd)
+
+
+@jax.custom_vjp
+def _jnp_combine_rows(out, weight, slot_of_pair, held, pair_of_slot):
+    return _oracle_sum(out, slot_of_pair, held, weight)
+
+
+def _jnp_combine_rows_fwd(out, weight, slot_of_pair, held, pair_of_slot):
+    return (_jnp_combine_rows(out, weight, slot_of_pair, held, pair_of_slot),
+            (out, weight, slot_of_pair, held, pair_of_slot))
+
+
+def _jnp_combine_rows_bwd(res, g):
+    out, weight, slot_of_pair, held, pair_of_slot = res
+    k = weight.shape[1]
+    picked = jnp.take(out, slot_of_pair, axis=0).astype(jnp.float32)
+    d_weight = jnp.where(held, jnp.sum(picked * g[:, None, :], axis=-1), 0.0)
+    w_slot = jnp.where(held, weight, 0.0).reshape(-1)[pair_of_slot]
+    d_out = w_slot[:, None] * jnp.take(g, pair_of_slot // k, axis=0)
+    return d_out.astype(out.dtype), d_weight, None, None, None
+
+
+_jnp_combine_rows.defvjp(_jnp_combine_rows_fwd, _jnp_combine_rows_bwd)
+
+
+def _layer_both_ways(monkeypatch, params, x, w, **kw):
+    """(y, gradients) of `dropless_ffn` with the row kernels, then with
+    the jnp gathers they replace."""
+    def loss(p, x):
+        y = moe.dropless_ffn(p, x, **kw).y
+        return jnp.sum(y.astype(jnp.float32) * w), y
+
+    def run():
+        g, y = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True))(
+            params, x)
+        return y, g
+
+    kernels = run()
+    monkeypatch.setattr(moe, "_take_rows", _jnp_take_rows)
+    monkeypatch.setattr(moe, "_combine_rows", _jnp_combine_rows)
+    return kernels, run()
+
+
+@pytest.mark.parametrize("n_experts,n_held,first,k", [
+    (8, 8, 0, 2), (128, 16, 0, 8), (128, 16, 48, 8), (64, 16, 0, 8),
+    (64, 16, 32, 8),
+])
+def test_layer_with_the_kernels_matches_the_gathers(monkeypatch, n_experts,
+                                                    n_held, first, k):
+    """float32 through the whole layer, values and every gradient: the
+    kernels move the rows bit for bit and sum in float32, so only the
+    order of a position's few terms differs."""
+    params, x = _layer(0, n_experts, n_held)
+    w = jax.random.normal(jax.random.key(9), x.shape, jnp.float32)
+    (y, g), (y0, g0) = _layer_both_ways(monkeypatch, params, x, w, k=k,
+                                        first_held=first)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y0), rtol=1e-6,
+                               atol=1e-7)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(g),
+                            jax.tree.leaves(g0)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
+                                   atol=1e-7, err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("case", ["token_mask", "none_held", "all_held",
+                                  "bf16"])
+def test_layer_corners_match_the_gathers(monkeypatch, case):
+    from paddle_tpu.core import dtypes
+
+    params, x = _layer(5, 8 if case == "all_held" else 128,
+                       8 if case == "all_held" else 16)
+    kw = dict(k=2 if case == "all_held" else 8)
+    if case == "token_mask":
+        kw["token_mask"] = jnp.arange(x.shape[0], dtype=jnp.int32) % 3 != 0
+    if case == "none_held":     # every position's top 8 is experts 40-47
+        x = jnp.concatenate([x, jnp.ones((x.shape[0], 1))], axis=1)
+        pad = lambda a, axis: jnp.concatenate([a, jnp.ones_like(
+            jnp.take(a, jnp.arange(1, dtype=jnp.int32), axis=axis))], axis=axis)
+        params = _biased({**params, "w_gate": pad(params["w_gate"], 1),
+                          "w_up": pad(params["w_up"], 1),
+                          "w_down": pad(params["w_down"], 2)},
+                         list(range(40, 48)))
+        kw["first_held"] = 16
+    w = jax.random.normal(jax.random.key(9), x.shape, jnp.float32)
+    prev = dtypes.default_policy()
+    if case == "bf16":
+        dtypes.set_default_policy(dtypes.bf16_compute_policy())
+    try:
+        (y, g), (y0, g0) = _layer_both_ways(monkeypatch, params, x, w, **kw)
+        held = moe.dropless_ffn(params, x, **kw).stats.rows_held
+    finally:
+        dtypes.set_default_policy(prev)
+    if case == "none_held":
+        assert int(held) == 0
+    if case == "all_held":
+        assert int(held) == 2 * x.shape[0]
+    close = dict(rtol=1e-6, atol=1e-7) if case != "bf16" else dict(
+        rtol=0.01, atol=1e-3)   # a bf16 rounding of a float32 sum
+    np.testing.assert_allclose(np.asarray(y, np.float32),
+                               np.asarray(y0, np.float32), **close)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(g),
+                            jax.tree.leaves(g0)):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), **close,
+                                   err_msg=jax.tree_util.keystr(path))

@@ -187,3 +187,46 @@ def test_checkpointed_block_runs_the_forward_kernel_once_for_v5e(
         + r"_?(?:\.\d+)? = ", text, re.M)
     assert sorted(kernels) == sorted(
         ["fwd", "bwd_dkv", "bwd_dq"] * cfg.n_layers), kernels
+
+
+@pytest.mark.parametrize("d,f", [
+    (2048, 768),        # sdar_30b_a3b_ep8: top 8 of 128, 16 held
+    (2304, 896),        # mellum2_12b_a2p5b_ep4: top 8 of 64, 16 held
+])
+def test_dropless_layer_moves_only_held_rows_for_v5e(one_chip, d, f):
+    """The dropless layer forward and backward at the cells' shapes
+    (16,384 positions, a 131,072-row buffer, bf16 rows): the row
+    kernels of `ops.moe_rows` compile and take the place of XLA's
+    gathers, so no instruction of `moe/dispatch` or `moe/combine` but
+    a kernel makes a `[131072, D]` array."""
+    from paddle_tpu.core import dtypes
+    from paddle_tpu.parallel import moe
+
+    t, k, experts = 16384, 8, 128 if d == 2048 else 64
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    params = jax.tree.map(sds, jax.eval_shape(
+        lambda: moe.init_dropless_params(jax.random.key(0), experts, 16,
+                                         d, f)))
+    x = jax.ShapeDtypeStruct((t, d), jnp.bfloat16, sharding=one_chip)
+
+    def loss(p, x):
+        return jnp.sum(moe.dropless_ffn(p, x, k=k).y.astype(jnp.float32))
+
+    prev = dtypes.default_policy()
+    dtypes.set_default_policy(dtypes.bf16_compute_policy())
+    try:
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            params, x).compile().as_text()
+    finally:
+        dtypes.set_default_policy(prev)
+    names = re.findall(r"^\s*%(moe_\w+?)(?:\.\d+)? = ", text, re.M)
+    assert sorted(names) == sorted(
+        ["moe_take_held_rows"] * 2 + ["moe_sum_held_rows"] * 2
+        + ["moe_pack_rows"] * 4 + ["moe_grouped_matmul"] * 6
+        + ["moe_grouped_matmul_dw"] * 3), names
+    # an instruction that computes such an array (not the kernel's call
+    # and the element of its tuple result)
+    buffer = re.compile(rf"^\s*%\S+ = \(?[a-z0-9]+\[{t * k},{d}\][^=]*? "
+                        r"(?!custom-call|get-tuple-element|bitcast)[\w-]+\("
+                        r".*op_name=\"[^\"]*moe/(dispatch|combine)", re.M)
+    assert not buffer.findall(text)

@@ -46,14 +46,29 @@ def _lower_for_tpu(fn, *args) -> int:
 
 
 def _kernels(text: str) -> list:
-    """(kernel name, operand types) of every Mosaic call in a lowered
-    program, in program order: ('flash_attention_fwd', ['tensor<4xi32>',
-    'tensor<4x256x128xbf16>', ...])."""
-    calls = [ln for ln in text.splitlines() if "@tpu_custom_call" in ln]
-    return [(re.search(r'kernel_name = "([^"]*)"', ln).group(1),
-             re.findall(r"tensor<[^>]*>",
-                        re.search(r" : \((.*?)\) -> ", ln).group(1)))
-            for ln in calls]
+    """(kernel name, operand types) of every Mosaic call a lowered
+    program makes, in program order: ('flash_attention_fwd',
+    ['tensor<4xi32>', 'tensor<4x256x128xbf16>', ...]). A kernel inside
+    a function (a jitted entry point, lowered once) counts once a call."""
+    body, fun = {}, None
+    for ln in text.splitlines():
+        head = re.search(r"func\.func (?:public |private )?@(\w+)\(", ln)
+        if head:
+            fun = head.group(1)
+            body[fun] = []
+        elif "@tpu_custom_call" in ln:
+            body[fun].append((
+                re.search(r'kernel_name = "([^"]*)"', ln).group(1),
+                re.findall(r"tensor<[^>]*>",
+                           re.search(r" : \((.*?)\) -> ", ln).group(1))))
+        elif fun is not None:
+            body[fun] += re.findall(r"\bcall @(\w+)\(", ln)
+
+    def expand(fun):
+        return [k for item in body[fun]
+                for k in (expand(item) if isinstance(item, str) else [item])]
+
+    return expand("main")
 
 
 def _sds(shape, dtype):
@@ -143,6 +158,12 @@ def test_model_hands_flash_the_policys_dtype(bf16):
             else [wide] * 3), name
 
 
+# a dropless layer's kernels in a step under remat
+EXPERTS = ["moe_grouped_matmul"] * 9 + ["moe_grouped_matmul_dw"] * 3
+ROWS = (["moe_take_held_rows"] * 3 + ["moe_sum_held_rows"] * 2
+        + ["moe_pack_rows"] * 5)
+
+
 def test_block_diffusion_model_lowers_with_its_kernels():
     """The second LM configuration's real path: grad of
     `T.block_diffusion_loss` on an RMSNorm, bias-free, QK-normed block
@@ -150,8 +171,11 @@ def test_block_diffusion_model_lowers_with_its_kernels():
     its experts, under the bf16 policy. A layer launches the flash
     forward (once: the checkpointed block keeps what the kernel names)
     and its two backward kernels on bf16 operands over 2L positions,
-    and the grouped products: three forward, three again under remat,
-    three input gradients, three weight gradients."""
+    the grouped products: three forward, three again under remat,
+    three input gradients, three weight gradients; and the row kernels
+    of `ops.moe_rows`: the take by slot forward, again under remat and
+    in the combine's backward, the sum by position in the combine's
+    forward and the take's backward, each behind a pack into words."""
     dtypes.set_default_policy(dtypes.bf16_compute_policy())
     jax.clear_caches()
     pallas_util._traced.clear()
@@ -175,19 +199,20 @@ def test_block_diffusion_model_lowers_with_its_kernels():
     names = [name for name, _ in calls]
     assert sorted(names) == sorted(
         (["flash_attention_fwd", "flash_attention_bwd_dkv",
-          "flash_attention_bwd_dq"]
-         + ["moe_grouped_matmul"] * 9 + ["moe_grouped_matmul_dw"] * 3) * 2)
+          "flash_attention_bwd_dq"] + EXPERTS + ROWS) * 2)
     wide = f"tensor<8x{2 * length}x128xbf16>"       # B * H, 2L, head size
     for name, operands in calls:
         if name.startswith("flash"):
             assert operands.count(wide) == (4 if "bwd" in name else 3), name
-        else:       # the row buffer holds every choice: 2 * 2L * k rows
+        elif name.startswith("moe_grouped"):
+            # the row buffer holds every choice: 2 * 2L * k rows
             assert any(op.startswith(f"tensor<{2 * 2 * length * 2}x")
                        and op.endswith("xbf16>") for op in operands), name
     traced = pallas_util.traced()
     assert traced["flash_attention.mask=block_diffusion"] > 0
     assert traced["transformer.ffn=moe_dropless"] > 0
     assert traced["moe.expert_matmul=pallas_grouped"] > 0
+    assert traced["moe.row_gather=held_rows"] > 0
 
 
 def test_model_with_kinds_by_layer_lowers_with_its_kernels():
@@ -220,8 +245,7 @@ def test_model_with_kinds_by_layer_lowers_with_its_kernels():
             "flash_attention_bwd_dkv_window", "flash_attention_bwd_dq_window"]
     full = ["flash_attention_fwd", "flash_attention_bwd_dkv",
             "flash_attention_bwd_dq"]
-    experts = ["moe_grouped_matmul"] * 9 + ["moe_grouped_matmul_dw"] * 3
-    assert sorted(names) == sorted(3 * band + full + 4 * experts)
+    assert sorted(names) == sorted(3 * band + full + 4 * (EXPERTS + ROWS))
     traced = pallas_util.traced()
     assert traced["transformer.layer_kinds=sliding:3,full:1"] > 0
     assert traced["transformer.rope=sliding:none,full:yarn"] > 0
