@@ -22,6 +22,12 @@ weights from a seed), in ONE process:
                      window 512: the band's kernels beside the full
                      layer's in one program, checked against the dense
                      path, and the expert layer's counts.
+  train_hybrid       the same step on a block whose layers differ in
+                     token mixer (three Gated DeltaNet layers, one gated
+                     full layer with partial rotary) over the dropless
+                     layer with a gated shared expert: the gated delta
+                     rule's kernels and flash beside each other, checked
+                     against the `jnp` chunked rule and the dense path.
   serve_http         the same transformer behind HttpEdge ->
                      ServingRouter -> ServingServer -> DecodeEngine as
                      `cli serve --http` wires them (slots 8, max_len
@@ -108,6 +114,7 @@ class Sizes:
     lm_batch: int
     kinds_lm: dict              # the block of `train_layer_kinds`
     kinds_window: int
+    hybrid: dict                # and what `train_hybrid` adds to it
     slots: int
     max_len: int
     prefill_chunk: int
@@ -123,6 +130,7 @@ FULL = Sizes(
     kinds_lm=dict(vocab=32000, dim=512, n_heads=4, n_kv_heads=2,
                   head_size=128, moe_experts=16, moe_held=4, moe_k=2,
                   moe_dim=256), kinds_window=512,
+    hybrid=dict(moe_shared_dim=256, gdn_key_heads=2, gdn_value_heads=4),
     slots=8, max_len=2048, prefill_chunk=256,
     prompt_lens=(16, 48, 100, 200, 300, 500, 777, 1024), max_new=32)
 
@@ -134,6 +142,8 @@ TINY = Sizes(
     kinds_lm=dict(vocab=97, dim=32, n_heads=2, n_kv_heads=1, head_size=16,
                   moe_experts=4, moe_held=2, moe_k=2, moe_dim=16),
     kinds_window=16,
+    hybrid=dict(moe_shared_dim=16, gdn_key_heads=1, gdn_value_heads=2,
+                gdn_key_dim=16, gdn_value_dim=16),
     slots=4, max_len=96, prefill_chunk=16,
     prompt_lens=(3, 5, 9, 14, 16, 20, 33, 48), max_new=6)
 
@@ -275,21 +285,13 @@ def train_transformer(sz: Sizes, devices) -> dict:
             "grad_norm_dense": gn_d, "tolerance": TRAIN_TOL}
 
 
-def train_layer_kinds(sz: Sizes, devices) -> dict:
-    """Attention kind by layer: three sliding-window layers and one
-    full layer with YaRN in the one block body, over a dropless expert
-    layer that holds a share of its experts; `T.loss_and_aux` and its
-    gradient, `auto` against dense, and the layer's counts added to the
-    timeline as a training loop would."""
+def _auto_against_dense(sz: Sizes, cfg) -> dict:
+    """One `T.loss_and_aux` + grad step of `cfg` (`attn_impl="auto"`)
+    and of its dense twin on one batch, checked against each other: the
+    losses, the gradients' norms and the expert layer's counts as a
+    training loop adds them to the timeline."""
     from paddle_tpu.parallel import moe
 
-    kinds = (("sliding", T.AttentionKind(window=sz.kinds_window)),
-             ("full", T.AttentionKind(rope_scaling="yarn", rope_factor=16.0,
-                                      rope_original=sz.lm_seq // 2)))
-    cfg = T.TransformerConfig(
-        **sz.kinds_lm, n_layers=4, norm="rms", bias=False, qk_norm=True,
-        layer_types=("sliding",) * 3 + ("full",), attention_kinds=kinds,
-        moe_router="dropless", moe_every=1, attn_impl="auto", remat=True)
     dense_cfg = dataclasses.replace(cfg, attn_impl="dense")
     params = T.init_params(jax.random.key(0), cfg)
     toks = jnp.asarray(np.random.default_rng(1).integers(
@@ -315,9 +317,42 @@ def train_layer_kinds(sz: Sizes, devices) -> dict:
                 if k.startswith("moe.")}
     check(0 < counters["moe.rows_held"] <= cfg.moe_k
           * counters["moe.positions"], f"rows held {counters}")
-    return {"seq": sz.lm_seq, "window": sz.kinds_window, "loss_auto": loss,
-            "loss_dense": loss_d, "grad_norm_auto": gn,
-            "grad_norm_dense": gn_d, "counters": counters}
+    return {"seq": sz.lm_seq, "loss_auto": loss, "loss_dense": loss_d,
+            "grad_norm_auto": gn, "grad_norm_dense": gn_d,
+            "counters": counters}
+
+
+def train_layer_kinds(sz: Sizes, devices) -> dict:
+    """Attention kind by layer: three sliding-window layers and one
+    full layer with YaRN in the one block body, over a dropless expert
+    layer that holds a share of its experts; `T.loss_and_aux` and its
+    gradient, `auto` against dense, and the layer's counts added to the
+    timeline as a training loop would."""
+    kinds = (("sliding", T.AttentionKind(window=sz.kinds_window)),
+             ("full", T.AttentionKind(rope_scaling="yarn", rope_factor=16.0,
+                                      rope_original=sz.lm_seq // 2)))
+    cfg = T.TransformerConfig(
+        **sz.kinds_lm, n_layers=4, norm="rms", bias=False, qk_norm=True,
+        layer_types=("sliding",) * 3 + ("full",), attention_kinds=kinds,
+        moe_router="dropless", moe_every=1, attn_impl="auto", remat=True)
+    return {"window": sz.kinds_window, **_auto_against_dense(sz, cfg)}
+
+
+def train_hybrid(sz: Sizes, devices) -> dict:
+    """Token mixer by layer: three Gated DeltaNet layers and one full
+    layer with a gated output and a rotary over a quarter of each
+    head's lanes, over the dropless layer with a gated shared expert;
+    `auto` against dense as `train_layer_kinds`."""
+    kinds = (("linear", T.AttentionKind(mixer="gated_delta")),
+             ("full", T.AttentionKind(
+                 output_gate=True,
+                 rotary_dim=sz.kinds_lm["head_size"] // 4)))
+    cfg = T.TransformerConfig(
+        **sz.kinds_lm, **sz.hybrid, n_layers=4, norm="rms", bias=False,
+        qk_norm=True, layer_types=("linear",) * 3 + ("full",),
+        attention_kinds=kinds, moe_router="dropless", moe_every=1,
+        attn_impl="auto", remat=True)
+    return _auto_against_dense(sz, cfg)
 
 
 def serve_http(sz: Sizes, devices) -> dict:
@@ -445,6 +480,7 @@ def main(argv=None) -> int:
     phases = [("train_resnet50", train_resnet50),
               ("train_transformer", train_transformer),
               ("train_layer_kinds", train_layer_kinds),
+              ("train_hybrid", train_hybrid),
               ("serve_http", serve_http)]
     if len(devices) > 1 and not args.tiny:
         phases.append(("multichip_dryrun", multichip_dryrun))

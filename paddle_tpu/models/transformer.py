@@ -51,6 +51,7 @@ from paddle_tpu.ops import norm as norm_ops
 from paddle_tpu.ops import pallas_util
 from paddle_tpu.ops import sampling as sampling_ops
 from paddle_tpu.ops.flash_attention import REMAT_SAVED, flash_attention
+from paddle_tpu.ops.gated_delta import gated_delta_rule
 from paddle_tpu.parallel.sharding import MEGATRON_RULES, MODEL_AXIS
 
 from jax.sharding import PartitionSpec as P
@@ -75,13 +76,24 @@ TP_MOE_RULES = ([(r"moe/router/kernel$", P())] + TP_RULES +
                  (r"(q_norm|k_norm)/scale$", P())])
 
 
+MIXERS = ("attention", "gated_delta")
+
+
 @dataclasses.dataclass(frozen=True)
 class AttentionKind:
-    """What a layer's attention may have of its own where a model mixes
-    kinds of layer: the window (None = full causal) and the rotary
+    """What a layer's token mixer may have of its own where a model
+    mixes kinds of layer: the window (None = full causal) and the rotary
     scaling, fields as `TransformerConfig`'s of the same names (its
     `attn_window` is `window` here). Hashable: it rides `jax.checkpoint`
-    as a static argument beside the config."""
+    as a static argument beside the config.
+
+    mixer: "attention" (softmax attention, the fields here) or
+    "gated_delta" (a Gated DeltaNet layer of the config's `gdn_*` sizes,
+    `ops.gated_delta`; it reads none of the attention's fields).
+    output_gate: the attention's output times sigmoid(gate), the gate a
+    second half of the query projection, one value a lane of each head
+    (Qwen3-Next). rotary_dim: the rotary embedding turns the first
+    `rotary_dim` lanes of each head and passes the rest (None: all)."""
     window: Optional[int] = None
     rope_scaling: str = "none"
     rope_factor: float = 1.0
@@ -89,6 +101,9 @@ class AttentionKind:
     rope_beta_fast: float = 32.0
     rope_beta_slow: float = 1.0
     rope_attention_factor: Optional[float] = None
+    mixer: str = "attention"
+    output_gate: bool = False
+    rotary_dim: Optional[int] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -179,6 +194,19 @@ class TransformerConfig:
     moe_dim: Optional[int] = None
     moe_held: Optional[int] = None
     moe_held_first: int = 0
+    # a dropless layer's shared expert: a gated-SiLU expert of this width
+    # that every position runs, its output scaled by sigmoid(h . w) and
+    # added to the routed sum (None: none)
+    moe_shared_dim: Optional[int] = None
+    # the sizes of a Gated DeltaNet layer (a kind whose mixer is
+    # "gated_delta"): key heads and value heads (the key heads divide
+    # the value heads), their widths, the causal depthwise convolution's
+    # kernel over q, k and v
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_dim: int = 128
+    gdn_value_dim: int = 128
+    gdn_conv: int = 4
     # descriptors of the block, read at trace time. norm: "layer"
     # (biased LayerNorm, eps 1e-5) or "rms" (RMSNorm, weight only, eps
     # 1e-6);
@@ -212,6 +240,24 @@ class TransformerConfig:
                     "with layer_types the window and the rotary scaling "
                     "are the kinds': leave attn_window and rope_scaling "
                     "unset")
+            for kind in kinds.values():
+                if kind.mixer not in MIXERS:
+                    raise ValueError(f"mixer must be one of {MIXERS}, got "
+                                     f"{kind.mixer!r}")
+                if kind.mixer == "gated_delta" and not (
+                        0 < self.gdn_key_heads
+                        and self.gdn_value_heads % self.gdn_key_heads == 0):
+                    raise ValueError(
+                        "a gated_delta layer needs gdn_key_heads > 0 "
+                        "dividing gdn_value_heads")
+                rd = kind.rotary_dim
+                if rd is not None and not (0 < rd <= self.head_dim
+                                           and rd % 2 == 0):
+                    raise ValueError(f"rotary_dim {rd} must be even and in "
+                                     f"(0, head_dim {self.head_dim}]")
+        if self.moe_shared_dim is not None and self.moe_router != "dropless":
+            raise ValueError("moe_shared_dim is the dropless layer's shared "
+                             "expert (moe_router='dropless')")
         if self.moe_router == "dropless":
             if not (self.moe_dim and 0 < self.experts_held
                     and 0 <= self.moe_held_first
@@ -285,22 +331,53 @@ def init_params(rng, cfg: TransformerConfig):
                     "bias": jnp.zeros((shape[1],))}
         return {"kernel": smart(key, shape)}
 
-    def block_params(i, k1, k2, k3, k4):
-        p = {
-            "ln1": norm(d),
-            "qkv": dense(k1, (d, qkv_w)),
-            "proj": dense(k2, (cfg.attn_dim, d)),
-            "ln2": norm(d),
+    def gated_delta_params(k1, k2):
+        """Projections to [q | k | v | z] and to [b | a], the convolution
+        over [q | k | v], A_log = log U(1, 16) and dt_bias (the inverse
+        softplus of U(0.001, 0.1)) by value head, the gated norm's
+        weight, the output projection."""
+        nk, nv = cfg.gdn_key_heads, cfg.gdn_value_heads
+        kd, vd = nk * cfg.gdn_key_dim, nv * cfg.gdn_value_dim
+        k_qkvz, k_ba, k_conv, k_a, k_dt = jax.random.split(k1, 5)
+        lim = 1.0 / math.sqrt(cfg.gdn_conv)
+        dt = jax.random.uniform(k_dt, (nv,), minval=1e-3, maxval=0.1)
+        return {
+            "qkvz": dense(k_qkvz, (d, 2 * kd + 2 * vd)),
+            "ba": dense(k_ba, (d, 2 * nv)),
+            "conv": {"kernel": jax.random.uniform(
+                k_conv, (cfg.gdn_conv, 2 * kd + vd), minval=-lim,
+                maxval=lim)},
+            "A_log": jnp.log(jax.random.uniform(k_a, (nv,), minval=1.0,
+                                                maxval=16.0)),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "o_norm": {"scale": jnp.ones((cfg.gdn_value_dim,))},
+            "proj": dense(k2, (vd, d)),
         }
-        if cfg.qk_norm:
-            p["q_norm"] = {"scale": jnp.ones((cfg.head_dim,))}
-            p["k_norm"] = {"scale": jnp.ones((cfg.head_dim,))}
+
+    def block_params(i, k1, k2, k3, k4):
+        kind = cfg.attention_kind(i)
+        if kind.mixer == "gated_delta":
+            p = {"ln1": norm(d), **gated_delta_params(k1, k2),
+                 "ln2": norm(d)}
+        else:
+            # a gated output takes a second query-wide block of columns
+            gate_w = cfg.attn_dim if kind.output_gate else 0
+            p = {
+                "ln1": norm(d),
+                "qkv": dense(k1, (d, qkv_w + gate_w)),
+                "proj": dense(k2, (cfg.attn_dim, d)),
+                "ln2": norm(d),
+            }
+            if cfg.qk_norm:
+                p["q_norm"] = {"scale": jnp.ones((cfg.head_dim,))}
+                p["k_norm"] = {"scale": jnp.ones((cfg.head_dim,))}
         if cfg.is_moe_block(i):
             from paddle_tpu.parallel import moe
 
             if cfg.moe_router == "dropless":
                 p["moe"] = moe.init_dropless_params(
-                    k3, cfg.moe_experts, cfg.experts_held, d, cfg.moe_dim)
+                    k3, cfg.moe_experts, cfg.experts_held, d, cfg.moe_dim,
+                    d_shared=cfg.moe_shared_dim)
             else:
                 p["moe"] = moe.init_moe_params(k3, cfg.moe_experts, d, h)
         else:
@@ -322,11 +399,15 @@ def _norm(cfg: TransformerConfig, p, x):
     """The block's normalisation over the last axis, in float32: biased
     LayerNorm, or RMSNorm x * rsqrt(mean(x^2) + eps) * scale."""
     if cfg.norm == "rms":
-        x32 = at_least_f32(x)
-        y = x32 * jax.lax.rsqrt(
-            jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + 1e-6)
-        return (y * p["scale"]).astype(x.dtype)
+        return _rms_norm(x, p["scale"])
     return norm_ops.layer_norm(x, p["scale"], p["offset"])
+
+
+def _rms_norm(x, scale):
+    x32 = at_least_f32(x)
+    y = x32 * jax.lax.rsqrt(
+        jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + 1e-6)
+    return (y * scale).astype(x.dtype)
 
 
 def require_decodable(cfg: TransformerConfig) -> None:
@@ -334,7 +415,19 @@ def require_decodable(cfg: TransformerConfig) -> None:
     engine) serve the biased-LayerNorm block with dim // n_heads heads,
     one window and one rotary scaling for every layer: their head and
     caches are written for it. A config they cannot serve yet is
-    refused here rather than mis-shaped."""
+    refused here rather than mis-shaped: among them a gated_delta mixer
+    (its cache would be a recurrent state, which no decode path keeps),
+    a gated attention output and partial rotary."""
+    kinds = dict(cfg.attention_kinds or ())
+    new = sorted({"gated_delta mixer" for k in kinds.values()
+                  if k.mixer == "gated_delta"}
+                 | {"output gate" for k in kinds.values() if k.output_gate}
+                 | {"partial rotary" for k in kinds.values()
+                    if k.rotary_dim is not None})
+    if new:
+        raise NotImplementedError(
+            f"decoding is not implemented for a {', '.join(new)}: this "
+            "model trains through loss() only")
     if (cfg.norm != "layer" or not cfg.bias or cfg.qk_norm
             or cfg.head_size is not None or cfg.moe_router == "dropless"
             or cfg.layer_types is not None or cfg.rope_scaling == "yarn"):
@@ -350,8 +443,11 @@ def require_decodable(cfg: TransformerConfig) -> None:
 def _rope(x, positions, base: float, scaling: str = "none",
           factor: float = 1.0, *, original: Optional[int] = None,
           beta_fast: float = 32.0, beta_slow: float = 1.0,
-          attention_factor: Optional[float] = None):
+          attention_factor: Optional[float] = None,
+          rotary_dim: Optional[int] = None):
     """Rotary embedding. x: [B,T,H,Dh] (Dh even), positions: [B,T].
+    rotary_dim: the first `rotary_dim` lanes of each head turn, at the
+    frequencies of a head that wide, and the rest pass (None: all).
 
     scaling extends usable context past the training length without new
     parameters: "linear" compresses positions by `factor` (every
@@ -363,6 +459,11 @@ def _rope(x, positions, base: float, scaling: str = "none",
     `beta_fast` times, blends linearly by lane between the two, and
     multiplies cos and sin by `attention_factor` (0.1 ln(factor) + 1
     where None), so the scores carry its square."""
+    if rotary_dim is not None and rotary_dim < x.shape[-1]:
+        turned = _rope(x[..., :rotary_dim], positions, base, scaling, factor,
+                       original=original, beta_fast=beta_fast,
+                       beta_slow=beta_slow, attention_factor=attention_factor)
+        return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     dh = x.shape[-1]
     if scaling not in ("none", "linear", "ntk", "yarn"):
         raise ValueError(
@@ -560,6 +661,61 @@ def _ffn(cfg: TransformerConfig, p, y, token_mask=None):
             jnp.zeros((), jnp.float32))
 
 
+def _causal_conv(x, kernel):
+    """Causal depthwise convolution over time: x [B, T, C], kernel [K, C]
+    -> out[t] = sum_i kernel[i] x[t - (K - 1) + i] (zeros before the
+    start), in float32."""
+    width = kernel.shape[0]
+    xp = jnp.pad(at_least_f32(x), ((0, 0), (width - 1, 0), (0, 0)))
+    t = x.shape[1]
+    return sum(xp[:, i:i + t] * kernel[i].astype(jnp.float32)
+               for i in range(width))
+
+
+def _l2_normalize(x, eps=1e-6):
+    x = at_least_f32(x)
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True)
+                             + eps)
+
+
+# the gated delta rule's implementation by the config's `attn_impl`, the
+# one switch of the token mixers' kernels
+_GATED_DELTA_IMPL = {"auto": "auto", "flash": "pallas", "dense": "jnp"}
+
+
+def _gated_delta_mixer(cfg: TransformerConfig, p, y):
+    """A Gated DeltaNet layer's token mixer (Qwen3-Next), y [B, T, D]
+    normalised -> [B, T, D]: [q | k | v | z] and [b | a] from y; a causal
+    depthwise convolution of [q | k | v] and SiLU; beta = sigmoid(b),
+    the log decay g = -exp(A_log) softplus(a + dt_bias) by value head in
+    float32; q and k L2-normalised over their lanes, q scaled by
+    dk^-1/2; the gated delta rule (`ops.gated_delta`); RMSNorm of each
+    head's output (one weight, shared) times silu(z); the output
+    projection."""
+    b, t, _ = y.shape
+    nk, nv = cfg.gdn_key_heads, cfg.gdn_value_heads
+    dk, dv = cfg.gdn_key_dim, cfg.gdn_value_dim
+    policy = default_policy()
+    qkvz = linalg.dense(y, p["qkvz"]["kernel"], p["qkvz"].get("bias"))
+    ba = at_least_f32(linalg.dense(y, p["ba"]["kernel"], p["ba"].get("bias")))
+    mixed = jax.nn.silu(_causal_conv(qkvz[..., :2 * nk * dk + nv * dv],
+                                     p["conv"]["kernel"]))
+    q = _l2_normalize(mixed[..., :nk * dk].reshape(b, t, nk, dk)) * dk ** -0.5
+    k = _l2_normalize(mixed[..., nk * dk:2 * nk * dk].reshape(b, t, nk, dk))
+    v = mixed[..., 2 * nk * dk:].reshape(b, t, nv, dv)
+    z = qkvz[..., 2 * nk * dk + nv * dv:].reshape(b, t, nv, dv)
+    beta = jax.nn.sigmoid(ba[..., :nv])
+    g = -jnp.exp(p["A_log"].astype(jnp.float32)) * jax.nn.softplus(
+        ba[..., nv:] + p["dt_bias"].astype(jnp.float32))
+    q, k, v = policy.cast_to_compute(q, k, v)
+    o = gated_delta_rule(q, k, v, g, beta,
+                         impl=_GATED_DELTA_IMPL[cfg.attn_impl])
+    o = _rms_norm(at_least_f32(o), p["o_norm"]["scale"]) * jax.nn.silu(
+        at_least_f32(z))
+    return linalg.dense(o.reshape(b, t, nv * dv).astype(policy.compute_dtype),
+                        p["proj"]["kernel"], p["proj"].get("bias"))
+
+
 def _block_parts(cfg: TransformerConfig, p, x, positions, attn_fn,
                  token_mask=None, kind: Optional[AttentionKind] = None):
     """One pre-LN block with a pluggable attention: attn_fn(q, k, v) ->
@@ -573,26 +729,39 @@ def _block_parts(cfg: TransformerConfig, p, x, positions, attn_fn,
     (_attention's dense/flash, external ring/Ulysses fns) expand at
     their own entry (`_expand_kv`). kind: the layer's rotary scaling
     where the config has kinds by layer (None: the config's own; the
-    window is `attn_fn`'s business)."""
+    window is `attn_fn`'s business). A kind whose mixer is "gated_delta"
+    runs `_gated_delta_mixer` in the attention's place and returns no
+    k, v (None)."""
     b, t, d = x.shape
     h, hkv, dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     kind = kind if kind is not None else cfg.attention_kind()
-    rope = functools.partial(
-        _rope, positions=positions, base=cfg.rope_base,
-        scaling=kind.rope_scaling, factor=kind.rope_factor,
-        original=kind.rope_original, beta_fast=kind.rope_beta_fast,
-        beta_slow=kind.rope_beta_slow,
-        attention_factor=kind.rope_attention_factor)
     y = _norm(cfg, p["ln1"], x)
-    qkv = linalg.dense(y, p["qkv"]["kernel"], p["qkv"].get("bias"))
-    q = qkv[..., :h * dh].reshape(b, t, h, dh)
-    k = qkv[..., h * dh:(h + hkv) * dh].reshape(b, t, hkv, dh)
-    v = qkv[..., (h + hkv) * dh:].reshape(b, t, hkv, dh)
-    if cfg.qk_norm:
-        q, k = _norm(cfg, p["q_norm"], q), _norm(cfg, p["k_norm"], k)
-    q, k = rope(q), rope(k)
-    a = attn_fn(q, k, v).reshape(b, t, cfg.attn_dim)
-    x = x + linalg.dense(a, p["proj"]["kernel"], p["proj"].get("bias"))
+    if kind.mixer == "gated_delta":
+        k = v = None
+        x = x + _gated_delta_mixer(cfg, p, y)
+    else:
+        rope = functools.partial(
+            _rope, positions=positions, base=cfg.rope_base,
+            scaling=kind.rope_scaling, factor=kind.rope_factor,
+            original=kind.rope_original, beta_fast=kind.rope_beta_fast,
+            beta_slow=kind.rope_beta_slow,
+            attention_factor=kind.rope_attention_factor,
+            rotary_dim=kind.rotary_dim)
+        qkv = linalg.dense(y, p["qkv"]["kernel"], p["qkv"].get("bias"))
+        q = qkv[..., :h * dh].reshape(b, t, h, dh)
+        # [q | gate | k | v] where the kind gates its output
+        kv0 = 2 * h * dh if kind.output_gate else h * dh
+        k = qkv[..., kv0:kv0 + hkv * dh].reshape(b, t, hkv, dh)
+        v = qkv[..., kv0 + hkv * dh:].reshape(b, t, hkv, dh)
+        if cfg.qk_norm:
+            q, k = _norm(cfg, p["q_norm"], q), _norm(cfg, p["k_norm"], k)
+        q, k = rope(q), rope(k)
+        a = attn_fn(q, k, v).reshape(b, t, cfg.attn_dim)
+        if kind.output_gate:
+            pallas_util.note_traced("transformer.attention.gate", "sigmoid")
+            gate = jax.nn.sigmoid(at_least_f32(qkv[..., h * dh:kv0]))
+            a = (at_least_f32(a) * gate).astype(a.dtype)
+        x = x + linalg.dense(a, p["proj"]["kernel"], p["proj"].get("bias"))
     y = _norm(cfg, p["ln2"], x)
     out, aux = _ffn(cfg, p, y, token_mask)
     return x + out, k, v, aux
@@ -615,6 +784,16 @@ def _block(cfg: TransformerConfig, p, x, positions, token_mask=None,
     out, _, _, aux = _block_parts(cfg, p, x, positions, attn_fn,
                                   token_mask, kind)
     return out, aux
+
+
+def _rope_label(kind: AttentionKind) -> str:
+    """The rotary embedding of a kind, for the `transformer.rope` counter:
+    its scaling, and `partial_<lanes>` where it turns some lanes only."""
+    if kind.rotary_dim is None:
+        return kind.rope_scaling
+    partial = f"partial_{kind.rotary_dim}"
+    return partial if kind.rope_scaling == "none" else (
+        f"{kind.rope_scaling}_{partial}")
 
 
 def _remat_block(*args):
@@ -663,9 +842,12 @@ def _forward(params, cfg: TransformerConfig, tokens, positions=None,
         names = list(dict.fromkeys(cfg.layer_types))
         pallas_util.note_traced("transformer.layer_kinds", ",".join(
             f"{n}:{cfg.layer_types.count(n)}" for n in names))
+        by_name = dict(cfg.attention_kinds)
+        pallas_util.note_traced("transformer.mixer", ",".join(
+            f"{n}:{by_name[n].mixer}" for n in names))
         pallas_util.note_traced("transformer.rope", ",".join(
-            f"{n}:{dict(cfg.attention_kinds)[n].rope_scaling}"
-            for n in names))
+            f"{n}:{_rope_label(by_name[n])}" for n in names
+            if by_name[n].mixer == "attention"))
     auxes = []
     for p, kind in zip(params["blocks"], kinds):
         x, a = blk(cfg, p, x, positions, token_mask, attn_fn,
@@ -844,6 +1026,13 @@ def make_context_parallel_loss(cfg: TransformerConfig, mesh, *,
     """
     from paddle_tpu import parallel as par
 
+    if any(cfg.attention_kind(i).mixer != "attention"
+           for i in range(cfg.n_layers)):
+        raise ValueError(
+            "a gated_delta mixer is not supported under context "
+            "parallelism: its recurrent state crosses the sequence shards, "
+            "and the ring/Ulysses attention stands in for softmax "
+            "attention alone")
     if any(cfg.attention_kind(i).window is not None
            for i in range(cfg.n_layers)):
         raise ValueError(

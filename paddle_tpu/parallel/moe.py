@@ -486,9 +486,11 @@ def count_dropless_stats(stats: DroplessStats, positions: int,
 
 
 def init_dropless_params(rng, n_experts: int, n_held: int, d_model: int,
-                         d_ff: int, dtype=jnp.float32):
+                         d_ff: int, dtype=jnp.float32, d_shared=None):
     """Router over all `n_experts` + the `n_held` gated-SiLU experts this
-    layer holds, stacked [n_held, ...], no bias anywhere."""
+    layer holds, stacked [n_held, ...], no bias anywhere. `d_shared`: a
+    shared gated-SiLU expert of that width (`shared/{gate,up,down}_proj`)
+    and the kernel [d_model, 1] of its output's scale (`shared_scale`)."""
     k_r, k_g, k_u, k_d = jax.random.split(rng, 4)
     smart = initializers.smart_uniform()
 
@@ -496,12 +498,21 @@ def init_dropless_params(rng, n_experts: int, n_held: int, d_model: int,
         return jnp.stack([smart(k, shape) for k in
                           jax.random.split(key, n_held)]).astype(dtype)
 
-    return {
+    params = {
         "router": {"kernel": smart(k_r, (d_model, n_experts)).astype(dtype)},
         "w_gate": stack(k_g, (d_model, d_ff)),
         "w_up": stack(k_u, (d_model, d_ff)),
         "w_down": stack(k_d, (d_ff, d_model)),
     }
+    if d_shared is not None:
+        k_sg, k_su, k_sd, k_ss = jax.random.split(jax.random.fold_in(rng, 1),
+                                                  4)
+        kernel = lambda key, shape: {"kernel": smart(key, shape).astype(dtype)}
+        params["shared"] = {"gate_proj": kernel(k_sg, (d_model, d_shared)),
+                            "up_proj": kernel(k_su, (d_model, d_shared)),
+                            "down_proj": kernel(k_sd, (d_shared, d_model))}
+        params["shared_scale"] = kernel(k_ss, (d_model, 1))
+    return params
 
 
 @jax.custom_vjp
@@ -569,7 +580,12 @@ def dropless_ffn(params, x, *, k: int, first_held: int = 0,
     another chip's part. No row is dropped whatever the imbalance: the
     row buffer is sized for every choice (T*k rows), and the kernels
     visit only the tiles the held rows fill. token_mask [T] bool:
-    positions that route nowhere."""
+    positions that route nowhere.
+
+    A layer with a shared expert (`params["shared"]`, from
+    `init_dropless_params(d_shared=...)`) adds sigmoid(x . w) times that
+    expert's output on every position to the routed sum (scope
+    `moe/shared`); `token_mask` does not reach it."""
     t, d = x.shape
     n_held = params["w_gate"].shape[0]
     cd = default_policy().compute_dtype
@@ -602,5 +618,16 @@ def dropless_ffn(params, x, *, k: int, first_held: int = 0,
         out = grouped_matmul(hidden, params["w_down"].astype(cd), sizes)
     with jax.named_scope("moe/combine"):
         y = _combine_rows(out, weight, slot_of_pair, held, pair_of_slot)
+    if "shared" in params:
+        pallas_util.note_traced("moe.shared_expert", "gated")
+        with jax.named_scope("moe/shared"):
+            s = params["shared"]
+            xc = x.astype(cd)
+            mm = lambda a, w: jnp.matmul(a, w.astype(cd),
+                                         preferred_element_type=jnp.float32)
+            hidden = (jax.nn.silu(mm(xc, s["gate_proj"]["kernel"]))
+                      * mm(xc, s["up_proj"]["kernel"])).astype(cd)
+            scale = jax.nn.sigmoid(mm(xc, params["shared_scale"]["kernel"]))
+            y = y + scale * mm(hidden, s["down_proj"]["kernel"])
     stats = DroplessStats(jnp.sum(sizes, dtype=jnp.int32), jnp.max(sizes))
     return DroplessOutput(y.astype(x.dtype), stats)

@@ -33,12 +33,21 @@ def test_tiny_runs_every_phase(tmp_path):
     assert lines[0]["compile_cache_dir"] == str(tmp_path / "cache")
     phases = {l["phase"]: l for l in lines if "phase" in l}
     assert list(phases) == ["train_resnet50", "train_transformer",
-                            "train_layer_kinds", "serve_http"]
+                            "train_layer_kinds", "train_hybrid",
+                            "serve_http"]
     kinds = phases["train_layer_kinds"]
     assert kinds["traced"]["transformer.layer_kinds=sliding:3,full:1"] > 0
     assert kinds["traced"]["transformer.rope=sliding:none,full:yarn"] > 0
     assert kinds["traced"]["transformer.ffn=moe_dropless"] > 0
     assert kinds["counters"]["moe.positions"] == 4 * 2 * 64
+    hybrid = phases["train_hybrid"]["traced"]
+    for name in ("transformer.layer_kinds=linear:3,full:1",
+                 "transformer.mixer=linear:gated_delta,full:attention",
+                 "transformer.rope=full:partial_4",
+                 "transformer.attention.gate=sigmoid",
+                 "moe.shared_expert=gated", "gated_delta.forward=jnp",
+                 "gated_delta.backward=jnp", "gated_delta.chunk=64"):
+        assert hybrid[name] > 0, name
     assert phases["train_resnet50"]["sharded_over"] == 2
     serve = phases["serve_http"]
     assert serve["traced"]["ragged_attention=jnp"] > 0

@@ -54,6 +54,9 @@ def _compiled_not_interpreted(monkeypatch):
     # (every needed block cut), and its full-causal layer
     (64, 8192, 128, jnp.bfloat16, 1024, False, (1024, 1024)),
     (64, 8192, 128, jnp.bfloat16, None, False, (1024, 1024)),
+    # qwen3_next_80b_a3b_ep16.train_seq8k's full layer: 2 x 16 heads of
+    # 256 (8 a KV head, expanded before the kernel)
+    (32, 8192, 256, jnp.bfloat16, None, False, (1024, 1024)),
     (16, 2048, 64, jnp.bfloat16, 512, True, (1024, 1024)),  # serving width
     (16, 2048, 64, jnp.bfloat16, None, True, (1024, 1024)),  # a prefill
     (16, 2048, 128, jnp.float32, None, False, (1024, 1024)),  # f32 policy
@@ -230,3 +233,69 @@ def test_dropless_layer_moves_only_held_rows_for_v5e(one_chip, d, f):
                         r"(?!custom-call|get-tuple-element|bitcast)[\w-]+\("
                         r".*op_name=\"[^\"]*moe/(dispatch|combine)", re.M)
     assert not buffer.findall(text)
+
+
+@pytest.mark.parametrize("b,t,hk,hv,dk,dv,dtype", [
+    # qwen3_next_80b_a3b_ep16.train_seq8k: 2 x 8192 positions, 16 key
+    # heads and 32 value heads of 128
+    (2, 8192, 16, 32, 128, 128, jnp.bfloat16),
+    (1, 200, 2, 4, 64, 128, jnp.float32),   # padded to whole chunks
+])
+def test_gated_delta_kernels_compile_for_v5e(one_chip, b, t, hk, hv, dk, dv,
+                                            dtype):
+    """The chunked gated delta rule's forward and backward kernels, with
+    their TN products and the float32 inverse, compile for the v5e."""
+    from paddle_tpu.ops import gated_delta as GD
+
+    sds = lambda shape, dt=dtype: jax.ShapeDtypeStruct(shape, dt,
+                                                       sharding=one_chip)
+
+    def loss(q, k, v, g, beta):
+        o = GD.gated_delta_rule(q, k, v, g, beta, impl="pallas")
+        return jnp.sum(o.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=range(5))).lower(
+        sds((b, t, hk, dk)), sds((b, t, hk, dk)), sds((b, t, hv, dv)),
+        sds((b, t, hv), jnp.float32), sds((b, t, hv), jnp.float32)
+    ).compile().as_text()
+    names = re.findall(r"^\s*%(\w*gated_delta_\w+?)(?:\.\d+)? = ", text,
+                       re.M)
+    assert sorted(names) == ["gated_delta_bwd", "jvp_gated_delta_fwd_"]
+
+
+def test_checkpointed_hybrid_block_compiles_for_v5e(one_chip):
+    """`value_and_grad` of a 2-layer `remat` model, a Gated DeltaNet
+    layer and a gated full-attention layer with partial rotary, at 2 x
+    2048 positions: the GDN layer runs its forward kernel in the forward
+    pass and again, keeping the chunk states, when the backward pass
+    recomputes the block; the attention layer keeps its flash output."""
+    from paddle_tpu.core import dtypes
+    from paddle_tpu.models import transformer as T
+
+    kinds = (("linear_attention", T.AttentionKind(mixer="gated_delta")),
+             ("full_attention", T.AttentionKind(output_gate=True,
+                                                rotary_dim=64)))
+    cfg = T.TransformerConfig(
+        vocab=512, dim=512, n_layers=2, n_heads=4, n_kv_heads=2,
+        head_size=256, mlp_ratio=1, norm="rms", bias=False, qk_norm=True,
+        layer_types=("linear_attention", "full_attention"),
+        attention_kinds=kinds, gdn_key_heads=2, gdn_value_heads=4,
+        attn_impl="flash", remat=True)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    shapes = jax.eval_shape(lambda: T.init_params(jax.random.key(0), cfg))
+    prev = dtypes.default_policy()
+    dtypes.set_default_policy(dtypes.bf16_compute_policy())
+    try:
+        text = jax.jit(jax.value_and_grad(
+            lambda p, toks: T.loss(p, cfg, toks))).lower(
+                jax.tree.map(lambda x: sds(x.shape, x.dtype), shapes),
+                sds((2, 2049), jnp.int32)).compile().as_text()
+    finally:
+        dtypes.set_default_policy(prev)
+    names = re.findall(r"^\s*%(\w*(?:gated_delta|flash_attention)_\w+?)"
+                       r"(?:\.\d+)? = ", text, re.M)
+    assert sorted(names) == sorted([
+        "gated_delta_fwd", "jvp_gated_delta_fwd_", "gated_delta_bwd",
+        "jvp_flash_attention_fwd_", "flash_attention_bwd_dkv",
+        "flash_attention_bwd_dq"]), names

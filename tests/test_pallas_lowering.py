@@ -316,3 +316,31 @@ def test_auto_keeps_kernels_out_of_partitioned_programs(monkeypatch):
     inside = jax.shard_map(run, mesh=mesh, in_specs=(P(), P("data")),
                            out_specs=P("data"), check_vma=False)
     assert _lower_for_tpu(inside, params, x) == 1
+
+
+def test_hybrid_model_lowers_with_the_gated_delta_kernels():
+    """`auto` on one chip: a Gated DeltaNet layer hands the chunked rule
+    the policy's bf16 q, k (repeated to the value heads) and v, float32
+    decays and betas by chunk, and its backward gets the chunk states in
+    float32; the gated full layer runs the flash kernels."""
+    dtypes.set_default_policy(dtypes.bf16_compute_policy())
+    kinds = (("linear_attention", T.AttentionKind(mixer="gated_delta")),
+             ("full_attention", T.AttentionKind(output_gate=True,
+                                                rotary_dim=16)))
+    cfg = T.TransformerConfig(
+        vocab=128, dim=256, n_layers=2, n_heads=4, n_kv_heads=2,
+        head_size=64, mlp_ratio=1, norm="rms", bias=False, qk_norm=True,
+        layer_types=("linear_attention", "full_attention"),
+        attention_kinds=kinds, gdn_key_heads=2, gdn_value_heads=4,
+        gdn_key_dim=64, gdn_value_dim=64, attn_impl="auto")
+    shapes = jax.eval_shape(lambda: T.init_params(jax.random.key(0), cfg))
+    text = _lowered_text(jax.grad(lambda p, toks: T.loss(p, cfg, toks)),
+                         shapes, _sds((2, 257), jnp.int32))
+    kernels = dict(_kernels(text))
+    rows = "tensor<8x256x64xbf16>"          # 2 x 4 value heads, 4 chunks
+    chunks = "tensor<8x4x1x64xf32>"
+    assert kernels["gated_delta_fwd"] == [rows] * 3 + [chunks] * 2
+    assert kernels["gated_delta_bwd"] == [rows] * 3 + [chunks] * 2 + [
+        "tensor<8x4x64x64xf32>", rows]
+    assert {"flash_attention_fwd", "flash_attention_bwd_dkv",
+            "flash_attention_bwd_dq"} <= set(kernels)

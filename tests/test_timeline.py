@@ -766,6 +766,13 @@ def _kernel_jaxpr(kernel: str) -> str:
             lambda q: flash_attention(q, q, q, causal=True))(q))
     if kernel.startswith("ragged"):
         return _ragged_jaxpr(kernel.endswith("int8"))
+    if kernel.startswith("gated_delta"):
+        from paddle_tpu.ops import gated_delta
+
+        x, g = jnp.ones((1, 16, 1, 8)), -jnp.ones((1, 16, 1))
+        loss = lambda q: gated_delta.gated_delta_rule(
+            q, x, x, g, -g, chunk=8, impl="pallas").sum()
+        return str(jax.make_jaxpr(jax.grad(loss))(x))
     run, init = {"gru": (rnn.gru, rnn.init_gru_params),
                  "lstm": (rnn.lstm, rnn.init_lstm_params),
                  "rnn": (rnn.simple_rnn, rnn.init_rnn_params)}[
@@ -776,7 +783,8 @@ def _kernel_jaxpr(kernel: str) -> str:
 @pytest.mark.parametrize("kernel", [
     "flash_attention_fwd", "fused_gru_fwd", "fused_gru_bwd",
     "fused_lstm_fwd", "fused_lstm_bwd", "fused_rnn_fwd", "fused_rnn_bwd",
-    "ragged_paged_attention", "ragged_paged_attention_int8"])
+    "ragged_paged_attention", "ragged_paged_attention_int8",
+    "gated_delta_fwd", "gated_delta_bwd"])
 def test_every_pallas_call_has_a_name(kernel):
     """The name reaches the device trace's event name, which is how a
     roofline reader finds its kernel."""
@@ -786,10 +794,10 @@ def test_every_pallas_call_has_a_name(kernel):
 
 
 def test_no_pallas_call_in_ops_is_unnamed():
-    """Sixteen `pallas_call`s in `ops/` (flash attention's two backward
+    """Eighteen `pallas_call`s in `ops/` (flash attention's two backward
     kernels since PR 32, the two grouped expert products since PR 34,
-    the three row kernels since PR 40), sixteen `name=`: a seventeenth
-    brings its own."""
+    the three row kernels since PR 40, the gated delta rule's two since
+    PR 41), eighteen `name=`: a nineteenth brings its own."""
     import pathlib
     import re
 
@@ -802,4 +810,4 @@ def test_no_pallas_call_in_ops_is_unnamed():
         # a flash call whose window cuts adds a suffix to its name
         names += len(re.findall(
             r"^\s+name=\"\w+\"( \+ _name_suffix\(window\))?,$", src, re.M))
-    assert calls == names == 16
+    assert calls == names == 18
