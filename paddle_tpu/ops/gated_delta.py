@@ -28,8 +28,9 @@ intermediate an entry of the inverse (a Neumann product such as
 (I - A)(I + A^2)(I + A^4)... does not: its powers of A grow before they
 cancel).
 
-* forward, `gated_delta_fwd`: grid (batch x value head, chunk), the
-  chunk axis sequential; S in float32 VMEM scratch across the chunks.
+* forward, `gated_delta_fwd`: grid (batch x value head / Hb, chunk),
+  a step takes Hb value heads of one chunk, the chunk axis sequential;
+  the heads' S in float32 VMEM scratch across the chunks.
   Differentiated, it also writes the state each chunk starts from
   (float32 [BH, chunks, dk, dv]) for the backward;
 * backward, `gated_delta_bwd`: the same grid with the chunks in reverse;
@@ -37,9 +38,18 @@ cancel).
   scratch. Each step recomputes its chunk's A, T, W and U from the
   inputs and the saved state, and writes dq, dk, dv and the gradients
   of G and beta;
-* products with q, k, v, U and the state take the operands' dtype (the
-  policy's compute dtype, bf16 on the chip) with float32 accumulation;
-  the decays, A, T and the state stay float32.
+* a step runs each head's arithmetic as one head alone would, as
+  products batched over its Hb heads; products with q, k, v, U and the
+  state take the operands' dtype (the policy's compute dtype, bf16 on
+  the chip) with float32 accumulation; the inverse and dA are float32
+  products at `HIGHEST`; the decays, A, T and the state stay float32.
+
+`_tiling` takes C and Hb from the call's shapes. A step of one head and
+one chunk is mostly fixed cost and the latency of its chain of about
+15 small dependent products, not MXU work: Hb heads share the fixed
+cost and give the scheduler Hb independent chains, at no extra work,
+where a longer chunk pays the inverse's C^2 log C passes a position
+for its shorter grid (`PERF.md` section 5 times both on the chip).
 
 Around the kernels, in XLA: the key heads' repeat to the value heads
 (value head h reads key head h // (Hv / Hk)), the padding of the
@@ -53,6 +63,7 @@ bodies themselves run in interpret mode for `impl="pallas"` there).
 from __future__ import annotations
 
 import functools
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -62,9 +73,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.ops import pallas_util
 
-#: positions a chunk: the inverse costs C^2 a position, the state's
-#: products 1 / C of theirs (PERF.md section 7 times the kernels by chunk)
-CHUNK = 64
+#: the tiling's longest chunk and most value heads a grid step
+#: (`_tiling`; PERF.md section 5 times the kernels by both)
+CHUNK_MAX = 128
+HEADS_MAX = 4
+#: Mosaic lays out the batched products' transposes only on a chunk of
+#: whole 128-lane tiles: a shorter chunk takes one head a step
+_LANE = 128
 HI = lax.Precision.HIGHEST
 
 
@@ -215,10 +230,10 @@ def _chunk_backward(q, k, v, g, beta, s, do, ds):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest):
-    """One (batch x value head, chunk) step. Refs: q/k [1, C, dk],
-    v/o [1, C, dv], g/beta [1, 1, 1, C] float32; with the states kept,
-    states [1, 1, dk, dv] float32 (the state the chunk starts from);
-    scratch s [dk, dv] float32."""
+    """One (Hb value heads, chunk) step. Refs: q/k [Hb, C, dk], v/o [Hb,
+    C, dv], g/beta [Hb, 1, 1, C] float32; with the states kept, states
+    [Hb, 1, dk, dv] float32 (the state the chunk starts from); scratch
+    s [Hb, dk, dv] float32."""
     *states, s_ref = rest
 
     @pl.when(pl.program_id(1) == 0)
@@ -227,31 +242,31 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest):
 
     s = s_ref[...]
     if states:
-        states[0][0, 0] = s
-    o, s_next = _chunk_forward(q_ref[0], k_ref[0], v_ref[0], g_ref[0, 0],
-                               b_ref[0, 0], s)
-    o_ref[0] = o.astype(o_ref.dtype)
+        states[0][:, 0] = s
+    o, s_next = jax.vmap(_chunk_forward)(
+        q_ref[...], k_ref[...], v_ref[...], g_ref[:, 0], b_ref[:, 0], s)
+    o_ref[...] = o.astype(o_ref.dtype)
     s_ref[...] = s_next
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, do_ref, dq_ref,
                 dk_ref, dv_ref, dg_ref, db_ref, ds_ref):
-    """One (batch x value head, chunk) step, the chunks last to first.
-    Refs as the forward's, s the state the chunk started from, do [1, C,
-    dv]; scratch ds [dk, dv] float32: the gradient of the state the
+    """One (Hb value heads, chunk) step, the chunks last to first. Refs
+    as the forward's, s the state the chunk started from, do [Hb, C,
+    dv]; scratch ds [Hb, dk, dv] float32: the gradient of the state the
     chunk leaves."""
     @pl.when(pl.program_id(1) == 0)
     def _init():
         ds_ref[...] = jnp.zeros_like(ds_ref)
 
-    dq, dk, dv, dg, db, ds0 = _chunk_backward(
-        q_ref[0], k_ref[0], v_ref[0], g_ref[0, 0], b_ref[0, 0], s_ref[0, 0],
-        do_ref[0], ds_ref[...])
-    dq_ref[0] = dq.astype(dq_ref.dtype)
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
-    dg_ref[0, 0] = dg
-    db_ref[0, 0] = db
+    dq, dk, dv, dg, db, ds0 = jax.vmap(_chunk_backward)(
+        q_ref[...], k_ref[...], v_ref[...], g_ref[:, 0], b_ref[:, 0],
+        s_ref[:, 0], do_ref[...], ds_ref[...])
+    dq_ref[...] = dq.astype(dq_ref.dtype)
+    dk_ref[...] = dk.astype(dk_ref.dtype)
+    dv_ref[...] = dv.astype(dv_ref.dtype)
+    dg_ref[:, 0] = dg
+    db_ref[:, 0] = db
     ds_ref[...] = ds0
 
 
@@ -261,27 +276,28 @@ def _params():
         vmem_limit_bytes=pallas_util.VMEM_LIMIT_BYTES)
 
 
-def _pallas_forward(q, k, v, g, beta, keep_states: bool):
-    """q, k [BH, T, dk], v [BH, T, dv]; g, beta [BH, NC, 1, C] float32 ->
-    (o [BH, T, dv], states [BH, NC, dk, dv] float32 or None)."""
+def _pallas_forward(q, k, v, g, beta, keep_states: bool, hb: int):
+    """q, k [BH, T, dk], v [BH, T, dv]; g, beta [BH, NC, 1, C] float32;
+    Hb value heads a grid step -> (o [BH, T, dv], states [BH, NC, dk,
+    dv] float32 or None)."""
     bh, t, dk = q.shape
     dv = v.shape[2]
     nc, c = g.shape[1], g.shape[3]
     vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
-    rows = lambda d: vmem((1, c, d), lambda b, i: (b, i, 0))
-    chunk = vmem((1, 1, 1, c), lambda b, i: (b, i, 0, 0))
-    state = vmem((1, 1, dk, dv), lambda b, i: (b, i, 0, 0))
+    rows = lambda d: vmem((hb, c, d), lambda b, i: (b, i, 0))
+    chunk = vmem((hb, 1, 1, c), lambda b, i: (b, i, 0, 0))
+    state = vmem((hb, 1, dk, dv), lambda b, i: (b, i, 0, 0))
     out_specs = [rows(dv)] + ([state] if keep_states else [])
     out_shape = [jax.ShapeDtypeStruct((bh, t, dv), v.dtype)] + (
         [jax.ShapeDtypeStruct((bh, nc, dk, dv), jnp.float32)]
         if keep_states else [])
     out = pl.pallas_call(
         _fwd_kernel,
-        grid=(bh, nc),
+        grid=(bh // hb, nc),
         in_specs=[rows(dk), rows(dk), rows(dv), chunk, chunk],
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((hb, dk, dv), jnp.float32)],
         compiler_params=_params(),
         interpret=pallas_util.interpret(),
         name="gated_delta_fwd",
@@ -289,18 +305,18 @@ def _pallas_forward(q, k, v, g, beta, keep_states: bool):
     return out[0], (out[1] if keep_states else None)
 
 
-def _pallas_backward(q, k, v, g, beta, states, do):
+def _pallas_backward(q, k, v, g, beta, states, do, hb: int):
     bh, t, dk = q.shape
     dv = v.shape[2]
     nc, c = g.shape[1], g.shape[3]
     vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
     last = lambda i: nc - 1 - i
-    rows = lambda d: vmem((1, c, d), lambda b, i: (b, last(i), 0))
-    chunk = vmem((1, 1, 1, c), lambda b, i: (b, last(i), 0, 0))
-    state = vmem((1, 1, dk, dv), lambda b, i: (b, last(i), 0, 0))
+    rows = lambda d: vmem((hb, c, d), lambda b, i: (b, last(i), 0))
+    chunk = vmem((hb, 1, 1, c), lambda b, i: (b, last(i), 0, 0))
+    state = vmem((hb, 1, dk, dv), lambda b, i: (b, last(i), 0, 0))
     return pl.pallas_call(
         _bwd_kernel,
-        grid=(bh, nc),
+        grid=(bh // hb, nc),
         in_specs=[rows(dk), rows(dk), rows(dv), chunk, chunk, state,
                   rows(dv)],
         out_specs=[rows(dk), rows(dk), rows(dv), chunk, chunk],
@@ -309,7 +325,7 @@ def _pallas_backward(q, k, v, g, beta, states, do):
                    jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct(g.shape, jnp.float32),
                    jax.ShapeDtypeStruct(beta.shape, jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((hb, dk, dv), jnp.float32)],
         compiler_params=_params(),
         interpret=pallas_util.interpret(),
         name="gated_delta_bwd",
@@ -332,7 +348,8 @@ def _by_row(x):
     return x.transpose(1, 0, 2, 3).reshape(bh, nc * c, d)
 
 
-def _scan_forward(q, k, v, g, beta, keep_states: bool):
+def _scan_forward(q, k, v, g, beta, keep_states: bool, hb: int):
+    del hb                  # the scan runs every head at once
     c = g.shape[3]
     xs = [_by_chunk(x, c) for x in (q, k, v, g, beta)]
 
@@ -345,7 +362,8 @@ def _scan_forward(q, k, v, g, beta, keep_states: bool):
     return _by_row(o), (states.transpose(1, 0, 2, 3) if keep_states else None)
 
 
-def _scan_backward(q, k, v, g, beta, states, do):
+def _scan_backward(q, k, v, g, beta, states, do, hb: int):
+    del hb
     c = g.shape[3]
     xs = [_by_chunk(x, c) for x in (q, k, v, g, beta)] + [
         states.transpose(1, 0, 2, 3), _by_chunk(do, c)]
@@ -365,26 +383,61 @@ _FORWARD = {"pallas": _pallas_forward, "jnp": _scan_forward}
 _BACKWARD = {"pallas": _pallas_backward, "jnp": _scan_backward}
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _rule(q, k, v, g, beta, impl):
-    return _FORWARD[impl](q, k, v, g, beta, False)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _rule(q, k, v, g, beta, impl, hb):
+    return _FORWARD[impl](q, k, v, g, beta, False, hb)[0]
 
 
-def _rule_fwd(q, k, v, g, beta, impl):
-    o, states = _FORWARD[impl](q, k, v, g, beta, True)
+def _rule_fwd(q, k, v, g, beta, impl, hb):
+    o, states = _FORWARD[impl](q, k, v, g, beta, True, hb)
     return o, (q, k, v, g, beta, states)
 
 
-def _rule_bwd(impl, res, do):
+def _rule_bwd(impl, hb, res, do):
     pallas_util.note_traced("gated_delta.backward", impl)
     with jax.named_scope("gated_delta_bwd"):
-        return _BACKWARD[impl](*res, do)
+        return _BACKWARD[impl](*res, do, hb)
 
 
 _rule.defvjp(_rule_fwd, _rule_bwd)
 
 
-def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK,
+def _bwd_vmem_bytes(c: int, hb: int, dk: int, dv: int,
+                    itemsize: int) -> int:
+    """What one grid step of the backward, the larger of the two, keeps
+    in VMEM, from its shapes: q, k, v, do and dq, dk, dv blocks with the
+    pipeline's second buffers, the g, beta, dg and dbeta rows (a [1, C]
+    float32 row fills an 8 x 128 tile), the saved state and the dS
+    scratch, and a head's temporaries, ~16 [C, C] and ~16 [C, d] float32
+    arrays, for each of the Hb heads."""
+    d = max(dk, dv)
+    tile_row = 4 * 8 * pl.cdiv(c, _LANE) * _LANE
+    blocks = hb * (itemsize * c * (4 * dk + 3 * dv) + 4 * tile_row
+                   + 4 * dk * dv)
+    return 2 * blocks + hb * (4 * dk * dv + 16 * 4 * c * (c + d))
+
+
+def _tiling(bh: int, t: int, dk: int, dv: int, dtype,
+            chunk: Optional[int] = None) -> Tuple[int, int]:
+    """(C, Hb) for a call of BH rows of T positions: C positions a chunk
+    (`chunk` where the caller gives one, else CHUNK_MAX, or the power of
+    two that covers a shorter T, at least 16) and Hb value heads a grid
+    step: where C fills whole lane tiles, the largest power of two up to
+    HEADS_MAX that divides BH and keeps a backward step
+    (`_bwd_vmem_bytes`) inside `VMEM_BUDGET_BYTES`; else 1. On the v5e
+    at bf16 [2, 8192], 32 value heads of 128, forward + backward: 49.7
+    ms at C 64 and one head a step, 36.2 at C 128, **26.0 at C 128 and
+    Hb 4**, no faster at Hb 8 or 16 (PERF.md section 5)."""
+    c = chunk or min(CHUNK_MAX, max(16, 1 << max(t - 1, 1).bit_length()))
+    itemsize = jnp.dtype(dtype).itemsize
+    hb = min(HEADS_MAX, bh & -bh) if c % _LANE == 0 else 1
+    while hb > 1 and _bwd_vmem_bytes(
+            c, hb, dk, dv, itemsize) > pallas_util.VMEM_BUDGET_BYTES:
+        hb //= 2
+    return c, hb
+
+
+def gated_delta_rule(q, k, v, g, beta, *, chunk: Optional[int] = None,
                      impl: str = "auto"):
     """The gated delta rule over a sequence, from a zero state.
 
@@ -395,8 +448,9 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK,
     should share a dtype: the products take it. impl: "pallas" (the
     kernels; interpreted off the chip), "jnp" (the same chunk functions
     under `lax.scan`) or "auto" (the kernels where `auto` may select
-    one: `pallas_util.auto_kernel`)."""
-    if chunk & (chunk - 1) or chunk < 2:
+    one: `pallas_util.auto_kernel`). chunk: positions a chunk, a power
+    of two; None takes `_tiling`'s from the shapes."""
+    if chunk is not None and (chunk & (chunk - 1) or chunk < 2):
         raise ValueError(f"chunk must be a power of two, got {chunk}")
     if impl == "auto":
         impl = "pallas" if pallas_util.auto_kernel() else "jnp"
@@ -406,8 +460,10 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK,
     hv, dv = v.shape[2:]
     if hv % hk:
         raise ValueError(f"{hk} key heads must divide {hv} value heads")
+    chunk, hb = _tiling(b * hv, t, dk, dv, v.dtype, chunk)
     pallas_util.note_traced("gated_delta.forward", impl)
     pallas_util.note_traced("gated_delta.chunk", str(chunk))
+    pallas_util.note_traced("gated_delta.heads_per_step", str(hb))
     q, k = (jnp.repeat(x, hv // hk, axis=2) for x in (q, k))
     nc = pl.cdiv(t, chunk)
     pad = nc * chunk - t
@@ -421,7 +477,7 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK,
         return x.transpose(0, 2, 1).reshape(b * hv, nc, 1, chunk)
 
     o = _rule(rows(q), rows(k), rows(v), jnp.cumsum(chunks(g), axis=3),
-              chunks(beta), impl)
+              chunks(beta), impl, hb)
     return o.reshape(b, hv, nc * chunk, dv)[:, :, :t].transpose(0, 2, 1, 3)
 
 

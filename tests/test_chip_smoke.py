@@ -46,7 +46,8 @@ def test_tiny_runs_every_phase(tmp_path):
                  "transformer.rope=full:partial_4",
                  "transformer.attention.gate=sigmoid",
                  "moe.shared_expert=gated", "gated_delta.forward=jnp",
-                 "gated_delta.backward=jnp", "gated_delta.chunk=64"):
+                 "gated_delta.backward=jnp", "gated_delta.chunk=64",
+                 "gated_delta.heads_per_step=1"):
         assert hybrid[name] > 0, name
     assert phases["train_resnet50"]["sharded_over"] == 2
     serve = phases["serve_http"]

@@ -235,17 +235,25 @@ def test_dropless_layer_moves_only_held_rows_for_v5e(one_chip, d, f):
     assert not buffer.findall(text)
 
 
-@pytest.mark.parametrize("b,t,hk,hv,dk,dv,dtype", [
+@pytest.mark.parametrize("b,t,hk,hv,dk,dv,dtype,tiling", [
     # qwen3_next_80b_a3b_ep16.train_seq8k: 2 x 8192 positions, 16 key
     # heads and 32 value heads of 128
-    (2, 8192, 16, 32, 128, 128, jnp.bfloat16),
-    (1, 200, 2, 4, 64, 128, jnp.float32),   # padded to whole chunks
+    (2, 8192, 16, 32, 128, 128, jnp.bfloat16, (128, 4)),
+    (1, 200, 2, 4, 64, 128, jnp.float32, (128, 4)),  # padded to a chunk
+    # short sequences: a chunk under the 128 lanes, a batch of one head
+    (1, 50, 2, 4, 64, 128, jnp.float32, (64, 1)),
+    (2, 20, 1, 2, 64, 64, jnp.bfloat16, (32, 1)),
 ])
 def test_gated_delta_kernels_compile_for_v5e(one_chip, b, t, hk, hv, dk, dv,
-                                            dtype):
+                                            dtype, tiling):
     """The chunked gated delta rule's forward and backward kernels, with
-    their TN products and the float32 inverse, compile for the v5e."""
+    their TN products and the float32 inverse, compile for the v5e on
+    the tiling `_tiling` chooses: Hb value heads a step, their products
+    batched."""
     from paddle_tpu.ops import gated_delta as GD
+
+    assert GD._tiling(b * hv, t, dk, dv, dtype) == tiling
+    before = pallas_util.traced()
 
     sds = lambda shape, dt=dtype: jax.ShapeDtypeStruct(shape, dt,
                                                        sharding=one_chip)
@@ -261,6 +269,10 @@ def test_gated_delta_kernels_compile_for_v5e(one_chip, b, t, hk, hv, dk, dv,
     names = re.findall(r"^\s*%(\w*gated_delta_\w+?)(?:\.\d+)? = ", text,
                        re.M)
     assert sorted(names) == ["gated_delta_bwd", "jvp_gated_delta_fwd_"]
+    noted = pallas_util.traced()
+    for name in (f"gated_delta.chunk={tiling[0]}",
+                 f"gated_delta.heads_per_step={tiling[1]}"):
+        assert noted.get(name, 0) > before.get(name, 0), name
 
 
 def test_checkpointed_hybrid_block_compiles_for_v5e(one_chip):

@@ -337,10 +337,10 @@ def test_hybrid_model_lowers_with_the_gated_delta_kernels():
     text = _lowered_text(jax.grad(lambda p, toks: T.loss(p, cfg, toks)),
                          shapes, _sds((2, 257), jnp.int32))
     kernels = dict(_kernels(text))
-    rows = "tensor<8x256x64xbf16>"          # 2 x 4 value heads, 4 chunks
-    chunks = "tensor<8x4x1x64xf32>"
+    rows = "tensor<8x256x64xbf16>"          # 2 x 4 value heads, 2 chunks
+    chunks = "tensor<8x2x1x128xf32>"
     assert kernels["gated_delta_fwd"] == [rows] * 3 + [chunks] * 2
     assert kernels["gated_delta_bwd"] == [rows] * 3 + [chunks] * 2 + [
-        "tensor<8x4x64x64xf32>", rows]
+        "tensor<8x2x64x64xf32>", rows]
     assert {"flash_attention_fwd", "flash_attention_bwd_dkv",
             "flash_attention_bwd_dq"} <= set(kernels)

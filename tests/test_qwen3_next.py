@@ -231,4 +231,5 @@ def test_the_new_pieces_are_noted_while_tracing():
             f"transformer.mixer={LINEAR}:gated_delta,{FULL}:attention",
             f"transformer.rope={FULL}:partial_8",
             "transformer.attention.gate=sigmoid", "moe.shared_expert=gated",
-            "gated_delta.forward=jnp", "gated_delta.chunk=64"} <= noted
+            "gated_delta.forward=jnp", "gated_delta.chunk=32",
+            "gated_delta.heads_per_step=1"} <= noted
