@@ -28,6 +28,13 @@ weights from a seed), in ONE process:
                      layer with a gated shared expert: the gated delta
                      rule's kernels and flash beside each other, checked
                      against the `jnp` chunked rule and the dense path.
+  train_afmoe        the same step on Trinity's block: a leading dense
+                     gated-SiLU layer, then three sliding-window layers
+                     and a full layer without rotary (NoPE), gated
+                     attention and sandwich norms, over the dropless
+                     layer with a sigmoid router choosing by an expert
+                     bias and an ungated shared expert; the router's
+                     counts over all its experts.
   serve_http         the same transformer behind HttpEdge ->
                      ServingRouter -> ServingServer -> DecodeEngine as
                      `cli serve --http` wires them (slots 8, max_len
@@ -115,6 +122,7 @@ class Sizes:
     kinds_lm: dict              # the block of `train_layer_kinds`
     kinds_window: int
     hybrid: dict                # and what `train_hybrid` adds to it
+    afmoe: dict                 # and what `train_afmoe` adds to it
     slots: int
     max_len: int
     prefill_chunk: int
@@ -131,6 +139,7 @@ FULL = Sizes(
                   head_size=128, moe_experts=16, moe_held=4, moe_k=2,
                   moe_dim=256), kinds_window=512,
     hybrid=dict(moe_shared_dim=256, gdn_key_heads=2, gdn_value_heads=4),
+    afmoe=dict(moe_shared_dim=256, mlp_ratio=2),
     slots=8, max_len=2048, prefill_chunk=256,
     prompt_lens=(16, 48, 100, 200, 300, 500, 777, 1024), max_new=32)
 
@@ -144,6 +153,7 @@ TINY = Sizes(
     kinds_window=16,
     hybrid=dict(moe_shared_dim=16, gdn_key_heads=1, gdn_value_heads=2,
                 gdn_key_dim=16, gdn_value_dim=16),
+    afmoe=dict(moe_shared_dim=16, mlp_ratio=2),
     slots=4, max_len=96, prefill_chunk=16,
     prompt_lens=(3, 5, 9, 14, 16, 20, 33, 48), max_new=6)
 
@@ -296,10 +306,15 @@ def _auto_against_dense(sz: Sizes, cfg) -> dict:
     params = T.init_params(jax.random.key(0), cfg)
     toks = jnp.asarray(np.random.default_rng(1).integers(
         0, cfg.vocab, (sz.lm_batch, sz.lm_seq + 1)), jnp.int32)
+    bias = None
+    if cfg.moe_expert_bias:     # a step's bias: it chooses, it has no grad
+        bias = 0.01 * jax.random.normal(jax.random.key(2),
+                                        T.init_expert_bias(cfg).shape)
 
     def step(c):
         return jax.jit(jax.value_and_grad(
-            lambda p, t: T.loss_and_aux(p, c, t), has_aux=True))
+            lambda p, t: T.loss_and_aux(p, c, t, expert_bias=bias),
+            has_aux=True))
 
     (loss, stats), grads = step(cfg)(params, toks)
     (loss_d, _), grads_d = step(dense_cfg)(params, toks)
@@ -317,6 +332,9 @@ def _auto_against_dense(sz: Sizes, cfg) -> dict:
                 if k.startswith("moe.")}
     check(0 < counters["moe.rows_held"] <= cfg.moe_k
           * counters["moe.positions"], f"rows held {counters}")
+    if cfg.moe_expert_bias:
+        check(counters["moe.route_rows"] == cfg.moe_k
+              * counters["moe.positions"], f"rows routed {counters}")
     return {"seq": sz.lm_seq, "loss_auto": loss, "loss_dense": loss_d,
             "grad_norm_auto": gn, "grad_norm_dense": gn_d,
             "counters": counters}
@@ -352,6 +370,27 @@ def train_hybrid(sz: Sizes, devices) -> dict:
         qk_norm=True, layer_types=("linear",) * 3 + ("full",),
         attention_kinds=kinds, moe_router="dropless", moe_every=1,
         attn_impl="auto", remat=True)
+    return _auto_against_dense(sz, cfg)
+
+
+def train_afmoe(sz: Sizes, devices) -> dict:
+    """Trinity's block (AfMoE): a leading dense gated-SiLU layer, then
+    three sliding-window layers with rotary and a full layer without
+    (NoPE), every attention gated, sandwich norms, over the dropless
+    layer with a sigmoid router choosing by an expert bias and an
+    ungated shared expert; `auto` against dense as `train_layer_kinds`."""
+    kinds = (("sliding", T.AttentionKind(window=sz.kinds_window,
+                                         output_gate=True)),
+             ("full", T.AttentionKind(output_gate=True, rotary_dim=0)))
+    cfg = T.TransformerConfig(
+        **sz.kinds_lm, **sz.afmoe, n_layers=5, norm="rms", bias=False,
+        qk_norm=True, rms_eps=1e-5, sandwich_norm=True,
+        embed_scale=float(sz.kinds_lm["dim"]) ** 0.5,
+        layer_types=("sliding",) * 4 + ("full",), attention_kinds=kinds,
+        mlp="swiglu", moe_dense_layers=1, moe_router="dropless",
+        moe_every=1, moe_shared_gate=False, moe_score="sigmoid",
+        moe_route_scale=2.826, moe_expert_bias=True, attn_impl="auto",
+        remat=True)
     return _auto_against_dense(sz, cfg)
 
 
@@ -481,6 +520,7 @@ def main(argv=None) -> int:
               ("train_transformer", train_transformer),
               ("train_layer_kinds", train_layer_kinds),
               ("train_hybrid", train_hybrid),
+              ("train_afmoe", train_afmoe),
               ("serve_http", serve_http)]
     if len(devices) > 1 and not args.tiny:
         phases.append(("multichip_dryrun", multichip_dryrun))

@@ -93,7 +93,8 @@ class AttentionKind:
     output_gate: the attention's output times sigmoid(gate), the gate a
     second half of the query projection, one value a lane of each head
     (Qwen3-Next). rotary_dim: the rotary embedding turns the first
-    `rotary_dim` lanes of each head and passes the rest (None: all)."""
+    `rotary_dim` lanes of each head and passes the rest (None: all; 0:
+    none, a NoPE layer whose positions only the causal mask gives)."""
     window: Optional[int] = None
     rope_scaling: str = "none"
     rope_factor: float = 1.0
@@ -195,9 +196,27 @@ class TransformerConfig:
     moe_held: Optional[int] = None
     moe_held_first: int = 0
     # a dropless layer's shared expert: a gated-SiLU expert of this width
-    # that every position runs, its output scaled by sigmoid(h . w) and
-    # added to the routed sum (None: none)
+    # that every position runs, its output scaled by sigmoid(h . w)
+    # (`moe_shared_gate`; else added as it is) and added to the routed
+    # sum (None: none)
     moe_shared_dim: Optional[int] = None
+    moe_shared_gate: bool = True
+    # the dropless router's score: "softmax" over all experts or
+    # "sigmoid" an expert (the chosen weights renormalised either way),
+    # times `moe_route_scale`. moe_expert_bias: each expert layer chooses
+    # by score + a bias that is not a parameter but the training step's
+    # state (`init_expert_bias`; `moe.update_expert_bias` moves it after
+    # each step, auxiliary-loss-free balancing), and weights by the
+    # unbiased score
+    moe_score: str = "softmax"
+    moe_route_scale: float = 1.0
+    moe_expert_bias: bool = False
+    # FFN kind by layer: the first `moe_dense_layers` blocks keep the
+    # dense MLP whatever `moe_every` says. mlp: the dense MLP of width
+    # mlp_ratio * dim, "gelu" (fc2(gelu(fc1 x))) or "swiglu"
+    # (down(silu(gate x) * up x))
+    moe_dense_layers: int = 0
+    mlp: str = "gelu"
     # the sizes of a Gated DeltaNet layer (a kind whose mixer is
     # "gated_delta"): key heads and value heads (the key heads divide
     # the value heads), their widths, the causal depthwise convolution's
@@ -209,15 +228,21 @@ class TransformerConfig:
     gdn_conv: int = 4
     # descriptors of the block, read at trace time. norm: "layer"
     # (biased LayerNorm, eps 1e-5) or "rms" (RMSNorm, weight only, eps
-    # 1e-6);
+    # `rms_eps`);
     # bias: whether the projections and the MLP carry one; head_size:
     # the width of a head where it is not dim // n_heads; qk_norm: an
     # RMSNorm over each head's lanes of q and of k before the rotation
-    # (one weight vector each, shared by the heads).
+    # (one weight vector each, shared by the heads); sandwich_norm: the
+    # attention's and the FFN's outputs are normalised again before
+    # their residuals (`post_ln1`, `post_ln2`); embed_scale: the
+    # embedding rows times this on the way in (None: as they are).
     norm: str = "layer"
     bias: bool = True
     head_size: Optional[int] = None
     qk_norm: bool = False
+    rms_eps: float = 1e-6
+    sandwich_norm: bool = False
+    embed_scale: Optional[float] = None
 
     def __post_init__(self):
         if self.norm not in ("layer", "rms"):
@@ -251,13 +276,25 @@ class TransformerConfig:
                         "a gated_delta layer needs gdn_key_heads > 0 "
                         "dividing gdn_value_heads")
                 rd = kind.rotary_dim
-                if rd is not None and not (0 < rd <= self.head_dim
+                if rd is not None and not (0 <= rd <= self.head_dim
                                            and rd % 2 == 0):
                     raise ValueError(f"rotary_dim {rd} must be even and in "
-                                     f"(0, head_dim {self.head_dim}]")
+                                     f"[0, head_dim {self.head_dim}]")
         if self.moe_shared_dim is not None and self.moe_router != "dropless":
             raise ValueError("moe_shared_dim is the dropless layer's shared "
                              "expert (moe_router='dropless')")
+        if self.mlp not in ("gelu", "swiglu"):
+            raise ValueError(f"mlp must be 'gelu' or 'swiglu', got "
+                             f"{self.mlp!r}")
+        if self.moe_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"moe_score must be 'softmax' or 'sigmoid', "
+                             f"got {self.moe_score!r}")
+        if self.moe_router != "dropless" and (
+                self.moe_score != "softmax" or self.moe_route_scale != 1.0
+                or self.moe_expert_bias or not self.moe_shared_gate):
+            raise ValueError("moe_score, moe_route_scale, moe_expert_bias "
+                             "and moe_shared_gate describe the dropless "
+                             "layer (moe_router='dropless')")
         if self.moe_router == "dropless":
             if not (self.moe_dim and 0 < self.experts_held
                     and 0 <= self.moe_held_first
@@ -307,8 +344,20 @@ class TransformerConfig:
             self.rope_attention_factor)
 
     def is_moe_block(self, i: int) -> bool:
-        return self.moe_experts > 0 and i % self.moe_every == (
-            self.moe_every - 1)
+        return (self.moe_experts > 0 and i >= self.moe_dense_layers
+                and i % self.moe_every == self.moe_every - 1)
+
+    @property
+    def moe_layers(self) -> tuple:
+        """The indices of the expert layers, in order."""
+        return tuple(i for i in range(self.n_layers) if self.is_moe_block(i))
+
+
+def init_expert_bias(cfg: TransformerConfig):
+    """The expert bias of a config with `moe_expert_bias` at its start:
+    zeros [expert layers, moe_experts] float32, the training step's state
+    beside the parameters (`loss_and_aux(expert_bias=...)`)."""
+    return jnp.zeros((len(cfg.moe_layers), cfg.moe_experts), jnp.float32)
 
 
 def init_params(rng, cfg: TransformerConfig):
@@ -377,12 +426,21 @@ def init_params(rng, cfg: TransformerConfig):
             if cfg.moe_router == "dropless":
                 p["moe"] = moe.init_dropless_params(
                     k3, cfg.moe_experts, cfg.experts_held, d, cfg.moe_dim,
-                    d_shared=cfg.moe_shared_dim)
+                    d_shared=cfg.moe_shared_dim,
+                    shared_gate=cfg.moe_shared_gate)
             else:
                 p["moe"] = moe.init_moe_params(k3, cfg.moe_experts, d, h)
+        elif cfg.mlp == "swiglu":
+            k_up = jax.random.fold_in(k3, 1)
+            p["mlp"] = {"gate_proj": dense(k3, (d, h)),
+                        "up_proj": dense(k_up, (d, h)),
+                        "down_proj": dense(k4, (h, d))}
         else:
             p["fc1"] = dense(k3, (d, h))
             p["fc2"] = dense(k4, (h, d))
+        if cfg.sandwich_norm:
+            p["post_ln1"] = norm(d)
+            p["post_ln2"] = norm(d)
         return p
 
     return {
@@ -399,14 +457,14 @@ def _norm(cfg: TransformerConfig, p, x):
     """The block's normalisation over the last axis, in float32: biased
     LayerNorm, or RMSNorm x * rsqrt(mean(x^2) + eps) * scale."""
     if cfg.norm == "rms":
-        return _rms_norm(x, p["scale"])
+        return _rms_norm(x, p["scale"], cfg.rms_eps)
     return norm_ops.layer_norm(x, p["scale"], p["offset"])
 
 
-def _rms_norm(x, scale):
+def _rms_norm(x, scale, eps: float = 1e-6):
     x32 = at_least_f32(x)
     y = x32 * jax.lax.rsqrt(
-        jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + 1e-6)
+        jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
     return (y * scale).astype(x.dtype)
 
 
@@ -417,13 +475,25 @@ def require_decodable(cfg: TransformerConfig) -> None:
     caches are written for it. A config they cannot serve yet is
     refused here rather than mis-shaped: among them a gated_delta mixer
     (its cache would be a recurrent state, which no decode path keeps),
-    a gated attention output and partial rotary."""
+    a gated attention output, partial rotary or none (NoPE), a sigmoid
+    router, an expert bias, an ungated shared expert, leading dense
+    layers, a gated-SiLU MLP, sandwich norms and an embedding
+    multiplier."""
     kinds = dict(cfg.attention_kinds or ())
+    block = {"sigmoid router": cfg.moe_score == "sigmoid",
+             "expert bias": cfg.moe_expert_bias,
+             "ungated shared expert": not cfg.moe_shared_gate,
+             "leading dense layers": cfg.moe_dense_layers > 0,
+             "gated-SiLU MLP": cfg.mlp == "swiglu",
+             "sandwich norms": cfg.sandwich_norm,
+             "embedding multiplier": cfg.embed_scale is not None}
     new = sorted({"gated_delta mixer" for k in kinds.values()
                   if k.mixer == "gated_delta"}
                  | {"output gate" for k in kinds.values() if k.output_gate}
                  | {"partial rotary" for k in kinds.values()
-                    if k.rotary_dim is not None})
+                    if k.rotary_dim}
+                 | {"NoPE" for k in kinds.values() if k.rotary_dim == 0}
+                 | {name for name, has in block.items() if has})
     if new:
         raise NotImplementedError(
             f"decoding is not implemented for a {', '.join(new)}: this "
@@ -458,7 +528,10 @@ def _rope(x, positions, base: float, scaling: str = "none",
     times in `original` positions, keeps those that turn more than
     `beta_fast` times, blends linearly by lane between the two, and
     multiplies cos and sin by `attention_factor` (0.1 ln(factor) + 1
-    where None), so the scores carry its square."""
+    where None), so the scores carry its square. rotary_dim 0: x as it
+    is (NoPE)."""
+    if rotary_dim == 0:
+        return x
     if rotary_dim is not None and rotary_dim < x.shape[-1]:
         turned = _rope(x[..., :rotary_dim], positions, base, scaling, factor,
                        original=original, beta_fast=beta_fast,
@@ -628,9 +701,10 @@ def _attention(cfg: TransformerConfig, q, k, v, causal: bool,
 
 
 def _ffn(cfg: TransformerConfig, p, y, token_mask=None):
-    """The block's position-wise FFN: dense MLP or MoE when the block
-    carries expert params. Returns (out, aux_loss). token_mask [B, T]
-    keeps padding from claiming expert capacity."""
+    """The block's position-wise FFN: dense MLP (tanh-GELU, or gated
+    SiLU where the block carries `mlp`) or MoE when the block carries
+    expert params. Returns (out, aux_loss). token_mask [B, T] keeps
+    padding from claiming expert capacity."""
     if "moe" in p:
         from paddle_tpu.parallel import moe
 
@@ -640,7 +714,8 @@ def _ffn(cfg: TransformerConfig, p, y, token_mask=None):
             pallas_util.note_traced("transformer.ffn", "moe_dropless")
             out = moe.dropless_ffn(
                 p["moe"], y.reshape(b * t, d), k=cfg.moe_k,
-                first_held=cfg.moe_held_first, token_mask=flat_mask)
+                first_held=cfg.moe_held_first, token_mask=flat_mask,
+                score=cfg.moe_score, route_scale=cfg.moe_route_scale)
             return out.y.reshape(b, t, d), out.stats
         if cfg.moe_router == "expert_choice":
             out = moe.expert_choice_ffn(
@@ -656,6 +731,13 @@ def _ffn(cfg: TransformerConfig, p, y, token_mask=None):
                 f"moe_router must be 'topk', 'expert_choice' or "
                 f"'dropless', got {cfg.moe_router!r}")
         return out.y.reshape(b, t, d), out.aux_loss
+    if "mlp" in p:
+        pallas_util.note_traced("transformer.ffn", "dense_swiglu")
+        m = p["mlp"]
+        dense = lambda a, w: linalg.dense(a, w["kernel"], w.get("bias"))
+        hidden = (jax.nn.silu(at_least_f32(dense(y, m["gate_proj"])))
+                  * at_least_f32(dense(y, m["up_proj"]))).astype(y.dtype)
+        return (dense(hidden, m["down_proj"]), jnp.zeros((), jnp.float32))
     y = jax.nn.gelu(linalg.dense(y, p["fc1"]["kernel"], p["fc1"].get("bias")))
     return (linalg.dense(y, p["fc2"]["kernel"], p["fc2"].get("bias")),
             jnp.zeros((), jnp.float32))
@@ -710,7 +792,8 @@ def _gated_delta_mixer(cfg: TransformerConfig, p, y):
     q, k, v = policy.cast_to_compute(q, k, v)
     o = gated_delta_rule(q, k, v, g, beta,
                          impl=_GATED_DELTA_IMPL[cfg.attn_impl])
-    o = _rms_norm(at_least_f32(o), p["o_norm"]["scale"]) * jax.nn.silu(
+    o = _rms_norm(at_least_f32(o), p["o_norm"]["scale"],
+                  cfg.rms_eps) * jax.nn.silu(
         at_least_f32(z))
     return linalg.dense(o.reshape(b, t, nv * dv).astype(policy.compute_dtype),
                         p["proj"]["kernel"], p["proj"].get("bias"))
@@ -731,14 +814,19 @@ def _block_parts(cfg: TransformerConfig, p, x, positions, attn_fn,
     where the config has kinds by layer (None: the config's own; the
     window is `attn_fn`'s business). A kind whose mixer is "gated_delta"
     runs `_gated_delta_mixer` in the attention's place and returns no
-    k, v (None)."""
+    k, v (None). Under `cfg.sandwich_norm` each branch's output is
+    normalised again (`post_ln1`, `post_ln2`) before its residual."""
     b, t, d = x.shape
     h, hkv, dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     kind = kind if kind is not None else cfg.attention_kind()
+    post = lambda name, out: out
+    if cfg.sandwich_norm:
+        pallas_util.note_traced("transformer.post_norm", "sandwich")
+        post = lambda name, out: _norm(cfg, p[name], out)
     y = _norm(cfg, p["ln1"], x)
     if kind.mixer == "gated_delta":
         k = v = None
-        x = x + _gated_delta_mixer(cfg, p, y)
+        x = x + post("post_ln1", _gated_delta_mixer(cfg, p, y))
     else:
         rope = functools.partial(
             _rope, positions=positions, base=cfg.rope_base,
@@ -761,10 +849,11 @@ def _block_parts(cfg: TransformerConfig, p, x, positions, attn_fn,
             pallas_util.note_traced("transformer.attention.gate", "sigmoid")
             gate = jax.nn.sigmoid(at_least_f32(qkv[..., h * dh:kv0]))
             a = (at_least_f32(a) * gate).astype(a.dtype)
-        x = x + linalg.dense(a, p["proj"]["kernel"], p["proj"].get("bias"))
+        x = x + post("post_ln1", linalg.dense(a, p["proj"]["kernel"],
+                                              p["proj"].get("bias")))
     y = _norm(cfg, p["ln2"], x)
     out, aux = _ffn(cfg, p, y, token_mask)
-    return x + out, k, v, aux
+    return x + post("post_ln2", out), k, v, aux
 
 
 def _block(cfg: TransformerConfig, p, x, positions, token_mask=None,
@@ -788,7 +877,10 @@ def _block(cfg: TransformerConfig, p, x, positions, token_mask=None,
 
 def _rope_label(kind: AttentionKind) -> str:
     """The rotary embedding of a kind, for the `transformer.rope` counter:
-    its scaling, and `partial_<lanes>` where it turns some lanes only."""
+    its scaling, `partial_<lanes>` where it turns some lanes only, and
+    `nope` where it turns none."""
+    if kind.rotary_dim == 0:
+        return "nope"
     if kind.rotary_dim is None:
         return kind.rope_scaling
     partial = f"partial_{kind.rotary_dim}"
@@ -812,7 +904,7 @@ def _remat_block(*args):
 
 def _forward(params, cfg: TransformerConfig, tokens, positions=None,
              token_mask=None, attn_fn=None, return_hidden=False,
-             block_diffusion=None):
+             block_diffusion=None, expert_bias=None):
     """tokens [B,T] int32 -> (logits [B,T,V], summed MoE aux loss; for
     a dropless MoE its `DroplessStats`, stacked over the layers).
     token_mask [B,T] bool marks real (non-padding) positions for MoE
@@ -820,9 +912,27 @@ def _forward(params, cfg: TransformerConfig, tokens, positions=None,
     context-parallel builder injects ring/Ulysses attention here).
     return_hidden=True skips the LM-head matmul and returns the final
     post-norm hidden [B,T,D] instead (the fused-CE loss path folds the
-    head into its chunked scan)."""
+    head into its chunked scan). expert_bias [expert layers, E]: the
+    step's expert bias where the config has `moe_expert_bias` (row j is
+    the j-th expert layer's; `init_expert_bias`)."""
+    if cfg.moe_expert_bias:
+        if expert_bias is None:
+            raise ValueError("moe_expert_bias: the experts are chosen by "
+                             "the step's expert bias; pass expert_bias "
+                             "(init_expert_bias(cfg) at the start)")
+        # the bias rides each expert layer's params into its block: it
+        # only chooses, so no gradient reaches it
+        moe_at = {i: j for j, i in enumerate(cfg.moe_layers)}
+        params = {**params, "blocks": [
+            {**p, "moe": {**p["moe"], "expert_bias": jax.lax.stop_gradient(
+                expert_bias[moe_at[i]])}} if i in moe_at else p
+            for i, p in enumerate(params["blocks"])]}
+    elif expert_bias is not None:
+        raise ValueError("expert_bias needs a config with moe_expert_bias")
     policy = default_policy()
     x = jnp.take(params["embed"]["table"], tokens, axis=0)
+    if cfg.embed_scale is not None:
+        x = at_least_f32(x) * cfg.embed_scale
     x = x.astype(policy.compute_dtype)
     if positions is None:
         positions = jnp.broadcast_to(
@@ -864,18 +974,23 @@ def _forward(params, cfg: TransformerConfig, tokens, positions=None,
     return linalg.matmul(x, params["lm_head"]["kernel"]), aux
 
 
-def apply(params, cfg: TransformerConfig, tokens, positions=None):
+def apply(params, cfg: TransformerConfig, tokens, positions=None,
+          expert_bias=None):
     """tokens [B,T] int32 -> logits [B,T,V]."""
-    return _forward(params, cfg, tokens, positions)[0]
+    return _forward(params, cfg, tokens, positions,
+                    expert_bias=expert_bias)[0]
 
 
 def loss_and_aux(params, cfg: TransformerConfig, tokens, lengths=None,
-                 attn_fn=None):
+                 attn_fn=None, expert_bias=None):
     """`loss()` and the forward's auxiliary output: for a dropless MoE
     its `DroplessStats`, stacked over the layers (what
     `moe.count_dropless_stats` adds to the timeline's `moe.*` counters,
-    as `block_diffusion_loss` hands them back); for the other routers
-    the summed load-balance term, already in the loss."""
+    as `block_diffusion_loss` hands them back, and whose `route_counts`
+    `moe.update_expert_bias` reads where the config has
+    `moe_expert_bias`); for the other routers the summed load-balance
+    term, already in the loss. expert_bias: the step's, as `_forward`
+    takes it."""
     tmask = None
     if lengths is not None:
         tmask = jnp.arange(
@@ -883,13 +998,15 @@ def loss_and_aux(params, cfg: TransformerConfig, tokens, lengths=None,
     targets = tokens[:, 1:]
     if cfg.fused_ce_chunk:
         hid, aux = _forward(params, cfg, tokens[:, :-1], token_mask=tmask,
-                            attn_fn=attn_fn, return_hidden=True)
+                            attn_fn=attn_fn, return_hidden=True,
+                            expert_bias=expert_bias)
         nll = losses_ops.chunked_lm_head_nll(
             hid, params["lm_head"]["kernel"], targets,
             chunk=cfg.fused_ce_chunk)
     else:
         logits, aux = _forward(params, cfg, tokens[:, :-1],
-                               token_mask=tmask, attn_fn=attn_fn)
+                               token_mask=tmask, attn_fn=attn_fn,
+                               expert_bias=expert_bias)
         lse = jax.nn.logsumexp(at_least_f32(logits), axis=-1)
         gold = jnp.take_along_axis(
             at_least_f32(logits), targets[..., None], axis=-1)[..., 0]
@@ -906,14 +1023,15 @@ def loss_and_aux(params, cfg: TransformerConfig, tokens, lengths=None,
 
 
 def loss(params, cfg: TransformerConfig, tokens, lengths=None,
-         attn_fn=None):
+         attn_fn=None, expert_bias=None):
     """Next-token cross entropy over tokens [B, T+1] (+ the weighted
     load-balance term where the config has "topk" or "expert_choice"
     experts; a dropless layer has none); positions >= lengths are masked
     out of the CE term AND of the experts' routing. Every layer attends
     by its own kind where the config gives kinds by layer.
     `loss_and_aux` also returns the forward's auxiliary output."""
-    return loss_and_aux(params, cfg, tokens, lengths, attn_fn)[0]
+    return loss_and_aux(params, cfg, tokens, lengths, attn_fn,
+                        expert_bias)[0]
 
 
 def block_diffusion_noise(rng, tokens, block_length: int, *,

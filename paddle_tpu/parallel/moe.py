@@ -460,9 +460,13 @@ def make_expert_parallel_ffn(mesh: Mesh, *, axis: str = MODEL_AXIS,
 
 
 class DroplessStats(NamedTuple):
-    """Counts of one dropless layer's step, int32 scalars."""
+    """Counts of one dropless layer's step, int32. `route_counts`: a
+    layer that chooses by an expert bias also counts the rows routed to
+    each of the router's experts, held or not ([E]; what
+    `update_expert_bias` reads), and None elsewhere."""
     rows_held: jnp.ndarray          # (position, choice) rows of held experts
     rows_max_expert: jnp.ndarray    # rows of the fullest held expert
+    route_counts: Optional[jnp.ndarray] = None
 
 
 class DroplessOutput(NamedTuple):
@@ -475,7 +479,10 @@ def count_dropless_stats(stats: DroplessStats, positions: int,
     """Host side, where a training loop reads its loss: add a step's
     counts (the loss's auxiliary output, stacked over the layers) to the
     timeline's counters `moe.rows_held`, `moe.rows_max_expert` and
-    `moe.positions` (positions routed, a layer each)."""
+    `moe.positions` (positions routed, a layer each); where the layers
+    count their routes over all experts, also `moe.route_rows` (rows
+    routed) and `moe.route_rows_max` (rows of the fullest of all the
+    router's experts, summed over the layers)."""
     from paddle_tpu.obs.trace import default_timeline
 
     timeline = timeline if timeline is not None else default_timeline()
@@ -483,14 +490,40 @@ def count_dropless_stats(stats: DroplessStats, positions: int,
     timeline.count("moe.rows_held", int(jnp.sum(rows_held)))
     timeline.count("moe.rows_max_expert", int(jnp.sum(stats.rows_max_expert)))
     timeline.count("moe.positions", positions * rows_held.shape[0])
+    if stats.route_counts is not None:
+        counts = jnp.asarray(stats.route_counts)
+        timeline.count("moe.route_rows", int(jnp.sum(counts)))
+        timeline.count("moe.route_rows_max",
+                       int(jnp.sum(jnp.max(counts, axis=-1))))
+
+
+def update_expert_bias(bias, route_counts, coeff: float):
+    """Auxiliary-loss-free load balancing (Wang et al. 2024, as
+    DeepSeek-V3 and Trinity train): after a step, each expert's bias
+    moves `coeff` towards the mean load, up where the expert took fewer
+    rows than the mean and down where it took more, and the moves are
+    centred so that the biases keep their mean:
+
+        delta_e = coeff * sign(mean(c) - c_e);  b <- b + delta - mean(delta)
+
+    bias and route_counts [..., E] (a layer's `DroplessStats.route_counts`,
+    or a stack of layers'); float32 out. No gradient reaches the bias:
+    it only chooses, and the step updates it outside the optimizer."""
+    with jax.named_scope("moe/bias_update"):
+        c = route_counts.astype(jnp.float32)
+        delta = coeff * jnp.sign(jnp.mean(c, axis=-1, keepdims=True) - c)
+        return (bias.astype(jnp.float32) + delta
+                - jnp.mean(delta, axis=-1, keepdims=True))
 
 
 def init_dropless_params(rng, n_experts: int, n_held: int, d_model: int,
-                         d_ff: int, dtype=jnp.float32, d_shared=None):
+                         d_ff: int, dtype=jnp.float32, d_shared=None,
+                         shared_gate: bool = True):
     """Router over all `n_experts` + the `n_held` gated-SiLU experts this
     layer holds, stacked [n_held, ...], no bias anywhere. `d_shared`: a
     shared gated-SiLU expert of that width (`shared/{gate,up,down}_proj`)
-    and the kernel [d_model, 1] of its output's scale (`shared_scale`)."""
+    and, where `shared_gate`, the kernel [d_model, 1] of its output's
+    scale (`shared_scale`)."""
     k_r, k_g, k_u, k_d = jax.random.split(rng, 4)
     smart = initializers.smart_uniform()
 
@@ -511,7 +544,8 @@ def init_dropless_params(rng, n_experts: int, n_held: int, d_model: int,
         params["shared"] = {"gate_proj": kernel(k_sg, (d_model, d_shared)),
                             "up_proj": kernel(k_su, (d_model, d_shared)),
                             "down_proj": kernel(k_sd, (d_shared, d_model))}
-        params["shared_scale"] = kernel(k_ss, (d_model, 1))
+        if shared_gate:
+            params["shared_scale"] = kernel(k_ss, (d_model, 1))
     return params
 
 
@@ -568,11 +602,19 @@ _combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
 
 
 def dropless_ffn(params, x, *, k: int, first_held: int = 0,
-                 token_mask=None) -> DroplessOutput:
+                 token_mask=None, score: str = "softmax",
+                 route_scale: float = 1.0) -> DroplessOutput:
     """Dropless token-choice MoE over the experts this layer holds.
-    x: [T, D]. The router is a softmax over all E = router width in
-    float32, the k largest, renormalised over the k chosen (held or
-    not). Of the T*k (position, choice) rows, those whose expert is one
+    x: [T, D]. The router scores all E = router width in float32, by a
+    softmax or, with `score="sigmoid"`, an independent sigmoid an
+    expert; the k largest are chosen and renormalised over the k chosen
+    (held or not; a sigmoid's sum + 1e-20, as the published router),
+    times `route_scale`. A layer whose params carry `expert_bias` [E]
+    chooses the k largest of score + bias and weights them by the
+    unbiased scores (auxiliary-loss-free balancing: the bias only
+    chooses, `update_expert_bias` moves it), and counts the rows routed
+    to every expert (`DroplessStats.route_counts`).
+    Of the T*k (position, choice) rows, those whose expert is one
     of the `params["w_gate"].shape[0]` held, from expert `first_held`,
     are ordered by expert and computed as grouped products: gate and up,
     silu(gate) * up, down; each position then adds its rows by its
@@ -585,18 +627,36 @@ def dropless_ffn(params, x, *, k: int, first_held: int = 0,
     A layer with a shared expert (`params["shared"]`, from
     `init_dropless_params(d_shared=...)`) adds sigmoid(x . w) times that
     expert's output on every position to the routed sum (scope
-    `moe/shared`); `token_mask` does not reach it."""
+    `moe/shared`), or the output alone where the layer has no
+    `shared_scale`; `token_mask` does not reach it."""
+    if score not in ("softmax", "sigmoid"):
+        raise ValueError(f"score must be 'softmax' or 'sigmoid', got "
+                         f"{score!r}")
     t, d = x.shape
     n_held = params["w_gate"].shape[0]
     cd = default_policy().compute_dtype
     pallas_util.note_traced("moe.expert_matmul", "pallas_grouped")
     pallas_util.note_traced("moe.row_gather", "held_rows")
+    bias = params.get("expert_bias")
+    pallas_util.note_traced("moe.router", score if bias is None
+                            else f"{score}_bias")
     with jax.named_scope("moe/router"):
         logits = jnp.matmul(x.astype(jnp.float32),
                             params["router"]["kernel"].astype(jnp.float32),
                             precision=lax.Precision.HIGHEST)
-        top_p, top_e = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
-        weight = top_p / jnp.sum(top_p, axis=-1, keepdims=True)   # [T, k]
+        if score == "softmax":
+            probs = jax.nn.softmax(logits, axis=-1)
+        else:
+            probs = jax.nn.sigmoid(logits)
+        if bias is None:
+            top_p, top_e = lax.top_k(probs, k)
+        else:
+            _, top_e = lax.top_k(probs + bias.astype(jnp.float32), k)
+            top_p = jnp.take_along_axis(probs, top_e, axis=-1)
+        total = jnp.sum(top_p, axis=-1, keepdims=True)
+        weight = top_p / (total if score == "softmax" else total + 1e-20)
+        if route_scale != 1.0:
+            weight = weight * route_scale                         # [T, k]
     with jax.named_scope("moe/dispatch"):
         local = top_e.astype(jnp.int32) - first_held
         held = (local >= 0) & (local < n_held)
@@ -619,7 +679,9 @@ def dropless_ffn(params, x, *, k: int, first_held: int = 0,
     with jax.named_scope("moe/combine"):
         y = _combine_rows(out, weight, slot_of_pair, held, pair_of_slot)
     if "shared" in params:
-        pallas_util.note_traced("moe.shared_expert", "gated")
+        gated = "shared_scale" in params
+        pallas_util.note_traced("moe.shared_expert",
+                                "gated" if gated else "plain")
         with jax.named_scope("moe/shared"):
             s = params["shared"]
             xc = x.astype(cd)
@@ -627,7 +689,18 @@ def dropless_ffn(params, x, *, k: int, first_held: int = 0,
                                          preferred_element_type=jnp.float32)
             hidden = (jax.nn.silu(mm(xc, s["gate_proj"]["kernel"]))
                       * mm(xc, s["up_proj"]["kernel"])).astype(cd)
-            scale = jax.nn.sigmoid(mm(xc, params["shared_scale"]["kernel"]))
-            y = y + scale * mm(hidden, s["down_proj"]["kernel"])
-    stats = DroplessStats(jnp.sum(sizes, dtype=jnp.int32), jnp.max(sizes))
+            if gated:
+                scale = jax.nn.sigmoid(mm(xc, params["shared_scale"]["kernel"]))
+                y = y + scale * mm(hidden, s["down_proj"]["kernel"])
+            else:
+                y = y + mm(hidden, s["down_proj"]["kernel"])
+    route_counts = None
+    if bias is not None:
+        routed = (jnp.ones_like(top_e, jnp.int32) if token_mask is None
+                  else jnp.broadcast_to(token_mask[:, None], top_e.shape
+                                        ).astype(jnp.int32))
+        route_counts = jnp.zeros((probs.shape[-1],), jnp.int32).at[
+            top_e.reshape(-1)].add(routed.reshape(-1))
+    stats = DroplessStats(jnp.sum(sizes, dtype=jnp.int32), jnp.max(sizes),
+                          route_counts)
     return DroplessOutput(y.astype(x.dtype), stats)

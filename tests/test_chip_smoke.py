@@ -34,7 +34,7 @@ def test_tiny_runs_every_phase(tmp_path):
     phases = {l["phase"]: l for l in lines if "phase" in l}
     assert list(phases) == ["train_resnet50", "train_transformer",
                             "train_layer_kinds", "train_hybrid",
-                            "serve_http"]
+                            "train_afmoe", "serve_http"]
     kinds = phases["train_layer_kinds"]
     assert kinds["traced"]["transformer.layer_kinds=sliding:3,full:1"] > 0
     assert kinds["traced"]["transformer.rope=sliding:none,full:yarn"] > 0
@@ -49,6 +49,15 @@ def test_tiny_runs_every_phase(tmp_path):
                  "gated_delta.backward=jnp", "gated_delta.chunk=64",
                  "gated_delta.heads_per_step=1"):
         assert hybrid[name] > 0, name
+    afmoe = phases["train_afmoe"]
+    for name in ("transformer.layer_kinds=sliding:4,full:1",
+                 "transformer.rope=sliding:none,full:nope",
+                 "transformer.ffn=dense_swiglu", "transformer.ffn=moe_dropless",
+                 "transformer.post_norm=sandwich", "moe.router=sigmoid_bias",
+                 "moe.shared_expert=plain"):
+        assert afmoe["traced"][name] > 0, name
+    assert afmoe["counters"]["moe.positions"] == 4 * 2 * 64
+    assert afmoe["counters"]["moe.route_rows"] == 2 * 4 * 2 * 64
     assert phases["train_resnet50"]["sharded_over"] == 2
     serve = phases["serve_http"]
     assert serve["traced"]["ragged_attention=jnp"] > 0

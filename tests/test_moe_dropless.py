@@ -13,11 +13,19 @@ from paddle_tpu.ops import moe_grouped_matmul as G
 from paddle_tpu.parallel import moe
 
 
-def every_expert(params, x, k, first_held=0):
-    """No sort, no grouping: each held expert applied to all of x."""
-    probs = jax.nn.softmax(x @ params["router"]["kernel"], axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, k)
-    w = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+def every_expert(params, x, k, first_held=0, score="softmax",
+                 route_scale=1.0):
+    """No sort, no grouping: each held expert applied to all of x. A
+    sigmoid router chooses by score + `expert_bias` where the params have
+    one, and weights by the unbiased scores."""
+    logits = x @ params["router"]["kernel"]
+    if score == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+    else:
+        probs = jax.nn.sigmoid(logits)
+    top_e = jax.lax.top_k(probs + params.get("expert_bias", 0.0), k)[1]
+    top_p = jnp.take_along_axis(probs, top_e, axis=-1)
+    w = route_scale * top_p / jnp.sum(top_p, axis=-1, keepdims=True)
     y = jnp.zeros_like(x)
     for e in range(params["w_gate"].shape[0]):
         w_e = jnp.sum(jnp.where(top_e == first_held + e, w, 0.0), axis=-1)
@@ -43,27 +51,48 @@ def _layer(seed, n_experts, n_held, d=32, f=16, t=32):
     return params, x
 
 
-@pytest.mark.parametrize("n_experts,n_held,first,k", [
-    (8, 8, 0, 2),           # top-2 of 8, all held
-    (128, 16, 0, 8),        # the cell's share: top-8 of 128, 16 held
-    (128, 16, 48, 8),       # another chip's share
-    (64, 16, 0, 8),         # top-8 of 64, 16 held: a 4-way share
-    (64, 16, 32, 8),        # the third chip of the four
-])
-def test_values_and_gradients(n_experts, n_held, first, k):
+# the sigmoid router of Trinity: its route_scale, and a bias that
+# chooses (the gradient of the layer does not reach it)
+SIGMOID = dict(score="sigmoid", route_scale=2.826)
+
+
+@pytest.mark.parametrize("n_experts,n_held,first,k,router", [
+    (8, 8, 0, 2, {}),       # top-2 of 8, all held
+    (128, 16, 0, 8, {}),    # the cell's share: top-8 of 128, 16 held
+    (128, 16, 48, 8, {}),   # another chip's share
+    (64, 16, 0, 8, {}),     # top-8 of 64, 16 held: a 4-way share
+    (64, 16, 32, 8, {}),    # the third chip of the four
+    (128, 16, 0, 8, SIGMOID),   # Trinity's share: sigmoid, biased choice
+    (128, 16, 112, 8, SIGMOID),  # the last chip of its eight
+], ids=["8-8-0-2", "128-16-0-8", "128-16-48-8", "64-16-0-8", "64-16-32-8",
+        "128-16-0-8-sigmoid_bias", "128-16-112-8-sigmoid_bias"])
+def test_values_and_gradients(n_experts, n_held, first, k, router):
     params, x = _layer(0, n_experts, n_held)
-    out = moe.dropless_ffn(params, x, k=k, first_held=first)
+    if router:
+        params["expert_bias"] = 0.05 * jax.random.normal(
+            jax.random.key(8), (n_experts,))
+    out = moe.dropless_ffn(params, x, k=k, first_held=first, **router)
     np.testing.assert_allclose(np.asarray(out.y), np.asarray(
-        every_expert(params, x, k, first)), rtol=1e-4, atol=1e-5)
-    rows = rows_of_held(params, x, k, first)
-    assert int(out.stats.rows_held) == rows.sum()       # no row dropped
-    assert int(out.stats.rows_max_expert) == rows.max()
+        every_expert(params, x, k, first, **router)), rtol=1e-4, atol=1e-5)
+    if not router:
+        rows = rows_of_held(params, x, k, first)
+        assert int(out.stats.rows_held) == rows.sum()   # no row dropped
+        assert int(out.stats.rows_max_expert) == rows.max()
+        assert out.stats.route_counts is None
+    else:
+        counts = out.stats.route_counts
+        assert counts.shape == (n_experts,) and int(counts.sum()) == (
+            k * x.shape[0])
+        assert int(out.stats.rows_held) == int(
+            counts[first:first + n_held].sum())
 
     w = jax.random.normal(jax.random.key(9), x.shape, jnp.float32)
     got = jax.grad(lambda p, x: jnp.sum(moe.dropless_ffn(
-        p, x, k=k, first_held=first).y * w), argnums=(0, 1))(params, x)
-    want = jax.grad(lambda p, x: jnp.sum(every_expert(p, x, k, first) * w),
-                    argnums=(0, 1))(params, x)
+        p, x, k=k, first_held=first, **router).y * w), argnums=(0, 1))(
+            params, x)
+    want = jax.grad(lambda p, x: jnp.sum(
+        every_expert(p, x, k, first, **router) * w), argnums=(0, 1))(
+            params, x)
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
                             jax.tree.leaves(want)):
         np.testing.assert_allclose(
@@ -127,22 +156,29 @@ def test_masked_positions_route_nowhere():
     assert int(out.stats.rows_held) == 2 * int(mask.sum())
 
 
-@pytest.mark.parametrize("n_experts,chips", [(128, 8), (64, 4)])
-def test_the_shares_add_up_to_the_whole_layer(n_experts, chips):
+@pytest.mark.parametrize("n_experts,chips,router", [
+    (128, 8, {}), (64, 4, {}), (128, 8, SIGMOID)],
+    ids=["128-8", "64-4", "128-8-sigmoid_bias"])
+def test_the_shares_add_up_to_the_whole_layer(n_experts, chips, router):
     """The share test: a chip of an 8-way (4-way) expert-parallel layer
     routes over all 128 (64) experts and computes its 16; the partial
-    results of all the shares add up to the uncut layer."""
+    results of all the shares add up to the uncut layer. With an expert
+    bias every chip holds the same bias and chooses alike."""
     whole, x = _layer(4, n_experts, n_experts)
-    uncut = every_expert(whole, x, 8)
+    if router:
+        whole["expert_bias"] = 0.05 * jax.random.normal(
+            jax.random.key(8), (n_experts,))
+    uncut = every_expert(whole, x, 8, **router)
     np.testing.assert_allclose(
-        np.asarray(moe.dropless_ffn(whole, x, k=8).y), np.asarray(uncut),
-        rtol=1e-4, atol=1e-5)
+        np.asarray(moe.dropless_ffn(whole, x, k=8, **router).y),
+        np.asarray(uncut), rtol=1e-4, atol=1e-5)
     total, rows = jnp.zeros_like(x), 0
     for chip in range(chips):
-        share = {"router": whole["router"],
-                 **{name: whole[name][16 * chip:16 * chip + 16]
-                    for name in ("w_gate", "w_up", "w_down")}}
-        out = moe.dropless_ffn(share, x, k=8, first_held=16 * chip)
+        share = {name: whole[name] for name in ("router", "expert_bias")
+                 if name in whole}
+        share.update({name: whole[name][16 * chip:16 * chip + 16]
+                      for name in ("w_gate", "w_up", "w_down")})
+        out = moe.dropless_ffn(share, x, k=8, first_held=16 * chip, **router)
         total, rows = total + out.y, rows + int(out.stats.rows_held)
     np.testing.assert_allclose(np.asarray(total), np.asarray(uncut),
                                rtol=1e-4, atol=1e-5)
@@ -208,6 +244,39 @@ def test_counts_reach_the_timeline():
     moe.count_dropless_stats(stats, positions=64, timeline=tl)
     assert tl.counters() == {"moe.rows_held": 440, "moe.rows_max_expert": 40,
                              "moe.positions": 256}
+    # layers that count their routes over all experts add two counters
+    routed = stats._replace(route_counts=jnp.asarray(
+        [[30, 50, 48], [40, 40, 48]], jnp.int32))
+    tl = Timeline()
+    moe.count_dropless_stats(routed, positions=64, timeline=tl)
+    assert tl.counters()["moe.route_rows"] == 256
+    assert tl.counters()["moe.route_rows_max"] == 50 + 48
+
+
+def test_the_bias_chooses_and_the_scores_weight():
+    """A sigmoid router under a bias: an expert the bias lifts into the
+    top k is chosen over a higher score, and the chosen are weighted by
+    their unbiased scores, renormalised and times route_scale."""
+    x = jnp.eye(4, dtype=jnp.float32)               # position t reads row t
+    logits = jnp.asarray([[2.0, 1.0, 0.0, -1.0]] * 4)
+    params = {"router": {"kernel": logits},
+              "w_gate": jnp.ones((4, 4, 2)), "w_up": jnp.ones((4, 4, 2)),
+              "w_down": jnp.ones((4, 2, 4)),
+              "expert_bias": jnp.asarray([0.0, 0.0, 0.5, 0.0])}
+    out = moe.dropless_ffn(params, x, k=2, score="sigmoid", route_scale=3.0)
+    np.testing.assert_array_equal(out.stats.route_counts, [4, 0, 4, 0])
+    s = jax.nn.sigmoid(jnp.asarray([2.0, 0.0]))
+    w = 3.0 * s / jnp.sum(s)
+    # every expert computes silu(1) * 1 * 2 on each lane of a one-hot row
+    unit = 2.0 * float(jax.nn.silu(1.0))
+    np.testing.assert_allclose(out.y, jnp.full((4, 4), unit) * jnp.sum(w),
+                               rtol=1e-6)
+    # without the bias the top 2 are experts 0 and 1
+    plain = moe.dropless_ffn({k: v for k, v in params.items()
+                              if k != "expert_bias"}, x, k=2,
+                             score="sigmoid")
+    assert plain.stats.route_counts is None
+    np.testing.assert_array_equal(out.stats.rows_held, 8)
 
 
 # -- the row kernels (`ops.moe_rows`) against the jnp gathers they replace --

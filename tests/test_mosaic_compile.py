@@ -65,6 +65,9 @@ def _compiled_not_interpreted(monkeypatch):
     (8, 1280, 128, jnp.bfloat16, None, False, (640, 768)),
     (8, 1536, 64, jnp.bfloat16, 512, True, (768, 768)),
     (4, 4100, 128, jnp.bfloat16, None, False, (384, 896)),
+    # trinity_mini_26b_a3b_ep8.train_seq8k: a band of 2048 (two of the
+    # eight blocks a row cut)
+    (64, 8192, 128, jnp.bfloat16, 2048, False, (1024, 1024)),
 ])
 def test_flash_forward_and_backward_compile_for_v5e(one_chip, bh, t, d,
                                                     dtype, window, lens,
@@ -119,6 +122,7 @@ def test_block_diffusion_flash_compiles_for_v5e(one_chip, length, bd, bh):
 @pytest.mark.parametrize("d,f", [
     (2048, 768),        # sdar_30b_a3b_ep8
     (2304, 896),        # mellum2_12b_a2p5b_ep4: K 2304 in two tiles of 1152
+    (2048, 1024),       # trinity_mini_26b_a3b_ep8
 ])
 def test_grouped_expert_products_compile_for_v5e(one_chip, d, f):
     """The dropless layer's three grouped products and their gradients
