@@ -23,7 +23,11 @@ framework makes fused O(T) -memory attention a first-class op:
     those run the streaming softmax with no mask at all; the blocks the
     mask cuts (the diagonal, the band's edge, a row's key length, the
     two diagonals of the block-diffusion square) run it masked; the
-    rest are skipped and fetch nothing. `_forward_blocks` picks the
+    rest are skipped and fetch nothing. Under the block-diffusion mask
+    a fourth kind, `_block_diagonal`, takes the noised-against-noised
+    blocks of the diagonal, whose pairs all lie in the 128 x 128
+    sub-squares on their own diagonal: those run masked on the
+    sub-squares alone, in all three kernels. `_forward_blocks` picks the
     blocks: 1024 x 1024 where the sequence carries them, smaller where
     it does not, never padding a sequence further than a 256 x 512
     grid would;
@@ -82,6 +86,14 @@ _LANE = 128  # TPU minimum tile width (lane count)
 _FWD_BLOCK_MAX = 1024
 _FWD_GRID_Q = 256
 _FWD_GRID_K = 512
+# the edge of the sub-squares a diagonal step of the block-diffusion
+# mask computes (`_diagonal_sub`): one lane tile, the least edge whose
+# slices of the backward's `lse` and `delta` rows are whole lane tiles.
+# Measured on one v5e chip, the three kernels alone at bf16[64, 8192,
+# 128], L 4096, Bd 4, forward + backward: 29.8-30.0 ms at 128, 30.3-30.4
+# at 256, 31.4-31.5 at 512, 33.0-33.2 on whole blocks; a sub-square's
+# work falls with its area faster than its fixed cost adds up
+_DIAGONAL_SUB = 128
 
 
 def _fit_block(t: int, grid: int, largest: int) -> int:
@@ -222,16 +234,66 @@ def _block_interior(qi, j, n_keys, *, block_q: int, block_k: int,
     return interior
 
 
+def _diagonal_sub(block_q: int, block_k: int, block_diffusion):
+    """The edge S of the sub-squares a diagonal step computes, or None
+    where no block of the call can be one: under `block_diffusion`
+    (L, Bd), square blocks of whole sub-squares larger than one, and Bd
+    dividing S, so that a Bd-block never straddles two sub-squares. S
+    is one lane tile: a sub-square's slice of the backward's `lse` and
+    `delta` rows ([1, 1, BQ], lanes) is then whole lane tiles."""
+    if block_diffusion is None or block_q != block_k:
+        return None
+    sub = _DIAGONAL_SUB
+    if block_q <= sub or block_q % sub or sub % block_diffusion[1]:
+        return None
+    return sub
+
+
+def _block_diagonal(qi, j, n_keys, *, block_q: int, block_k: int,
+                    block_diffusion=None, **_):
+    """Is (q block qi, k block j) a block of the noised-against-noised
+    diagonal whose admitted pairs all lie in the S x S sub-squares on
+    its own diagonal (`_diagonal_sub`)? Noised queries and noised keys
+    only, the same span of positions: a noised query attends only the
+    noised keys of its own Bd-block, and every Bd-block lies inside one
+    sub-square. Such a block runs the masked body on its block_q / S
+    sub-squares alone, an S / block_q share of its pairs; a Python
+    False for every other mask and shape. (The mask takes no key
+    lengths: n_keys is the whole sequence.)"""
+    if _diagonal_sub(block_q, block_k, block_diffusion) is None:
+        return False
+    return (qi == j) & ((j + 1) * block_k <= block_diffusion[0])
+
+
 def _block_kinds(nq: int, nk: int, n_keys: int, **masks):
-    """(interior, cut, skipped) grid steps of one (batch x head) row
-    whose keys are all valid, counted from the two block predicates:
-    what the forward runs unmasked, masked and not at all."""
+    """(interior, cut, diagonal, skipped) grid steps of one (batch x
+    head) row whose keys are all valid, counted from the three block
+    predicates: what the forward runs unmasked, masked, masked on the
+    sub-squares of its diagonal and not at all."""
     qi, j = np.arange(nq)[:, None], np.arange(nk)[None, :]
     needed = np.broadcast_to(_block_needed(qi, j, n_keys, **masks), (nq, nk))
     interior = np.broadcast_to(
         _block_interior(qi, j, n_keys, **masks), (nq, nk))
+    diagonal = np.broadcast_to(
+        _block_diagonal(qi, j, n_keys, **masks), (nq, nk))
     n_interior, n_needed = int(interior.sum()), int(needed.sum())
-    return n_interior, n_needed - n_interior, nq * nk - n_needed
+    n_diagonal = int(diagonal.sum())
+    return (n_interior, n_needed - n_interior - n_diagonal, n_diagonal,
+            nq * nk - n_needed)
+
+
+def _sub_squares(qi, j, n_keys, *, k_major: bool = False, **masks):
+    """The S x S sub-squares of diagonal block (qi, j), one by one: the
+    rows of the q block that hold its queries (the same rows of the k
+    block hold its keys) and a function of no arguments that gives its
+    `_pair_mask` (the sub-square's own block numbers on blocks of S)."""
+    sub = _diagonal_sub(masks["block_q"], masks["block_k"],
+                        masks["block_diffusion"])
+    per = masks["block_q"] // sub
+    on_sub = dict(masks, block_q=sub, block_k=sub, k_major=k_major)
+    for s in range(per):
+        yield pl.ds(s * sub, sub), functools.partial(
+            _pair_mask, qi * per + s, j * per + s, n_keys, **on_sub)
 
 
 def _pair_mask(qi, j, n_keys, *, block_q: int, block_k: int, causal: bool,
@@ -364,10 +426,11 @@ def _attn_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
     dim walks k/v blocks sequentially (TPU grids are sequential), so
     VMEM scratch (acc/m/l) carries streaming-softmax state across k
     steps; only one [BK, D] k/v tile is resident at a time. A step is
-    one of three kinds: interior (`_block_interior`: no mask), cut
-    (needed and not interior: the mask on the scores and on p), or
-    skipped; an admitted pair goes through the same float32 expression
-    in both bodies.
+    one of four kinds: interior (`_block_interior`: no mask), diagonal
+    (`_block_diagonal`: the masked body on each sub-square of the
+    block's diagonal, its rows of acc/m/l alone), cut (needed and
+    neither: the mask on the scores and on p), or skipped; an admitted
+    pair goes through the same float32 expression in every body.
 
     Refs: len [BH] i32, scalar-prefetched (row b's valid key count —
     t_kv when no key mask; tail padding and right-padded
@@ -392,18 +455,20 @@ def _attn_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
                  block_diffusion=block_diffusion)
     needed = _block_needed(qi, j, n_keys, **masks)
     interior = _block_interior(qi, j, n_keys, **masks)
+    diagonal = _block_diagonal(qi, j, n_keys, **masks)
 
-    def step(valid):
+    def step(valid, rows=slice(None)):
         """One block of the streaming softmax; `valid` None: every pair
-        attends, and the block pays for no mask."""
+        attends, and the block pays for no mask. `rows`: the queries,
+        and the keys, of one sub-square of a diagonal step."""
         # native-dtype (e.g. bf16) operands on the MXU, f32 accumulation
         s = jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            q_ref[0, rows], k_ref[0, rows], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [BQ, BK]
         if valid is not None:
             s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[:, :1]                          # [BQ, 1]
-        l_prev = l_ref[:, :1]
+        m_prev = m_ref[rows, :1]                       # [BQ, 1]
+        l_prev = l_ref[rows, :1]
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - m_new)
@@ -415,19 +480,28 @@ def _attn_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
             p = jnp.where(valid, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)                # [BQ, 1]
         l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+        acc_ref[rows] = acc_ref[rows] * alpha + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0, rows], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_ref[:] = jax.lax.broadcast_in_dim(
-            m_new[:, 0], m_ref.shape, (0,))
-        l_ref[:] = jax.lax.broadcast_in_dim(
-            l_new[:, 0], l_ref.shape, (0,))
+        m_ref[rows] = jax.lax.broadcast_in_dim(
+            m_new[:, 0], (m_new.shape[0], m_ref.shape[1]), (0,))
+        l_ref[rows] = jax.lax.broadcast_in_dim(
+            l_new[:, 0], (l_new.shape[0], l_ref.shape[1]), (0,))
 
     @pl.when(interior)
     def _interior():
         step(None)
 
-    @pl.when(needed & jnp.logical_not(interior))
+    cut = needed & jnp.logical_not(interior)
+    if diagonal is not False:
+        cut = cut & jnp.logical_not(diagonal)
+
+        @pl.when(diagonal)
+        def _diagonal():
+            for rows, valid in _sub_squares(qi, j, n_keys, **masks):
+                step(valid(), rows)
+
+    @pl.when(cut)
     def _cut():
         step(_pair_mask(qi, j, n_keys, **masks))
 
@@ -478,10 +552,17 @@ def _flash_forward(q, k, v, lens, *, causal: bool, block_q: int,
         pallas_util.note_traced("flash_attention.mask", "window")
     pallas_util.note_traced("flash_attention.fwd_blocks",
                             f"{block_q}x{block_k}")
+    interior, cut, diagonal, skipped = _block_kinds(
+        nq, nk, t_kv, block_diffusion=block_diffusion, **masks)
+    # a call with no diagonal step keeps the three kinds' text
+    diagonal_text = f"diagonal:{diagonal}," if diagonal else ""
     pallas_util.note_traced(
         "flash_attention.fwd_block_kinds",
-        "interior:%d,cut:%d,skipped:%d" % _block_kinds(
-            nq, nk, t_kv, block_diffusion=block_diffusion, **masks))
+        f"interior:{interior},cut:{cut},{diagonal_text}skipped:{skipped}")
+    if diagonal:
+        pallas_util.note_traced(
+            "flash_attention.diagonal_sub",
+            str(_diagonal_sub(block_q, block_k, block_diffusion)))
 
     # a skipped step names a block the row needs (the one the pipeline
     # already holds, or will need next) and fetches nothing: under block
@@ -550,6 +631,28 @@ def _recompute(q, k, v, g, lse, delta, valid, *, scale: float,
     return p, p * (dp - delta)
 
 
+def _masked_steps(compute, qi, j, n_keys, *, k_major: bool = False,
+                  **masks):
+    """The two masked kinds of a backward grid step: `compute(valid,
+    rows)` on the whole block where it is needed and not diagonal, and
+    on each sub-square where it is diagonal (`_block_diagonal`)."""
+    needed = _block_needed(qi, j, n_keys, **masks)
+    diagonal = _block_diagonal(qi, j, n_keys, **masks)
+    if diagonal is not False:
+        needed = needed & jnp.logical_not(diagonal)
+
+        @pl.when(diagonal)
+        def _diagonal():
+            for rows, valid in _sub_squares(qi, j, n_keys, k_major=k_major,
+                                            **masks):
+                compute(valid, rows)
+
+    @pl.when(needed)
+    def _compute():
+        compute(functools.partial(_pair_mask, qi, j, n_keys,
+                                  k_major=k_major, **masks))
+
+
 def _bwd_dkv_kernel(len_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref,
                     v_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
                     scale: float, causal: bool, window,
@@ -574,17 +677,20 @@ def _bwd_dkv_kernel(len_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    @pl.when(_block_needed(qi, j, n_keys, **masks))
-    def _compute():
-        q, g = q_ref[0], g_ref[0]
+    def compute(valid, rows=slice(None)):
+        """The block's share of dk and dv, or one sub-square's of a
+        diagonal step (`rows`: its queries and keys); `valid` gives the
+        mask."""
+        q, g = q_ref[0, rows], g_ref[0, rows]
         p, ds = _recompute(
-            q, k_ref[0], v_ref[0], g, lse_ref[0], delta_ref[0],
-            _pair_mask(qi, j, n_keys, k_major=True, **masks),
-            scale=scale, k_major=True)
-        dv_acc[:] += jnp.dot(p.astype(g.dtype), g,
-                             preferred_element_type=jnp.float32)
-        dk_acc[:] += jnp.dot(ds.astype(q.dtype), q,
-                             preferred_element_type=jnp.float32)
+            q, k_ref[0, rows], v_ref[0, rows], g, lse_ref[0, :, rows],
+            delta_ref[0, :, rows], valid(), scale=scale, k_major=True)
+        dv_acc[rows] += jnp.dot(p.astype(g.dtype), g,
+                                preferred_element_type=jnp.float32)
+        dk_acc[rows] += jnp.dot(ds.astype(q.dtype), q,
+                                preferred_element_type=jnp.float32)
+
+    _masked_steps(compute, qi, j, n_keys, k_major=True, **masks)
 
     @pl.when(qi == pl.num_programs(2) - 1)
     def _finish():
@@ -609,15 +715,18 @@ def _bwd_dq_kernel(len_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref,
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    @pl.when(_block_needed(qi, j, n_keys, **masks))
-    def _compute():
-        k = k_ref[0]
+    def compute(valid, rows=slice(None)):
+        """The block's share of dq, or one sub-square's of a diagonal
+        step (`rows`: its queries and keys); `valid` gives the mask."""
+        k = k_ref[0, rows]
         _, ds = _recompute(
-            q_ref[0], k, v_ref[0], g_ref[0], lse_ref[0, 0][:, None],
-            delta_ref[0, 0][:, None], _pair_mask(qi, j, n_keys, **masks),
-            scale=scale, k_major=False)
-        dq_acc[:] += jnp.dot(ds.astype(k.dtype), k,
-                             preferred_element_type=jnp.float32)
+            q_ref[0, rows], k, v_ref[0, rows], g_ref[0, rows],
+            lse_ref[0, 0, rows][:, None], delta_ref[0, 0, rows][:, None],
+            valid(), scale=scale, k_major=False)
+        dq_acc[rows] += jnp.dot(ds.astype(k.dtype), k,
+                                preferred_element_type=jnp.float32)
+
+    _masked_steps(compute, qi, j, n_keys, **masks)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
@@ -803,7 +912,10 @@ def flash_attention(q, k, v, *, causal: bool = False,
     copy of a sequence and then the clean copy, cut into Bd-token
     blocks: see `_pair_mask`. Not causal, no window, no key_lens. A
     kernel block with no admitted pair (about half of them, in two
-    runs a row) is skipped and fetches nothing, in all three kernels.
+    runs a row) is skipped and fetches nothing, in all three kernels;
+    a noised block against its own noised keys computes only the
+    sub-squares of its diagonal where Bd divides them
+    (`_block_diagonal`).
 
     Under `jax.checkpoint`: a differentiated call names its output and
     its row log-sum-exp (`REMAT_SAVED`), the residuals the backward

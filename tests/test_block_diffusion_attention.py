@@ -102,34 +102,50 @@ def test_block_predicate_and_runs_are_the_table(length, bd, block_q,
     (32, 8, 4, 4), (32, 16, 8, 4),      # blocks smaller than a Bd-block
     (32, 8, 8, 16), (64, 4, 16, 16),    # equal to one; much larger
     (12, 4, 4, 8),                      # a k block across the two halves
+    # blocks of two sub-squares: diagonal steps, and Bd 3, which
+    # straddles a sub-square's edge, with none
+    (512, 4, 256, 256), (512, 8, 256, 256), (512, 128, 256, 256),
+    (384, 3, 256, 256),
 ])
 def test_block_classifier_is_the_pair_mask(length, bd, block_q, block_k):
-    """The forward's three kinds of block: one `_block_interior` names
+    """The forward's four kinds of block: one `_block_interior` names
     holds only admitted pairs (it runs unmasked), one `_block_needed`
-    skips holds none; where no block lies across the two halves,
+    skips holds none, one `_block_diagonal` names holds them all inside
+    the S x S sub-squares on its diagonal (it runs masked on those
+    alone); where no block lies across the two halves,
     `_block_interior` names every block whose mask is all true."""
     nq, nk = _grid(length, block_q, block_k)
     kw = dict(block_q=block_q, block_k=block_k, causal=False, window=None,
               block_diffusion=(length, bd))
     whole = length % block_q == 0 and length % block_k == 0
-    kinds = {"interior": 0, "cut": 0, "skipped": 0}
+    sub = FA._diagonal_sub(block_q, block_k, (length, bd))
+    kinds = {"interior": 0, "cut": 0, "diagonal": 0, "skipped": 0}
     for qi in range(nq):
         for j in range(nk):
             mask = np.broadcast_to(np.asarray(FA._pair_mask(
                 qi, j, 2 * length, **kw)), (block_q, block_k))
             interior = bool(FA._block_interior(qi, j, 2 * length, **kw))
             needed = bool(FA._block_needed(qi, j, 2 * length, **kw))
+            diagonal = bool(FA._block_diagonal(qi, j, 2 * length, **kw))
             rows = min(block_q, 2 * length - qi * block_q)  # real queries
             assert not interior or mask.all(), (qi, j)
             assert needed or not mask[:rows].any(), (qi, j)
             assert needed or not interior
             if whole:
                 assert interior == mask.all(), (qi, j)
-            kinds["interior" if interior else "cut" if needed
-                  else "skipped"] += 1
+            if diagonal:
+                assert needed and not interior, (qi, j)
+                squares = np.kron(np.eye(block_q // sub, dtype=bool),
+                                  np.ones((sub, sub), bool))
+                assert not (mask & ~squares).any(), (qi, j)
+            kinds["interior" if interior else "diagonal" if diagonal
+                  else "cut" if needed else "skipped"] += 1
     assert tuple(kinds.values()) == FA._block_kinds(nq, nk, 2 * length, **kw)
     if whole and bd < block_q < length:      # the mask cuts some block
-        assert min(kinds.values()) > 0, kinds
+        assert min(kinds["interior"], kinds["cut"], kinds["skipped"]) > 0, \
+            kinds
+    assert (kinds["diagonal"] > 0) == (
+        sub is not None and block_q <= length), kinds
 
 
 def test_forward_blocks_under_the_mask_are_the_choosers():
@@ -166,14 +182,21 @@ def test_dense_path_uses_the_table(np_rng):
 
 
 @pytest.mark.parametrize("length,bd,bq,bk,bwd,kinds", [
-    # kinds: forward grid steps of a row (interior, cut, skipped)
-    (32, 4, 16, 16, (16, 16), (2, 6, 8)),
-    (32, 4, 8, 32, (32, 8), (0, 12, 4)),
-    (24, 4, 16, 16, (128, 128), (0, 7, 2)),  # one padded backward block
-    (64, 8, 32, 16, (32, 64), (4, 12, 16)),
-    (30, 3, 16, 16, (16, 16), (1, 11, 4)),   # Bd no power of two, ragged
-    (32, 16, 8, 8, (16, 16), (24, 0, 40)),   # blocks inside a Bd-block
-    (32, 4, 8, 8, (32, 32), (12, 12, 40)),   # the cell's counts, in small
+    # kinds: forward grid steps of a row (interior, cut, diagonal,
+    # skipped)
+    (32, 4, 16, 16, (16, 16), (2, 6, 0, 8)),
+    (32, 4, 8, 32, (32, 8), (0, 12, 0, 4)),
+    (24, 4, 16, 16, (128, 128), (0, 7, 0, 2)),  # one padded backward block
+    (64, 8, 32, 16, (32, 64), (4, 12, 0, 16)),
+    (30, 3, 16, 16, (16, 16), (1, 11, 0, 4)),   # Bd no power of two, ragged
+    (32, 16, 8, 8, (16, 16), (24, 0, 0, 40)),   # blocks inside a Bd-block
+    (32, 4, 8, 8, (32, 32), (12, 12, 0, 40)),   # the cell's counts, in small
+    # blocks of two sub-squares of 128: the noised diagonal's blocks
+    # run on their sub-squares alone, in all three kernels
+    (512, 4, 256, 256, (256, 256), (2, 4, 2, 8)),
+    (512, 8, 256, 256, (256, 256), (2, 4, 2, 8)),
+    (512, 128, 256, 256, (256, 256), (2, 4, 2, 8)),   # Bd equal to S
+    (384, 3, 256, 256, (256, 256), (0, 8, 0, 1)),     # Bd 3: none
 ])
 def test_flash_kernels_match_dense(np_rng, length, bd, bq, bk, bwd, kinds):
     q, k, v = (jnp.asarray(np_rng.randn(2, 2 * length, 2, 16), jnp.float32)
@@ -188,6 +211,10 @@ def test_flash_kernels_match_dense(np_rng, length, bd, bq, bk, bwd, kinds):
     assert kinds == FA._block_kinds(
         *_grid(length, bq, bk), 2 * length, block_q=bq, block_k=bk,
         causal=False, window=None, block_diffusion=(length, bd))
+    # the backward kernels classify their own blocks the same way
+    assert kinds[2] == FA._block_kinds(
+        *_grid(length, *bwd), 2 * length, block_q=bwd[0], block_k=bwd[1],
+        causal=False, window=None, block_diffusion=(length, bd))[2]
 
     np.testing.assert_allclose(np.asarray(flash(q, k, v)),
                                np.asarray(_dense(q, k, v, length, bd)),

@@ -238,8 +238,8 @@ class TestSWAFlopScaling:
 
     @staticmethod
     def _fwd_kinds(T, window):
-        """(interior, cut, skipped) grid steps of one head in the
-        forward, on the blocks it takes from the shape (1024 x 1024 at
+        """(interior, cut, diagonal, skipped) grid steps of one head in
+        the forward, on the blocks it takes from the shape (1024 x 1024 at
         these lengths), from the forward's own classifier."""
         from paddle_tpu.ops import flash_attention as FA
 
@@ -255,11 +255,11 @@ class TestSWAFlopScaling:
         the band or the diagonal, 7 -> 15 (linear), and the rest fetch
         nothing."""
         assert [self._fwd_kinds(t, None) for t in (4096, 8192)] == [
-            (6, 4, 6), (28, 8, 28)]
+            (6, 4, 0, 6), (28, 8, 0, 28)]
         assert [self._fwd_kinds(t, 256) for t in (4096, 8192)] == [
-            (0, 7, 9), (0, 15, 49)]
+            (0, 7, 0, 9), (0, 15, 0, 49)]
         # a band of two blocks and a half leaves whole blocks unmasked
-        assert self._fwd_kinds(8192, 2560) == (7, 19, 38)
+        assert self._fwd_kinds(8192, 2560) == (7, 19, 0, 38)
 
 
 class TestFusedCEResiduals:

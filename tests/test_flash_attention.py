@@ -486,19 +486,20 @@ def test_block_classifier_under_a_trace_is_the_same_arithmetic(predicate,
 
 _THREE_KIND_CASES = [
     # t, causal, window, key_lens, forward blocks (q, k), and each row's
-    # (interior, cut, skipped) grid steps
-    (40, True, None, None, (8, 8), [(10, 5, 10)]),
-    (48, True, None, None, (8, 16), [(6, 6, 6)]),
-    (48, True, None, None, (16, 8), [(6, 6, 6)]),
-    (48, True, 20, None, (8, 8), [(5, 13, 18)]),    # interior in the band
-    (48, True, 5, None, (8, 8), [(0, 11, 25)]),     # a band with none
-    (48, True, 30, (48, 19), (8, 16), [(2, 10, 6), (1, 9, 8)]),
+    # (interior, cut, diagonal, skipped) grid steps
+    (40, True, None, None, (8, 8), [(10, 5, 0, 10)]),
+    (48, True, None, None, (8, 16), [(6, 6, 0, 6)]),
+    (48, True, None, None, (16, 8), [(6, 6, 0, 6)]),
+    (48, True, 20, None, (8, 8), [(5, 13, 0, 18)]),  # interior in the band
+    (48, True, 5, None, (8, 8), [(0, 11, 0, 25)]),   # a band with none
+    (48, True, 30, (48, 19), (8, 16), [(2, 10, 0, 6), (1, 9, 0, 8)]),
     # not causal: only the lengths cut, inside a block and on its edge
     (40, False, None, (40, 21, 0), (8, 8),
-     [(25, 0, 0), (10, 5, 10), (0, 0, 25)]),
+     [(25, 0, 0, 0), (10, 5, 0, 10), (0, 0, 0, 25)]),
     (40, False, None, (16, 24, 40), (8, 8),
-     [(10, 0, 15), (15, 0, 10), (25, 0, 0)]),
-    (37, True, None, (37, 20), (8, 8), [(10, 5, 10), (7, 5, 13)]),  # tail
+     [(10, 0, 0, 15), (15, 0, 0, 10), (25, 0, 0, 0)]),
+    (37, True, None, (37, 20), (8, 8),                # tail
+     [(10, 5, 0, 10), (7, 5, 0, 13)]),
 ]
 
 
@@ -547,7 +548,7 @@ def test_three_kinds_of_block_match_dense(np_rng, t, causal, window, lens,
     # starcoder2_3b_l4.train_seq4k and sdar_30b_a3b_ep8.train_bd4_seq4k
     ((2, 4096, 24, 128), dict(causal=True), "interior:6,cut:4,skipped:6"),
     ((2, 8192, 32, 128), dict(block_diffusion=(4096, 4)),
-     "interior:12,cut:12,skipped:40"),
+     "interior:12,cut:8,diagonal:4,skipped:40"),
     # the dense cell's own call: 4095 positions, its window inert
     ((2, 4095, 24, 128), dict(causal=True, window=4096),
      "interior:6,cut:4,skipped:6"),

@@ -82,9 +82,16 @@ def test_flash_forward_and_backward_compile_for_v5e(one_chip, bh, t, d,
                                key_lens=key_lens)
         return jnp.sum(o.astype(jnp.float32))
 
+    before = pallas_util.traced()
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x, key_lens).compile()
     text = compiled.as_text()
+    # the causal and window masks take no diagonal step
+    noted = [key for key, n in pallas_util.traced().items()
+             if n > before.get(key, 0)]
+    assert any(key.startswith("flash_attention.fwd_block_kinds=")
+               for key in noted), noted
+    assert not any("diagonal" in key for key in noted), noted
     assert text.count('custom_call_target="tpu_custom_call"') == 3
     # under `grad` the forward's instruction is the jvp's; a window
     # that cuts names its kernels apart, an inert one does not
@@ -95,6 +102,12 @@ def test_flash_forward_and_backward_compile_for_v5e(one_chip, bh, t, d,
         assert (f"%{name}_window" in text or f"%{name}window" in text) == cut
 
 
+# a row's forward grid steps: the noised diagonal's four blocks a row
+# run on their sub-squares at the cell's shape, none where Bd is 3
+_BD_KINDS = {4096: "interior:12,cut:8,diagonal:4,skipped:40",
+             1536: "interior:0,cut:8,skipped:1"}
+
+
 @pytest.mark.parametrize("length,bd,bh", [
     # sdar_30b_a3b_ep8.train_bd4_seq4k: 2 sequences x 32 heads, 2 x 4096
     # positions each (noised copy, then clean copy), Bd 4
@@ -102,6 +115,7 @@ def test_flash_forward_and_backward_compile_for_v5e(one_chip, bh, t, d,
     (1536, 3, 8),       # Bd no power of two, L no multiple of a block
 ])
 def test_block_diffusion_flash_compiles_for_v5e(one_chip, length, bd, bh):
+    kinds = _BD_KINDS[length]
     assert FA._forward_blocks(2 * length, 2 * length, 128,
                               jnp.bfloat16) == (1024, 1024)
     x = jax.ShapeDtypeStruct((1, 2 * length, bh, 128), jnp.bfloat16,
@@ -111,12 +125,18 @@ def test_block_diffusion_flash_compiles_for_v5e(one_chip, length, bd, bh):
         o = FA.flash_attention(q, k, v, block_diffusion=(length, bd))
         return jnp.sum(o.astype(jnp.float32))
 
+    before = pallas_util.traced()
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
     for name in ("jvp_flash_attention_fwd_", "flash_attention_bwd_dkv",
                  "flash_attention_bwd_dq"):
         assert f"%{name}" in text, name
+    after = pallas_util.traced()
+    noted = [key for key, n in after.items() if n > before.get(key, 0)]
+    assert f"flash_attention.fwd_block_kinds={kinds}" in noted, noted
+    sub = f"flash_attention.diagonal_sub={FA._DIAGONAL_SUB}"
+    assert (sub in noted) == ("diagonal" in kinds), noted
 
 
 @pytest.mark.parametrize("d,f", [
