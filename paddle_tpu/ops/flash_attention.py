@@ -210,13 +210,13 @@ def _block_needed(qi, j, n_keys, *, block_q: int, block_k: int,
 def _block_interior(qi, j, n_keys, *, block_q: int, block_k: int,
                     causal: bool, window, block_diffusion=None):
     """Does `_pair_mask` admit EVERY pair of (q block qi, k block j)?
-    The twin of `_block_needed`, for the forward: such a block runs
-    with no mask. Every key inside the row's length, the whole block on
-    or below the diagonal, the whole block inside the band. Under
-    `block_diffusion`: clean keys only, of Bd-blocks before those of
-    every noised query of the block and up to those of every clean one
-    (or, where blocks are no larger than a Bd-block, noised queries and
-    noised keys of one Bd-block)."""
+    The twin of `_block_needed`, for the forward and both backward
+    kernels: such a block runs with no mask. Every key inside the row's
+    length, the whole block on or below the diagonal, the whole block
+    inside the band. Under `block_diffusion`: clean keys only, of
+    Bd-blocks before those of every noised query of the block and up to
+    those of every clean one (or, where blocks are no larger than a
+    Bd-block, noised queries and noised keys of one Bd-block)."""
     interior = (j + 1) * block_k <= n_keys
     if block_diffusion is not None:
         qn, qn0, qn1, qc, qc0, _ = _bd_spans(qi, block_q, block_diffusion)
@@ -268,7 +268,7 @@ def _block_diagonal(qi, j, n_keys, *, block_q: int, block_k: int,
 def _block_kinds(nq: int, nk: int, n_keys: int, **masks):
     """(interior, cut, diagonal, skipped) grid steps of one (batch x
     head) row whose keys are all valid, counted from the three block
-    predicates: what the forward runs unmasked, masked, masked on the
+    predicates: what a kernel runs unmasked, masked, masked on the
     sub-squares of its diagonal and not at all."""
     qi, j = np.arange(nq)[:, None], np.arange(nk)[None, :]
     needed = np.broadcast_to(_block_needed(qi, j, n_keys, **masks), (nq, nk))
@@ -622,35 +622,51 @@ def _recompute(q, k, v, g, lse, delta, valid, *, scale: float,
     float32 out of operand-dtype matmuls. [BQ, BK] with lse / delta as
     [BQ, 1] columns or, `k_major`, [BK, BQ] with [1, BQ] rows. The mask
     lands on p, not on the scores: a query with no valid key has lse =
-    NEG_INF, and exp(NEG_INF - NEG_INF) would be 1."""
+    NEG_INF, and exp(NEG_INF - NEG_INF) would be 1.
+
+    `valid` None: an interior block, whose every pair attends, pays for
+    no mask and no select. Its `lse` is finite on every query row (each
+    sees every key of the block), and a padded query row has lse 0 and
+    q = g = 0, so it adds zeros, as it does under the mask."""
     if k_major:
         q, k, g, v = k, q, v, g
     s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32)
     dp = jax.lax.dot_general(g, v, _NT, preferred_element_type=jnp.float32)
-    p = jnp.where(valid, jnp.exp(s * scale - lse), 0.0)
+    p = jnp.exp(s * scale - lse)
+    if valid is not None:
+        p = jnp.where(valid, p, 0.0)
     return p, p * (dp - delta)
 
 
-def _masked_steps(compute, qi, j, n_keys, *, k_major: bool = False,
-                  **masks):
-    """The two masked kinds of a backward grid step: `compute(valid,
-    rows)` on the whole block where it is needed and not diagonal, and
-    on each sub-square where it is diagonal (`_block_diagonal`)."""
+def _backward_steps(compute, qi, j, n_keys, *, k_major: bool = False,
+                    **masks):
+    """The three computed kinds of a backward grid step, the forward's
+    own (`_attn_kernel`): `compute(valid, rows)` with no mask where the
+    block is interior (`_block_interior`), on each sub-square where it
+    is diagonal (`_block_diagonal`), and under `_pair_mask` on the whole
+    block where it is cut (needed and neither). The predicates take the
+    block numbers of the pair, (qi, j), in either kernel's order."""
     needed = _block_needed(qi, j, n_keys, **masks)
+    interior = _block_interior(qi, j, n_keys, **masks)
     diagonal = _block_diagonal(qi, j, n_keys, **masks)
+
+    @pl.when(interior)
+    def _interior():
+        compute(None)
+
+    cut = needed & jnp.logical_not(interior)
     if diagonal is not False:
-        needed = needed & jnp.logical_not(diagonal)
+        cut = cut & jnp.logical_not(diagonal)
 
         @pl.when(diagonal)
         def _diagonal():
             for rows, valid in _sub_squares(qi, j, n_keys, k_major=k_major,
                                             **masks):
-                compute(valid, rows)
+                compute(valid(), rows)
 
-    @pl.when(needed)
-    def _compute():
-        compute(functools.partial(_pair_mask, qi, j, n_keys,
-                                  k_major=k_major, **masks))
+    @pl.when(cut)
+    def _cut():
+        compute(_pair_mask(qi, j, n_keys, k_major=k_major, **masks))
 
 
 def _bwd_dkv_kernel(len_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref,
@@ -679,18 +695,18 @@ def _bwd_dkv_kernel(len_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref,
 
     def compute(valid, rows=slice(None)):
         """The block's share of dk and dv, or one sub-square's of a
-        diagonal step (`rows`: its queries and keys); `valid` gives the
-        mask."""
+        diagonal step (`rows`: its queries and keys); `valid`: the mask,
+        or None where every pair attends."""
         q, g = q_ref[0, rows], g_ref[0, rows]
         p, ds = _recompute(
             q, k_ref[0, rows], v_ref[0, rows], g, lse_ref[0, :, rows],
-            delta_ref[0, :, rows], valid(), scale=scale, k_major=True)
+            delta_ref[0, :, rows], valid, scale=scale, k_major=True)
         dv_acc[rows] += jnp.dot(p.astype(g.dtype), g,
                                 preferred_element_type=jnp.float32)
         dk_acc[rows] += jnp.dot(ds.astype(q.dtype), q,
                                 preferred_element_type=jnp.float32)
 
-    _masked_steps(compute, qi, j, n_keys, k_major=True, **masks)
+    _backward_steps(compute, qi, j, n_keys, k_major=True, **masks)
 
     @pl.when(qi == pl.num_programs(2) - 1)
     def _finish():
@@ -717,16 +733,17 @@ def _bwd_dq_kernel(len_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref,
 
     def compute(valid, rows=slice(None)):
         """The block's share of dq, or one sub-square's of a diagonal
-        step (`rows`: its queries and keys); `valid` gives the mask."""
+        step (`rows`: its queries and keys); `valid`: the mask, or None
+        where every pair attends."""
         k = k_ref[0, rows]
         _, ds = _recompute(
             q_ref[0, rows], k, v_ref[0, rows], g_ref[0, rows],
             lse_ref[0, 0, rows][:, None], delta_ref[0, 0, rows][:, None],
-            valid(), scale=scale, k_major=False)
+            valid, scale=scale, k_major=False)
         dq_acc[rows] += jnp.dot(ds.astype(k.dtype), k,
                                 preferred_element_type=jnp.float32)
 
-    _masked_steps(compute, qi, j, n_keys, **masks)
+    _backward_steps(compute, qi, j, n_keys, **masks)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
@@ -761,6 +778,13 @@ def _flash_backward(q, k, v, lens, o, lse, g, *, causal: bool,
                 _pad_to(k, tk_pad, 1), _pad_to(v, tk_pad, 1))
     masks = dict(block_q=block_q, block_k=block_k, causal=causal,
                  window=window)
+    # the backward's own blocks, which may differ from the forward's
+    interior, cut, diagonal, skipped = _block_kinds(
+        nq, nk, t_kv, block_diffusion=block_diffusion, **masks)
+    diagonal_text = f"diagonal:{diagonal}," if diagonal else ""
+    pallas_util.note_traced(
+        "flash_attention.bwd_block_kinds",
+        f"interior:{interior},cut:{cut},{diagonal_text}skipped:{skipped}")
     static = dict(scale=1.0 / (d ** 0.5), causal=causal, window=window,
                   block_diffusion=block_diffusion)
     runs = dict(block_q=block_q, block_k=block_k,

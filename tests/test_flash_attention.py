@@ -544,6 +544,7 @@ def test_three_kinds_of_block_match_dense(np_rng, t, causal, window, lens,
                                    rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
 @pytest.mark.parametrize("shape,kw,kinds", [
     # starcoder2_3b_l4.train_seq4k and sdar_30b_a3b_ep8.train_bd4_seq4k
     ((2, 4096, 24, 128), dict(causal=True), "interior:6,cut:4,skipped:6"),
@@ -557,20 +558,90 @@ def test_three_kinds_of_block_match_dense(np_rng, t, causal, window, lens,
     ((2, 8192, 32, 128), dict(causal=True, window=1024),
      "interior:0,cut:15,skipped:49"),
     ((2, 8192, 32, 128), dict(causal=True), "interior:28,cut:8,skipped:28"),
+    # trinity_mini_26b_a3b_ep8.train_seq8k: a band of 2048
+    ((2, 8192, 32, 128), dict(causal=True, window=2048),
+     "interior:7,cut:14,skipped:43"),
 ])
-def test_forward_counters_at_the_cells_shapes(shape, kw, kinds):
-    """Traced abstractly, the forward says which blocks it took and how
-    many grid steps of a (batch x head) row run unmasked, masked and not
-    at all."""
+def test_forward_counters_at_the_cells_shapes(shape, kw, kinds, direction):
+    """Traced abstractly, the forward and the backward each say how many
+    grid steps of a (batch x head) row run unmasked, masked and not at
+    all, on their own blocks (1024 x 1024 in both at these shapes); the
+    forward also names its blocks."""
     from paddle_tpu.ops import pallas_util
 
     before = pallas_util.traced()
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
-    jax.eval_shape(lambda q, k, v: flash_attention(q, k, v, **kw), x, x, x)
+    fn = lambda q, k, v: jnp.sum(flash_attention(q, k, v, **kw))
+    if direction == "bwd":
+        fn = jax.grad(fn, argnums=(0, 1, 2))
+    jax.eval_shape(fn, x, x, x)
     after = pallas_util.traced()
-    for key in ("flash_attention.fwd_blocks=1024x1024",
-                f"flash_attention.fwd_block_kinds={kinds}"):
+    keys = [f"flash_attention.{direction}_block_kinds={kinds}"]
+    if direction == "fwd":
+        keys.append("flash_attention.fwd_blocks=1024x1024")
+    for key in keys:
         assert after.get(key, 0) == before.get(key, 0) + 1, (key, after)
+
+
+# -- the backward's interior kind: bit for bit the masked body ------------
+
+_INTERIOR_BWD_CASES = [
+    # t, causal, window, key_lens, block_diffusion, backward blocks,
+    # a row's interior steps (the last row's)
+    (40, True, None, None, None, (16, 8), 6),     # a tail of padded queries
+    (48, True, 20, None, None, (8, 8), 5),        # a band with interior
+    (48, True, 5, None, None, (8, 8), 0),         # a band with none
+    (48, True, None, (48, 19), None, (8, 8), 9),  # stops at a row's length
+    (40, False, None, (40, 21), None, (8, 8), 10),
+    (1024, False, None, None, (512, 4), (256, 256), 2),   # diagonal steps
+    (96, False, None, None, (48, 3), (16, 16), 4),         # Bd 3
+]
+
+
+@pytest.mark.parametrize("t,causal,window,lens,block_diffusion,blocks,"
+                         "n_interior", _INTERIOR_BWD_CASES)
+def test_interior_backward_steps_are_the_masked_body_bit_for_bit(
+        np_rng, monkeypatch, t, causal, window, lens, block_diffusion,
+        blocks, n_interior):
+    """dq, dk and dv of the backward kernels with interior steps equal,
+    bit for bit, those of the same kernels with every computed step
+    masked (`_block_interior` forced false), on one fixed o, lse and
+    cotangent: an interior block's admitted pairs go through the same
+    float32 expression, and it has no refused pair."""
+    from paddle_tpu.ops import flash_attention as FA
+    from paddle_tpu.ops import pallas_util
+
+    bh, d = 2, 16
+    q, k, v, g = (jnp.asarray(np_rng.randn(bh, t, d), jnp.float32)
+                  for _ in range(4))
+    lens = jnp.asarray((t, t) if lens is None else lens, jnp.int32)
+    masks = dict(causal=causal, window=window,
+                 block_diffusion=block_diffusion)
+    o, lse = FA._flash_forward(q, k, v, lens, block_q=8, block_k=8, **masks)
+    nq, nk = -(-t // blocks[0]), -(-t // blocks[1])
+
+    def backward():
+        before = pallas_util.traced()
+        grads = FA._flash_backward(q, k, v, lens, o, lse, g,
+                                   block_q=blocks[0], block_k=blocks[1],
+                                   **masks)
+        noted = [key for key, n in pallas_util.traced().items()
+                 if n > before.get(key, 0) and "bwd_block_kinds" in key]
+        return grads, noted
+
+    kinds = FA._block_kinds(nq, nk, int(lens[-1]), block_q=blocks[0],
+                            block_k=blocks[1], **masks)
+    assert kinds[0] == n_interior
+    got, noted = backward()
+    assert len(noted) == 1 and noted[0].startswith(
+        "flash_attention.bwd_block_kinds=interior:"), noted
+    monkeypatch.setattr(FA, "_block_interior",
+                        lambda qi, j, n_keys, **_: j < 0)
+    want, noted = backward()
+    assert noted[0].startswith("flash_attention.bwd_block_kinds=interior:0,")
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
 
 
 def _padded(t, block):

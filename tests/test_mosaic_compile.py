@@ -139,6 +139,47 @@ def test_block_diffusion_flash_compiles_for_v5e(one_chip, length, bd, bh):
     assert (sub in noted) == ("diagonal" in kinds), noted
 
 
+@pytest.mark.parametrize("bh,t,d,kw,kinds", [
+    # starcoder2_3b_l4.train_seq4k
+    (48, 4096, 128, dict(causal=True), "interior:6,cut:4,skipped:6"),
+    # mellum2_12b_a2p5b_ep4.train_seq8k's band and trinity_mini's
+    (64, 8192, 128, dict(causal=True, window=1024),
+     "interior:0,cut:15,skipped:49"),
+    (64, 8192, 128, dict(causal=True, window=2048),
+     "interior:7,cut:14,skipped:43"),
+    # their full layers, and qwen3_next_80b_a3b_ep16's at head_dim 256
+    (64, 8192, 128, dict(causal=True), "interior:28,cut:8,skipped:28"),
+    (32, 8192, 256, dict(causal=True), "interior:28,cut:8,skipped:28"),
+    # sdar_30b_a3b_ep8.train_bd4_seq4k
+    (64, 8192, 128, dict(causal=False, block_diffusion=(4096, 4)),
+     "interior:12,cut:8,diagonal:4,skipped:40"),
+])
+def test_backward_kernels_compile_for_v5e_with_their_kinds(one_chip, bh, t,
+                                                           d, kw, kinds):
+    """Both backward kernels, each with its interior, cut (and diagonal)
+    bodies, at the cells' shapes on their 1024 x 1024 blocks."""
+    spec = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    x, rows = spec((bh, t, d)), spec((bh, t), jnp.float32)
+    kw = dict(dict(window=None), **kw)
+
+    def backward(q, k, v, lens, o, lse, g):
+        return FA._flash_backward(q, k, v, lens, o, lse, g,
+                                  block_q=FA.BWD_BLOCK_Q,
+                                  block_k=FA.BWD_BLOCK_K, **kw)
+
+    before = pallas_util.traced()
+    text = jax.jit(backward).lower(
+        x, x, x, spec((bh,), jnp.int32), x, rows, x).compile().as_text()
+    noted = [key for key, n in pallas_util.traced().items()
+             if n > before.get(key, 0)]
+    assert f"flash_attention.bwd_block_kinds={kinds}" in noted, noted
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    suffix = "_window" if kw["window"] is not None else ""
+    for name in ("flash_attention_bwd_dkv", "flash_attention_bwd_dq"):
+        assert f"%{name}{suffix}" in text, name
+
+
 @pytest.mark.parametrize("d,f", [
     (2048, 768),        # sdar_30b_a3b_ep8
     (2304, 896),        # mellum2_12b_a2p5b_ep4: K 2304 in two tiles of 1152
